@@ -10,10 +10,6 @@ HuggingFace-compatible generation API. See SURVEY.md at the repo root.
 
 __version__ = "0.1.0"
 
-from nxdi_tpu import jax_compat as _jax_compat
-
-_jax_compat.ensure()
-
 from nxdi_tpu.config import (  # noqa: F401
     InferenceConfig,
     OnDeviceSamplingConfig,

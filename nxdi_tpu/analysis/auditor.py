@@ -32,15 +32,29 @@ from nxdi_tpu.analysis.checkers import (
     Finding,
     ProgramArtifacts,
 )
-from nxdi_tpu.jax_compat import (
-    compiled_input_formats,
-    lowered_donated_flags,
-    lowered_kept_args,
-    optimized_hlo_text,
-    stablehlo_text,
-)
 
 logger = logging.getLogger("nxdi_tpu")
+
+
+def _kept_args(lowered):
+    """Flat indices of the args the lowering KEPT (unused args are pruned
+    from the HLO signature) — a private field of ``Lowered``, read in this
+    one place."""
+    return tuple(sorted(lowered._lowering.compile_args["kept_var_idx"]))
+
+
+def _donated_flags(lowered):
+    """Per-flat-arg donation flags from ``Lowered.args_info``."""
+    flat = jtu.tree_leaves(
+        lowered.args_info, is_leaf=lambda x: hasattr(x, "donated")
+    )
+    return tuple(bool(a.donated) for a in flat)
+
+
+def _cache_input_formats(compiled):
+    """The resolved AUTO layouts of an executable's cache input subtree
+    (arg 1 of ``(params, cache, batch)``)."""
+    return compiled.input_formats[0][1]
 
 
 def _key_str(key) -> str:
@@ -200,12 +214,8 @@ def audit_wrapper(
             example = wrapper._example_for_key(key)
             with jax.set_mesh(wrapper._mesh):
                 base_mod._STRATEGY_TRACE.clear()
-                traced = None
-                if hasattr(prog.jitted, "trace"):
-                    traced = prog.jitted.trace(ps, cs, example)
-                    lowered = traced.lower()
-                else:  # very old jax: no Traced stage
-                    lowered = prog.jitted.lower(ps, cs, example)
+                traced = prog.jitted.trace(ps, cs, example)
+                lowered = traced.lower()
                 strategies = tuple(base_mod._STRATEGY_TRACE) or tuple(
                     prog.attention_strategies
                 )
@@ -227,14 +237,14 @@ def audit_wrapper(
             label=label,
             config=config,
             arch=wrapper.arch,
-            jaxpr=traced.jaxpr if traced is not None else None,
-            stablehlo=stablehlo_text(lowered),
-            hlo=optimized_hlo_text(compiled),
+            jaxpr=traced.jaxpr,
+            stablehlo=lowered.as_text(),
+            hlo=compiled.as_text(),
             strategies=strategies,
             n_param_leaves=n_param_leaves,
             cache_paths=cache_paths,
-            kept_args=lowered_kept_args(lowered),
-            donated_flags=lowered_donated_flags(lowered),
+            kept_args=_kept_args(lowered),
+            donated_flags=_donated_flags(lowered),
             const_threshold=const_threshold,
             compiled=compiled,
             param_bytes=param_bytes,
@@ -261,23 +271,15 @@ def audit_wrapper(
         )[0]
         report.strategies = list(strategies)
         report.cache_inputs = len(cache_paths)
-        if art.stablehlo is not None:
-            report.donated_cache_inputs = min(
-                len(hlo_views.aliased_arg_positions(art.stablehlo)),
-                len(cache_paths),
-            )
-        if traced is not None:
-            report.largest_const_bytes = _max_const_bytes(traced.jaxpr)
-        try:
-            # the resolved AUTO cache layout of this executable's cache
-            # input subtree (arg 1 of (params, cache, batch)) — compared
-            # across programs by check_cache_format_agreement
-            fmt_tree = compiled_input_formats(compiled)[0][1]
-            report.cache_formats = tuple(
-                str(f) for f in jtu.tree_leaves(fmt_tree)
-            )
-        except Exception:
-            report.cache_formats = None
+        report.donated_cache_inputs = min(
+            len(hlo_views.aliased_arg_positions(art.stablehlo)),
+            len(cache_paths),
+        )
+        report.largest_const_bytes = _max_const_bytes(traced.jaxpr)
+        # compared across programs by check_cache_format_agreement
+        report.cache_formats = tuple(
+            str(f) for f in jtu.tree_leaves(_cache_input_formats(compiled))
+        )
     return reports
 
 
@@ -375,9 +377,7 @@ def collective_summary(app) -> Dict[str, Dict[str, int]]:
             compiled = getattr(prog, "_compiled", None)
             if compiled is None:
                 continue
-            text = optimized_hlo_text(compiled)
-            if text is None:
-                continue
+            text = compiled.as_text()
             counts = hlo_views.collective_counts(text)
             label = getattr(prog, "label", f"{tag}[{_key_str(key)}]")
             out[label] = {op: n for op, n in counts.items() if n}

@@ -705,13 +705,8 @@ def check_lora_sharding(art: ProgramArtifacts) -> List[Finding]:
     # adapter_ids routing: the batch input must be fully replicated. Scan
     # every positional arg for the entry rather than assuming its position —
     # a reordered aot_compile signature must degrade to "not found", never
-    # to auditing the wrong input. compiled_arg_shardings returns None on
-    # jax releases without the input_shardings view (spec checks above
-    # still ran).
-    from nxdi_tpu.jax_compat import compiled_arg_shardings
-
-    args = compiled_arg_shardings(art.compiled)
-    for arg in args if isinstance(args, (tuple, list)) else ():
+    # to auditing the wrong input.
+    for arg in art.compiled.input_shardings[0]:
         sh = arg.get("adapter_ids") if isinstance(arg, dict) else None
         if sh is not None and not getattr(sh, "is_fully_replicated", True):
             findings.append(art.finding(

@@ -67,6 +67,8 @@ class ChipSpec:
     bf16_tflops: float  # peak dense bf16 TFLOP/s
     hbm_gbs: float      # peak HBM bandwidth, GB/s (1e9)
     hbm_gib: float      # HBM capacity per chip, GiB (2**30)
+    # the ``device_kind`` strings an attached device of this part reports
+    device_kinds: Tuple[str, ...] = ()
 
     @property
     def flops_per_s(self) -> float:
@@ -89,23 +91,51 @@ class ChipSpec:
         }
 
 
-#: datasheet peaks per supported chip generation
+#: THE peaks table: per-chip bf16 TFLOP/s, HBM GB/s and HBM GiB, looked up by
+#: declared name (``TpuConfig(chip=...)``) or by the ``device_kind`` an
+#: attached device reports. Source of the peaks: Google Cloud TPU
+#: documentation, system-architecture pages "TPU v4" / "TPU v5e" / "TPU v5p" /
+#: "TPU v6e" (per-chip figures); the device_kind strings are the ones
+#: jax/_src/pallas/mosaic/tpu_info.py matches on.
 CHIP_SPECS: Dict[str, ChipSpec] = {
-    "v4": ChipSpec("v4", bf16_tflops=275.0, hbm_gbs=1228.0, hbm_gib=32.0),
-    "v5e": ChipSpec("v5e", bf16_tflops=197.0, hbm_gbs=819.0, hbm_gib=16.0),
-    "v5p": ChipSpec("v5p", bf16_tflops=459.0, hbm_gbs=2765.0, hbm_gib=95.0),
-    "v6e": ChipSpec("v6e", bf16_tflops=918.0, hbm_gbs=1640.0, hbm_gib=32.0),
+    "v4": ChipSpec("v4", bf16_tflops=275.0, hbm_gbs=1228.0, hbm_gib=32.0,
+                   device_kinds=("TPU v4",)),
+    "v5e": ChipSpec("v5e", bf16_tflops=197.0, hbm_gbs=819.0, hbm_gib=16.0,
+                    device_kinds=("TPU v5 lite", "TPU v5e")),
+    "v5p": ChipSpec("v5p", bf16_tflops=459.0, hbm_gbs=2765.0, hbm_gib=95.0,
+                    device_kinds=("TPU v5", "TPU v5p")),
+    "v6e": ChipSpec("v6e", bf16_tflops=918.0, hbm_gbs=1640.0, hbm_gib=32.0,
+                    device_kinds=("TPU v6 lite", "TPU v6e")),
 }
 
+#: the part an undeclared config is costed for when NO accelerator is
+#: attached (CPU analysis runs); an attached TPU always answers for itself
 DEFAULT_CHIP = "v5e"
 
 
-def resolve_chip(tpu_config=None, override=None) -> ChipSpec:
-    """ChipSpec from ``TpuConfig(chip=...)`` (a name or a dict of overrides
-    on top of v5e) or an explicit ``override`` of the same forms."""
-    spec = override if override is not None else getattr(tpu_config, "chip", None)
-    if spec is None:
-        return CHIP_SPECS[DEFAULT_CHIP]
+def attached_chip() -> Optional[ChipSpec]:
+    """The table row of the attached accelerator, or None on a non-TPU
+    backend. A TPU whose ``device_kind`` the table does not hold is an error,
+    never a default."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return None
+    for spec in CHIP_SPECS.values():
+        if dev.device_kind in spec.device_kinds:
+            return spec
+    known = sorted(k for s in CHIP_SPECS.values() for k in s.device_kinds)
+    raise ValueError(
+        f"no ChipSpec for device kind {dev.device_kind!r}; known: {known} — "
+        "add its peaks (with their source) to analysis/costs.py CHIP_SPECS"
+    )
+
+
+def declared_chip(spec, default_base: str = DEFAULT_CHIP) -> ChipSpec:
+    """ChipSpec from a declaration: a table name, a dict of field overrides
+    on top of ``base`` (default ``default_base``), or a ChipSpec. Pure — no
+    device is asked."""
     if isinstance(spec, ChipSpec):
         return spec
     if isinstance(spec, str):
@@ -116,19 +146,44 @@ def resolve_chip(tpu_config=None, override=None) -> ChipSpec:
             )
         return CHIP_SPECS[spec]
     if isinstance(spec, dict):
-        base_name = spec.get("base", DEFAULT_CHIP)
+        base_name = spec.get("base", default_base)
         if base_name not in CHIP_SPECS:
             raise ValueError(
                 f"unknown chip base {base_name!r}; known: {sorted(CHIP_SPECS)}"
             )
-        base = CHIP_SPECS[base_name].to_dict()
-        base["name"] = "custom"
-        base.update({k: v for k, v in spec.items() if k != "base"})
+        base = CHIP_SPECS[base_name]
+        fields = base.to_dict()
+        fields["name"] = "custom"
+        fields.update({k: v for k, v in spec.items() if k != "base"})
         try:
-            return ChipSpec(**base)
+            return ChipSpec(device_kinds=base.device_kinds, **fields)
         except TypeError as e:
             raise ValueError(f"bad chip spec fields {sorted(spec)}: {e}")
     raise TypeError(f"chip must be a name, dict, or ChipSpec; got {type(spec)}")
+
+
+def resolve_chip(tpu_config=None, override=None) -> ChipSpec:
+    """The ChipSpec rooflines divide through.
+
+    On a TPU backend the attached device answers (its ``device_kind`` looked
+    up in :data:`CHIP_SPECS`; an unknown kind raises), and a declaration —
+    ``TpuConfig(chip=...)`` or ``override``, a name or a dict of overrides —
+    that names a different part raises. With no accelerator attached the
+    declaration alone decides (the analysis CLIs cost a program for a named
+    part), defaulting to :data:`DEFAULT_CHIP`."""
+    spec = override if override is not None else getattr(tpu_config, "chip", None)
+    attached = attached_chip()
+    if spec is None:
+        return attached if attached is not None else CHIP_SPECS[DEFAULT_CHIP]
+    if attached is None:
+        return declared_chip(spec)
+    declared = declared_chip(spec, default_base=attached.name)
+    if declared.device_kinds != attached.device_kinds:
+        raise ValueError(
+            f"declared chip {declared.name!r} contradicts the attached "
+            f"device ({attached.device_kinds[0]!r} = {attached.name!r})"
+        )
+    return declared
 
 
 # ---------------------------------------------------------------------------

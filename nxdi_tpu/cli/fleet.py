@@ -42,10 +42,12 @@ import sys
 import time
 from typing import List, Optional
 
+from nxdi_tpu.cli import add_on_cpu_flag, use_cpu_backend
 from nxdi_tpu.telemetry.fleet import UNREACHABLE, FleetMonitor
 
 
 def setup_fleet_parser(p: argparse.ArgumentParser) -> None:
+    add_on_cpu_flag(p)
     p.add_argument("targets", nargs="*",
                    help="replica base URLs (http://host:port), optionally "
                         "named as name=url")
@@ -336,12 +338,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         print_autoscale_log(payload)
         return 0
     if args.demo:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-        from nxdi_tpu.jax_compat import set_num_cpu_devices
-
-        set_num_cpu_devices(8)
+        if args.on_cpu:
+            use_cpu_backend()
         demo_targets, servers = build_demo_fleet(
             args.demo, args.demo_requests, args.quiet
         )

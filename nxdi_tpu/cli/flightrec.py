@@ -3,8 +3,8 @@ manual surface.
 
 Two modes:
 
-- **demo / manual dump** (default): drive the tiny llama CPU-mesh reference
-  app (the same one ``cli.serve`` uses) through a Poisson serving workload
+- **demo / manual dump** (default): drive the tiny llama reference
+  app (the same one ``cli.serve`` uses; ``--on-cpu`` for the CPU backend) through a Poisson serving workload
   with the flight recorder on, print the per-step engine timeline (wall /
   dispatch / host split, admissions, decode rows, preemptions,
   retirements, KV headroom), and optionally write a manual postmortem
@@ -35,10 +35,13 @@ import json
 import sys
 from typing import List, Optional
 
+from nxdi_tpu.cli import add_on_cpu_flag, use_cpu_backend
+
 import numpy as np
 
 
 def setup_flightrec_parser(p: argparse.ArgumentParser) -> None:
+    add_on_cpu_flag(p)
     p.add_argument("--requests", type=int, default=8,
                    help="Poisson workload size (default 8)")
     p.add_argument("--rate", type=float, default=30.0,
@@ -220,14 +223,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     if args.inspect is not None:
         return inspect_bundle(args.inspect)
 
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    from nxdi_tpu.config import OnDeviceSamplingConfig
-    from nxdi_tpu.jax_compat import set_num_cpu_devices
-
-    set_num_cpu_devices(8)
+    if args.on_cpu:
+        use_cpu_backend()
     from nxdi_tpu.cli.metrics import build_loaded_reference_app
+    from nxdi_tpu.config import OnDeviceSamplingConfig
 
     tpu_kwargs = dict(
         tp_degree=1,

@@ -33,6 +33,8 @@ import json
 import sys
 from typing import List, Optional
 
+from nxdi_tpu.cli import use_cpu_backend
+
 
 def setup_lint_parser(p: argparse.ArgumentParser) -> None:
     p.add_argument("--model-type", default=None, help="registry key, e.g. llama")
@@ -201,12 +203,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         return 2
 
     if args.reference_app or args.on_cpu:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-        from nxdi_tpu.jax_compat import set_num_cpu_devices
-
-        set_num_cpu_devices(max(8, args.tp_degree))
+        use_cpu_backend(max(8, args.tp_degree))
 
     from nxdi_tpu.analysis import CHECKERS, audit_application
 

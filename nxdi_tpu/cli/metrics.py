@@ -1,8 +1,8 @@
 """``python -m nxdi_tpu.cli.metrics`` — the serving-telemetry export surface.
 
-Builds the tiny llama CPU-mesh reference app (the same one
-``nxdi_tpu.cli.lint`` audits, here with random weights so it can actually
-generate), drives a short burst of demo traffic through the paged-KV serving
+Builds the tiny llama reference app (the same one ``nxdi_tpu.cli.lint``
+audits, here with random weights so it can actually generate; on the attached
+backend, ``--on-cpu`` for the CPU), drives a short burst of demo traffic through the paged-KV serving
 path (block manager + request spans + generation dispatches), and emits the
 telemetry three ways:
 
@@ -41,10 +41,13 @@ import sys
 import time
 from typing import List, Optional
 
+from nxdi_tpu.cli import add_on_cpu_flag, use_cpu_backend
+
 import numpy as np
 
 
 def setup_metrics_parser(p: argparse.ArgumentParser) -> None:
+    add_on_cpu_flag(p)
     p.add_argument("--format", choices=["prom", "json", "both"], default="both",
                    help="what to print to stdout (default: both)")
     p.add_argument("--json", dest="json_path", default=None,
@@ -164,12 +167,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     setup_metrics_parser(parser)
     args = parser.parse_args(argv)
 
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    from nxdi_tpu.jax_compat import set_num_cpu_devices
-
-    set_num_cpu_devices(8)
+    if args.on_cpu:
+        use_cpu_backend()
 
     tpu_kwargs = dict(
         tp_degree=1,

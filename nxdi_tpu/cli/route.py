@@ -37,10 +37,12 @@ import sys
 import time
 from typing import List, Optional
 
+from nxdi_tpu.cli import add_on_cpu_flag, use_cpu_backend
 from nxdi_tpu.runtime.faults import jittered_backoff
 
 
 def setup_route_parser(p: argparse.ArgumentParser) -> None:
+    add_on_cpu_flag(p)
     p.add_argument("targets", nargs="*",
                    help="replica targets: name,metrics_url,ingest_url")
     p.add_argument("--demo", type=int, default=0, metavar="N",
@@ -253,12 +255,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     ingests, servers = [], []
     targets = list(args.targets)
     if args.demo:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-        from nxdi_tpu.jax_compat import set_num_cpu_devices
-
-        set_num_cpu_devices(8)
+        if args.on_cpu:
+            use_cpu_backend()
         demo_targets, ingests, servers = build_demo_replicas(
             args.demo, args.quiet, step_delay_s=args.step_delay
         )

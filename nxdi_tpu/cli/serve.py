@@ -1,7 +1,8 @@
 """``python -m nxdi_tpu.cli.serve`` — continuous-batching engine demo.
 
-Drives the tiny llama CPU-mesh reference app (the same one ``cli.lint``
-audits and ``cli.metrics`` exports) through the serving engine
+Drives the tiny llama reference app (the same one ``cli.lint`` audits and
+``cli.metrics`` exports; on the attached backend, ``--on-cpu`` for the CPU)
+through the serving engine
 (``nxdi_tpu/serving``) under a **Poisson arrival** workload: requests
 arrive at ``--rate`` req/s (seeded exponential interarrivals), stream
 their tokens through per-request callbacks, and ride the slot scheduler —
@@ -31,10 +32,13 @@ import sys
 import time
 from typing import List, Optional
 
+from nxdi_tpu.cli import add_on_cpu_flag, use_cpu_backend
+
 import numpy as np
 
 
 def setup_serve_parser(p: argparse.ArgumentParser) -> None:
+    add_on_cpu_flag(p)
     p.add_argument("--requests", type=int, default=8,
                    help="Poisson workload size (default 8)")
     p.add_argument("--rate", type=float, default=30.0,
@@ -258,14 +262,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     setup_serve_parser(parser)
     args = parser.parse_args(argv)
 
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    from nxdi_tpu.config import OnDeviceSamplingConfig
-    from nxdi_tpu.jax_compat import set_num_cpu_devices
-
-    set_num_cpu_devices(8)
+    if args.on_cpu:
+        use_cpu_backend()
     from nxdi_tpu.cli.metrics import build_loaded_reference_app
+    from nxdi_tpu.config import OnDeviceSamplingConfig
 
     tpu_kwargs = dict(
         tp_degree=1,

@@ -1069,7 +1069,6 @@ class TpuConfig:
         self.logical_nc_config = kwargs.pop("logical_nc_config", 1)
         self.skip_warmup = kwargs.pop("skip_warmup", False)
         self.save_sharded_checkpoint = kwargs.pop("save_sharded_checkpoint", False)
-        self.compilation_cache_dir = kwargs.pop("compilation_cache_dir", None)
         tcc = kwargs.pop("tensor_capture_config", None)
         if isinstance(tcc, dict):
             tcc = TensorCaptureConfig(**tcc)
@@ -1132,7 +1131,9 @@ class TpuConfig:
         # declared chip generation for the cost observatory's roofline math
         # and the hbm_fit auditor checker (analysis/costs.py): a name from
         # CHIP_SPECS ("v4"|"v5e"|"v5p"|"v6e"), or a dict of ChipSpec field
-        # overrides (optionally with "base": name). None = v5e.
+        # overrides (optionally with "base": name). None = the attached TPU,
+        # v5e where none is attached; a declaration that names another part
+        # than the attached one raises when the chip is resolved.
         self.chip = kwargs.pop("chip", None)
         # serve-time retrace guard (analysis/retrace.py): "warn" logs and
         # "error" raises when any submodel program lowers AFTER warmup sealed
@@ -1163,10 +1164,10 @@ class TpuConfig:
                 )
             # resolve eagerly so a typo'd name/field fails HERE, not inside a
             # swallowed export attachment or an auditor checker at serve time
-            from nxdi_tpu.analysis.costs import resolve_chip
+            from nxdi_tpu.analysis.costs import declared_chip
 
             try:
-                resolve_chip(override=self.chip)
+                declared_chip(self.chip)
             except (TypeError, ValueError) as e:
                 raise ValueError(f"invalid TpuConfig chip={self.chip!r}: {e}")
         if self.max_context_length > self.seq_len:

@@ -39,11 +39,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from nxdi_tpu.ops.kernels import mode
+
 NEG_INF = -30000.0
-
-
-def _interpret() -> bool:
-    return jax.devices()[0].platform != "tpu"
 
 
 # single source of truth for the prefill block defaults (cte_probe and the
@@ -64,7 +62,7 @@ def prefill_kernel_supported(q_shape, k_shape) -> bool:
     KV, Sk = k_shape[1], k_shape[2]
     if H % KV:
         return False
-    if _interpret():
+    if mode.interpret():
         return True
     # Mosaic pads the lane (head_dim) axis internally — D=64/96 (llama 1B/3B,
     # qwen2, phi) verified bit-compatible on v5e hardware; only the sequence
@@ -77,7 +75,7 @@ def decode_kernel_supported(q_shape, k_shape) -> bool:
     KV, Sk = k_shape[1], k_shape[2]
     if H % KV or Sq != 1:
         return False
-    if _interpret():
+    if mode.interpret():
         return True
     return D % 8 == 0 and Sk % 128 == 0
 
@@ -235,7 +233,7 @@ def flash_attention_prefill(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
-        interpret=_interpret(),
+        interpret=mode.interpret(),
     )(q_start, kv_start, qf, kf, vf)
     return out.reshape(B, H, Sq, D)
 
@@ -338,7 +336,7 @@ def flash_attention_decode(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, G, D), q.dtype),
-        interpret=_interpret(),
+        interpret=mode.interpret(),
     )(q_start, kv_start, qf, kf, vf)
     return out.reshape(B, KV, G, D).reshape(B, H, 1, D).astype(q.dtype)
 
@@ -510,7 +508,7 @@ def flash_attention_decode_fused_stacked(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, G, D), q.dtype),
-        interpret=_interpret(),
+        interpret=mode.interpret(),
     )(li, q_start, kv_start, qf, kf, vf, knf, vnf)
     return out.reshape(B, KV, G, D).reshape(B, H, 1, D).astype(q.dtype)
 
@@ -630,7 +628,7 @@ def flash_attention_decode_fused(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, G, D), q.dtype),
-        interpret=_interpret(),
+        interpret=mode.interpret(),
     )(q_start, kv_start, qf, kf, vf, knf, vnf)
     return out.reshape(B, KV, G, D).reshape(B, H, 1, D).astype(q.dtype)
 
@@ -681,7 +679,7 @@ def paged_decode_kernel_supported(q_shape, cache_shape, block_size) -> bool:
     total_slots, KV = cache_shape[0], cache_shape[1]
     if H % KV or Sq != 1 or total_slots % block_size:
         return False
-    if _interpret():
+    if mode.interpret():
         return True
     # the cache block is (block_size, KV, D): Mosaic needs the last two dims
     # (KV, D) full (they are) and the head count small enough that the
@@ -803,7 +801,7 @@ def paged_attention_decode(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
-        interpret=_interpret(),
+        interpret=mode.interpret(),
     )(bt, qp, qf, k_cache, v_cache)
     return out.reshape(B, H, 1, D)
 
@@ -814,7 +812,7 @@ def paged_prefill_kernel_supported(q_shape, cache_shape, block_size) -> bool:
     G = H // KV if H % KV == 0 else 0
     if not G or total_slots % block_size:
         return False
-    if _interpret():
+    if mode.interpret():
         return True
     return D % 8 == 0 and block_size % 128 == 0 and Sq % 8 == 0 and KV <= 16
 
@@ -947,7 +945,7 @@ def paged_attention_prefill(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, Sq, D), q.dtype),
-        interpret=_interpret(),
+        interpret=mode.interpret(),
     )(bt, qs, qf, k_cache, v_cache)
     return out.reshape(B, H, Sq, D)
 

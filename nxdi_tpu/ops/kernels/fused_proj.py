@@ -37,11 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from nxdi_tpu.ops.kernels import mode
 from nxdi_tpu.parallel.mesh import AXIS_MP
-
-
-def _interpret() -> bool:
-    return jax.devices()[0].platform != "tpu"
 
 
 def _pick_block(s: int, target: int) -> int:
@@ -51,14 +48,20 @@ def _pick_block(s: int, target: int) -> int:
     return max(b, 1)
 
 
-def _mlp_block_i(i_dim: int, h: int, target: int) -> int:
-    """Intermediate-dim tile clamped to the VMEM budget: each grid step
+def _mlp_blocks(m: int, i_dim: int, h: int, target_m: int, target_i: int):
+    """(bm, bi) tiles clamped to the 16 MiB scoped VMEM. Each grid step
     streams gate+up (H, bi) and down (bi, H) double-buffered — 12*H*bi bytes
-    in flight (bf16). Keep that under ~10 MB of the ~16 MB scoped VMEM."""
-    bi = _pick_block(i_dim, target)
+    in flight (bf16), kept under ~10 MB — beside the row side: x and out
+    (bm, H) double-buffered plus the f32 accumulator, 12*bm*H bytes. The
+    v5e compiler refused H=4096 at bm=256 (18 MiB of 16) until the row tile
+    counted too; the two together stay under 14 MiB."""
+    bi = _pick_block(i_dim, target_i)
     while bi > 128 and 12 * h * bi > 10 * 1024 * 1024:
         bi //= 2
-    return bi
+    bm = _pick_block(m, target_m)
+    while bm > 8 and bm % 2 == 0 and 12 * h * (bi + bm) > 14 * 1024 * 1024:
+        bm //= 2
+    return bm, bi
 
 
 _KERNEL_ACTS = ("silu", "gelu", "gelu_pytorch_tanh", "gelu_new", "relu")
@@ -85,7 +88,7 @@ def fused_mlp_supported(m: int, h: int, i_local: int, act: str) -> bool:
     """Static eligibility for the LOCAL (per-rank) problem shape."""
     if act not in _KERNEL_ACTS:
         return False
-    if _interpret():
+    if mode.interpret():
         return True
     # Mosaic wants lane-aligned minor dims; H rides VMEM whole per block
     return h % 128 == 0 and i_local % 128 == 0
@@ -121,8 +124,7 @@ def fused_mlp(
 ) -> jax.Array:
     M, H = x.shape
     I = gate_w.shape[1]
-    bm = _pick_block(M, block_m)
-    bi = _mlp_block_i(I, H, block_i)
+    bm, bi = _mlp_blocks(M, I, H, block_m, block_i)
     n_m, n_i = M // bm, I // bi
     kernel = functools.partial(_fused_mlp_kernel, act=act, n_i=n_i)
     return pl.pallas_call(
@@ -137,7 +139,7 @@ def fused_mlp(
         out_specs=pl.BlockSpec((bm, H), lambda m, i: (m, 0)),
         out_shape=jax.ShapeDtypeStruct((M, H), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, H), jnp.float32)],
-        interpret=_interpret(),
+        interpret=mode.interpret(),
     )(x, gate_w, up_w, down_w)
 
 
@@ -227,8 +229,7 @@ def fused_mlp_stacked(
 ) -> jax.Array:
     M, H = x.shape
     I = gate_s.shape[2]
-    bm = _pick_block(M, block_m)
-    bi = _mlp_block_i(I, H, block_i)
+    bm, bi = _mlp_blocks(M, I, H, block_m, block_i)
     n_m, n_i = M // bm, I // bi
     kernel = functools.partial(_fused_mlp_stacked_kernel, act=act, n_i=n_i)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -247,7 +248,7 @@ def fused_mlp_stacked(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, H), x.dtype),
-        interpret=_interpret(),
+        interpret=mode.interpret(),
     )(layer_idx.astype(jnp.int32), x, gate_s, up_s, down_s)
 
 
@@ -328,7 +329,7 @@ def qkv_matmul_stacked(
         _qkv_stacked_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, T), x.dtype),
-        interpret=_interpret(),
+        interpret=mode.interpret(),
     )(layer_idx.astype(jnp.int32), x, w_s)
     if b_s is not None:
         out = out + jnp.take(
@@ -378,7 +379,7 @@ def sharded_qkv_stacked_call(
 
 
 def qkv_matmul_supported(m: int, h_in: int, t_local: int) -> bool:
-    if _interpret():
+    if mode.interpret():
         return True
     return h_in % 128 == 0 and t_local % 128 == 0
 
@@ -411,7 +412,7 @@ def qkv_matmul(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda m, n: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, T), x.dtype),
-        interpret=_interpret(),
+        interpret=mode.interpret(),
     )(x, w)
     if b is not None:
         out = out + b.astype(out.dtype)

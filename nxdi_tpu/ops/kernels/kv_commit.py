@@ -58,11 +58,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from nxdi_tpu.ops.kernels import mode
+
 _WIN = 128  # lane-aligned slot window per write (S is the minor dim)
-
-
-def _interpret() -> bool:
-    return jax.devices()[0].platform != "tpu"
 
 
 def commit_rows_supported(k_cache_shape, v_cache_shape, k_rows_shape, v_rows_shape) -> bool:
@@ -82,7 +80,7 @@ def commit_rows_supported(k_cache_shape, v_cache_shape, k_rows_shape, v_rows_sha
         return False
     if k_rows_shape[3] != 1:
         return False
-    if _interpret():
+    if mode.interpret():
         return True
     return S % _WIN == 0 and D % 8 == 0 and Dv % 8 == 0
 
@@ -176,7 +174,7 @@ def kv_commit_rows(
         ],
         # inputs are (slots, lines, k_rows, v_rows, k_cache, v_cache)
         input_output_aliases={4: 0, 5: 1},
-        interpret=_interpret(),
+        interpret=mode.interpret(),
     )(slots, lines, kr_t, vr_t, k_t, v_t)
     return jnp.swapaxes(out_k, 3, 4), jnp.swapaxes(out_v, 3, 4)
 

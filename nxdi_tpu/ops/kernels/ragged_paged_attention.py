@@ -40,9 +40,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from nxdi_tpu.ops.kernels import mode
 from nxdi_tpu.ops.kernels.flash_attention import (
     NEG_INF,
-    _interpret,
     _online_softmax_step,
     _pick_block,
 )
@@ -55,7 +55,7 @@ def ragged_paged_kernel_supported(q_shape, cache_shape, block_size) -> bool:
     total_slots, KV = cache_shape[0], cache_shape[1]
     if B != 1 or H % KV or total_slots % block_size:
         return False
-    if _interpret():
+    if mode.interpret():
         return True
     return D % 8 == 0 and block_size % 128 == 0 and T % 8 == 0 and KV <= 16
 
@@ -200,7 +200,7 @@ def ragged_paged_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, KV, G, T, D), q.dtype),
-        interpret=_interpret(),
+        interpret=mode.interpret(),
     )(bt, tile_min, tile_max, rid[:, None], qp[:, None], qf, k_cache, v_cache)
     return out.reshape(1, H, T, D)
 

@@ -7,10 +7,11 @@ models/model_base.py:3078 CausalLM submodel construction and :3367 dispatch).
 Artifact model: the reference serializes traced NEFFs into
 ``--compiled-model-path``. Here the artifact directory holds
   - ``tpu_config.json``   — the InferenceConfig round trip (config.py),
-  - ``cache/``            — JAX persistent compilation cache entries, written
-                            by AOT ``lower().compile()`` of every bucket
-                            program (so a later ``load()`` never recompiles),
   - ``weights/``          — optional presharded safetensors.
+The compiled programs themselves live in JAX's persistent compilation cache,
+written by AOT ``lower().compile()`` of every bucket program so a later
+``load()`` never recompiles; its directory is decided by
+:func:`enable_persistent_cache`, not by the artifact path.
 """
 
 from __future__ import annotations
@@ -96,11 +97,39 @@ def maybe_quantize_struct(struct, tc):
     )
 
 
-def enable_persistent_cache(path: str) -> None:
+#: the compile cache's place when the environment names none: one fixed path
+#: inside the checkout (listed in .gitignore). The path is part of how an
+#: entry is found again, so it never holds a temp name, a pid or a time.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    THE one place that decides the directory: ``JAX_COMPILATION_CACHE_DIR``
+    when the environment sets it (JAX reads that variable itself, so no
+    directory is set in code), else :data:`DEFAULT_COMPILE_CACHE_DIR`. Call
+    before the first compilation of the process — JAX decides once whether
+    the cache is in use."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = DEFAULT_COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
     os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # An entry's key must not depend on the Python call path that lowered the
+    # program. JAX strips locations from the module it hashes, but a Mosaic
+    # kernel rides in it as serialized bytes WITH its locations, and by
+    # default those hold the traceback of the pallas_call: the same TKG
+    # program lowered from compile() and from load() got two keys on the
+    # v5e, so a load never found what compile had written. One frame
+    # (file:line of the op) is the same from every caller.
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    return path
 
 
 class ApplicationBase:
@@ -311,11 +340,12 @@ class ApplicationBase:
     # ------------------------------------------------------------------
     def compile(self, compiled_model_path: str) -> None:
         """AOT-compile every (submodel, bucket) program into the persistent
-        cache at ``compiled_model_path`` (reference: application_base.py:292)."""
+        compilation cache and save the config to ``compiled_model_path``
+        (reference: application_base.py:292)."""
         t0 = time.time()
         os.makedirs(compiled_model_path, exist_ok=True)
         self.config.save(compiled_model_path)
-        enable_persistent_cache(os.path.join(compiled_model_path, "cache"))
+        enable_persistent_cache()
         self._build_wrappers()
         params_struct = self.build_params_struct()
         cache_struct = self._cache_struct()
@@ -396,7 +426,7 @@ class ApplicationBase:
         """Weights to HBM (sharded), KV cache allocated, programs built, warmup
         (reference: application_base.py:317-372)."""
         if compiled_model_path is not None:
-            enable_persistent_cache(os.path.join(compiled_model_path, "cache"))
+            enable_persistent_cache()
         self.mesh = mesh_from_config(self.tpu_config)
         self._build_wrappers()
 

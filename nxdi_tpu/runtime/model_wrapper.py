@@ -16,8 +16,10 @@ subsumes most of the reference's async_execution machinery.
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import logging
-
+import secrets
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
@@ -94,16 +96,77 @@ def kv_layout_from_config(tc, arch=None):
         return ContiguousKVLayout(route_by_seq_id=True, **scales)
     return ContiguousKVLayout(**scales)
 
+# --- around a fault of the runtime's persistent compilation cache -----------
+# jax 0.9.0 / libtpu 0.0.34 (seen on `TPU v5 lite`, PR 22): an executable
+# SERVED FROM THE PERSISTENT CACHE in a later process reports — and labels the
+# arrays it returns with — the DEFAULT memory layout, whatever layout it was
+# compiled for and really reads and writes (scripts/relayout_cache_probe.py
+# shows it with a three-line program). Where the compiler's AUTO choice for
+# the KV cache IS the default (the contiguous D=64 cache) nothing goes wrong
+# and such programs are cached like any other. The paged pool's is not (the
+# compiler wants D minor, the default puts the slot dim there), and a served
+# paged program fails its first hand-over. So two kinds of program stay out
+# of that cache — compiled under a name no entry has (the module name is part
+# of the key), in a window in which nothing is written: the layout-changing
+# identity below, and every step program over the block KV layout
+# (_AutoLayoutProgram(persist=False)); they cost their compile in each process.
+_PROCESS_TOKEN = secrets.token_hex(6)
+_UNCACHED_NAMES = itertools.count()
+
+
+@contextlib.contextmanager
+def _outside_the_persistent_cache():
+    """Yields a program name no persistent-cache entry has or will have;
+    what compiles inside the block is not written to the cache either."""
+    was = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1e9)
+    try:
+        yield f"{_PROCESS_TOKEN}_{next(_UNCACHED_NAMES)}"
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", was)
+
+
+def _named(fn, name: str):
+    def call(*args):
+        return fn(*args)
+
+    call.__name__ = name
+    return call
+
+
+# (from format, to format, shape, dtype) -> compiled layout-changing identity
+_RELAYOUTS: Dict[Any, Callable] = {}
+
+
+def _relayout(a, fmt):
+    """``a`` in the memory layout ``fmt`` — ``jax.device_put(a, fmt)`` through
+    an identity program that never touches the persistent cache."""
+    key = (a.format, fmt, a.shape, a.dtype)
+    move = _RELAYOUTS.get(key)
+    if move is None:
+        with _outside_the_persistent_cache() as name:
+            move = _RELAYOUTS[key] = (
+                jax.jit(_named(lambda x: x, f"relayout_{name}"), out_shardings=fmt)
+                .lower(a)
+                .compile()
+            )
+    return move(a)
+
+
 class _AutoLayoutProgram:
     """Bucket program compiled with AUTO cache layouts (see _make_program):
-    lazily lowered on the first concrete call; the cache pytree is
-    ``device_put`` into the executable's preferred input formats when (and
+    lazily lowered on the first concrete call; the cache pytree is moved
+    (``_relayout``) into the executable's preferred input formats when (and
     only when) its current layout differs — one relayout at a program
     transition (e.g. prefill -> decode), zero in the steady-state chain."""
 
-    def __init__(self, jitted, label: str = "?", required_strategies=(),
-                 retrace_guard=None):
-        self.jitted = jitted
+    def __init__(self, fn, jit_kwargs, label: str = "?", required_strategies=(),
+                 retrace_guard=None, persist: bool = True):
+        self._fn, self._jit_kwargs = fn, jit_kwargs
+        self.jitted = jax.jit(fn, **jit_kwargs)
+        # False: compile outside the persistent compilation cache (see
+        # _outside_the_persistent_cache) — a served executable would be wrong
+        self.persist = persist
         self.label = label
         self._compiled = None
         self._cache_formats = None
@@ -120,9 +183,9 @@ class _AutoLayoutProgram:
         self.retrace_guard = retrace_guard
 
     def _lower(self, *args):
-        """The ONE lowering path — AOT artifact (`lower`) and lazy first-call
-        (`__call__`) both come through here, so required-strategy verification
-        and retrace-guard recording provably run on both."""
+        """The ONE lowering path — AOT artifact (`compile`) and lazy
+        first-call (`__call__`) both come through here, so required-strategy
+        verification and retrace-guard recording provably run on both."""
         from nxdi_tpu.models import base as base_mod
 
         if self.retrace_guard is not None:
@@ -131,9 +194,6 @@ class _AutoLayoutProgram:
         lowered = self.jitted.lower(*args)
         self._snap_strategies(base_mod)
         return lowered
-
-    def lower(self, *args):  # AOT artifact path
-        return self._lower(*args)
 
     def _snap_strategies(self, base_mod):
         if not base_mod._STRATEGY_TRACE:
@@ -156,6 +216,16 @@ class _AutoLayoutProgram:
         ):
             raise RuntimeError(required_strategy_error(self.label, flag, names))
 
+    def compile(self, *args):
+        """Lower and compile for abstract ``args`` — through the persistent
+        compilation cache, or around it where a served executable would be
+        wrong (``persist=False``)."""
+        if self.persist:
+            return self._lower(*args).compile()
+        with _outside_the_persistent_cache() as name:
+            self.jitted = jax.jit(_named(self._fn, name), **self._jit_kwargs)
+            return self._lower(*args).compile()
+
     def __call__(self, params, cache, batch):
         if self._compiled is None:
             # AUTO layouts resolve at compile time, so lowering must see
@@ -165,17 +235,12 @@ class _AutoLayoutProgram:
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
                 (params, cache, batch),
             )
-            lowered = self._lower(*absargs)
-            self._compiled = lowered.compile()
-            from nxdi_tpu.jax_compat import compiled_input_formats
-
-            self._cache_formats = compiled_input_formats(self._compiled)[0][1]
-        from nxdi_tpu.jax_compat import array_format
-
+            self._compiled = self.compile(*absargs)
+            self._cache_formats = self._compiled.input_formats[0][1]
         flat, treedef = jax.tree_util.tree_flatten(cache)
         fmts = jax.tree_util.tree_leaves(self._cache_formats)
         moved = [
-            a if array_format(a) == f else jax.device_put(a, f)
+            a if a.format == f else _relayout(a, f)
             for a, f in zip(flat, fmts)
         ]
         cache = jax.tree_util.tree_unflatten(treedef, moved)
@@ -384,17 +449,17 @@ class ModelWrapper:
         auto = jax.tree_util.tree_map(
             lambda sh: Format(Layout.AUTO, sh), cache_shardings
         )
-        jitted = jax.jit(
-            fn,
-            in_shardings=(None, auto, batch_shardings),
-            out_shardings=(None, auto),
-            donate_argnums=(1,),
-        )
         return _AutoLayoutProgram(
-            jitted,
+            fn,
+            dict(
+                in_shardings=(None, auto, batch_shardings),
+                out_shardings=(None, auto),
+                donate_argnums=(1,),
+            ),
             label=f"{self.tag}[{bucket}]",
             required_strategies=self._required_strategies(),
             retrace_guard=self.retrace_guard,
+            persist=not isinstance(self.layout, BlockKVLayout),
         )
 
     def _required_strategies(self):
@@ -482,10 +547,9 @@ class ModelWrapper:
         # persistent-cache entries would never match the serve-time programs
         with jax.set_mesh(self._mesh):
             for key, prog in self._programs.items():
-                lowered = prog.lower(
+                compiled[key] = prog.compile(
                     params_struct, cache_struct, self._example_for_key(key)
                 )
-                compiled[key] = lowered.compile()
         return compiled
 
     def _example_for_key(self, key):

@@ -1,16 +1,15 @@
 #!/usr/bin/env python
-"""Bench regression gate: a fresh bench.py JSON vs the BENCH_r*.json
-trajectory, with per-metric tolerances. The documented tier-2 step after a
-bench run:
+"""Bench regression gate: a fresh bench.py JSON vs an earlier record, with
+per-metric tolerances. The documented tier-2 step after a bench run:
 
     python bench.py > /tmp/bench_fresh.json
-    python scripts/bench_gate.py /tmp/bench_fresh.json
+    python scripts/bench_gate.py /tmp/bench_fresh.json --baseline EARLIER.json
 
 Baseline resolution: ``--baseline FILE`` or the newest ``BENCH_r*.json``
-(lexicographically last round) in the repo root. Metrics missing or null on
-EITHER side are skipped with a note — the bench folds in cached side files
-(BENCH_8B/BS1/MULTISTEP) that not every run refreshes, and older rounds
-predate the CostSheet fields.
+(lexicographically last round) in the repo root, where a trajectory is kept
+there (the repo carries none today). Metrics missing or null on EITHER side
+are skipped with a note — bench modes print different fields, and older
+records predate the CostSheet fields.
 
 Exit status: 0 = no metric regressed beyond its tolerance, 1 = regression,
 2 = usage error. Improvements and within-tolerance noise both pass (the

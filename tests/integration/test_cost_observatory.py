@@ -214,3 +214,96 @@ def test_bench_sheet_selection_contract(loaded_app):
     assert 0 < tkg.hbm_bw_pct(measured_s) < 100
     assert tkg.gap_ratio(measured_s) > 1
     assert cte.mfu_pct(measured_s) > 0
+
+
+# ---------------------------------------------------------------------------
+# nothing hides the device: the chip is the attached one, the compile cache
+# is where the environment says
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize(
+    "kind,declared,want",
+    [
+        ("TPU v5 lite", None, "v5e"),
+        ("TPU v5e", None, "v5e"),
+        ("TPU v6 lite", None, "v6e"),
+        ("TPU v5 lite", "v5e", "v5e"),
+        ("TPU v5 lite", {"hbm_gib": 8.0}, "custom"),  # overrides ride the attached part
+        ("TPU v5 lite", "v5p", "contradicts the attached"),
+        ("TPU v5 lite", {"base": "v4"}, "contradicts the attached"),
+        ("TPU v9 mega", None, "no ChipSpec for device kind"),
+        ("TPU v9 mega", "v5e", "no ChipSpec for device kind"),
+    ],
+)
+def test_resolve_chip_on_a_tpu_backend(monkeypatch, kind, declared, want):
+    """On a TPU backend the attached device answers through the one table:
+    an unknown kind raises, a contradicting declaration raises."""
+    import types
+
+    import jax
+
+    from nxdi_tpu.analysis.costs import CHIP_SPECS, resolve_chip
+
+    fake = types.SimpleNamespace(platform="tpu", device_kind=kind)
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [fake])
+    if want in CHIP_SPECS or want == "custom":
+        chip = resolve_chip(None, override=declared)
+        assert chip.name == want
+        assert kind in chip.device_kinds
+        if want == "custom":
+            assert chip.hbm_gib == 8.0
+            assert chip.bf16_tflops == CHIP_SPECS["v5e"].bf16_tflops
+    else:
+        with pytest.raises(ValueError, match=want):
+            resolve_chip(None, override=declared)
+
+
+@pytest.mark.parametrize("env_dir", [None, "named-from-outside"])
+def test_compile_cache_directory_rule(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR set -> that directory, and no code sets
+    another; unset -> ONE fixed path inside the checkout."""
+    import os
+
+    import jax
+
+    from nxdi_tpu.runtime import application
+
+    updates, made = {}, []
+    monkeypatch.setattr(jax.config, "update", lambda k, v: updates.__setitem__(k, v))
+    monkeypatch.setattr(application.os, "makedirs", lambda p, **kw: made.append(p))
+    repo = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(repo, ".jax_cache")
+        assert application.enable_persistent_cache() == want
+        assert updates["jax_compilation_cache_dir"] == want
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+        assert application.enable_persistent_cache() == want
+        assert "jax_compilation_cache_dir" not in updates
+    assert made == [want]
+    # keys must not depend on who lowered the program (Mosaic kernel bodies
+    # carry their locations; see test_chip_compile.py)
+    assert updates["jax_include_full_tracebacks_in_locations"] is False
+    # same answer the next time: no temp name, pid or time in the path
+    assert application.enable_persistent_cache() == want
+
+
+def test_one_site_sets_the_compile_cache_directory():
+    import os
+    import re
+
+    repo = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
+    sites = []
+    for root in ("nxdi_tpu", "scripts", "bench.py", "chip_smoke.py"):
+        top = os.path.join(repo, root)
+        files = [top] if os.path.isfile(top) else [
+            os.path.join(d, f) for d, _, fs in os.walk(top) for f in fs if f.endswith(".py")
+        ]
+        for path in files:
+            with open(path) as f:
+                for n, line in enumerate(f, 1):
+                    if re.search(r"jax_compilation_cache_dir", line):
+                        sites.append(f"{os.path.relpath(path, repo)}:{n}")
+    assert len(sites) == 1 and sites[0].startswith("nxdi_tpu/runtime/application.py:"), sites

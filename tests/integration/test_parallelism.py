@@ -44,16 +44,6 @@ def _build_app(hf_model, hf_cfg, **tcfg_kwargs):
 
 PROMPT = np.array([[5, 9, 3, 17, 2, 8, 11, 42]], dtype=np.int64)
 
-# jax 0.4.x cannot lower the GPipe shard_map (partial-auto ppermute ring hits
-# the legacy SPMD partitioner's ambiguous PartitionId); newer jax runs these
-from nxdi_tpu.jax_compat import LEGACY_JAX as _LEGACY_JAX
-
-_pp_old_jax = pytest.mark.skipif(
-    _LEGACY_JAX,
-    reason="pipeline-parallel shard_map needs jax >= 0.5 (PartitionId "
-    "lowering missing in the 0.4.x SPMD partitioner)",
-)
-
 
 @pytest.mark.parametrize(
     "tcfg_kwargs",
@@ -73,16 +63,16 @@ _pp_old_jax = pytest.mark.skipif(
             dict(cp_degree=2, attention_dp_degree=2, batch_size=2), id="cp2+dp2"
         ),
         pytest.param(
-            dict(tp_degree=4, pp_degree=2, batch_size=2), id="pp2xtp4", marks=_pp_old_jax
+            dict(tp_degree=4, pp_degree=2, batch_size=2), id="pp2xtp4"
         ),
         pytest.param(
             dict(tp_degree=2, pp_degree=2, batch_size=4, pp_microbatches=4),
-            id="pp2-micro4", marks=_pp_old_jax,
+            id="pp2-micro4",
         ),
         pytest.param(
             dict(tp_degree=4, pp_degree=2, batch_size=2,
                  sequence_parallel_enabled=True),
-            id="pp2+sp", marks=_pp_old_jax,
+            id="pp2+sp",
         ),
     ],
 )
@@ -291,7 +281,6 @@ def test_attention_strategy_observability(tiny_hf_llama):
     ), tkg_strats
 
 
-@_pp_old_jax
 def test_segmented_pp2_deepseek_token_matching():
     """Heterogeneous segment stack (deepseek-V3 first_k_dense head + MoE
     rest) under pp2: each segment pipelines as its own GPipe lap (multi-lap
@@ -337,7 +326,6 @@ def test_segmented_pp2_deepseek_token_matching():
     np.testing.assert_array_equal(actual, expected)
 
 
-@_pp_old_jax
 def test_collect_hidden_under_pp_matches_tp(tiny_hf_llama):
     """EAGLE3 aux taps / tensor capture need per-layer hiddens; under pp the
     stages bank their layers' hiddens per microbatch and the pp out-spec

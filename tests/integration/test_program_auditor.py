@@ -724,15 +724,14 @@ def test_cache_format_disagreement_detected(monkeypatch):
     every phase transition would pay a full-cache relayout."""
     from nxdi_tpu.analysis import auditor as auditor_mod
 
-    real = auditor_mod.compiled_input_formats
     calls = {"n": 0}
 
     def drifting_formats(compiled):
         # each compiled program reports a different per-leaf layout
         calls["n"] += 1
-        return ((None, {"k": f"fmt{calls['n']}", "v": f"fmt{calls['n']}"}, None),)
+        return {"k": f"fmt{calls['n']}", "v": f"fmt{calls['n']}"}
 
-    monkeypatch.setattr(auditor_mod, "compiled_input_formats", drifting_formats)
+    monkeypatch.setattr(auditor_mod, "_cache_input_formats", drifting_formats)
     report = make_app().audit()
     findings = errors_of(report, "cache_format")
     assert findings, report.to_json()
@@ -741,7 +740,6 @@ def test_cache_format_disagreement_detected(monkeypatch):
     # names both sides of the disagreeing pair
     assert "context_encoding_model[32]" in msg
     assert "token_generation_model[64]" in msg
-    monkeypatch.setattr(auditor_mod, "compiled_input_formats", real)
 
 
 def test_unknown_checker_name_still_surfaces():
@@ -866,13 +864,18 @@ def test_retrace_guard_not_sealed_with_skip_warmup():
 
 def test_required_strategy_check_runs_on_aot_compile_path(monkeypatch, tmp_path):
     """Regression: `app.compile()` (the AOT artifact path through
-    `_AutoLayoutProgram.lower`) must enforce required kernel strategies just
+    `_AutoLayoutProgram.compile`) must enforce required kernel strategies just
     like the lazy first-call path — a flag that cannot engage raises at
     compile time, naming the submodel and bucket."""
     monkeypatch.setattr(
         ModelWrapper,
         "_required_strategies",
         lambda self: (("fake_kernel_flag", ("strategy_that_never_engages",)),),
+    )
+    # the check under test raises while lowering; keep this worker's later
+    # tests out of the persistent compile cache
+    monkeypatch.setattr(
+        "nxdi_tpu.runtime.application.enable_persistent_cache", lambda: None
     )
     app = make_app()
     with pytest.raises(RuntimeError, match=r"fake_kernel_flag") as ei:

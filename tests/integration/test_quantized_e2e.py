@@ -270,16 +270,6 @@ def test_static_activation_quant_calibrated_e2e(tiny_hf_llama, tmp_path):
     np.testing.assert_array_equal(out, out_b)
 
 
-from nxdi_tpu.jax_compat import LEGACY_JAX as _LEGACY_JAX
-
-_fp8_old_jax = pytest.mark.skipif(
-    _LEGACY_JAX,
-    reason="fp8 KV rounding differs on jax 0.4.x XLA (tokens drift past the "
-    "0.75 match threshold); exercised on jax >= 0.5",
-)
-
-
-@_fp8_old_jax
 def test_kv_cache_fp8_quant(tiny_hf_llama):
     """fp8 KV cache (reference: kv_cache_manager.py:642-692 direct-cast)."""
     hf_model, hf_cfg = tiny_hf_llama
@@ -293,7 +283,6 @@ def test_kv_cache_fp8_quant(tiny_hf_llama):
     assert match >= 0.75, (actual, expected)
 
 
-@_fp8_old_jax
 def test_kv_cache_fp8_per_tensor_scaled(tiny_hf_llama):
     """Scaled fp8 KV cache (scale_mode="per_tensor"): values stored as v/scale
     and rescaled on read (reference: calibrated scale buffers,

@@ -13,7 +13,6 @@ on-device, test/unit/modules/kernels).
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 from nxdi_tpu.ops.attention import attention_with_positions
@@ -23,9 +22,7 @@ from nxdi_tpu.ops.kernels.flash_attention import (
     prefill_kernel_supported,
 )
 
-pytestmark = pytest.mark.skipif(
-    jax.devices()[0].platform != "tpu", reason="needs TPU hardware"
-)
+pytestmark = pytest.mark.usefixtures("tpu")
 
 
 def _rand(shape, seed=0, dtype=jnp.bfloat16):

@@ -18,9 +18,7 @@ from nxdi_tpu.ops.kernels import (
 )
 from nxdi_tpu.ops.kernels.kv_commit import kv_commit_rows
 
-pytestmark = pytest.mark.skipif(
-    jax.devices()[0].platform != "tpu", reason="needs TPU hardware"
-)
+pytestmark = pytest.mark.usefixtures("tpu")
 
 
 def _rand(shape, seed=0, dtype=jnp.bfloat16):

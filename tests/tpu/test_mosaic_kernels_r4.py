@@ -14,9 +14,7 @@ import jax.numpy as jnp
 
 import nxdi_tpu.ops.kernels.fused_proj as fk
 
-pytestmark = pytest.mark.skipif(
-    jax.devices()[0].platform != "tpu", reason="needs TPU hardware"
-)
+pytestmark = pytest.mark.usefixtures("tpu")
 
 
 def _rand(shape, seed=0, scale=0.05, dtype=jnp.bfloat16):

@@ -114,22 +114,31 @@ def test_wrapped_trajectory_baseline_unwraps(tmp_path):
     assert rc == 1  # the wrapped baseline's metrics were actually compared
 
 
-def test_gate_against_real_trajectory_file():
-    # BENCH_r05.json vs itself: every comparable metric is identical -> pass
-    root = os.path.join(os.path.dirname(__file__), "..", "..")
-    r05 = os.path.join(root, "BENCH_r05.json")
+def _write_trajectory(tmp_path, *rounds):
+    """Driver-wrapped trajectory records (the bench line under "parsed"),
+    written where the gate globs for them."""
+    for n in rounds:
+        wrapped = {"n": n, "cmd": "python bench.py", "rc": 0, "parsed": dict(BASE)}
+        _write(tmp_path, f"BENCH_r{n:02d}.json", wrapped)
+
+
+def test_gate_against_trajectory_file(tmp_path):
+    # a trajectory file vs itself: every comparable metric is identical -> pass
+    _write_trajectory(tmp_path, 5)
+    r05 = str(tmp_path / "BENCH_r05.json")
     assert bench_gate.main([r05, "--baseline", r05, "-q"]) == 0
     rec = bench_gate.bench_record(json.load(open(r05)))
     rows, _ = bench_gate.compare(rec, rec, bench_gate.TOLERANCES)
-    assert rows, "real trajectory file yielded no comparable metrics"
+    assert rows, "trajectory file yielded no comparable metrics"
 
 
-def test_default_baseline_picks_latest_round():
-    # the repo root carries the BENCH_r*.json trajectory; the gate must pick
-    # the newest round
-    root = os.path.join(os.path.dirname(__file__), "..", "..")
-    picked = bench_gate.default_baseline(root)
-    assert picked is not None and os.path.basename(picked) >= "BENCH_r05.json"
+def test_default_baseline_picks_latest_round(tmp_path):
+    # a root that carries a BENCH_r*.json trajectory: the gate must pick the
+    # newest round; a root without one has no default
+    assert bench_gate.default_baseline(str(tmp_path)) is None
+    _write_trajectory(tmp_path, 4, 5)
+    picked = bench_gate.default_baseline(str(tmp_path))
+    assert picked is not None and os.path.basename(picked) == "BENCH_r05.json"
 
 
 def test_usage_errors(tmp_path):

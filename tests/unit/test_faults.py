@@ -69,7 +69,7 @@ def test_classify_stdlib_exception_types():
 
 def test_classify_real_xla_runtime_errors_by_status_phrase():
     e = _xla_error("RESOURCE_EXHAUSTED: Out of memory allocating 2.1G")
-    assert type(e).__name__ == "XlaRuntimeError"  # the real class, not a fake
+    assert type(e).__name__ == "JaxRuntimeError"  # the real class, not a fake
     assert classify(e) == "exhausted"
     assert classify(_xla_error("DEADLINE_EXCEEDED: slow collective")) == "transient"
     assert classify(_xla_error("UNAVAILABLE: channel reset")) == "transient"

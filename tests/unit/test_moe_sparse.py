@@ -118,14 +118,6 @@ def _expert_matmul_flops(moe: MoEArch, H=32, T=8):
     return flops, seen_ragged
 
 
-from nxdi_tpu.jax_compat import LEGACY_JAX as _LEGACY_JAX
-
-
-@pytest.mark.skipif(
-    _LEGACY_JAX,
-    reason="jax 0.4.x lowers ragged_dot through a different primitive, so "
-    "the grouped-matmul FLOP counter finds no ragged ops",
-)
 def test_sparse_flops_scale_with_topk_not_experts():
     """Decode-shaped MoE: dense dispatch pays E/top_k x the expert FLOPs; the
     sparse path's grouped-matmul work is fixed at T*top_k rows as E grows."""

@@ -138,7 +138,7 @@ def test_closures_globals_and_builtins_not_flagged():
 def test_repo_is_lint_clean():
     roots = [
         os.path.join(REPO, d)
-        for d in ("nxdi_tpu", "tests", "scripts", "bench.py", "setup.py")
+        for d in ("nxdi_tpu", "tests", "scripts", "bench.py", "chip_smoke.py", "setup.py")
     ]
     errs = lint_paths(roots, repo_root=REPO)
     assert not errs, "source lint violations (see ruff.toml policy):\n" + "\n".join(
