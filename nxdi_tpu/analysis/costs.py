@@ -637,11 +637,14 @@ def attach_cost_gauges(app) -> None:
     / ``nxdi_roofline_gap_ratio``, and the sheet table rides the JSON
     snapshot as ``_cost_sheets``.
 
-    The gauges measure *achieved vs declared-chip-peak*; they are truthful
-    step utilization at ``telemetry="full"`` (synced host dispatch) or for
-    device-resident chains timed externally, and an upper bound on host
-    cost otherwise. Attach errors never propagate into serving: the update
-    recomputes lazily and any failure leaves the gauges unset.
+    The gauges measure *achieved vs declared-chip-peak*, so they are
+    published only where the latency they divide by includes the device:
+    ``sync_dispatch`` (``telemetry="full"``, or while a ``SubmodelProfiler``
+    is attached). At ``"basic"`` the histogram times the enqueue, and a
+    share of a peak computed from it is one no chip can give: the three
+    series are then absent, not impossible. Attach errors never propagate
+    into serving: the update recomputes lazily and any failure leaves the
+    gauges unset.
 
     The hooks hold the app through a WEAK reference: ``app.telemetry`` owns
     the hook closures, so a strong capture would cycle app <-> telemetry
@@ -690,6 +693,8 @@ def attach_cost_gauges(app) -> None:
         return out
 
     def _update() -> None:
+        if not tel.sync_dispatch:
+            return
         for sheet in _sheets():
             labels = dict(
                 submodel=sheet.tag, bucket=str(sheet.bucket), steps=str(sheet.steps)

@@ -158,13 +158,26 @@ def inspect_bundle(path: str) -> int:
     return 0
 
 
+def _phases_text(r: dict) -> str:
+    """``schedule=0.05 ... other=0.01``: the step's phases in ms, in the
+    order a step enters them, then the time under no phase."""
+    from nxdi_tpu.telemetry import PHASES
+
+    phases = r.get("phases") or {}
+    parts = [f"{k}={phases[k] * 1e3:.2f}" for k in PHASES if k in phases]
+    if "other_s" in r:
+        parts.append(f"other={r['other_s'] * 1e3:.2f}")
+    return " ".join(parts)
+
+
 def _print_timeline(records: List[dict], last: int) -> None:
     shown = records[-last:]
     if len(shown) < len(records):
         print(f"... {len(records) - len(shown)} earlier steps elided ...")
     hdr = (f"{'step':>5} {'wall_ms':>8} {'disp_ms':>8} {'host_ms':>8} "
            f"{'adm':>3} {'cached':>9} {'pf':>3} {'dec':>3} {'pre':>3} "
-           f"{'ret':>3} {'kv_free':>7} {'queue':>5}  program")
+           f"{'ret':>3} {'kv_free':>7} {'queue':>5}  program  "
+           "phases_ms (host_ms = wall - fetch)")
     print(hdr)
     print("-" * len(hdr))
     for r in shown:
@@ -207,7 +220,7 @@ def _print_timeline(records: List[dict], last: int) -> None:
             f"{len(dec['rows']) if dec else 0:>3} "
             f"{len(r['preempted']):>3} {len(r['retired']):>3} "
             f"{r['kv_blocks_free'] if r['kv_blocks_free'] is not None else '-':>7} "
-            f"{r['queue_depth']:>5}  {prog}"
+            f"{r['queue_depth']:>5}  {prog}  {_phases_text(r)}"
         )
 
 

@@ -456,128 +456,131 @@ def attention_block(
         multiplied by sigmoid(gate_proj(attention input)) before o_proj —
         composes with every attention strategy since the gate acts on the
         kernel-agnostic context)."""
-        if arch.attn_out_gate:
-            g = jax.nn.sigmoid(
-                (hidden @ p_attn["gate_proj"]["w"]).astype(jnp.float32)
-            )
-            ctx2d = (ctx2d.astype(jnp.float32) * g).astype(ctx2d.dtype)
-        return _linear(ctx2d, p_attn["o_proj"], aq, ac, adapter_ids)
-    if arch.fused_qkv:
-        if "qkv_proj" not in p_attn:
-            raise NotImplementedError(
-                "fused_qkv is enabled but this model's params carry no fused "
-                "qkv_proj weight — the family's converter does not support "
-                "fused QKV; disable the flag"
-            )
-        pq = p_attn["qkv_proj"]
-        Tq, Tk, Tv = H * D, KV * D, KV * Dv
-        if arch.qkv_kernel_enabled:
-            if adapter_ids is not None or ("w" not in pq and qkv_stacked is None):
+        with jax.named_scope("attn.out"):
+            if arch.attn_out_gate:
+                g = jax.nn.sigmoid(
+                    (hidden @ p_attn["gate_proj"]["w"]).astype(jnp.float32)
+                )
+                ctx2d = (ctx2d.astype(jnp.float32) * g).astype(ctx2d.dtype)
+            return _linear(ctx2d, p_attn["o_proj"], aq, ac, adapter_ids)
+    with jax.named_scope("attn.qkv"):
+        if arch.fused_qkv:
+            if "qkv_proj" not in p_attn:
                 raise NotImplementedError(
-                    "qkv_kernel_enabled requires an unquantized, non-LoRA "
-                    "fused qkv_proj weight"
+                    "fused_qkv is enabled but this model's params carry no fused "
+                    "qkv_proj weight — the family's converter does not support "
+                    "fused QKV; disable the flag"
                 )
-            if qkv_stacked is not None:
-                w_s, b_s = qkv_stacked
-                qkv = attn_kernels.sharded_qkv_stacked_call(
-                    hidden, w_s,
-                    layer_idx if stacked_layer_idx is None else stacked_layer_idx,
-                    b_s,
-                )
+            pq = p_attn["qkv_proj"]
+            Tq, Tk, Tv = H * D, KV * D, KV * Dv
+            if arch.qkv_kernel_enabled:
+                if adapter_ids is not None or ("w" not in pq and qkv_stacked is None):
+                    raise NotImplementedError(
+                        "qkv_kernel_enabled requires an unquantized, non-LoRA "
+                        "fused qkv_proj weight"
+                    )
+                if qkv_stacked is not None:
+                    w_s, b_s = qkv_stacked
+                    qkv = attn_kernels.sharded_qkv_stacked_call(
+                        hidden, w_s,
+                        layer_idx if stacked_layer_idx is None else stacked_layer_idx,
+                        b_s,
+                    )
+                else:
+                    qkv = attn_kernels.sharded_qkv_call(hidden, pq["w"], pq.get("b"))
+                if qkv is None:
+                    raise NotImplementedError(
+                        "qkv_kernel_enabled: fused projection shape is not "
+                        "kernel-eligible; disable the flag"
+                    )
+                _record_strategy("qkv_fused_kernel")
             else:
-                qkv = attn_kernels.sharded_qkv_call(hidden, pq["w"], pq.get("b"))
-            if qkv is None:
-                raise NotImplementedError(
-                    "qkv_kernel_enabled: fused projection shape is not "
-                    "kernel-eligible; disable the flag"
+                qkv = _linear(hidden, pq, aq, ac, adapter_ids)
+                _record_strategy("qkv_fused_matmul")
+            # undo the per-rank interleave on the LOGICAL view: rank blocks are
+            # head blocks in order, so regrouping by rank reassembles q/k/v
+            tp = arch.fused_qkv_tp
+            t = qkv.reshape(B, S, tp, (Tq + Tk + Tv) // tp)
+            q = t[..., : Tq // tp].reshape(B, S, Tq)
+            k = t[..., Tq // tp : (Tq + Tk) // tp].reshape(B, S, Tk)
+            v = t[..., (Tq + Tk) // tp :].reshape(B, S, Tv)
+        else:
+            q = _linear(hidden, p_attn["q_proj"], aq, ac, adapter_ids)
+            k = _linear(hidden, p_attn["k_proj"], aq, ac, adapter_ids)
+            v = _linear(hidden, p_attn["v_proj"], aq, ac, adapter_ids)
+        if arch.clip_qkv is not None:  # dbrx clamps the qkv outputs
+            q = jnp.clip(q, -arch.clip_qkv, arch.clip_qkv)
+            k = jnp.clip(k, -arch.clip_qkv, arch.clip_qkv)
+            v = jnp.clip(v, -arch.clip_qkv, arch.clip_qkv)
+        if arch.qk_norm_flat:
+            # minimax-m2: rmsnorm over the whole flattened projection, pre-reshape
+            def flat_rms(x, w, denom):
+                xf = x.astype(jnp.float32)
+                ms = jnp.sum(xf * xf, axis=-1, keepdims=True) / denom
+                return (xf * jax.lax.rsqrt(ms + arch.rms_norm_eps) * w).astype(x.dtype)
+
+            q = flat_rms(q, p_attn["q_norm"], arch.qk_norm_flat_qdim or q.shape[-1])
+            k = flat_rms(k, p_attn["k_norm"], k.shape[-1])
+        q = q.reshape(B, S, H, D)
+        k = k.reshape(B, S, KV, D)
+        v = v.reshape(B, S, KV, Dv)
+
+        if arch.qk_norm:
+            q = _norm(arch, q, p_attn["q_norm"])
+            k = _norm(arch, k, p_attn["k_norm"])
+
+        q = jnp.swapaxes(q, 1, 2)  # (B, H, S, D)
+        k = jnp.swapaxes(k, 1, 2)  # (B, KV, S, D)
+        v = jnp.swapaxes(v, 1, 2)
+
+        q = constrain(q, policy.q)
+        k = constrain(k, policy.kv)
+        v = constrain(v, policy.kv)
+
+    with jax.named_scope("attn.rope"):
+        rope_fn = apply_rotary_pos_emb
+        if arch.rope_interleaved:
+            from nxdi_tpu.ops.rope import apply_rotary_pos_emb_interleaved as rope_fn
+        if arch.rotary_dim is not None and arch.rotary_dim < D:
+            # partial rotary: rotate the first rotary_dim channels only
+            # (cos/sin are built from a rotary_dim-sized frequency table)
+            rd, base_rope = arch.rotary_dim, rope_fn
+
+            def rope_fn(q_, k_, cos_, sin_):
+                qr, kr = base_rope(q_[..., :rd], k_[..., :rd], cos_, sin_)
+                return (
+                    jnp.concatenate([qr, q_[..., rd:]], axis=-1),
+                    jnp.concatenate([kr, k_[..., rd:]], axis=-1),
                 )
-            _record_strategy("qkv_fused_kernel")
+        if arch.no_rope:
+            pass  # gpt2 lineage: positions come from learned embeddings
+        elif use_rope is None:
+            q, k = rope_fn(q, k, cos, sin)
         else:
-            qkv = _linear(hidden, pq, aq, ac, adapter_ids)
-            _record_strategy("qkv_fused_matmul")
-        # undo the per-rank interleave on the LOGICAL view: rank blocks are
-        # head blocks in order, so regrouping by rank reassembles q/k/v
-        tp = arch.fused_qkv_tp
-        t = qkv.reshape(B, S, tp, (Tq + Tk + Tv) // tp)
-        q = t[..., : Tq // tp].reshape(B, S, Tq)
-        k = t[..., Tq // tp : (Tq + Tk) // tp].reshape(B, S, Tk)
-        v = t[..., (Tq + Tk) // tp :].reshape(B, S, Tv)
-    else:
-        q = _linear(hidden, p_attn["q_proj"], aq, ac, adapter_ids)
-        k = _linear(hidden, p_attn["k_proj"], aq, ac, adapter_ids)
-        v = _linear(hidden, p_attn["v_proj"], aq, ac, adapter_ids)
-    if arch.clip_qkv is not None:  # dbrx clamps the qkv outputs
-        q = jnp.clip(q, -arch.clip_qkv, arch.clip_qkv)
-        k = jnp.clip(k, -arch.clip_qkv, arch.clip_qkv)
-        v = jnp.clip(v, -arch.clip_qkv, arch.clip_qkv)
-    if arch.qk_norm_flat:
-        # minimax-m2: rmsnorm over the whole flattened projection, pre-reshape
-        def flat_rms(x, w, denom):
-            xf = x.astype(jnp.float32)
-            ms = jnp.sum(xf * xf, axis=-1, keepdims=True) / denom
-            return (xf * jax.lax.rsqrt(ms + arch.rms_norm_eps) * w).astype(x.dtype)
+            # llama4: some layers skip rope entirely (per-layer scan flag)
+            qr, kr = rope_fn(q, k, cos, sin)
+            q = jnp.where(use_rope, qr, q)
+            k = jnp.where(use_rope, kr, k)
 
-        q = flat_rms(q, p_attn["q_norm"], arch.qk_norm_flat_qdim or q.shape[-1])
-        k = flat_rms(k, p_attn["k_norm"], k.shape[-1])
-    q = q.reshape(B, S, H, D)
-    k = k.reshape(B, S, KV, D)
-    v = v.reshape(B, S, KV, Dv)
+        if arch.qk_l2norm:
+            # llama4 unweighted qk norm, AFTER rope, on rope layers only
+            from nxdi_tpu.ops.rope import l2_norm
 
-    if arch.qk_norm:
-        q = _norm(arch, q, p_attn["q_norm"])
-        k = _norm(arch, k, p_attn["k_norm"])
+            qn, kn = l2_norm(q, arch.rms_norm_eps), l2_norm(k, arch.rms_norm_eps)
+            if use_rope is None:
+                q, k = qn, kn
+            else:
+                q = jnp.where(use_rope, qn, q)
+                k = jnp.where(use_rope, kn, k)
 
-    q = jnp.swapaxes(q, 1, 2)  # (B, H, S, D)
-    k = jnp.swapaxes(k, 1, 2)  # (B, KV, S, D)
-    v = jnp.swapaxes(v, 1, 2)
-
-    q = constrain(q, policy.q)
-    k = constrain(k, policy.kv)
-    v = constrain(v, policy.kv)
-
-    rope_fn = apply_rotary_pos_emb
-    if arch.rope_interleaved:
-        from nxdi_tpu.ops.rope import apply_rotary_pos_emb_interleaved as rope_fn
-    if arch.rotary_dim is not None and arch.rotary_dim < D:
-        # partial rotary: rotate the first rotary_dim channels only
-        # (cos/sin are built from a rotary_dim-sized frequency table)
-        rd, base_rope = arch.rotary_dim, rope_fn
-
-        def rope_fn(q_, k_, cos_, sin_):
-            qr, kr = base_rope(q_[..., :rd], k_[..., :rd], cos_, sin_)
-            return (
-                jnp.concatenate([qr, q_[..., rd:]], axis=-1),
-                jnp.concatenate([kr, k_[..., rd:]], axis=-1),
-            )
-    if arch.no_rope:
-        pass  # gpt2 lineage: positions come from learned embeddings
-    elif use_rope is None:
-        q, k = rope_fn(q, k, cos, sin)
-    else:
-        # llama4: some layers skip rope entirely (per-layer scan flag)
-        qr, kr = rope_fn(q, k, cos, sin)
-        q = jnp.where(use_rope, qr, q)
-        k = jnp.where(use_rope, kr, k)
-
-    if arch.qk_l2norm:
-        # llama4 unweighted qk norm, AFTER rope, on rope layers only
-        from nxdi_tpu.ops.rope import l2_norm
-
-        qn, kn = l2_norm(q, arch.rms_norm_eps), l2_norm(k, arch.rms_norm_eps)
-        if use_rope is None:
-            q, k = qn, kn
-        else:
-            q = jnp.where(use_rope, qn, q)
-            k = jnp.where(use_rope, kn, k)
-
-    if arch.attn_temperature_tuning and use_rope is not None:
-        # per-position query temperature on NO-rope layers
-        # (reference: llama4 attn temperature tuning)
-        pos = position_ids.astype(jnp.float32)
-        scales = (
-            jnp.log1p(jnp.floor((pos + 1.0) / arch.floor_scale)) * arch.attn_scale + 1.0
-        )[:, None, :, None]
-        q = jnp.where(use_rope, q, (q * scales).astype(q.dtype))
+        if arch.attn_temperature_tuning and use_rope is not None:
+            # per-position query temperature on NO-rope layers
+            # (reference: llama4 attn temperature tuning)
+            pos = position_ids.astype(jnp.float32)
+            scales = (
+                jnp.log1p(jnp.floor((pos + 1.0) / arch.floor_scale)) * arch.attn_scale + 1.0
+            )[:, None, :, None]
+            q = jnp.where(use_rope, q, (q * scales).astype(q.dtype))
 
     ci = dict(cache_inputs or {})
     ci["position_ids"] = position_ids
@@ -599,28 +602,30 @@ def attention_block(
         # attends exactly the same (position, value) set as the per-step
         # commit path; only the two-part summation split differs.
         k_sp, v_sp, win_pos, slot = spec_window
-        k_sp = jax.lax.dynamic_update_slice(
-            k_sp, k.astype(k_sp.dtype), (0, 0, slot, 0)
-        )
-        v_sp = jax.lax.dynamic_update_slice(
-            v_sp, v.astype(v_sp.dtype), (0, 0, slot, 0)
-        )
-        kk, vv, kv_pos = layout.read(k_cache_l, v_cache_l, ci, cache_spec)
-        kk = constrain(kk, policy.cache_kv)
-        vv = constrain(vv, policy.cache_kv)
-        kv_pos = jnp.where(kv_pos >= win_pos[:, :1], jnp.int32(2 ** 30), kv_pos)
-        _record_strategy("tkg_spec_window_xla")
-        ctx = attn_ops.attention_two_part(
-            q, kk, vv, k_sp, v_sp, position_ids, kv_pos, win_pos,
-            scale=arch.attention_scale,
-            softmax_dtype=jnp.float32,
-            sliding_window=arch.sliding_window,
-            chunk_size=arch.chunk_size,
-            sink=p_attn.get("sink") if arch.attention_sink else None,
-            sliding_window_enabled=window_enabled,
-            chunk_enabled=use_rope,
-            logit_softcap=arch.attn_logit_softcap,
-        )
+        with jax.named_scope("kv.write"):
+            k_sp = jax.lax.dynamic_update_slice(
+                k_sp, k.astype(k_sp.dtype), (0, 0, slot, 0)
+            )
+            v_sp = jax.lax.dynamic_update_slice(
+                v_sp, v.astype(v_sp.dtype), (0, 0, slot, 0)
+            )
+        with jax.named_scope("attn.core"):
+            kk, vv, kv_pos = layout.read(k_cache_l, v_cache_l, ci, cache_spec)
+            kk = constrain(kk, policy.cache_kv)
+            vv = constrain(vv, policy.cache_kv)
+            kv_pos = jnp.where(kv_pos >= win_pos[:, :1], jnp.int32(2 ** 30), kv_pos)
+            _record_strategy("tkg_spec_window_xla")
+            ctx = attn_ops.attention_two_part(
+                q, kk, vv, k_sp, v_sp, position_ids, kv_pos, win_pos,
+                scale=arch.attention_scale,
+                softmax_dtype=jnp.float32,
+                sliding_window=arch.sliding_window,
+                chunk_size=arch.chunk_size,
+                sink=p_attn.get("sink") if arch.attention_sink else None,
+                sliding_window_enabled=window_enabled,
+                chunk_enabled=use_rope,
+                logit_softcap=arch.attn_logit_softcap,
+            )
         ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * Dv)
         out = _o_proj(ctx)
         return out, (k_sp, v_sp)
@@ -631,26 +636,27 @@ def attention_block(
     if defer:
         # OLD cache; this step's slots are masked below and the fresh rows
         # appended — no per-layer full-cache write-back
-        kk, vv, kv_pos = layout.read(k_cache_l, v_cache_l, ci, cache_spec)
-        kk = constrain(kk, policy.cache_kv)
-        vv = constrain(vv, policy.cache_kv)
-        store = cache_spec.store_dtype
-        array_scales = getattr(layout, "has_array_scales", lambda: False)()
-        if store != k.dtype or getattr(layout, "k_scale", 1.0) != 1.0 or array_scales:
-            # quantized cache: round-trip the fresh rows through the store
-            # dtype/scale so this step's numerics match the non-deferred
-            # path (which attends the quantize->dequantize'd row) exactly
-            if array_scales:
-                ks = layout._scale_for("k", ci, stacked=False)
-                vs = layout._scale_for("v", ci, stacked=False)
+        with jax.named_scope("attn.core"):
+            kk, vv, kv_pos = layout.read(k_cache_l, v_cache_l, ci, cache_spec)
+            kk = constrain(kk, policy.cache_kv)
+            vv = constrain(vv, policy.cache_kv)
+            store = cache_spec.store_dtype
+            array_scales = getattr(layout, "has_array_scales", lambda: False)()
+            if store != k.dtype or getattr(layout, "k_scale", 1.0) != 1.0 or array_scales:
+                # quantized cache: round-trip the fresh rows through the store
+                # dtype/scale so this step's numerics match the non-deferred
+                # path (which attends the quantize->dequantize'd row) exactly
+                if array_scales:
+                    ks = layout._scale_for("k", ci, stacked=False)
+                    vs = layout._scale_for("v", ci, stacked=False)
+                else:
+                    ks = getattr(layout, "k_scale", 1.0)
+                    vs = getattr(layout, "v_scale", 1.0)
+                clip = getattr(ContiguousKVLayout, "clip_to_store")
+                k_att = (clip(k / ks, store).astype(store).astype(k.dtype) * ks).astype(k.dtype)
+                v_att = (clip(v / vs, store).astype(store).astype(v.dtype) * vs).astype(v.dtype)
             else:
-                ks = getattr(layout, "k_scale", 1.0)
-                vs = getattr(layout, "v_scale", 1.0)
-            clip = getattr(ContiguousKVLayout, "clip_to_store")
-            k_att = (clip(k / ks, store).astype(store).astype(k.dtype) * ks).astype(k.dtype)
-            v_att = (clip(v / vs, store).astype(store).astype(v.dtype) * vs).astype(v.dtype)
-        else:
-            k_att, v_att = k, v
+                k_att, v_att = k, v
         # STACKED fused TKG kernel (round-4): reads the OLD cache straight
         # from the (L, B, KV, S, D) stack via a scalar-prefetched layer
         # index — no per-layer cache slice ever materializes for the pallas
@@ -664,13 +670,14 @@ def attention_block(
             and ci.get("write_positions") is None
         ):
             k_s, v_s, kv_len_s = tkg_stacked
-            ctx = attn_kernels.sharded_fused_decode_stacked_call(
-                policy, q, k_s, v_s, k, v, position_ids, stacked_layer_idx,
-                scale=arch.attention_scale,
-                sliding_window=arch.sliding_window,
-                chunk_size=arch.chunk_size,
-                kv_len=kv_len_s,
-            )
+            with jax.named_scope("attn.core"):
+                ctx = attn_kernels.sharded_fused_decode_stacked_call(
+                    policy, q, k_s, v_s, k, v, position_ids, stacked_layer_idx,
+                    scale=arch.attention_scale,
+                    sliding_window=arch.sliding_window,
+                    chunk_size=arch.chunk_size,
+                    kv_len=kv_len_s,
+                )
             if ctx is not None:
                 _record_strategy("tkg_fused_kernel_stacked")
                 ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * Dv)
@@ -692,37 +699,40 @@ def attention_block(
             and ci.get("write_positions") is None
             and attn_kernels.fused_decode_kernel_supported(q.shape, kk.shape)
         ):
-            ctx = attn_kernels.sharded_fused_decode_call(
-                policy, q, kk, vv, k_att, v_att, position_ids, kv_pos,
-                scale=arch.attention_scale,
-                sliding_window=arch.sliding_window,
-                chunk_size=arch.chunk_size,
-            )
+            with jax.named_scope("attn.core"):
+                ctx = attn_kernels.sharded_fused_decode_call(
+                    policy, q, kk, vv, k_att, v_att, position_ids, kv_pos,
+                    scale=arch.attention_scale,
+                    sliding_window=arch.sliding_window,
+                    chunk_size=arch.chunk_size,
+                )
             if ctx is not None:
                 _record_strategy("tkg_fused_kernel")
                 ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * Dv)
                 out = _o_proj(ctx)
                 return out, (k, v)
-        _record_strategy("tkg_two_part_xla")
-        wpos = ci.get("write_positions", position_ids).astype(jnp.int32)
-        hit = jnp.any(kv_pos[:, None, :] == wpos[:, :, None], axis=1)
-        kv_pos = jnp.where(hit, jnp.int32(2 ** 30), kv_pos)
-        ctx = attn_ops.attention_two_part(
-            q, kk, vv, k_att, v_att, position_ids, kv_pos, wpos,
-            scale=arch.attention_scale,
-            softmax_dtype=jnp.float32,
-            sliding_window=arch.sliding_window,
-            chunk_size=arch.chunk_size,
-            sink=p_attn.get("sink") if arch.attention_sink else None,
-            sliding_window_enabled=window_enabled,
-            chunk_enabled=use_rope,
-            logit_softcap=arch.attn_logit_softcap,
-        )
+        with jax.named_scope("attn.core"):
+            _record_strategy("tkg_two_part_xla")
+            wpos = ci.get("write_positions", position_ids).astype(jnp.int32)
+            hit = jnp.any(kv_pos[:, None, :] == wpos[:, :, None], axis=1)
+            kv_pos = jnp.where(hit, jnp.int32(2 ** 30), kv_pos)
+            ctx = attn_ops.attention_two_part(
+                q, kk, vv, k_att, v_att, position_ids, kv_pos, wpos,
+                scale=arch.attention_scale,
+                softmax_dtype=jnp.float32,
+                sliding_window=arch.sliding_window,
+                chunk_size=arch.chunk_size,
+                sink=p_attn.get("sink") if arch.attention_sink else None,
+                sliding_window_enabled=window_enabled,
+                chunk_enabled=use_rope,
+                logit_softcap=arch.attn_logit_softcap,
+            )
         ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * Dv)
         out = _o_proj(ctx)
         return out, (k, v)  # fresh rows only; committed after the scan
 
-    new_k, new_v = layout.update(k_cache_l, v_cache_l, k, v, ci, cache_spec)
+    with jax.named_scope("kv.write"):
+        new_k, new_v = layout.update(k_cache_l, v_cache_l, k, v, ci, cache_spec)
 
     if attend_to_cache:
         if ci and ci.get("bidir_spans") is not None and S > 1:
@@ -763,13 +773,14 @@ def attention_block(
                     q.shape, new_k.shape, layout.block_size
                 )
             ):
-                ctx = attn_kernels.sharded_ragged_paged_call(
-                    policy, q, new_k, new_v, bt, rids[0], position_ids[0],
-                    block_size=layout.block_size,
-                    scale=arch.attention_scale,
-                    k_scale=layout.k_scale,
-                    v_scale=layout.v_scale,
-                )
+                with jax.named_scope("attn.core"):
+                    ctx = attn_kernels.sharded_ragged_paged_call(
+                        policy, q, new_k, new_v, bt, rids[0], position_ids[0],
+                        block_size=layout.block_size,
+                        scale=arch.attention_scale,
+                        k_scale=layout.k_scale,
+                        v_scale=layout.v_scale,
+                    )
                 if ctx is not None:
                     _record_strategy("mixed_ragged_kernel")
                     ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * D)
@@ -779,25 +790,26 @@ def attention_block(
             # ragged causal mask from the token tags — kv col g serves row
             # g // row_width at in-row position g % row_width; holes carry
             # the layout's poisoned 2**30 position
-            kk, vv, kv_pos = layout.read(new_k, new_v, ci, cache_spec)
-            kk = constrain(kk, policy.cache_kv)
-            vv = constrain(vv, policy.cache_kv)
-            W = kk.shape[2]
-            row_width = W // R
-            g = jnp.arange(W, dtype=jnp.int32)
-            kv_row = g // row_width
-            kv_in = g % row_width
-            live = kv_pos[0] < jnp.int32(2 ** 30)
-            mask = (
-                (rids[:, :, None] == kv_row[None, None, :])
-                & (kv_in[None, None, :] <= position_ids[:, :, None])
-                & live[None, None, :]
-            )
-            _record_strategy("mixed_ragged_xla")
-            ctx = attn_ops.grouped_attention(
-                q, kk, vv, mask,
-                scale=arch.attention_scale, softmax_dtype=jnp.float32,
-            )
+            with jax.named_scope("attn.core"):
+                kk, vv, kv_pos = layout.read(new_k, new_v, ci, cache_spec)
+                kk = constrain(kk, policy.cache_kv)
+                vv = constrain(vv, policy.cache_kv)
+                W = kk.shape[2]
+                row_width = W // R
+                g = jnp.arange(W, dtype=jnp.int32)
+                kv_row = g // row_width
+                kv_in = g % row_width
+                live = kv_pos[0] < jnp.int32(2 ** 30)
+                mask = (
+                    (rids[:, :, None] == kv_row[None, None, :])
+                    & (kv_in[None, None, :] <= position_ids[:, :, None])
+                    & live[None, None, :]
+                )
+                _record_strategy("mixed_ragged_xla")
+                ctx = attn_ops.grouped_attention(
+                    q, kk, vv, mask,
+                    scale=arch.attention_scale, softmax_dtype=jnp.float32,
+                )
             ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * Dv)
             out = _o_proj(ctx)
             return out, (new_k, new_v)
@@ -824,19 +836,21 @@ def attention_block(
                 q.shape, new_k.shape, layout.block_size
             )
         ):
-            ctx = attn_kernels.sharded_paged_prefill_call(
-                policy, q, new_k, new_v, ci["block_table"], position_ids,
-                block_size=layout.block_size,
-                scale=arch.attention_scale,
-                k_scale=layout.k_scale,
-                v_scale=layout.v_scale,
-            )
+            with jax.named_scope("attn.core"):
+                ctx = attn_kernels.sharded_paged_prefill_call(
+                    policy, q, new_k, new_v, ci["block_table"], position_ids,
+                    block_size=layout.block_size,
+                    scale=arch.attention_scale,
+                    k_scale=layout.k_scale,
+                    v_scale=layout.v_scale,
+                )
             if ctx is not None:
                 _record_strategy("cte_paged_kernel")
                 ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * D)
-                out = _linear(
-                    ctx, p_attn["o_proj"], arch.act_quant, arch.act_clamp, adapter_ids
-                )
+                with jax.named_scope("attn.out"):
+                    out = _linear(
+                        ctx, p_attn["o_proj"], arch.act_quant, arch.act_clamp, adapter_ids
+                    )
                 return out, (new_k, new_v)
         # paged decode: read K/V straight through the block table inside the
         # kernel — skips the materialized O(table-width) gather of
@@ -859,113 +873,119 @@ def attention_block(
                 q.shape, new_k.shape, layout.block_size
             )
         ):
-            ctx = attn_kernels.sharded_paged_decode_call(
-                policy, q, new_k, new_v, ci["block_table"], position_ids,
-                block_size=layout.block_size,
-                scale=arch.attention_scale,
-                k_scale=layout.k_scale,
-                v_scale=layout.v_scale,
-            )
+            with jax.named_scope("attn.core"):
+                ctx = attn_kernels.sharded_paged_decode_call(
+                    policy, q, new_k, new_v, ci["block_table"], position_ids,
+                    block_size=layout.block_size,
+                    scale=arch.attention_scale,
+                    k_scale=layout.k_scale,
+                    v_scale=layout.v_scale,
+                )
             if ctx is not None:
                 _record_strategy("tkg_paged_kernel")
                 ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * D)
-                out = _linear(
-                    ctx, p_attn["o_proj"], arch.act_quant, arch.act_clamp, adapter_ids
-                )
+                with jax.named_scope("attn.out"):
+                    out = _linear(
+                        ctx, p_attn["o_proj"], arch.act_quant, arch.act_clamp, adapter_ids
+                    )
                 return out, (new_k, new_v)
-        kk, vv, kv_pos = layout.read(new_k, new_v, ci, cache_spec)
-        kk = constrain(kk, policy.cache_kv)
-        vv = constrain(vv, policy.cache_kv)
+        with jax.named_scope("attn.core"):
+            kk, vv, kv_pos = layout.read(new_k, new_v, ci, cache_spec)
+            kk = constrain(kk, policy.cache_kv)
+            vv = constrain(vv, policy.cache_kv)
         mask_override = ci.get("attn_mask")
         if mask_override is not None:
             # explicit (B, S, W) mask — tree-attention verify passes
             # (speculation/token_tree.py) where causal-by-position is wrong.
             # Sink/softcap still apply; window/chunk masks cannot compose with
             # an override (applications reject those combinations up front).
-            W = kk.shape[2]
-            _record_strategy("attn_mask_override_xla")
-            ctx = attn_ops.grouped_attention(
-                q, kk, vv, mask_override[:, :, :W],
-                scale=arch.attention_scale, softmax_dtype=jnp.float32,
-                sink=p_attn.get("sink") if arch.attention_sink else None,
-                logit_softcap=arch.attn_logit_softcap,
-            )
+            with jax.named_scope("attn.core"):
+                W = kk.shape[2]
+                _record_strategy("attn_mask_override_xla")
+                ctx = attn_ops.grouped_attention(
+                    q, kk, vv, mask_override[:, :, :W],
+                    scale=arch.attention_scale, softmax_dtype=jnp.float32,
+                    sink=p_attn.get("sink") if arch.attention_sink else None,
+                    logit_softcap=arch.attn_logit_softcap,
+                )
             ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * Dv)
             out = _o_proj(ctx)
             return out, (new_k, new_v)
-        ctx = None
-        if (
-            arch.attn_tkg_kernel_enabled
-            and arch.v_head_dim is None
-            and not arch.attention_sink
-            and arch.attn_logit_softcap is None
-            and window_enabled is None
-            and use_rope is None
-            and attn_kernels.decode_kernel_supported(q.shape, kk.shape)
-        ):
-            ctx = attn_kernels.sharded_kernel_call(
-                policy, q, kk, vv, position_ids, kv_pos,
-                decode=True,
-                scale=arch.attention_scale,
-                sliding_window=arch.sliding_window,
-                chunk_size=arch.chunk_size,
-            )
-        _record_strategy("tkg_xla" if ctx is None else "tkg_kernel")
-        if ctx is None:
-            ctx = attn_ops.attention_with_positions(
-                q, kk, vv, position_ids, kv_pos,
-                scale=arch.attention_scale,
-                softmax_dtype=jnp.float32,
-                sliding_window=arch.sliding_window,
-                chunk_size=arch.chunk_size,
-                sink=p_attn.get("sink") if arch.attention_sink else None,
-                sliding_window_enabled=window_enabled,
-                chunk_enabled=use_rope,
-                logit_softcap=arch.attn_logit_softcap,
-            )
+        with jax.named_scope("attn.core"):
+            ctx = None
+            if (
+                arch.attn_tkg_kernel_enabled
+                and arch.v_head_dim is None
+                and not arch.attention_sink
+                and arch.attn_logit_softcap is None
+                and window_enabled is None
+                and use_rope is None
+                and attn_kernels.decode_kernel_supported(q.shape, kk.shape)
+            ):
+                ctx = attn_kernels.sharded_kernel_call(
+                    policy, q, kk, vv, position_ids, kv_pos,
+                    decode=True,
+                    scale=arch.attention_scale,
+                    sliding_window=arch.sliding_window,
+                    chunk_size=arch.chunk_size,
+                )
+            _record_strategy("tkg_xla" if ctx is None else "tkg_kernel")
+            if ctx is None:
+                ctx = attn_ops.attention_with_positions(
+                    q, kk, vv, position_ids, kv_pos,
+                    scale=arch.attention_scale,
+                    softmax_dtype=jnp.float32,
+                    sliding_window=arch.sliding_window,
+                    chunk_size=arch.chunk_size,
+                    sink=p_attn.get("sink") if arch.attention_sink else None,
+                    sliding_window_enabled=window_enabled,
+                    chunk_enabled=use_rope,
+                    logit_softcap=arch.attn_logit_softcap,
+                )
     else:
         # gemma3-vision: image-span tokens attend each other BIDIRECTIONALLY
         # during prefill (HF token_type_ids_mask_function OR-ed into both the
         # full and sliding masks); spans are derived in-graph from input_ids
         # (causal_lm_forward), so only the CTE program pays for it
-        bidir = ci.get("bidir_spans") if ci else None
-        extra_or = None
-        if bidir is not None and S > 1:
-            extra_or = (bidir[:, None, :] == bidir[:, :, None]) & (
-                bidir[:, :, None] > 0
-            )
-        ctx = None
-        if (
-            arch.attn_kernel_enabled
-            and arch.v_head_dim is None
-            and not arch.attention_sink
-            and arch.attn_logit_softcap is None
-            and window_enabled is None
-            and use_rope is None
-            and extra_or is None
-            and attn_kernels.prefill_kernel_supported(q.shape, k.shape)
-        ):
-            ctx = attn_kernels.sharded_kernel_call(
-                policy, q, k, v, position_ids, position_ids,
-                decode=False,
-                scale=arch.attention_scale,
-                sliding_window=arch.sliding_window,
-                chunk_size=arch.chunk_size,
-            )
-        _record_strategy("cte_xla" if ctx is None else "cte_flash_kernel")
-        if ctx is None:
-            ctx = attn_ops.attention_with_positions(
-                q, k, v, position_ids, position_ids,
-                scale=arch.attention_scale,
-                softmax_dtype=jnp.float32,
-                sliding_window=arch.sliding_window,
-                chunk_size=arch.chunk_size,
-                sink=p_attn.get("sink") if arch.attention_sink else None,
-                sliding_window_enabled=window_enabled,
-                chunk_enabled=use_rope,
-                logit_softcap=arch.attn_logit_softcap,
-                extra_or_mask=extra_or,
-            )
+        with jax.named_scope("attn.core"):
+            bidir = ci.get("bidir_spans") if ci else None
+            extra_or = None
+            if bidir is not None and S > 1:
+                extra_or = (bidir[:, None, :] == bidir[:, :, None]) & (
+                    bidir[:, :, None] > 0
+                )
+            ctx = None
+            if (
+                arch.attn_kernel_enabled
+                and arch.v_head_dim is None
+                and not arch.attention_sink
+                and arch.attn_logit_softcap is None
+                and window_enabled is None
+                and use_rope is None
+                and extra_or is None
+                and attn_kernels.prefill_kernel_supported(q.shape, k.shape)
+            ):
+                ctx = attn_kernels.sharded_kernel_call(
+                    policy, q, k, v, position_ids, position_ids,
+                    decode=False,
+                    scale=arch.attention_scale,
+                    sliding_window=arch.sliding_window,
+                    chunk_size=arch.chunk_size,
+                )
+            _record_strategy("cte_xla" if ctx is None else "cte_flash_kernel")
+            if ctx is None:
+                ctx = attn_ops.attention_with_positions(
+                    q, k, v, position_ids, position_ids,
+                    scale=arch.attention_scale,
+                    softmax_dtype=jnp.float32,
+                    sliding_window=arch.sliding_window,
+                    chunk_size=arch.chunk_size,
+                    sink=p_attn.get("sink") if arch.attention_sink else None,
+                    sliding_window_enabled=window_enabled,
+                    chunk_enabled=use_rope,
+                    logit_softcap=arch.attn_logit_softcap,
+                    extra_or_mask=extra_or,
+                )
 
     ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * Dv)
     out = _o_proj(ctx)
@@ -991,59 +1011,60 @@ def mlp_block(
     config.py:364,374-375): when set, the input stream is constrained
     S-sharded on entry and the output re-replicates at the residual join —
     GSPMD inserts the scatter/gather pair the reference wires by hand."""
-    if policy.mlp_hidden is not None and x.shape[1] > 1:
-        x = constrain(x, policy.mlp_hidden)
-    if arch.mlp_kernel_enabled:
-        bad = None
+    with jax.named_scope("mlp"):
+        if policy.mlp_hidden is not None and x.shape[1] > 1:
+            x = constrain(x, policy.mlp_hidden)
+        if arch.mlp_kernel_enabled:
+            bad = None
+            if not arch.gated_mlp:
+                bad = "non-gated MLP"
+            elif arch.mlp_bias:
+                bad = "MLP biases"
+            elif adapter_ids is not None:
+                bad = "LoRA adapters"
+            elif mlp_stacked is None and any(
+                "w" not in p_mlp[k] for k in ("gate_proj", "up_proj", "down_proj")
+            ):
+                bad = "quantized weights"
+            if bad is not None:
+                raise NotImplementedError(
+                    f"mlp_kernel_enabled does not support {bad}; disable the flag"
+                )
+            if mlp_stacked is not None:
+                gs, us, ds = mlp_stacked
+                out = attn_kernels.sharded_fused_mlp_stacked_call(
+                    x, gs, us, ds, layer_idx, act=arch.hidden_act
+                )
+            else:
+                out = attn_kernels.sharded_fused_mlp_call(
+                    x,
+                    p_mlp["gate_proj"]["w"],
+                    p_mlp["up_proj"]["w"],
+                    p_mlp["down_proj"]["w"],
+                    act=arch.hidden_act,
+                )
+            if out is None:
+                raise NotImplementedError(
+                    f"mlp_kernel_enabled: MLP shape (act={arch.hidden_act!r}) is "
+                    "not kernel-eligible; disable the flag"
+                )
+            _record_strategy("mlp_fused_kernel")
+            return out
+        aq, ac = arch.act_quant, arch.act_clamp
+        if arch.hidden_act == "xielu":
+            # apertus: per-layer learnable activation scalars ride the scan with
+            # the mlp params (p_mlp["xielu"] = {"alpha_p", "alpha_n"}, f32)
+            a = p_mlp["xielu"]
+            up = xielu(_linear(x, p_mlp["up_proj"], aq, ac, adapter_ids),
+                       a["alpha_p"], a["alpha_n"])
+            return _linear(up, p_mlp["down_proj"], aq, ac, adapter_ids)
+        act = ACT_FNS[arch.hidden_act]
         if not arch.gated_mlp:
-            bad = "non-gated MLP"
-        elif arch.mlp_bias:
-            bad = "MLP biases"
-        elif adapter_ids is not None:
-            bad = "LoRA adapters"
-        elif mlp_stacked is None and any(
-            "w" not in p_mlp[k] for k in ("gate_proj", "up_proj", "down_proj")
-        ):
-            bad = "quantized weights"
-        if bad is not None:
-            raise NotImplementedError(
-                f"mlp_kernel_enabled does not support {bad}; disable the flag"
-            )
-        if mlp_stacked is not None:
-            gs, us, ds = mlp_stacked
-            out = attn_kernels.sharded_fused_mlp_stacked_call(
-                x, gs, us, ds, layer_idx, act=arch.hidden_act
-            )
-        else:
-            out = attn_kernels.sharded_fused_mlp_call(
-                x,
-                p_mlp["gate_proj"]["w"],
-                p_mlp["up_proj"]["w"],
-                p_mlp["down_proj"]["w"],
-                act=arch.hidden_act,
-            )
-        if out is None:
-            raise NotImplementedError(
-                f"mlp_kernel_enabled: MLP shape (act={arch.hidden_act!r}) is "
-                "not kernel-eligible; disable the flag"
-            )
-        _record_strategy("mlp_fused_kernel")
-        return out
-    aq, ac = arch.act_quant, arch.act_clamp
-    if arch.hidden_act == "xielu":
-        # apertus: per-layer learnable activation scalars ride the scan with
-        # the mlp params (p_mlp["xielu"] = {"alpha_p", "alpha_n"}, f32)
-        a = p_mlp["xielu"]
-        up = xielu(_linear(x, p_mlp["up_proj"], aq, ac, adapter_ids),
-                   a["alpha_p"], a["alpha_n"])
-        return _linear(up, p_mlp["down_proj"], aq, ac, adapter_ids)
-    act = ACT_FNS[arch.hidden_act]
-    if not arch.gated_mlp:
-        up = act(_linear(x, p_mlp["up_proj"], aq, ac, adapter_ids))
-        return _linear(up, p_mlp["down_proj"], aq, ac, adapter_ids)
-    gate = act(_linear(x, p_mlp["gate_proj"], aq, ac, adapter_ids))
-    up = _linear(x, p_mlp["up_proj"], aq, ac, adapter_ids)
-    return _linear(gate * up, p_mlp["down_proj"], aq, ac, adapter_ids)
+            up = act(_linear(x, p_mlp["up_proj"], aq, ac, adapter_ids))
+            return _linear(up, p_mlp["down_proj"], aq, ac, adapter_ids)
+        gate = act(_linear(x, p_mlp["gate_proj"], aq, ac, adapter_ids))
+        up = _linear(x, p_mlp["up_proj"], aq, ac, adapter_ids)
+        return _linear(gate * up, p_mlp["down_proj"], aq, ac, adapter_ids)
 
 
 def decoder_layer(
@@ -1788,8 +1809,9 @@ def run_decoder_layers(
                 h = jnp.where(rm > 0, rv.astype(h.dtype), h)
             return h, ((nk, nv, h) if collect_hidden else (nk, nv))
 
-        k_seg = jax.lax.slice_in_dim(cache["k"], off, off + n_seg, axis=0)
-        v_seg = jax.lax.slice_in_dim(cache["v"], off, off + n_seg, axis=0)
+        with jax.named_scope("layers"):
+            k_seg = jax.lax.slice_in_dim(cache["k"], off, off + n_seg, axis=0)
+            v_seg = jax.lax.slice_in_dim(cache["v"], off, off + n_seg, axis=0)
         ksp_seg = vsp_seg = None
         if spec_mode:
             ksp_seg = jax.lax.slice_in_dim(cache["k_spec"], off, off + n_seg, axis=0)
@@ -1813,7 +1835,8 @@ def run_decoder_layers(
         )
         xs = (seg, k_seg, v_seg, ksp_seg, vsp_seg, inj_seg,
               off + jnp.arange(n_seg, dtype=jnp.int32), repl_seg)
-        hidden, ys = jax.lax.scan(body, hidden, xs)
+        with jax.named_scope("layers"):
+            hidden, ys = jax.lax.scan(body, hidden, xs)
         off += n_seg
         if collect_hidden:
             ks.append(ys[0]); vs.append(ys[1]); hs.append(ys[2])
@@ -1832,9 +1855,10 @@ def run_decoder_layers(
     elif defer:
         ci_commit = dict(cache_inputs or {})
         ci_commit["position_ids"] = position_ids
-        new_cache = layout.commit_rows(
-            cache, cat(ks), cat(vs), ci_commit, cache_spec, policy=policy
-        )
+        with jax.named_scope("kv.write"):
+            new_cache = layout.commit_rows(
+                cache, cat(ks), cat(vs), ci_commit, cache_spec, policy=policy
+            )
     else:
         new_cache = {"k": cat(ks), "v": cat(vs)}
     if collect_hidden:
@@ -1905,45 +1929,46 @@ def causal_lm_forward(
     position_ids = batch["position_ids"]
     compute_dtype = to_jax_dtype(arch.dtype)
 
-    hidden = jnp.take(params["embed_tokens"], input_ids, axis=0).astype(compute_dtype)
-    if arch.embed_scale is not None:
-        # gemma scales embeddings by sqrt(hidden) AFTER the dtype downcast
-        # (reference: modeling_gemma3.py:238-241)
-        hidden = hidden * jnp.asarray(arch.embed_scale, compute_dtype)
-    if arch.learned_pos_embeds:
-        hidden = hidden + jnp.take(
-            params["position_embeddings"], position_ids, axis=0
-        ).astype(compute_dtype)
-    if image_token_id is not None and "image_embeds" in batch:
-        # multimodal prefill: replace image-placeholder token embeddings with
-        # the projected vision features, row-local order (reference: the
-        # image-to-text CTE merging vision embeds, image_to_text_model_base.py)
-        img = batch["image_embeds"].astype(compute_dtype)  # (B, N, hidden)
-        is_img = input_ids == image_token_id  # (B, S)
-        idx = jnp.clip(jnp.cumsum(is_img, axis=1) - 1, 0, img.shape[1] - 1)
-        gathered = jnp.take_along_axis(
-            img, idx[:, :, None].astype(jnp.int32), axis=1
-        )
-        hidden = jnp.where(is_img[:, :, None], gathered, hidden)
-    if "fc" in params:
-        # EAGLE draft input: concat(token embedding, previous-position feature)
-        # projected back to the hidden size (reference: the EAGLE draft fc,
-        # modeling_llama.py:1408, fed target hidden states model_base.py:1581).
-        feats = batch["prev_hidden"][:, : input_ids.shape[1]].astype(compute_dtype)
-        hidden = _linear(
-            jnp.concatenate([hidden, feats], axis=-1),
-            params["fc"], arch.act_quant, arch.act_clamp,
-        )
-    if tensor_replacement and "embeds" in tensor_replacement:
-        # tensor replacement (capture in reverse, reference:
-        # utils/tensor_replacement/registry.py): swap the post-embedding
-        # stream for the injected host tensor when its mask is set — one
-        # compiled program serves both plain (zero mask) and replaced runs
-        hidden = jnp.where(
-            batch["tr_embeds_mask"][0] > 0,
-            batch["tr_embeds"].astype(compute_dtype), hidden,
-        )
-    hidden = constrain(hidden, policy.hidden)
+    with jax.named_scope("embed"):
+        hidden = jnp.take(params["embed_tokens"], input_ids, axis=0).astype(compute_dtype)
+        if arch.embed_scale is not None:
+            # gemma scales embeddings by sqrt(hidden) AFTER the dtype downcast
+            # (reference: modeling_gemma3.py:238-241)
+            hidden = hidden * jnp.asarray(arch.embed_scale, compute_dtype)
+        if arch.learned_pos_embeds:
+            hidden = hidden + jnp.take(
+                params["position_embeddings"], position_ids, axis=0
+            ).astype(compute_dtype)
+        if image_token_id is not None and "image_embeds" in batch:
+            # multimodal prefill: replace image-placeholder token embeddings with
+            # the projected vision features, row-local order (reference: the
+            # image-to-text CTE merging vision embeds, image_to_text_model_base.py)
+            img = batch["image_embeds"].astype(compute_dtype)  # (B, N, hidden)
+            is_img = input_ids == image_token_id  # (B, S)
+            idx = jnp.clip(jnp.cumsum(is_img, axis=1) - 1, 0, img.shape[1] - 1)
+            gathered = jnp.take_along_axis(
+                img, idx[:, :, None].astype(jnp.int32), axis=1
+            )
+            hidden = jnp.where(is_img[:, :, None], gathered, hidden)
+        if "fc" in params:
+            # EAGLE draft input: concat(token embedding, previous-position feature)
+            # projected back to the hidden size (reference: the EAGLE draft fc,
+            # modeling_llama.py:1408, fed target hidden states model_base.py:1581).
+            feats = batch["prev_hidden"][:, : input_ids.shape[1]].astype(compute_dtype)
+            hidden = _linear(
+                jnp.concatenate([hidden, feats], axis=-1),
+                params["fc"], arch.act_quant, arch.act_clamp,
+            )
+        if tensor_replacement and "embeds" in tensor_replacement:
+            # tensor replacement (capture in reverse, reference:
+            # utils/tensor_replacement/registry.py): swap the post-embedding
+            # stream for the injected host tensor when its mask is set — one
+            # compiled program serves both plain (zero mask) and replaced runs
+            hidden = jnp.where(
+                batch["tr_embeds_mask"][0] > 0,
+                batch["tr_embeds"].astype(compute_dtype), hidden,
+            )
+        hidden = constrain(hidden, policy.hidden)
     inv_freq = np.asarray(inv_freq)
     if arch.mrope_section is not None and "mrope_position_ids" in batch:
         from nxdi_tpu.ops.rope import mrope_cos_sin
@@ -2096,35 +2121,37 @@ def causal_lm_forward(
         )
         hidden = constrain(hidden, policy.hidden)
     pre_norm_hidden = hidden
-    if "norm" in params:  # EAGLE drafts have no final norm
-        hidden = _norm(arch, hidden, params["norm"])
+    with jax.named_scope("final_norm"):
+        if "norm" in params:  # EAGLE drafts have no final norm
+            hidden = _norm(arch, hidden, params["norm"])
 
-    lm_head = params.get("lm_head")
-    if lm_head is None:  # tied embeddings
-        lm_head = jnp.swapaxes(params["embed_tokens"], 0, 1)
+    with jax.named_scope("lm_head"):
+        lm_head = params.get("lm_head")
+        if lm_head is None:  # tied embeddings
+            lm_head = jnp.swapaxes(params["embed_tokens"], 0, 1)
 
-    if mixed_rows:
-        # packed mixed stream: gather each ROW's newest token off the single
-        # packed batch row — everything below (lm_head, stats, sampling)
-        # sees (R, 1, hidden) exactly like an R-row decode batch
-        idx = batch["last_token_index"].astype(jnp.int32)  # (R,)
-        hidden = jnp.take(hidden[0], idx, axis=0)[:, None, :]
-    elif gather_last_token and not output_all_logits:
-        idx = batch["last_token_index"][:, None, None]  # (B,1,1)
-        hidden = jnp.take_along_axis(
-            hidden, jnp.broadcast_to(idx, (hidden.shape[0], 1, hidden.shape[2])), axis=1
-        )  # (B, 1, hidden)
+        if mixed_rows:
+            # packed mixed stream: gather each ROW's newest token off the single
+            # packed batch row — everything below (lm_head, stats, sampling)
+            # sees (R, 1, hidden) exactly like an R-row decode batch
+            idx = batch["last_token_index"].astype(jnp.int32)  # (R,)
+            hidden = jnp.take(hidden[0], idx, axis=0)[:, None, :]
+        elif gather_last_token and not output_all_logits:
+            idx = batch["last_token_index"][:, None, None]  # (B,1,1)
+            hidden = jnp.take_along_axis(
+                hidden, jnp.broadcast_to(idx, (hidden.shape[0], 1, hidden.shape[2])), axis=1
+            )  # (B, 1, hidden)
 
-    logits = (hidden @ lm_head.astype(hidden.dtype)).astype(jnp.float32)
-    if "lm_head_bias" in params:  # phi lineage: biased lm_head
-        logits = logits + params["lm_head_bias"].astype(jnp.float32)
-    if arch.logits_scaling != 1.0:
-        logits = logits / arch.logits_scaling
-    if arch.final_logit_softcap is not None:
-        cap = arch.final_logit_softcap
-        logits = cap * jnp.tanh(logits / cap)
-    logits = constrain(logits, policy.logits)
-    logits = sampling_ops.mask_padded_logits(logits, arch.vocab_pad)
+        logits = (hidden @ lm_head.astype(hidden.dtype)).astype(jnp.float32)
+        if "lm_head_bias" in params:  # phi lineage: biased lm_head
+            logits = logits + params["lm_head_bias"].astype(jnp.float32)
+        if arch.logits_scaling != 1.0:
+            logits = logits / arch.logits_scaling
+        if arch.final_logit_softcap is not None:
+            cap = arch.final_logit_softcap
+            logits = cap * jnp.tanh(logits / cap)
+        logits = constrain(logits, policy.logits)
+        logits = sampling_ops.mask_padded_logits(logits, arch.vocab_pad)
 
     outputs: Dict[str, jax.Array] = {}
     if tensor_capture:
@@ -2159,22 +2186,23 @@ def causal_lm_forward(
         # in-graph — the full-vocab fp32 logits never cross the program
         # boundary, the accept/gather logic downstream runs on (B, S) tokens
         outputs["tokens"] = sampling_ops.greedy_sample(logits)
-    if on_device_sampling:
-        sample_in = last_logits[:, -1, :]
-        if dp_sampling:
-            # DataParallelSampler analog (reference: sampling.py:469-569):
-            # batch rows shard over the tp world for the top-k stages; GSPMD
-            # gathers the sampled tokens
-            sample_in = constrain(sample_in, P(AXIS_MP, None))
-        tokens = sampling_ops.sample(
-            sample_in,
-            batch["sampling_params"],
-            rng=batch.get("rng"),
-            do_sample=do_sample,
-            global_topk=global_topk,
-            deterministic=deterministic,
-        )
-        outputs["tokens"] = tokens[:, None]  # (B, 1)
+    with jax.named_scope("sample"):
+        if on_device_sampling:
+            sample_in = last_logits[:, -1, :]
+            if dp_sampling:
+                # DataParallelSampler analog (reference: sampling.py:469-569):
+                # batch rows shard over the tp world for the top-k stages; GSPMD
+                # gathers the sampled tokens
+                sample_in = constrain(sample_in, P(AXIS_MP, None))
+            tokens = sampling_ops.sample(
+                sample_in,
+                batch["sampling_params"],
+                rng=batch.get("rng"),
+                do_sample=do_sample,
+                global_topk=global_topk,
+                deterministic=deterministic,
+            )
+            outputs["tokens"] = tokens[:, None]  # (B, 1)
     if output_logits or output_all_logits or (
         not on_device_sampling and not output_argmax_all
     ):

@@ -233,6 +233,7 @@ def flash_attention_prefill(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
+        name="flash_attention_prefill",
         interpret=mode.interpret(),
     )(q_start, kv_start, qf, kf, vf)
     return out.reshape(B, H, Sq, D)
@@ -336,6 +337,7 @@ def flash_attention_decode(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, G, D), q.dtype),
+        name="flash_attention_decode",
         interpret=mode.interpret(),
     )(q_start, kv_start, qf, kf, vf)
     return out.reshape(B, KV, G, D).reshape(B, H, 1, D).astype(q.dtype)
@@ -508,6 +510,7 @@ def flash_attention_decode_fused_stacked(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, G, D), q.dtype),
+        name="flash_attention_decode_fused_stacked",
         interpret=mode.interpret(),
     )(li, q_start, kv_start, qf, kf, vf, knf, vnf)
     return out.reshape(B, KV, G, D).reshape(B, H, 1, D).astype(q.dtype)
@@ -628,6 +631,7 @@ def flash_attention_decode_fused(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, G, D), q.dtype),
+        name="flash_attention_decode_fused",
         interpret=mode.interpret(),
     )(q_start, kv_start, qf, kf, vf, knf, vnf)
     return out.reshape(B, KV, G, D).reshape(B, H, 1, D).astype(q.dtype)
@@ -801,6 +805,7 @@ def paged_attention_decode(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
+        name="paged_attention_decode",
         interpret=mode.interpret(),
     )(bt, qp, qf, k_cache, v_cache)
     return out.reshape(B, H, 1, D)
@@ -945,6 +950,7 @@ def paged_attention_prefill(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, Sq, D), q.dtype),
+        name="paged_attention_prefill",
         interpret=mode.interpret(),
     )(bt, qs, qf, k_cache, v_cache)
     return out.reshape(B, H, Sq, D)

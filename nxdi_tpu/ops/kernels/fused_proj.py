@@ -139,6 +139,7 @@ def fused_mlp(
         out_specs=pl.BlockSpec((bm, H), lambda m, i: (m, 0)),
         out_shape=jax.ShapeDtypeStruct((M, H), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, H), jnp.float32)],
+        name="fused_mlp",
         interpret=mode.interpret(),
     )(x, gate_w, up_w, down_w)
 
@@ -248,6 +249,7 @@ def fused_mlp_stacked(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, H), x.dtype),
+        name="fused_mlp_stacked",
         interpret=mode.interpret(),
     )(layer_idx.astype(jnp.int32), x, gate_s, up_s, down_s)
 
@@ -329,6 +331,7 @@ def qkv_matmul_stacked(
         _qkv_stacked_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, T), x.dtype),
+        name="qkv_matmul_stacked",
         interpret=mode.interpret(),
     )(layer_idx.astype(jnp.int32), x, w_s)
     if b_s is not None:
@@ -412,6 +415,7 @@ def qkv_matmul(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda m, n: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, T), x.dtype),
+        name="qkv_matmul",
         interpret=mode.interpret(),
     )(x, w)
     if b is not None:

@@ -174,6 +174,7 @@ def kv_commit_rows(
         ],
         # inputs are (slots, lines, k_rows, v_rows, k_cache, v_cache)
         input_output_aliases={4: 0, 5: 1},
+        name="kv_commit_rows",
         interpret=mode.interpret(),
     )(slots, lines, kr_t, vr_t, k_t, v_t)
     return jnp.swapaxes(out_k, 3, 4), jnp.swapaxes(out_v, 3, 4)

@@ -200,6 +200,7 @@ def ragged_paged_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, KV, G, T, D), q.dtype),
+        name="ragged_paged_attention",
         interpret=mode.interpret(),
     )(bt, tile_min, tile_max, rid[:, None], qp[:, None], qf, k_cache, v_cache)
     return out.reshape(1, H, T, D)

@@ -19,6 +19,7 @@ from __future__ import annotations
 import contextlib
 import itertools
 import logging
+import re
 import secrets
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence
@@ -110,20 +111,42 @@ def kv_layout_from_config(tc, arch=None):
 # of the key), in a window in which nothing is written: the layout-changing
 # identity below, and every step program over the block KV layout
 # (_AutoLayoutProgram(persist=False)); they cost their compile in each process.
+# The name still says what the program is: ``<label>__<process token>_<n>``,
+# e.g. ``token_generation_model_4096__3fa9c2d41b07_2``, is the XLA module
+# ``jit_token_generation_model_4096__...`` in a profiler trace.
 _PROCESS_TOKEN = secrets.token_hex(6)
 _UNCACHED_NAMES = itertools.count()
 
 
+def _sanitised(label: str) -> str:
+    """``token_generation_model[4096]`` -> ``token_generation_model_4096``:
+    what of a label a function (and so an XLA module) name may carry."""
+    return re.sub(r"[^A-Za-z0-9]+", "_", label).strip("_")
+
+
 @contextlib.contextmanager
-def _outside_the_persistent_cache():
-    """Yields a program name no persistent-cache entry has or will have;
-    what compiles inside the block is not written to the cache either."""
+def _outside_the_persistent_cache(label: str):
+    """Yields a program name that starts with the sanitised ``label`` and
+    that no persistent-cache entry has or will have; what compiles inside
+    the block is not written to the cache either.
+
+    Inside the block JAX keeps whole tracebacks in the program's locations
+    (its own default). ``enable_persistent_cache`` turns that off so that a
+    cached program's key does not depend on who called ``compile()`` — and
+    with it off JAX 0.9.0 also drops the name stack from every operation:
+    ``jax.named_scope`` regions and a Pallas kernel's ``name=`` never reach
+    the HLO (the paged decode kernel is the instruction ``tpu_custom_call.3``
+    in a trace, not ``paged_attention_decode.3``). A program that no cache
+    entry will ever hold has no key to keep stable, so it keeps its names."""
     was = jax.config.jax_persistent_cache_min_compile_time_secs
+    tracebacks = jax.config.jax_include_full_tracebacks_in_locations
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1e9)
+    jax.config.update("jax_include_full_tracebacks_in_locations", True)
     try:
-        yield f"{_PROCESS_TOKEN}_{next(_UNCACHED_NAMES)}"
+        yield f"{_sanitised(label)}__{_PROCESS_TOKEN}_{next(_UNCACHED_NAMES)}"
     finally:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", was)
+        jax.config.update("jax_include_full_tracebacks_in_locations", tracebacks)
 
 
 def _named(fn, name: str):
@@ -144,9 +167,10 @@ def _relayout(a, fmt):
     key = (a.format, fmt, a.shape, a.dtype)
     move = _RELAYOUTS.get(key)
     if move is None:
-        with _outside_the_persistent_cache() as name:
+        what = f"relayout_{a.dtype}_{'x'.join(map(str, a.shape))}"
+        with _outside_the_persistent_cache(what) as name:
             move = _RELAYOUTS[key] = (
-                jax.jit(_named(lambda x: x, f"relayout_{name}"), out_shardings=fmt)
+                jax.jit(_named(lambda x: x, name), out_shardings=fmt)
                 .lower(a)
                 .compile()
             )
@@ -222,7 +246,7 @@ class _AutoLayoutProgram:
         wrong (``persist=False``)."""
         if self.persist:
             return self._lower(*args).compile()
-        with _outside_the_persistent_cache() as name:
+        with _outside_the_persistent_cache(self.label) as name:
             self.jitted = jax.jit(_named(self._fn, name), **self._jit_kwargs)
             return self._lower(*args).compile()
 
@@ -246,6 +270,9 @@ class _AutoLayoutProgram:
         cache = jax.tree_util.tree_unflatten(treedef, moved)
         return self._compiled(params, cache, batch)
 
+
+#: Telemetry.phase of a wrapper with no (or disabled) telemetry
+_NO_PHASE = contextlib.nullcontext()
 
 TAG_CONTEXT_ENCODING = "context_encoding_model"
 TAG_TOKEN_GENERATION = "token_generation_model"
@@ -590,6 +617,7 @@ class ModelWrapper:
         Returns (outputs, new_cache) with outputs still on device (async).
         """
         tel = self.telemetry
+        phase = self._phase
         if tel is not None and tel.enabled:
             _t0 = tel.clock()
         else:
@@ -599,131 +627,142 @@ class ModelWrapper:
             # for the watchdog + step-recovery machinery. Fires BEFORE any
             # KV write lands, so a retried dispatch replays identically.
             faults.fire(faults.SITE_DISPATCH, self.telemetry)
-        input_ids = np.asarray(batch_np["input_ids"], dtype=np.int32)
-        position_ids = np.asarray(batch_np["position_ids"], dtype=np.int32)
-        b, s = input_ids.shape
+        with phase("pad"):
+            input_ids = np.asarray(batch_np["input_ids"], dtype=np.int32)
+            position_ids = np.asarray(batch_np["position_ids"], dtype=np.int32)
+            b, s = input_ids.shape
 
-        if self.attend_to_cache and not self.prefill_to_cache:
-            if s != self.n_active_tokens:
-                raise ValueError(
-                    f"{self.tag}: expected {self.n_active_tokens} active tokens, got {s}"
-                )
-            length = int(position_ids.max()) + 1
-            # real overflow must still raise loudly in select_bucket; only the
-            # speculative lookahead may be clamped to the largest bucket
-            # (overshooting writes are dropped and the host discards their tokens)
-            if length <= self.buckets[-1]:
-                length = min(length + self.lookahead, self.buckets[-1])
-            bucket = self.select_bucket(length)
-            pad_s = s
-        else:
-            bucket = self.select_bucket(s)
-            pad_s = bucket
-
-        # pad sequence dim (right padding; pad positions continue arange so
-        # their garbage KV lands at future positions that decode overwrites)
-        if pad_s > s:
-            pad_ids = np.zeros((b, pad_s - s), dtype=np.int32)
-            last_pos = position_ids[:, -1:]
-            pad_pos = last_pos + np.arange(1, pad_s - s + 1, dtype=np.int32)[None, :]
-            input_ids = np.concatenate([input_ids, pad_ids], axis=1)
-            position_ids = np.concatenate([position_ids, pad_pos], axis=1)
-
-        last_token_index = np.asarray(
-            batch_np.get("last_token_index", np.full((b,), s - 1)), dtype=np.int32
-        )
-        sampling_params = np.asarray(
-            batch_np.get("sampling_params", np.tile([1.0, 1.0, 1.0], (b, 1))),
-            dtype=np.float32,
-        )
-        extra = self._layout_inputs(batch_np, b, s, pad_s, position_ids)
-        if self.lora_enabled:
-            extra["adapter_ids"] = np.asarray(
-                batch_np.get("adapter_ids", np.zeros((b,))), dtype=np.int32
-            )
-        seq_now = (
-            self.n_active_tokens
-            if self.attend_to_cache and not self.prefill_to_cache
-            else pad_s
-        )
-        for key, (shape, dtype) in self.extra_inputs.items():
-            nd = np.dtype(dtype)
-            shape = tuple(seq_now if d == -1 else d for d in shape)
-            val = batch_np.get(key)
-            if val is None:
-                val = np.zeros((b,) + tuple(shape), dtype=nd)
+            if self.attend_to_cache and not self.prefill_to_cache:
+                if s != self.n_active_tokens:
+                    raise ValueError(
+                        f"{self.tag}: expected {self.n_active_tokens} active tokens, got {s}"
+                    )
+                length = int(position_ids.max()) + 1
+                # real overflow must still raise loudly in select_bucket; only the
+                # speculative lookahead may be clamped to the largest bucket
+                # (overshooting writes are dropped and the host discards their tokens)
+                if length <= self.buckets[-1]:
+                    length = min(length + self.lookahead, self.buckets[-1])
+                bucket = self.select_bucket(length)
+                pad_s = s
             else:
-                val = np.asarray(val, dtype=nd)
-                # right-pad any short dim up to the compiled shape (seq dims
-                # grow with the bucket; replacement masks make pads inert)
-                pads = [(0, 0)] + [
-                    (0, t - s) for t, s in zip(shape, val.shape[1:])
-                ]
-                if any(p[1] for p in pads):
-                    val = np.pad(val, pads)
-            extra[key] = np.asarray(val, dtype=nd)
+                bucket = self.select_bucket(s)
+                pad_s = bucket
 
-        # pad batch dim (reference: _forward_with_pad model_wrapper.py:569)
-        orig_b = b
-        if b < self.batch_size:
-            input_ids = pad_with_first_batchline(input_ids, self.batch_size)
-            position_ids = pad_with_first_batchline(position_ids, self.batch_size)
-            last_token_index = pad_with_first_batchline(last_token_index, self.batch_size)
-            sampling_params = pad_with_first_batchline(sampling_params, self.batch_size)
-            extra = {
-                k: pad_with_first_batchline(v, self.batch_size) for k, v in extra.items()
-            }
-        elif b > self.batch_size:
-            raise ValueError(f"{self.tag}: batch {b} exceeds compiled batch {self.batch_size}")
+            # pad sequence dim (right padding; pad positions continue arange so
+            # their garbage KV lands at future positions that decode overwrites)
+            if pad_s > s:
+                pad_ids = np.zeros((b, pad_s - s), dtype=np.int32)
+                last_pos = position_ids[:, -1:]
+                pad_pos = last_pos + np.arange(1, pad_s - s + 1, dtype=np.int32)[None, :]
+                input_ids = np.concatenate([input_ids, pad_ids], axis=1)
+                position_ids = np.concatenate([position_ids, pad_pos], axis=1)
 
-        device_batch = {
-            "input_ids": jnp.asarray(input_ids),
-            "position_ids": jnp.asarray(position_ids),
-            "last_token_index": jnp.asarray(last_token_index),
-            "sampling_params": jnp.asarray(sampling_params),
-        }
-        device_batch.update({k: jnp.asarray(v) for k, v in extra.items()})
-        if self.needs_rng:
-            rng = batch_np.get("rng")
-            if rng is None:
-                rng = np.zeros((2,), dtype=np.uint32)
-            device_batch["rng"] = jnp.asarray(rng, dtype=jnp.uint32)
-        if self.snapshot_hook is not None:
-            snap = {
-                "input_ids": input_ids,
-                "position_ids": position_ids,
-                "last_token_index": last_token_index,
-                "sampling_params": sampling_params,
-                **extra,
-            }
-            self.snapshot_hook(self.tag, snap)
-        for hook in self.pre_hooks:
-            hook(self.tag)
-        # dispatch under this app's mesh: several apps with different meshes
-        # can coexist in one process (the reference runs draft+target or
-        # encoder+decoder apps side by side the same way)
-        with jax.set_mesh(self._mesh):
-            outputs, new_cache = self._run_program(bucket, params, cache, device_batch)
-        if self.post_hooks:
-            jax.block_until_ready(outputs)
-            for hook in self.post_hooks:
-                hook(self.tag)
-        if tel is not None:
-            if tel.sync_dispatch and not self.post_hooks:
-                jax.block_until_ready(outputs)
-            tel.record_dispatch(
-                self.tag, bucket, self._telemetry_steps(),
-                tel.clock() - _t0,
-                real_tokens=orig_b * s,
-                padded_tokens=self.batch_size * pad_s,
+            last_token_index = np.asarray(
+                batch_np.get("last_token_index", np.full((b,), s - 1)), dtype=np.int32
             )
-        outputs = self._slice_batch_padding(outputs, orig_b)
+            sampling_params = np.asarray(
+                batch_np.get("sampling_params", np.tile([1.0, 1.0, 1.0], (b, 1))),
+                dtype=np.float32,
+            )
+            extra = self._layout_inputs(batch_np, b, s, pad_s, position_ids)
+            if self.lora_enabled:
+                extra["adapter_ids"] = np.asarray(
+                    batch_np.get("adapter_ids", np.zeros((b,))), dtype=np.int32
+                )
+            seq_now = (
+                self.n_active_tokens
+                if self.attend_to_cache and not self.prefill_to_cache
+                else pad_s
+            )
+            for key, (shape, dtype) in self.extra_inputs.items():
+                nd = np.dtype(dtype)
+                shape = tuple(seq_now if d == -1 else d for d in shape)
+                val = batch_np.get(key)
+                if val is None:
+                    val = np.zeros((b,) + tuple(shape), dtype=nd)
+                else:
+                    val = np.asarray(val, dtype=nd)
+                    # right-pad any short dim up to the compiled shape (seq dims
+                    # grow with the bucket; replacement masks make pads inert)
+                    pads = [(0, 0)] + [
+                        (0, t - s) for t, s in zip(shape, val.shape[1:])
+                    ]
+                    if any(p[1] for p in pads):
+                        val = np.pad(val, pads)
+                extra[key] = np.asarray(val, dtype=nd)
+
+            # pad batch dim (reference: _forward_with_pad model_wrapper.py:569)
+            orig_b = b
+            if b < self.batch_size:
+                input_ids = pad_with_first_batchline(input_ids, self.batch_size)
+                position_ids = pad_with_first_batchline(position_ids, self.batch_size)
+                last_token_index = pad_with_first_batchline(last_token_index, self.batch_size)
+                sampling_params = pad_with_first_batchline(sampling_params, self.batch_size)
+                extra = {
+                    k: pad_with_first_batchline(v, self.batch_size) for k, v in extra.items()
+                }
+            elif b > self.batch_size:
+                raise ValueError(f"{self.tag}: batch {b} exceeds compiled batch {self.batch_size}")
+
+        with phase("enqueue"):
+            device_batch = {
+                "input_ids": jnp.asarray(input_ids),
+                "position_ids": jnp.asarray(position_ids),
+                "last_token_index": jnp.asarray(last_token_index),
+                "sampling_params": jnp.asarray(sampling_params),
+            }
+            device_batch.update({k: jnp.asarray(v) for k, v in extra.items()})
+            if self.needs_rng:
+                rng = batch_np.get("rng")
+                if rng is None:
+                    rng = np.zeros((2,), dtype=np.uint32)
+                device_batch["rng"] = jnp.asarray(rng, dtype=jnp.uint32)
+            if self.snapshot_hook is not None:
+                snap = {
+                    "input_ids": input_ids,
+                    "position_ids": position_ids,
+                    "last_token_index": last_token_index,
+                    "sampling_params": sampling_params,
+                    **extra,
+                }
+                self.snapshot_hook(self.tag, snap)
+            for hook in self.pre_hooks:
+                hook(self.tag)
+            # dispatch under this app's mesh: several apps with different meshes
+            # can coexist in one process (the reference runs draft+target or
+            # encoder+decoder apps side by side the same way)
+            with jax.set_mesh(self._mesh):
+                outputs, new_cache = self._run_program(bucket, params, cache, device_batch)
+            if self.post_hooks or (tel is not None and tel.sync_dispatch):
+                # the one place a dispatch waits for the device: the engine
+                # step's ``fetch`` phase, wherever it is entered (a phase
+                # opened inside another stops the outer one's count)
+                with phase("fetch"):
+                    jax.block_until_ready(outputs)
+                for hook in self.post_hooks:
+                    hook(self.tag)
+            if tel is not None:
+                tel.record_dispatch(
+                    self.tag, bucket, self._telemetry_steps(),
+                    tel.clock() - _t0,
+                    real_tokens=orig_b * s,
+                    padded_tokens=self.batch_size * pad_s,
+                )
+            outputs = self._slice_batch_padding(outputs, orig_b)
         if tel is not None and tel.sentinel is not None and "logit_stats" in outputs:
             # numerics sentinel: the compiled-in (B, 5) health readout is
             # recorded AFTER batch-padding rows are sliced away (padding
             # repeats row 0 — double-counting it would skew the series)
             tel.sentinel.observe(self.tag, bucket, outputs["logit_stats"])
         return outputs, new_cache
+
+    def _phase(self, name: str):
+        """``Telemetry.phase`` of this wrapper's telemetry: ``pad`` is bucket
+        choice and numpy padding up to the first ``jnp.asarray``, ``enqueue``
+        the host-to-device puts, the program call and the output slice."""
+        tel = self.telemetry
+        return _NO_PHASE if tel is None else tel.phase(name)
 
     def _slice_batch_padding(self, outputs, orig_b: int):
         """Drop batch-padding rows from per-row outputs. The mixed wrapper
@@ -832,7 +871,7 @@ class ModelWrapper:
         tel = self.telemetry
         if tel is not None and tel.enabled:
             t0 = tel.clock()
-            with jax.set_mesh(self._mesh):
+            with tel.phase("enqueue"), jax.set_mesh(self._mesh):
                 out = self._run_program(bucket, params, cache, device_batch)
             tel.record_dispatch(
                 self.tag, bucket, self._telemetry_steps(), tel.clock() - t0
@@ -932,24 +971,25 @@ class MultiStepTKGWrapper(ModelWrapper):
         return autobucketing.get_target_steps(remaining, self.steps_ladder)
 
     def forward(self, params, cache, batch_np):
-        batch_np = dict(batch_np)
-        steps = int(batch_np.pop("decode_steps", self.max_steps))
-        if steps not in self.steps_ladder:
-            raise ValueError(
-                f"{self.tag}: decode_steps {steps} is not a compiled rung "
-                f"({self.steps_ladder})"
+        with self._phase("pad"):
+            batch_np = dict(batch_np)
+            steps = int(batch_np.pop("decode_steps", self.max_steps))
+            if steps not in self.steps_ladder:
+                raise ValueError(
+                    f"{self.tag}: decode_steps {steps} is not a compiled rung "
+                    f"({self.steps_ladder})"
+                )
+            self._steps_hint = steps
+            b = np.asarray(batch_np["input_ids"]).shape[0]
+            if "eos_token_ids" not in batch_np:
+                batch_np["eos_token_ids"] = np.full(
+                    (b, MULTISTEP_EOS_SLOTS), -1, dtype=np.int32
+                )
+            if "pad_token_id" not in batch_np:
+                batch_np["pad_token_id"] = np.zeros((b,), dtype=np.int32)
+            batch_np["budget_steps"] = _pad_budget_rows(
+                batch_np.get("budget_steps"), b, self.batch_size
             )
-        self._steps_hint = steps
-        b = np.asarray(batch_np["input_ids"]).shape[0]
-        if "eos_token_ids" not in batch_np:
-            batch_np["eos_token_ids"] = np.full(
-                (b, MULTISTEP_EOS_SLOTS), -1, dtype=np.int32
-            )
-        if "pad_token_id" not in batch_np:
-            batch_np["pad_token_id"] = np.zeros((b,), dtype=np.int32)
-        batch_np["budget_steps"] = _pad_budget_rows(
-            batch_np.get("budget_steps"), b, self.batch_size
-        )
         return super().forward(params, cache, batch_np)
 
     def _run_program(self, bucket, params, cache, device_batch):
@@ -1086,47 +1126,48 @@ class DeviceLoopTKGWrapper(ModelWrapper):
         return autobucketing.get_target_steps(max_budget, self.cap_ladder)
 
     def forward(self, params, cache, batch_np):
-        batch_np = dict(batch_np)
-        b = np.asarray(batch_np["input_ids"]).shape[0]
-        if "eos_token_ids" not in batch_np:
-            batch_np["eos_token_ids"] = np.full(
-                (b, MULTISTEP_EOS_SLOTS), -1, dtype=np.int32
+        with self._phase("pad"):
+            batch_np = dict(batch_np)
+            b = np.asarray(batch_np["input_ids"]).shape[0]
+            if "eos_token_ids" not in batch_np:
+                batch_np["eos_token_ids"] = np.full(
+                    (b, MULTISTEP_EOS_SLOTS), -1, dtype=np.int32
+                )
+            if "pad_token_id" not in batch_np:
+                batch_np["pad_token_id"] = np.zeros((b,), dtype=np.int32)
+            real_budget = np.asarray(
+                batch_np.get("budget_steps", np.zeros((b,), np.int32)),
+                dtype=np.int32,
             )
-        if "pad_token_id" not in batch_np:
-            batch_np["pad_token_id"] = np.zeros((b,), dtype=np.int32)
-        real_budget = np.asarray(
-            batch_np.get("budget_steps", np.zeros((b,), np.int32)),
-            dtype=np.int32,
-        )
-        cap = batch_np.pop("loop_cap", None)
-        if cap is None:
-            # smallest rung covering the largest per-row ask; an unlimited
-            # (<= 0) budget asks for the full ladder
-            max_ask = (
-                int(real_budget.max(initial=0))
-                if (real_budget > 0).all() and real_budget.size
-                else self.max_cap
+            cap = batch_np.pop("loop_cap", None)
+            if cap is None:
+                # smallest rung covering the largest per-row ask; an unlimited
+                # (<= 0) budget asks for the full ladder
+                max_ask = (
+                    int(real_budget.max(initial=0))
+                    if (real_budget > 0).all() and real_budget.size
+                    else self.max_cap
+                )
+                cap = self.select_cap(max_ask)
+            cap = int(cap)
+            if cap not in self.cap_ladder:
+                raise ValueError(
+                    f"{self.tag}: loop_cap {cap} is not a compiled rung "
+                    f"({self.cap_ladder})"
+                )
+            self._cap_hint = cap
+            batch_np["budget_steps"] = _pad_budget_rows(
+                real_budget, b, self.batch_size
             )
-            cap = self.select_cap(max_ask)
-        cap = int(cap)
-        if cap not in self.cap_ladder:
-            raise ValueError(
-                f"{self.tag}: loop_cap {cap} is not a compiled rung "
-                f"({self.cap_ladder})"
-            )
-        self._cap_hint = cap
-        batch_np["budget_steps"] = _pad_budget_rows(
-            real_budget, b, self.batch_size
-        )
-        # per-row last write position p_i + min(budget_i, cap) sizes the KV
-        # bucket; the base forward adds `lookahead` to pos.max()+1, so feed
-        # it the gap between that and the loop's true reach
-        pos = np.asarray(batch_np["position_ids"], dtype=np.int32)
-        p_last = pos.max(axis=1)  # (b,)
-        m = np.where(real_budget > 0, np.minimum(real_budget, cap), cap)
-        needed = int((p_last + m).max()) if b else cap
-        self.lookahead = max(needed - (int(pos.max()) + 1), 0)
-        self._outfeed_ring.clear()
+            # per-row last write position p_i + min(budget_i, cap) sizes the KV
+            # bucket; the base forward adds `lookahead` to pos.max()+1, so feed
+            # it the gap between that and the loop's true reach
+            pos = np.asarray(batch_np["position_ids"], dtype=np.int32)
+            p_last = pos.max(axis=1)  # (b,)
+            m = np.where(real_budget > 0, np.minimum(real_budget, cap), cap)
+            needed = int((p_last + m).max()) if b else cap
+            self.lookahead = max(needed - (int(pos.max()) + 1), 0)
+            self._outfeed_ring.clear()
         return super().forward(params, cache, batch_np)
 
     def _run_program(self, bucket, params, cache, device_batch):
@@ -1188,27 +1229,28 @@ class MixedModelWrapper(ModelWrapper):
         return batch
 
     def forward(self, params, cache, batch_np):
-        batch_np = dict(batch_np)
-        if "slot_mapping" not in batch_np:
-            # the base derive path maps position -> combined-table entry,
-            # which aliases every row onto row 0's pages — never legal here
-            raise ValueError(
-                f"{self.tag}: mixed dispatch requires a host-computed "
-                "slot_mapping (per-token, through each row's own table)"
-            )
-        s = int(np.asarray(batch_np["input_ids"]).shape[1])
-        bucket = self.select_bucket(s)
-        # pre-pad the row tags with -1 BEFORE the generic extra-input pad:
-        # np.pad's zero fill would tag padding tokens as row 0
-        rids = np.asarray(batch_np["mixed_row_ids"], dtype=np.int32)
-        if rids.ndim == 1:
-            rids = rids[None, :]
-        if rids.shape[1] < bucket:
-            rids = np.concatenate(
-                [rids, np.full((rids.shape[0], bucket - rids.shape[1]), -1, np.int32)],
-                axis=1,
-            )
-        batch_np["mixed_row_ids"] = rids
+        with self._phase("pad"):
+            batch_np = dict(batch_np)
+            if "slot_mapping" not in batch_np:
+                # the base derive path maps position -> combined-table entry,
+                # which aliases every row onto row 0's pages — never legal here
+                raise ValueError(
+                    f"{self.tag}: mixed dispatch requires a host-computed "
+                    "slot_mapping (per-token, through each row's own table)"
+                )
+            s = int(np.asarray(batch_np["input_ids"]).shape[1])
+            bucket = self.select_bucket(s)
+            # pre-pad the row tags with -1 BEFORE the generic extra-input pad:
+            # np.pad's zero fill would tag padding tokens as row 0
+            rids = np.asarray(batch_np["mixed_row_ids"], dtype=np.int32)
+            if rids.ndim == 1:
+                rids = rids[None, :]
+            if rids.shape[1] < bucket:
+                rids = np.concatenate(
+                    [rids, np.full((rids.shape[0], bucket - rids.shape[1]), -1, np.int32)],
+                    axis=1,
+                )
+            batch_np["mixed_row_ids"] = rids
         out = super().forward(params, cache, batch_np)
         tel = self.telemetry
         if tel is not None and tel.enabled:

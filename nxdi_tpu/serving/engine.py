@@ -36,7 +36,9 @@ counter, and one request span per request covering
 queue -> prefill -> decode with TTFT measured from arrival (under load it
 includes queueing, as a serving TTFT should). On top of that the engine
 owns a flight recorder (``telemetry/flight.py``: one StepRecord per
-``step()`` with the host-vs-dispatch time split, postmortem bundles on
+``step()`` with the step's time split by phase — schedule, kv, pack, pad,
+enqueue, fetch, emit; ``Telemetry.phase`` opens each where the work happens,
+as a ``nxdi.step.<phase>`` span of the profiler too — postmortem bundles on
 SLO breach / preemption storm / retrace trip) and, when
 ``TpuConfig(slo=...)`` declares targets, an SLO tracker
 (``telemetry/slo.py``: rolling attainment + SLO-conditioned goodput).
@@ -44,6 +46,7 @@ SLO breach / preemption storm / retrace trip) and, when
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 import time
@@ -87,6 +90,8 @@ logger = logging.getLogger("nxdi_tpu")
 #: an error finish whose message starts with this is a replica-side crash
 #: the router retries elsewhere — a validation rejection is not
 ENGINE_FAULT_PREFIX = "engine step failed"
+
+_NO_SPAN = contextlib.nullcontext()
 
 
 class InferenceEngine:
@@ -145,6 +150,10 @@ class InferenceEngine:
             )
         self.telemetry = getattr(app, "telemetry", None)
         tel = self.telemetry if (self.telemetry and self.telemetry.enabled) else None
+        # Telemetry.phase: the phases of a step, each opened where its work
+        # happens (here: schedule, kv, pack, fetch, emit; the wrappers: pad,
+        # enqueue); the shared null context when telemetry is off
+        self._phase = tel.phase if tel is not None else (lambda _name: _NO_SPAN)
 
         # work on a copy: the resolved chunk_size below must not mutate a
         # caller-owned config (the Scheduler re-copies for the same reason)
@@ -594,11 +603,16 @@ class InferenceEngine:
         ``mixed_model`` program. Returns the requests that FINISHED during
         this step. With the flight recorder enabled every iteration
         journals one StepRecord (admissions, prefill chunks, the decode or
-        mixed dispatch, preemptions, retirements, KV level,
-        host-vs-dispatch time split)."""
+        mixed dispatch, preemptions, retirements, KV level, the time in each
+        phase) and runs inside the profiler span ``nxdi.step`` of the same
+        step number."""
         fl = self.flight
-        if fl is not None:
-            fl.begin_step()
+        if fl is None:
+            return self._step(None)
+        with self.telemetry.step_span(fl.begin_step().step):
+            return self._step(fl)
+
+    def _step(self, fl) -> List[RequestOutput]:
         finished: List[RequestOutput] = []
         try:
             if faults.ACTIVE_PLAN is not None:
@@ -620,7 +634,8 @@ class InferenceEngine:
                     self._recovery_fatal.inc()
                 raise
             self._recover_step_fault(e, kind, finished)
-        self.scheduler.publish()
+        with self._phase("schedule"):
+            self.scheduler.publish()
         if fl is not None:
             fl.end_step(
                 self.scheduler.queue_depth,
@@ -715,49 +730,54 @@ class InferenceEngine:
     def _step_split(self, finished: List[RequestOutput]) -> None:
         """The classic two-phase step: per-request prefill dispatches, then
         one batched decode dispatch."""
+        phase = self._phase
         preempted: List[Request] = []
-        if self.role == "decode" and self.scheduler.waiting:
-            # a decode-role engine compiles no prefill program: anything in
-            # the waiting queue (a preempted import) cannot be replayed
-            # locally — error-finish with the engine-fault marker so the
-            # router re-routes it through a prefill replica (prompt replay
-            # + fresh handoff; greedy tokens are identical, delivered ones
-            # are cursor-skipped)
-            while self.scheduler.waiting:
-                req = self.scheduler.waiting.popleft()
-                req.error = (
-                    f"{ENGINE_FAULT_PREFIX}: decode-role replica cannot "
-                    "re-prefill a preempted request"
-                )
-                self._finish(req, "error", finished)
-        prefills = self.scheduler.schedule_prefills()
-        self._note_resumes(prefills)
+        with phase("schedule"):
+            if self.role == "decode" and self.scheduler.waiting:
+                # a decode-role engine compiles no prefill program: anything
+                # in the waiting queue (a preempted import) cannot be
+                # replayed locally — error-finish with the engine-fault
+                # marker so the router re-routes it through a prefill replica
+                # (prompt replay + fresh handoff; greedy tokens are
+                # identical, delivered ones are cursor-skipped)
+                while self.scheduler.waiting:
+                    req = self.scheduler.waiting.popleft()
+                    req.error = (
+                        f"{ENGINE_FAULT_PREFIX}: decode-role replica cannot "
+                        "re-prefill a preempted request"
+                    )
+                    self._finish(req, "error", finished)
+            prefills = self.scheduler.schedule_prefills()
+            self._note_resumes(prefills)
         for req in prefills:
             self._prefill_chunk(req, finished)
-        rows = self.scheduler.decodable()
-        if self._handoffs and rows:
-            # parked prefill-role requests hold their slot/chain for export;
-            # they never join a decode batch
-            rows = [
-                (s, r) for s, r in rows if r.request_id not in self._handoffs
-            ]
+        with phase("schedule"):
+            rows = self.scheduler.decodable()
+            if self._handoffs and rows:
+                # parked prefill-role requests hold their slot/chain for
+                # export; they never join a decode batch
+                rows = [
+                    (s, r) for s, r in rows if r.request_id not in self._handoffs
+                ]
         if rows:
-            rows, preempted = self.scheduler.ensure_decode_capacity(rows)
-            for victim in preempted:
-                logger.info(
-                    "preempted request %d (recompute on re-admission)",
-                    victim.request_id,
-                )
-            rows = self._cow_decode_rows(rows)
+            with phase("kv"):
+                rows, preempted = self.scheduler.ensure_decode_capacity(rows)
+                for victim in preempted:
+                    logger.info(
+                        "preempted request %d (recompute on re-admission)",
+                        victim.request_id,
+                    )
+                rows = self._cow_decode_rows(rows)
         if rows:
-            if self._use_device_loop(rows):
+            with phase("schedule"):
+                loop = self._use_device_loop(rows)
+                steps = 1 if loop else self._choose_steps(rows)
+            if loop:
                 self._decode_device_loop(rows, finished)
+            elif steps > 1:
+                self._decode_multistep(rows, steps, finished)
             else:
-                steps = self._choose_steps(rows)
-                if steps > 1:
-                    self._decode_multistep(rows, steps, finished)
-                else:
-                    self._decode_single(rows, finished)
+                self._decode_single(rows, finished)
         # a preemption-only step still made progress (the freed blocks are
         # what lets the NEXT step admit) — only a true no-op step may trip
         # the stall guard in run()
@@ -774,22 +794,25 @@ class InferenceEngine:
         step — so chunked prefill needs no separate admission path and no
         prefix-prefill submodel."""
         tc = self.tpu_config
+        phase = self._phase
         preempted: List[Request] = []
-        prefills = self.scheduler.schedule_prefills()
-        self._note_resumes(prefills)
-        rows = self.scheduler.decodable()
+        with phase("schedule"):
+            prefills = self.scheduler.schedule_prefills()
+            self._note_resumes(prefills)
+            rows = self.scheduler.decodable()
         if rows:
             # grow every decode row's table BEFORE packing: a preemption
             # must evict its victim from THIS step's packed batch, never
             # fault mid-dispatch. The victim may be a request admitted just
             # above — the state filter below drops it from the pack.
-            rows, preempted = self.scheduler.ensure_decode_capacity(rows)
-            for victim in preempted:
-                logger.info(
-                    "preempted request %d (recompute on re-admission)",
-                    victim.request_id,
-                )
-            rows = self._cow_decode_rows(rows)
+            with phase("kv"):
+                rows, preempted = self.scheduler.ensure_decode_capacity(rows)
+                for victim in preempted:
+                    logger.info(
+                        "preempted request %d (recompute on re-admission)",
+                        victim.request_id,
+                    )
+                rows = self._cow_decode_rows(rows)
         prefills = [r for r in prefills if r.state == RUNNING]
 
         w = self._mixed
@@ -807,117 +830,120 @@ class InferenceEngine:
             chunk = req.seq_tokens[: req.prefill_target][start : start + room]
             if not chunk:
                 continue
-            try:
-                self._cow_for_write(req, start, start + len(chunk))
-            except RuntimeError:
-                logger.info(
-                    "preempted request %d: no block for its COW copy",
-                    req.request_id,
-                )
-                self.scheduler._preempt(req)
-                continue
-            tokens.extend(chunk)
-            positions.extend(range(start, start + len(chunk)))
-            row_ids.extend([req.slot] * len(chunk))
-            packed_prefills.append((req, len(chunk)))
-            budget -= len(chunk)
-        for slot, req in rows:
-            tokens.append(req.generated[-1])
-            positions.append(req.total_len - 1)
-            row_ids.append(slot)
-
+            with phase("kv"):
+                try:
+                    self._cow_for_write(req, start, start + len(chunk))
+                except RuntimeError:
+                    logger.info(
+                        "preempted request %d: no block for its COW copy",
+                        req.request_id,
+                    )
+                    self.scheduler._preempt(req)
+                    continue
+            with phase("pack"):
+                tokens.extend(chunk)
+                positions.extend(range(start, start + len(chunk)))
+                row_ids.extend([req.slot] * len(chunk))
+                packed_prefills.append((req, len(chunk)))
+                budget -= len(chunk)
         self._progress = bool(packed_prefills) or bool(rows) or bool(preempted)
-        if not tokens:
+        if not packed_prefills and not rows:
             return
 
-        R = tc.tkg_batch_size
-        wt = self._table_width
-        bs = tc.pa_block_size
-        total = len(tokens)
-        bt = np.full((R, wt), -1, dtype=np.int32)
-        lti = np.zeros((R,), dtype=np.int32)
-        params_rows: List[Optional[SamplingParams]] = [None] * R
-        tables: Dict[int, np.ndarray] = {}
-        by_slot: Dict[int, Request] = {req.slot: req for req, _ in packed_prefills}
-        by_slot.update({slot: req for slot, req in rows})
-        for slot, req in by_slot.items():
-            table = np.asarray(
-                self.block_manager.block_table(req.request_id, wt),
-                dtype=np.int32,
-            )
-            tables[slot] = table
-            bt[slot] = table
-            params_rows[slot] = req.params
-        sm = np.empty((total,), dtype=np.int32)
-        for t, (slot, p) in enumerate(zip(row_ids, positions)):
-            entry = int(tables[slot][p // bs])
-            sm[t] = entry * bs + p % bs if entry >= 0 else -1
-            lti[slot] = t  # per-row tokens are packed ascending: last wins
-
-        kwargs: Dict[str, np.ndarray] = {
-            "block_table": bt.reshape(1, R * wt),
-            "slot_mapping": sm[None, :],
-            "mixed_row_ids": np.asarray(row_ids, dtype=np.int32)[None, :],
-        }
-        if w.needs_rng:
-            kwargs["rng"] = self._rng.next()
-        bucket = w.select_bucket(total)
-        if self.flight is not None:
-            self.flight.record_mixed(
-                TAG_MIXED, bucket, len(packed_prefills), len(rows),
-                total, bucket,
-            )
-            for req, n in packed_prefills:
-                self.flight.record_prefill(
-                    req.request_id, req.slot, TAG_MIXED, req.num_prefilled, n
+        with phase("pack"):
+            for slot, req in rows:
+                tokens.append(req.generated[-1])
+                positions.append(req.total_len - 1)
+                row_ids.append(slot)
+            R = tc.tkg_batch_size
+            wt = self._table_width
+            bs = tc.pa_block_size
+            total = len(tokens)
+            bt = np.full((R, wt), -1, dtype=np.int32)
+            lti = np.zeros((R,), dtype=np.int32)
+            params_rows: List[Optional[SamplingParams]] = [None] * R
+            tables: Dict[int, np.ndarray] = {}
+            by_slot: Dict[int, Request] = {req.slot: req for req, _ in packed_prefills}
+            by_slot.update({slot: req for slot, req in rows})
+            for slot, req in by_slot.items():
+                table = np.asarray(
+                    self.block_manager.block_table(req.request_id, wt),
+                    dtype=np.int32,
                 )
+                tables[slot] = table
+                bt[slot] = table
+                params_rows[slot] = req.params
+            sm = np.empty((total,), dtype=np.int32)
+            for t, (slot, p) in enumerate(zip(row_ids, positions)):
+                entry = int(tables[slot][p // bs])
+                sm[t] = entry * bs + p % bs if entry >= 0 else -1
+                lti[slot] = t  # per-row tokens are packed ascending: last wins
+
+            kwargs: Dict[str, np.ndarray] = {
+                "block_table": bt.reshape(1, R * wt),
+                "slot_mapping": sm[None, :],
+                "mixed_row_ids": np.asarray(row_ids, dtype=np.int32)[None, :],
+            }
+            if w.needs_rng:
+                kwargs["rng"] = self._rng.next()
+            bucket = w.select_bucket(total)
+            if self.flight is not None:
+                self.flight.record_mixed(
+                    TAG_MIXED, bucket, len(packed_prefills), len(rows),
+                    total, bucket,
+                )
+                for req, n in packed_prefills:
+                    self.flight.record_prefill(
+                        req.request_id, req.slot, TAG_MIXED, req.num_prefilled, n
+                    )
+            ids = np.asarray(tokens, dtype=np.int32)[None, :]
+            pos = np.asarray(positions, dtype=np.int32)[None, :]
+            sampling = SamplingParams.rows_tensor(
+                [p if p is not None else SamplingParams() for p in params_rows]
+            )
         clock = self.telemetry.clock if self.telemetry is not None else None
         t0 = clock() if clock else 0.0
         out = self._dispatch_guarded(
             TAG_MIXED,
             lambda: self.app.forward(
-                np.asarray(tokens, dtype=np.int32)[None, :],
-                np.asarray(positions, dtype=np.int32)[None, :],
-                last_token_index=lti,
-                sampling_params=SamplingParams.rows_tensor(
-                    [p if p is not None else SamplingParams() for p in params_rows]
-                ),
-                submodel=TAG_MIXED,
-                **kwargs,
+                ids, pos, last_token_index=lti, sampling_params=sampling,
+                submodel=TAG_MIXED, **kwargs,
             ),
         )
-        toks = self._tokens_of(out)  # (R,): one per slot; idle rows garbage
+        with phase("fetch"):
+            toks = self._tokens_of(out)  # (R,): one per slot; idle rows garbage
         dt = (clock() - t0) if clock else None
 
-        for req, n in packed_prefills:
-            req.num_prefilled += n
-            if not req.prefill_done:
-                continue  # more chunks next step; decodes keep interleaving
-            self.scheduler.note_prefill_complete(req)
-            if (
-                self.sentinel is not None
-                and self.sentinel.config.preemption_check
-                and req.preemptions > 0
-                and req.generated
-            ):
-                # preemption-replay invariant, same as the split path
-                self.sentinel.verify_replay(req, "preemption")
-            if req.span is not None:
-                req.span.first_token()
-                req.span.phase("decode")
-                req.span.tokens(1)
-            self._trace_hop(req, HOP_ENGINE_PREFILL)
-            req.emit(int(toks[req.slot]))
-            reason = req.check_finish()
-            if reason:
-                self._finish(req, reason, finished)
-        for slot, req in rows:
-            if req.span is not None:
-                req.span.tokens(1, dt)
-            req.emit(int(toks[slot]))
-            reason = req.check_finish()
-            if reason:
-                self._finish(req, reason, finished)
+        with phase("emit"):
+            for req, n in packed_prefills:
+                req.num_prefilled += n
+                if not req.prefill_done:
+                    continue  # more chunks next step; decodes keep interleaving
+                self.scheduler.note_prefill_complete(req)
+                if (
+                    self.sentinel is not None
+                    and self.sentinel.config.preemption_check
+                    and req.preemptions > 0
+                    and req.generated
+                ):
+                    # preemption-replay invariant, same as the split path
+                    self.sentinel.verify_replay(req, "preemption")
+                if req.span is not None:
+                    req.span.first_token()
+                    req.span.phase("decode")
+                    req.span.tokens(1)
+                self._trace_hop(req, HOP_ENGINE_PREFILL)
+                req.emit(int(toks[req.slot]))
+                reason = req.check_finish()
+                if reason:
+                    self._finish(req, reason, finished)
+            for slot, req in rows:
+                if req.span is not None:
+                    req.span.tokens(1, dt)
+                req.emit(int(toks[slot]))
+                reason = req.check_finish()
+                if reason:
+                    self._finish(req, reason, finished)
 
     def run(self, max_steps: Optional[int] = None) -> List[RequestOutput]:
         """Step until every queued request finishes; returns all outputs."""
@@ -991,9 +1017,15 @@ class InferenceEngine:
 
     # -- prefill ------------------------------------------------------------
     def _prefill_chunk(self, req: Request, finished: List[RequestOutput]) -> None:
-        seq = req.seq_tokens[: req.prefill_target]
-        start = req.num_prefilled
-        limit = self.scheduler.config.chunk_size or self.tpu_config.max_context_length
+        phase = self._phase
+        with phase("pack"):
+            seq = req.seq_tokens[: req.prefill_target]
+            start = req.num_prefilled
+            limit = (
+                self.scheduler.config.chunk_size or self.tpu_config.max_context_length
+            )
+            chunk = seq[start : start + limit]
+            n = len(chunk)
         if len(seq) > limit and not self._can_continue_prefill:
             # a preempted request's prompt+generated replay outgrew the one
             # CTE pass and no prefix/chunked submodel is compiled to continue
@@ -1007,32 +1039,30 @@ class InferenceEngine:
             )
             self._finish(req, "error", finished)
             return
-        chunk = seq[start : start + limit]
-        n = len(chunk)
-        try:
-            self._cow_for_write(req, start, start + n)
-        except RuntimeError:
-            # pool dry even after cache eviction: requeue rather than fault
-            logger.info(
-                "preempted request %d: no block for its COW copy",
-                req.request_id,
-            )
-            self.scheduler._preempt(req)
-            return
-        ids = np.asarray([chunk], dtype=np.int32)
-        pos = (start + np.arange(n, dtype=np.int32))[None, :]
-        kwargs = self._layout_kwargs([(req.slot, req)])
-        self._maybe_rng(kwargs)
-        submodel = TAG_CONTEXT_ENCODING if start == 0 else TAG_PREFIX_PREFILL
+        with phase("kv"):
+            try:
+                self._cow_for_write(req, start, start + n)
+            except RuntimeError:
+                # pool dry even after cache eviction: requeue rather than fault
+                logger.info(
+                    "preempted request %d: no block for its COW copy",
+                    req.request_id,
+                )
+                self.scheduler._preempt(req)
+                return
+        with phase("pack"):
+            ids = np.asarray([chunk], dtype=np.int32)
+            pos = (start + np.arange(n, dtype=np.int32))[None, :]
+            kwargs = self._layout_kwargs([(req.slot, req)])
+            self._maybe_rng(kwargs)
+            submodel = TAG_CONTEXT_ENCODING if start == 0 else TAG_PREFIX_PREFILL
+            last = np.array([n - 1], dtype=np.int32)
+            sampling = req.params.tensor(1)
         out = self._dispatch_guarded(
             submodel,
             lambda: self.app.forward(
-                ids,
-                pos,
-                last_token_index=np.array([n - 1], dtype=np.int32),
-                sampling_params=req.params.tensor(1),
-                submodel=submodel,
-                **kwargs,
+                ids, pos, last_token_index=last, sampling_params=sampling,
+                submodel=submodel, **kwargs,
             ),
         )
         if self.flight is not None:
@@ -1056,18 +1086,20 @@ class InferenceEngine:
             # {kind="preemption"} and bundles instead of silently serving a
             # forked continuation
             self.sentinel.verify_replay(req, "preemption")
-        tok = int(self._tokens_of(out)[0])
-        if req.span is not None:
-            req.span.first_token()  # idempotent: a resume keeps the original
-            req.span.phase("decode")
-            req.span.tokens(1)
-        self._trace_hop(req, HOP_ENGINE_PREFILL)
-        req.emit(tok)
-        reason = req.check_finish()
-        if reason:
-            self._finish(req, reason, finished)
-        elif self.role == "prefill":
-            self._park_for_handoff(req)
+        with phase("fetch"):
+            tok = int(self._tokens_of(out)[0])
+        with phase("emit"):
+            if req.span is not None:
+                req.span.first_token()  # idempotent: a resume keeps the original
+                req.span.phase("decode")
+                req.span.tokens(1)
+            self._trace_hop(req, HOP_ENGINE_PREFILL)
+            req.emit(tok)
+            reason = req.check_finish()
+            if reason:
+                self._finish(req, reason, finished)
+            elif self.role == "prefill":
+                self._park_for_handoff(req)
 
     # -- KV handoff plane (prefill/decode disaggregation) -------------------
     def _park_for_handoff(self, req: Request) -> None:
@@ -1303,41 +1335,41 @@ class InferenceEngine:
     def _decode_single(
         self, rows: List[Tuple[int, Request]], finished: List[RequestOutput]
     ) -> None:
-        B = len(rows)
-        ids = np.array([[r.generated[-1]] for _, r in rows], dtype=np.int32)
-        pos = np.array([[r.total_len - 1] for _, r in rows], dtype=np.int32)
-        kwargs = self._layout_kwargs(rows)
-        self._maybe_rng(kwargs)
-        if self.flight is not None:
-            self.flight.record_decode(
-                TAG_TOKEN_GENERATION, 1, rows, self.tpu_config.tkg_batch_size
-            )
+        phase = self._phase
+        with phase("pack"):
+            B = len(rows)
+            ids = np.array([[r.generated[-1]] for _, r in rows], dtype=np.int32)
+            pos = np.array([[r.total_len - 1] for _, r in rows], dtype=np.int32)
+            kwargs = self._layout_kwargs(rows)
+            self._maybe_rng(kwargs)
+            last = np.zeros((B,), dtype=np.int32)
+            sampling = SamplingParams.rows_tensor([r.params for _, r in rows])
+            if self.flight is not None:
+                self.flight.record_decode(
+                    TAG_TOKEN_GENERATION, 1, rows, self.tpu_config.tkg_batch_size
+                )
         clock = self.telemetry.clock if self.telemetry is not None else None
         t0 = clock() if clock else 0.0
         out = self._dispatch_guarded(
             TAG_TOKEN_GENERATION,
             lambda: self.app.forward(
-                ids,
-                pos,
-                last_token_index=np.zeros((B,), dtype=np.int32),
-                sampling_params=SamplingParams.rows_tensor(
-                    [r.params for _, r in rows]
-                ),
-                submodel=TAG_TOKEN_GENERATION,
-                **kwargs,
+                ids, pos, last_token_index=last, sampling_params=sampling,
+                submodel=TAG_TOKEN_GENERATION, **kwargs,
             ),
         )
-        toks = self._tokens_of(out)
+        with phase("fetch"):
+            toks = self._tokens_of(out)
         dt = (clock() - t0) if clock else None
-        for (slot, req), tok in zip(rows, toks):
-            if req.span is not None:
-                req.span.tokens(1, dt)
-            req.emit(int(tok))
-            reason = req.check_finish()
-            if reason:
-                self._finish(req, reason, finished)
-        if self.flight is not None:
-            self.flight.note_decode_tokens(len(rows))
+        with phase("emit"):
+            for (slot, req), tok in zip(rows, toks):
+                if req.span is not None:
+                    req.span.tokens(1, dt)
+                req.emit(int(tok))
+                reason = req.check_finish()
+                if reason:
+                    self._finish(req, reason, finished)
+            if self.flight is not None:
+                self.flight.note_decode_tokens(len(rows))
 
     def _decode_multistep(
         self,
@@ -1345,63 +1377,67 @@ class InferenceEngine:
         steps: int,
         finished: List[RequestOutput],
     ) -> None:
-        B = len(rows)
-        eos = np.full((B, MULTISTEP_EOS_SLOTS), -1, dtype=np.int32)
-        for i, (_, r) in enumerate(rows):
-            for j, e in enumerate(r.params.eos_token_ids):
-                eos[i, j] = e
-        batch = {
-            "input_ids": np.array(
-                [[r.generated[-1]] for _, r in rows], dtype=np.int32
-            ),
-            "position_ids": np.array(
-                [[r.total_len - 1] for _, r in rows], dtype=np.int32
-            ),
-            "last_token_index": np.zeros((B,), dtype=np.int32),
-            "sampling_params": SamplingParams.rows_tensor(
-                [r.params for _, r in rows]
-            ),
-            "eos_token_ids": eos,
-            "pad_token_id": np.zeros((B,), dtype=np.int32),
-            # per-row remaining budgets: the in-scan mask freezes a row
-            # after its budget-hit token, which is what lets _choose_steps
-            # hand near-EOS rows a window bigger than their budget
-            "budget_steps": np.array(
-                [r.remaining for _, r in rows], dtype=np.int32
-            ),
-            "decode_steps": steps,
-        }
-        batch.update(self._layout_kwargs(rows))
-        self._maybe_rng(batch)
-        if self.flight is not None:
-            self.flight.record_decode(
-                TAG_TOKEN_GENERATION_MULTISTEP, steps, rows,
-                self.tpu_config.tkg_batch_size,
-            )
+        phase = self._phase
+        with phase("pack"):
+            B = len(rows)
+            eos = np.full((B, MULTISTEP_EOS_SLOTS), -1, dtype=np.int32)
+            for i, (_, r) in enumerate(rows):
+                for j, e in enumerate(r.params.eos_token_ids):
+                    eos[i, j] = e
+            batch = {
+                "input_ids": np.array(
+                    [[r.generated[-1]] for _, r in rows], dtype=np.int32
+                ),
+                "position_ids": np.array(
+                    [[r.total_len - 1] for _, r in rows], dtype=np.int32
+                ),
+                "last_token_index": np.zeros((B,), dtype=np.int32),
+                "sampling_params": SamplingParams.rows_tensor(
+                    [r.params for _, r in rows]
+                ),
+                "eos_token_ids": eos,
+                "pad_token_id": np.zeros((B,), dtype=np.int32),
+                # per-row remaining budgets: the in-scan mask freezes a row
+                # after its budget-hit token, which is what lets _choose_steps
+                # hand near-EOS rows a window bigger than their budget
+                "budget_steps": np.array(
+                    [r.remaining for _, r in rows], dtype=np.int32
+                ),
+                "decode_steps": steps,
+            }
+            batch.update(self._layout_kwargs(rows))
+            self._maybe_rng(batch)
+            if self.flight is not None:
+                self.flight.record_decode(
+                    TAG_TOKEN_GENERATION_MULTISTEP, steps, rows,
+                    self.tpu_config.tkg_batch_size,
+                )
         clock = self.telemetry.clock if self.telemetry is not None else None
         t0 = clock() if clock else 0.0
         out = self._dispatch_guarded(
             "token_gen_multistep", lambda: self.app.token_gen_multistep(batch)
         )
-        toks = np.asarray(jax.device_get(out["tokens"]))[:B]  # (B, steps)
+        with phase("fetch"):
+            toks = np.asarray(jax.device_get(out["tokens"]))[:B]  # (B, steps)
         dt = (clock() - t0) if clock else None
-        total_emitted = 0
-        for i, (slot, req) in enumerate(rows):
-            emitted = 0
-            for j in range(steps):
-                req.emit(int(toks[i, j]))
-                emitted += 1
-                reason = req.check_finish()
-                if reason:
-                    # later in-window tokens for this row are pad-masked by
-                    # the in-scan EOS/budget logic; discard them
-                    self._finish(req, reason, finished)
-                    break
-            total_emitted += emitted
-            if req.span is not None and emitted:
-                req.span.tokens(emitted, dt if dt is None else dt * emitted / steps)
-        if self.flight is not None:
-            self.flight.note_decode_tokens(total_emitted)
+        with phase("emit"):
+            total_emitted = 0
+            for i, (slot, req) in enumerate(rows):
+                emitted = 0
+                for j in range(steps):
+                    req.emit(int(toks[i, j]))
+                    emitted += 1
+                    reason = req.check_finish()
+                    if reason:
+                        # later in-window tokens for this row are pad-masked by
+                        # the in-scan EOS/budget logic; discard them
+                        self._finish(req, reason, finished)
+                        break
+                total_emitted += emitted
+                if req.span is not None and emitted:
+                    req.span.tokens(emitted, dt if dt is None else dt * emitted / steps)
+            if self.flight is not None:
+                self.flight.note_decode_tokens(total_emitted)
 
     def _use_device_loop(self, rows: List[Tuple[int, Request]]) -> bool:
         """Device-loop admissibility for THIS window: the submodel is
@@ -1427,77 +1463,81 @@ class InferenceEngine:
         instead of one per token (or per rung). ``device_loop_fence`` caps
         tokens per launch — the preemption fence: admission, retirement,
         and preemption all get a scheduling point between launches."""
-        tc = self.tpu_config
-        B = len(rows)
-        eos = np.full((B, MULTISTEP_EOS_SLOTS), -1, dtype=np.int32)
-        for i, (_, r) in enumerate(rows):
-            for j, e in enumerate(r.params.eos_token_ids):
-                eos[i, j] = e
-        budgets = np.array([r.remaining for _, r in rows], dtype=np.int32)
-        fence = int(getattr(tc, "device_loop_fence", 0) or 0)
-        if fence:
-            budgets = np.minimum(budgets, fence)
-        cap = self._dloop.select_cap(int(budgets.max()))
-        batch = {
-            "input_ids": np.array(
-                [[r.generated[-1]] for _, r in rows], dtype=np.int32
-            ),
-            "position_ids": np.array(
-                [[r.total_len - 1] for _, r in rows], dtype=np.int32
-            ),
-            "last_token_index": np.zeros((B,), dtype=np.int32),
-            "sampling_params": SamplingParams.rows_tensor(
-                [r.params for _, r in rows]
-            ),
-            "eos_token_ids": eos,
-            "pad_token_id": np.zeros((B,), dtype=np.int32),
-            "budget_steps": budgets,
-            "loop_cap": cap,
-        }
-        batch.update(self._layout_kwargs(rows))
-        if self._dloop.needs_rng:
-            batch["rng"] = self._rng.next()
+        phase = self._phase
+        with phase("pack"):
+            tc = self.tpu_config
+            B = len(rows)
+            eos = np.full((B, MULTISTEP_EOS_SLOTS), -1, dtype=np.int32)
+            for i, (_, r) in enumerate(rows):
+                for j, e in enumerate(r.params.eos_token_ids):
+                    eos[i, j] = e
+            budgets = np.array([r.remaining for _, r in rows], dtype=np.int32)
+            fence = int(getattr(tc, "device_loop_fence", 0) or 0)
+            if fence:
+                budgets = np.minimum(budgets, fence)
+            cap = self._dloop.select_cap(int(budgets.max()))
+            batch = {
+                "input_ids": np.array(
+                    [[r.generated[-1]] for _, r in rows], dtype=np.int32
+                ),
+                "position_ids": np.array(
+                    [[r.total_len - 1] for _, r in rows], dtype=np.int32
+                ),
+                "last_token_index": np.zeros((B,), dtype=np.int32),
+                "sampling_params": SamplingParams.rows_tensor(
+                    [r.params for _, r in rows]
+                ),
+                "eos_token_ids": eos,
+                "pad_token_id": np.zeros((B,), dtype=np.int32),
+                "budget_steps": budgets,
+                "loop_cap": cap,
+            }
+            batch.update(self._layout_kwargs(rows))
+            if self._dloop.needs_rng:
+                batch["rng"] = self._rng.next()
         clock = self.telemetry.clock if self.telemetry is not None else None
         t0 = clock() if clock else 0.0
         out = self._dispatch_guarded(
             "token_gen_device_loop", lambda: self.app.token_gen_device_loop(batch)
         )
-        toks = np.asarray(jax.device_get(out["tokens"]))[:B]  # (B, cap)
-        iters = int(jax.device_get(out["loop_iters"]))
+        with phase("fetch"):
+            toks = np.asarray(jax.device_get(out["tokens"]))[:B]  # (B, cap)
+            iters = int(jax.device_get(out["loop_iters"]))
         dt = (clock() - t0) if clock else None
         if self._dloop.needs_rng and iters > 1:
             # iteration t sampled with counter base+t IN-GRAPH; land the
             # host schedule where ``iters`` chained 1-step dispatches would
             # have (the sampled loop-ON/OFF parity contract)
             self._rng.advance(iters - 1)
-        total_emitted = 0
-        for i, (slot, req) in enumerate(rows):
-            emitted = 0
-            for j in range(min(iters, int(budgets[i]))):
-                req.emit(int(toks[i, j]))
-                emitted += 1
-                reason = req.check_finish()
-                if reason:
-                    # this row halted mid-loop; its later buffer columns
-                    # are pad fill — discard them
-                    self._finish(req, reason, finished)
-                    break
-            total_emitted += emitted
-            if req.span is not None and emitted:
-                req.span.tokens(
-                    emitted, dt if dt is None else dt * emitted / max(iters, 1)
+        with phase("emit"):
+            total_emitted = 0
+            for i, (slot, req) in enumerate(rows):
+                emitted = 0
+                for j in range(min(iters, int(budgets[i]))):
+                    req.emit(int(toks[i, j]))
+                    emitted += 1
+                    reason = req.check_finish()
+                    if reason:
+                        # this row halted mid-loop; its later buffer columns
+                        # are pad fill — discard them
+                        self._finish(req, reason, finished)
+                        break
+                total_emitted += emitted
+                if req.span is not None and emitted:
+                    req.span.tokens(
+                        emitted, dt if dt is None else dt * emitted / max(iters, 1)
+                    )
+            if self.flight is not None:
+                self.flight.record_decode(
+                    TAG_DEVICE_LOOP, cap, rows, tc.tkg_batch_size,
+                    tokens_emitted=total_emitted,
                 )
-        if self.flight is not None:
-            self.flight.record_decode(
-                TAG_DEVICE_LOOP, cap, rows, tc.tkg_batch_size,
-                tokens_emitted=total_emitted,
-            )
-        if self._loop_launches is not None:
-            lbl = str(cap)
-            self._loop_launches.inc(cap=lbl)
-            self._loop_iters_total.inc(iters, cap=lbl)
-            self._loop_tokens_total.inc(total_emitted, cap=lbl)
-            self._loop_tokens_per_dispatch.set(float(total_emitted))
+            if self._loop_launches is not None:
+                lbl = str(cap)
+                self._loop_launches.inc(cap=lbl)
+                self._loop_iters_total.inc(iters, cap=lbl)
+                self._loop_tokens_total.inc(total_emitted, cap=lbl)
+                self._loop_tokens_per_dispatch.set(float(total_emitted))
 
     # -- retirement ---------------------------------------------------------
     def _finish(

@@ -24,7 +24,7 @@ Metric catalog (labels in parens):
 
 ====================================  =========  ==================================
 ``nxdi_dispatches_total``             counter    (submodel, bucket, steps)
-``nxdi_dispatch_seconds``             histogram  (submodel, bucket, steps)
+``nxdi_dispatch_seconds``             histogram  (submodel, bucket, steps) pad + enqueue
 ``nxdi_padding_waste_ratio``          histogram  (submodel)
 ``nxdi_real_tokens_total``            counter    (submodel)
 ``nxdi_padded_tokens_total``          counter    (submodel)
@@ -57,7 +57,8 @@ Metric catalog (labels in parens):
 ``nxdi_spans_dropped_total``          counter
 ``nxdi_engine_steps_total``           counter
 ``nxdi_engine_step_seconds``          histogram
-``nxdi_engine_host_seconds``          histogram
+``nxdi_engine_host_seconds``          histogram  step wall - its ``fetch`` phase
+``nxdi_engine_phase_seconds``         histogram  (phase) one engine step's phases
 ``nxdi_postmortems_total``            counter    (trigger)
 ``nxdi_slo_target_seconds``           gauge      (kind: ttft|tpot)
 ``nxdi_slo_requests_total``           counter    (outcome)
@@ -118,14 +119,29 @@ The three roofline gauges are published by the cost observatory
 (:func:`nxdi_tpu.analysis.costs.attach_cost_gauges`, wired at ``app.load()``):
 at every export the measured mean dispatch latency is divided through each
 program's :class:`~nxdi_tpu.analysis.costs.CostSheet`, and the sheet table
-itself rides the JSON snapshot as ``_cost_sheets``.
+itself rides the JSON snapshot as ``_cost_sheets``. They exist only at
+``detail="full"``, where that latency includes the device: at ``"basic"``
+``nxdi_dispatch_seconds`` times the enqueue, a share of a peak computed from
+it would be one no chip can give, and the three series are absent.
+
+Engine-step phases (``Telemetry.phase``): while an engine step is open, the
+step's host work is split into ``schedule``, ``kv``, ``pack``, ``pad``,
+``enqueue``, ``fetch`` and ``emit`` (:data:`PHASES`). Each phase is a
+``jax.profiler.TraceAnnotation("nxdi.step.<phase>")`` — so it sits on the
+profiler's clock beside the device's operations, inside the step's
+``StepTraceAnnotation("nxdi.step", step_num=StepRecord.step)`` — and its
+``Telemetry.clock`` time adds into the open ``StepRecord.phases``, which
+``nxdi_engine_phase_seconds{phase}`` observes as the step closes.
 """
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import time
 from typing import Callable, Dict, Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from nxdi_tpu.telemetry import export as _export
 from nxdi_tpu.telemetry.registry import (
@@ -205,6 +221,58 @@ __all__ = [
 MetricsServer = _export.MetricsServer
 
 DETAIL_LEVELS = ("off", "basic", "full")
+
+#: the phases of one engine step, exhaustive and non-overlapping: who opens
+#: each is in serving/engine.py (schedule, kv, pack, fetch, emit) and
+#: runtime/model_wrapper.py (pad, enqueue)
+PHASES = ("schedule", "kv", "pack", "pad", "enqueue", "fetch", "emit")
+STEP_SPAN = "nxdi.step"
+_PHASE_SPANS = {name: f"{STEP_SPAN}.{name}" for name in PHASES}
+_NULL_CONTEXT = contextlib.nullcontext()
+
+
+class _Phase:
+    """One open phase (``Telemetry.phase``): a host span in the profiler's
+    trace and, while an engine step is open, its time added to that
+    StepRecord's ``phases``. A phase opened inside another (a sentinel replay
+    dispatching from inside ``emit``) stops the outer one's count while it
+    runs, so a step's phases never overlap. Outside an open step (the HF
+    adapter calling a wrapper) a phase only annotates."""
+
+    __slots__ = ("_tel", "_name", "_span", "_rec", "_outer", "_t0")
+
+    def __init__(self, tel: "Telemetry", name: str):
+        self._tel = tel
+        self._name = name
+        self._span = TraceAnnotation(_PHASE_SPANS[name])
+
+    def _credit(self, now: float) -> None:
+        phases = self._rec.phases
+        phases[self._name] = phases.get(self._name, 0.0) + (now - self._t0)
+
+    def __enter__(self):
+        self._span.__enter__()
+        fl = self._tel.flight
+        rec = self._rec = fl.current if fl is not None else None
+        if rec is not None:
+            now = self._tel.clock()
+            outer = self._outer = rec.open_phase
+            if outer is not None:
+                outer._credit(now)
+            rec.open_phase = self
+            self._t0 = now
+        return self
+
+    def __exit__(self, *exc):
+        rec = self._rec
+        if rec is not None:
+            now = self._tel.clock()
+            self._credit(now)
+            outer = rec.open_phase = self._outer
+            if outer is not None:
+                outer._t0 = now
+        self._span.__exit__(*exc)
+        return False
 
 
 class Telemetry:
@@ -307,7 +375,9 @@ class Telemetry:
         )
         self.dispatch_seconds = r.histogram(
             "nxdi_dispatch_seconds",
-            "host wall-clock per dispatch (sync_dispatch adds device wait)",
+            "host wall-clock of one dispatch in the wrapper, pad + enqueue: "
+            "at detail=basic the device's time is NOT in it (sync_dispatch, "
+            "detail=full, adds the wait for the device)",
             disp_labels, bounds=TIME_BOUNDS_S,
         )
         self.padding_waste = r.histogram(
@@ -417,6 +487,15 @@ class Telemetry:
             "measured mean dispatch latency / CostSheet roofline floor",
             disp_labels,
         )
+        self.phase_seconds = r.histogram(
+            "nxdi_engine_phase_seconds",
+            "time one engine step spent in each of its phases (observed as "
+            "the step closes; a phase entered twice is summed first)",
+            ("phase",), bounds=TIME_BOUNDS_S,
+        )
+        if self.enabled:
+            for name in PHASES:
+                self.phase_seconds.observe(0.0, n=0, phase=name)
         # export-time hooks: attachments run before every snapshot/scrape
         # (the cost observatory refreshes its gauges here); snapshot extras
         # merge additional keys (e.g. _cost_sheets) into the JSON snapshot.
@@ -501,6 +580,21 @@ class Telemetry:
         return self.trace_buffer.snapshot()
 
     # -- hot-path recorders -------------------------------------------------
+    def phase(self, name: str):
+        """Context manager around one phase of the engine step (``name`` in
+        :data:`PHASES`); the shared null context when telemetry is off."""
+        if not self.enabled:
+            return _NULL_CONTEXT
+        return _Phase(self, name)
+
+    def step_span(self, step_num: int):
+        """``StepTraceAnnotation("nxdi.step", step_num=...)`` around one
+        engine step, so a flight record and the profiler's spans join by
+        step number; the shared null context when telemetry is off."""
+        if not self.enabled:
+            return _NULL_CONTEXT
+        return StepTraceAnnotation(STEP_SPAN, step_num=step_num)
+
     def record_dispatch(
         self,
         submodel: str,

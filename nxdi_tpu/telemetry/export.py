@@ -164,8 +164,9 @@ def _engine_timeline_events(flight, records, us, dur_us) -> list:
             events.append(slot_slice("preempted", pe["slot"], rec, {
                 "request_id": pe["request_id"],
             }))
-        # where the step's wall went that no dispatch accounts for — the
-        # host-side sync/orchestration boundary (Kernel Looping's target)
+        # the step's wall less its wait for the device's tokens (the fetch
+        # phase): the host-side sync/orchestration boundary (Kernel
+        # Looping's target), split by phase in the arguments
         events.append({
             "name": "host",
             "cat": "engine",
@@ -178,6 +179,10 @@ def _engine_timeline_events(flight, records, us, dur_us) -> list:
                 "step": rec.step,
                 "wall_ms": round(rec.wall_s * 1e3, 3),
                 "dispatch_ms": round(rec.dispatch_s * 1e3, 3),
+                "phases_ms": {
+                    k: round(v * 1e3, 3) for k, v in rec.phases.items()
+                },
+                "other_ms": round(rec.other_s * 1e3, 3),
             },
         })
     return events

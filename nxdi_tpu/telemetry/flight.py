@@ -11,15 +11,20 @@ ring buffer, carrying
   the decode dispatch (rows occupied, multistep rung, padding rows),
   preemptions (with the vacated slot), retirements,
 - the resource picture: free KV blocks, queue depth, busy slots,
-- the **host-vs-dispatch time split**: ``dispatch_s`` is the sum of the
-  step's per-program dispatch latencies (the existing
-  ``nxdi_dispatch_seconds`` path feeds it via
-  ``Telemetry.record_dispatch``, so there is ONE timing source); the
-  remainder ``host_s = wall - dispatch_s`` is host orchestration — the
-  sync-boundary cost Kernel Looping targets. At ``telemetry="full"``
-  dispatches block on device completion, so ``host_s`` is pure host
-  overhead; at ``"basic"`` dispatch is the async enqueue cost and the
-  device wait lands in ``host_s`` of whichever later step blocks.
+- **where the step's wall-clock went**: ``phases`` holds the seconds the
+  step spent in each of its phases (``telemetry.PHASES``: schedule, kv,
+  pack, pad, enqueue, fetch, emit), added up by ``Telemetry.phase`` where
+  the work happens, on ``Telemetry.clock``; ``other_s = wall - sum(phases)``
+  is the time under no phase, so nothing is lost silently. ``fetch`` is the
+  one phase in which the host waits for the device's tokens, so
+  ``host_s = wall - phases["fetch"]`` is the time this engine kept the
+  device without work to wait for — what overlapping the host with the
+  device (ROADMAP D1) can win back. ``dispatch_s`` is the wrapper's own
+  ``pad`` + ``enqueue`` time per program dispatch (the
+  ``nxdi_dispatch_seconds`` path feeds it via ``Telemetry.record_dispatch``,
+  ONE timing source): at ``detail="basic"`` the time to hand the device its
+  work, never the device's time; at ``"full"`` dispatches block on device
+  completion and it includes it.
 
 Trigger-based **postmortem capture**: on SLO breach (fed by
 :class:`~nxdi_tpu.telemetry.slo.SloTracker`), preemption storm
@@ -32,8 +37,10 @@ its lifetime, scheduler queue state, and a full metrics snapshot — to
 ``cli.metrics --serve`` / ``cli.serve --serve``.
 
 The ring rides the Perfetto export: one track per decode slot
-(prefill / decode / preempted segments) plus a host-overhead track, so a
-``cli.serve`` run opens in the Perfetto UI as a per-slot Gantt chart.
+(prefill / decode / preempted segments) plus a host-overhead track (each
+step's ``host_s``, its phases in the arguments), so a ``cli.serve`` run
+opens in the Perfetto UI as a per-slot Gantt chart. The same step, by number,
+is the ``nxdi.step`` span of a ``jax.profiler`` trace.
 """
 
 from __future__ import annotations
@@ -65,6 +72,7 @@ class StepRecord:
         "step", "t_start", "t_end", "admitted", "prefills", "decode",
         "mixed", "preempted", "retired", "programs", "kv_blocks_free",
         "queue_depth", "slots_busy", "dispatch_s", "host_s", "faults",
+        "phases", "open_phase",
     )
 
     def __init__(self, step: int, t_start: float):
@@ -98,11 +106,22 @@ class StepRecord:
         self.queue_depth = 0
         self.slots_busy = 0
         self.dispatch_s = 0.0
+        #: wall - phases["fetch"], set as the step closes
         self.host_s = 0.0
+        #: {phase -> seconds} — added to by Telemetry.phase while the step
+        #: is open; a phase entered twice (a prefill and a decode) sums
+        self.phases: Dict[str, float] = {}
+        #: the innermost phase open right now (Telemetry.phase keeps it)
+        self.open_phase = None
 
     @property
     def wall_s(self) -> float:
         return (self.t_end - self.t_start) if self.t_end is not None else 0.0
+
+    @property
+    def other_s(self) -> float:
+        """Wall time under no phase."""
+        return self.wall_s - sum(self.phases.values())
 
     def overlaps(self, t0: float, t1: float) -> bool:
         end = self.t_end if self.t_end is not None else self.t_start
@@ -116,6 +135,8 @@ class StepRecord:
             "wall_s": self.wall_s,
             "dispatch_s": self.dispatch_s,
             "host_s": self.host_s,
+            "phases": dict(self.phases),
+            "other_s": self.other_s,
             "admitted": list(self.admitted),
             "prefills": list(self.prefills),
             "decode": self.decode,
@@ -201,7 +222,8 @@ class FlightRecorder:
         )
         self.host_seconds = r.histogram(
             "nxdi_engine_host_seconds",
-            "host-orchestration remainder per engine step (wall - dispatch)",
+            "engine step wall-clock minus its fetch phase: the time the "
+            "engine kept the device without work to wait for",
         )
         self.postmortems_total = r.counter(
             "nxdi_postmortems_total", "postmortem bundles by trigger", ("trigger",)
@@ -353,7 +375,7 @@ class FlightRecorder:
         rec.queue_depth = int(queue_depth)
         rec.slots_busy = int(slots_busy)
         rec.kv_blocks_free = kv_blocks_free
-        rec.host_s = max(rec.wall_s - rec.dispatch_s, 0.0)
+        rec.host_s = rec.wall_s - rec.phases.get("fetch", 0.0)
         with self._lock:
             self.records.append(rec)
             if len(self.records) > self.max_records:
@@ -362,6 +384,8 @@ class FlightRecorder:
         self.steps_total.inc()
         self.step_seconds.observe(rec.wall_s)
         self.host_seconds.observe(rec.host_s)
+        for name, seconds in rec.phases.items():
+            self.telemetry.phase_seconds.observe(seconds, phase=name)
         self._check_storm(rec)
         self._check_retrace(rec)
         return rec

@@ -289,6 +289,98 @@ def test_engine_chunked_prefill_admission(tiny_hf_llama):
     assert chunks >= 2, "the 20-token prompt must continue through 2+ chunks"
 
 
+class _TickClock:
+    """An injected telemetry clock: every reading is 1 ms after the last,
+    so each phase, and each stretch under no phase, has a time of its own."""
+
+    def __init__(self):
+        self.t = 1000.0
+
+    def __call__(self):
+        self.t += 1e-3
+        return self.t
+
+
+_PHASE_PATHS = {
+    # the path the benchmark's cells run: paged KV, one prefill row, 1-step decode
+    "split": dict(is_block_kv_layout=True, pa_block_size=8, pa_num_blocks=32,
+                  ctx_batch_size=1, tkg_batch_size=2),
+    "mixed": dict(is_block_kv_layout=True, pa_block_size=8, pa_num_blocks=32,
+                  ctx_batch_size=1, tkg_batch_size=2, mixed_dispatch=True),
+    "multistep": dict(is_continuous_batching=True, ctx_batch_size=2, tkg_batch_size=2,
+                      kv_cache_batch_size=2, decode_steps_per_dispatch=4),
+    "device_loop": dict(is_continuous_batching=True, ctx_batch_size=2, tkg_batch_size=2,
+                        kv_cache_batch_size=2, device_loop=True, device_loop_fence=3),
+}
+
+
+@pytest.mark.parametrize("path", sorted(_PHASE_PATHS))
+def test_engine_step_phases_cover_the_step(tiny_hf_llama, path):
+    """Every StepRecord's phases are exhaustive and non-overlapping under an
+    injected clock: sum(phases) + other_s == wall_s with other_s >= 0, a
+    decode-only step enters schedule, pack, pad, enqueue, fetch and emit,
+    host_s is the wall less the wait for the device's tokens, and the phase
+    histogram holds what the records hold."""
+    from nxdi_tpu.telemetry import PHASES
+
+    hf_model, hf_cfg = tiny_hf_llama
+    app = _build_app(hf_model, hf_cfg, **_PHASE_PATHS[path])
+    app.telemetry.clock = _TickClock()
+    engine = InferenceEngine(app, SchedulerConfig(num_slots=2))
+    engine.add_request(P0, SamplingParams(max_new_tokens=12))
+    outs = engine.step()
+    engine.add_request(P1, SamplingParams(max_new_tokens=10))  # a prefill joins a decode
+    outs += engine.run()
+    assert len(outs) == 2 and all(o.finish_reason == "length" for o in outs)
+
+    records = engine.flight.snapshot_records()
+    assert [r.step for r in records] == list(range(len(records)))
+    decode_only = [r for r in records if (r.decode or r.mixed) and not r.prefills]
+    assert decode_only and any(r.prefills for r in records)
+    for r in records:
+        assert set(r.phases) <= set(PHASES)
+        assert sum(r.phases.values()) + r.other_s == pytest.approx(r.wall_s)
+        assert r.other_s > -1e-9  # no instant counted under two phases
+        assert r.host_s == pytest.approx(r.wall_s - r.phases["fetch"])
+        assert r.open_phase is None
+        d = r.to_dict()
+        assert d["phases"] == r.phases and d["other_s"] == pytest.approx(r.other_s)
+    for r in decode_only:
+        assert {"schedule", "pack", "pad", "enqueue", "fetch", "emit"} <= set(r.phases)
+    if path in ("split", "mixed"):
+        assert all("kv" in r.phases for r in decode_only)  # the paged pool grows rows
+    hist = app.telemetry.registry.get("nxdi_engine_phase_seconds")
+    for name in PHASES:
+        series = hist.snapshot_series(phase=name)
+        assert series.sum == pytest.approx(sum(r.phases.get(name, 0.0) for r in records))
+        assert series.count == sum(1 for r in records if name in r.phases)
+    host = app.telemetry.registry.get("nxdi_engine_host_seconds").snapshot_series()
+    assert host.sum == pytest.approx(sum(r.host_s for r in records))
+
+
+def test_engine_and_wrappers_make_no_annotation_when_telemetry_is_off(
+    tiny_hf_llama, monkeypatch
+):
+    """``TpuConfig(telemetry={"detail": "off"})``: the engine and the
+    wrappers create no TraceAnnotation and record no phase."""
+    import nxdi_tpu.telemetry as telemetry
+
+    made = []
+    monkeypatch.setattr(telemetry, "TraceAnnotation", lambda *a, **k: made.append(a))
+    monkeypatch.setattr(telemetry, "StepTraceAnnotation", lambda *a, **k: made.append(a))
+    hf_model, hf_cfg = tiny_hf_llama
+    app = _build_app(
+        hf_model, hf_cfg, telemetry={"detail": "off"},
+        **_PHASE_PATHS["split"],
+    )
+    engine = InferenceEngine(app, SchedulerConfig(num_slots=2))
+    engine.add_request(P0, SamplingParams(max_new_tokens=4))
+    outs = engine.run()
+    assert outs[0].token_ids == _expected(hf_model, P0, 4)
+    assert made == [] and engine.flight is None
+    assert app.telemetry.registry.get("nxdi_engine_phase_seconds").series() == {}
+
+
 def test_serve_cli_demo_tier1_smoke(capsys):
     """Tier-1 serving smoke: the cli.serve demo (tiny llama, 8 Poisson
     requests, forced preemption) completes and its exported Prometheus text

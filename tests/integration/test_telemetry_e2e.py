@@ -106,7 +106,7 @@ def test_telemetry_off_records_nothing(tmp_path):
 # the cost -> telemetry join (PR: cost observatory)
 # ---------------------------------------------------------------------------
 
-def test_cost_gauges_exact_join_with_injected_latency(loaded_app):
+def test_cost_gauges_exact_join_with_injected_latency(loaded_app, monkeypatch):
     """Injected dispatch latencies against the app's CostSheet must yield
     EXACT roofline gauge values in both the JSON snapshot and the
     Prometheus text: the join divides the histogram's mean (sum/count —
@@ -117,6 +117,15 @@ def test_cost_gauges_exact_join_with_injected_latency(loaded_app):
     for _ in range(3):  # three known dispatches, 2 ms each
         tel.record_dispatch("token_generation_model", 64, 1, 0.002)
 
+    # at detail="basic" a dispatch's latency is its enqueue: a share of the
+    # chip's peak from it would be one no chip can give, so none is published
+    assert not tel.sync_dispatch
+    basic = tel.snapshot()
+    for fam in ("nxdi_program_mfu_pct", "nxdi_program_hbm_bw_pct", "nxdi_roofline_gap_ratio"):
+        assert not basic.get(fam, {}).get("series") and f"{fam}{{" not in tel.prometheus_text()
+    assert basic["_cost_sheets"]  # the sheets themselves ride every snapshot
+
+    monkeypatch.setattr(tel, "sync_dispatch", True)  # detail="full": the wait is in it
     snap = tel.snapshot()
     sheets = {s["program"]: s for s in snap["_cost_sheets"]}
     sheet = sheets["token_generation_model[64]"]

@@ -1,6 +1,6 @@
 """Flight recorder unit suite (nxdi_tpu/telemetry/flight.py): StepRecord
-ring semantics, dispatch attribution + the host-vs-dispatch split under an
-injected clock, postmortem triggers (storm cooldown, retrace trip, manual),
+ring semantics, dispatch attribution + the step's phases (and ``host_s`` =
+wall - fetch) under an injected clock, postmortem triggers (storm cooldown, retrace trip, manual),
 bundle structure, and the Perfetto per-slot track golden."""
 
 import json
@@ -51,6 +51,11 @@ def test_step_record_ring_bounded_and_counts_drops():
     assert tel.registry.get("nxdi_engine_steps_total").total() == 5
 
 
+def _spend(tel, clock, phase, seconds):
+    with tel.phase(phase):
+        clock.advance(seconds)
+
+
 def test_dispatch_attribution_and_host_split():
     rec, tel, clock = make_recorder()
     rec.begin_step()
@@ -61,12 +66,29 @@ def test_dispatch_attribution_and_host_split():
     tel.record_dispatch("context_encoding_model", 32, 1, 0.004)
     tel.record_dispatch("token_generation_model", 64, 1, 0.002)
     tel.record_dispatch("token_generation_model", 64, 1, 0.002)
-    clock.advance(0.010)
+    # a prefill and a decode in one step: fetch is entered twice and sums
+    _spend(tel, clock, "schedule", 0.0005)
+    _spend(tel, clock, "fetch", 0.003)
+    _spend(tel, clock, "pack", 0.001)
+    _spend(tel, clock, "fetch", 0.005)
+    clock.advance(0.0005)  # under no phase
     r = rec.end_step(queue_depth=2, slots_busy=1, kv_blocks_free=17)
     assert r.dispatch_s == pytest.approx(0.008)
     assert r.wall_s == pytest.approx(0.010)
-    assert r.host_s == pytest.approx(0.002)
+    assert r.phases == pytest.approx({"schedule": 0.0005, "fetch": 0.008, "pack": 0.001})
+    # host_s is the step less its wait for the device's tokens, whatever
+    # record_dispatch timed; what no phase covers stays visible
+    assert r.host_s == pytest.approx(r.wall_s - r.phases["fetch"]) == pytest.approx(0.002)
+    assert r.other_s == pytest.approx(0.0005)
+    assert sum(r.phases.values()) + r.other_s == pytest.approx(r.wall_s)
     d = r.to_dict()
+    assert d["phases"] == r.phases and d["other_s"] == pytest.approx(0.0005)
+    assert d["host_s"] == pytest.approx(0.002)
+    hist = tel.registry.get("nxdi_engine_phase_seconds")
+    assert hist.snapshot_series(phase="fetch").sum == pytest.approx(0.008)
+    assert hist.snapshot_series(phase="fetch").count == 1  # one observation per step
+    assert hist.snapshot_series(phase="emit").count == 0  # pre-seeded, never entered
+    assert tel.registry.get("nxdi_engine_host_seconds").snapshot_series().sum == pytest.approx(0.002)
     assert d["programs"] == [
         {"submodel": "context_encoding_model", "bucket": "32", "steps": "1",
          "dispatches": 1, "seconds": pytest.approx(0.004)},
@@ -77,7 +99,10 @@ def test_dispatch_attribution_and_host_split():
         {"request_id": 7, "slot": 1, "resumed": False, "cached": 0, "total": 0}
     ]
     assert d["kv_blocks_free"] == 17 and d["queue_depth"] == 2
-    # dispatches OUTSIDE a step (static generate traffic) attribute nowhere
+    # dispatches and phases OUTSIDE a step (static generate traffic)
+    # attribute nowhere: the phase only annotates the profiler's trace
+    _spend(tel, clock, "pad", 0.001)
+    assert rec.current is None and "pad" not in r.phases
     tel.record_dispatch("token_generation_model", 64, 1, 0.002)
     assert rec.current is None
     json.dumps(d)
@@ -216,26 +241,30 @@ def test_manual_postmortem_bundle_structure(tmp_path):
 
 def test_perfetto_engine_timeline_golden():
     rec, tel, clock = make_recorder(num_slots=2)
-    # step 0: admit + prefill request 1 into slot 0 (10 ms)
+    # step 0: admit + prefill request 1 into slot 0 (10 ms, 8 of them
+    # waiting for the device's token)
     rec.begin_step()
     rec.record_admission(1, 0, resumed=False)
     rec.record_prefill(1, 0, "context_encoding_model", 0, 8)
-    tel.record_dispatch("context_encoding_model", 32, 1, 0.008)
-    clock.advance(0.010)
+    tel.record_dispatch("context_encoding_model", 32, 1, 0.001)
+    _spend(tel, clock, "enqueue", 0.001)
+    _spend(tel, clock, "fetch", 0.008)
+    clock.advance(0.001)
     rec.end_step(0, 1, None)
-    # step 1: decode slots 0+1 (4 ms)
+    # step 1: decode slots 0+1 (4 ms, 3 of them in fetch)
     rec.begin_step()
     rec.record_admission(2, 1, resumed=False)
     rec.record_prefill(2, 1, "context_encoding_model", 0, 5)
     rec.record_decode("token_generation_model", 1, [(0, req(1))], batch=2)
-    tel.record_dispatch("token_generation_model", 64, 1, 0.003)
-    clock.advance(0.004)
+    tel.record_dispatch("token_generation_model", 64, 1, 0.0005)
+    _spend(tel, clock, "fetch", 0.003)
+    _spend(tel, clock, "emit", 0.001)
     rec.end_step(0, 2, None)
-    # step 2: request 2 preempted off slot 1
+    # step 2: request 2 preempted off slot 1; nothing fetched
     rec.begin_step()
     rec.record_preemption(2, 1)
     rec.record_decode("token_generation_model", 1, [(0, req(1))], batch=2)
-    clock.advance(0.002)
+    _spend(tel, clock, "kv", 0.002)
     rec.end_step(1, 1, None)
 
     trace = tel.perfetto_trace()
@@ -269,11 +298,61 @@ def test_perfetto_engine_timeline_golden():
     assert by_name["decode"][0]["args"]["steps"] == 1
     # the preempted segment lands on the VACATED slot's track
     assert [(e["tid"], e["ts"]) for e in by_name["preempted"]] == [(1, 14000.0)]
-    # one host-overhead slice per step, dur = wall - dispatch
+    # one host-overhead slice per step, dur = wall - fetch, the phases and
+    # the time under none of them in its arguments
     host = [(e["tid"], e["ts"], e["dur"]) for e in by_name["host"]]
     assert host == [
         (2, 0.0, 2000.0), (2, 10000.0, 1000.0), (2, 14000.0, 2000.0),
     ]
+    assert by_name["host"][0]["args"]["phases_ms"] == {"enqueue": 1.0, "fetch": 8.0}
+    assert by_name["host"][0]["args"]["other_ms"] == 1.0
+    assert by_name["host"][2]["args"] == {
+        "step": 2, "wall_ms": 2.0, "dispatch_ms": 0.0,
+        "phases_ms": {"kv": 2.0}, "other_ms": 0.0,
+    }
+
+
+def test_phase_is_the_shared_null_context_when_telemetry_is_off(monkeypatch):
+    """detail="off": no TraceAnnotation is made and nothing is recorded;
+    the hot path pays one boolean check."""
+    import nxdi_tpu.telemetry as telemetry
+
+    made = []
+    monkeypatch.setattr(telemetry, "TraceAnnotation", lambda name: made.append(name))
+    monkeypatch.setattr(telemetry, "StepTraceAnnotation", lambda *a, **k: made.append(a))
+    off = Telemetry(detail="off", clock=FakeClock())
+    assert off.phase("fetch") is off.phase("pack") is off.step_span(3)
+    with off.step_span(3), off.phase("fetch"):
+        pass
+    assert made == []
+    assert off.registry.get("nxdi_engine_phase_seconds").series() == {}
+
+
+def test_phase_annotates_on_the_profilers_clock(monkeypatch):
+    """An enabled phase is ``TraceAnnotation("nxdi.step.<phase>")`` and a
+    step ``StepTraceAnnotation("nxdi.step", step_num=StepRecord.step)``."""
+    import contextlib
+
+    import nxdi_tpu.telemetry as telemetry
+
+    made = []
+
+    def fake(name, **kw):
+        made.append((name, kw))
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(telemetry, "TraceAnnotation", fake)
+    monkeypatch.setattr(telemetry, "StepTraceAnnotation", fake)
+    rec, tel, clock = make_recorder()
+    step = rec.begin_step().step
+    with tel.step_span(step):
+        _spend(tel, clock, "schedule", 0.001)
+    rec.end_step(0, 0, None)
+    assert made == [("nxdi.step", {"step_num": 0}), ("nxdi.step.schedule", {})]
+    with pytest.raises(KeyError):
+        tel.phase("not-a-phase")
+    assert set(telemetry.PHASES) == {
+        "schedule", "kv", "pack", "pad", "enqueue", "fetch", "emit"}
 
 
 def test_perfetto_without_flight_unchanged():
