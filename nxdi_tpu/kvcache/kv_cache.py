@@ -9,9 +9,13 @@ which is what the reference's parameter aliasing achieves.
 
 Layout choice: one array per cache side, stacked over layers —
 ``(n_layers, batch, kv_heads, max_len, head_dim)`` — so the decoder runs as a
-single ``lax.scan`` over layers (cache slices are scan xs, updated slices are
-scan ys). One compiled layer body instead of n_layers unrolled copies: much
-faster XLA compiles at 70B scale, same runtime code.
+single ``lax.scan`` over layers. One compiled layer body instead of n_layers
+unrolled copies: much faster XLA compiles at 70B scale, same runtime code.
+How the cache meets that scan is the layout's (models/base.py
+run_decoder_layers): the contiguous decode path reads the old stack and
+commits the fresh rows once after the scan; the paged pool is the scan's
+carry, written and read in place at (layer, slot); only the remaining paths
+(contiguous prefill, ring, MLA) hand per-layer slices through as xs/ys.
 
 Write semantics: exact-position scatter. New K/V for token at position p of
 sequence b is written at [b, :, p, :]. Combined with position-derived causal
@@ -390,17 +394,24 @@ class BlockKVLayout:
     (slot-mapping scatter) and :150 ``_get_block_cache_and_reshape_bhsd``
     (active-block-table gather). Negative slots drop the write (padding lanes);
     the block-table gather returns rows in logical token order so kv positions
-    are simply 0..W-1."""
+    are simply 0..W-1.
+
+    ``update`` and ``read`` take the WHOLE pool ``(L, slots, KV, D)`` and the
+    layer from ``cache_inputs["layer_idx"]``: the pool is one buffer that a
+    step program writes at ``(layer, slot)`` in place and reads at
+    ``(layer, slot)``; no per-layer slice of it is ever taken (a slice riding
+    the layer scan as xs/ys costs whole-pool copies every step)."""
 
     block_size: int
     k_scale: float = 1.0  # scaled fp8 store, see ContiguousKVLayout
     v_scale: float = 1.0
 
-    def update(self, k_cache_l, v_cache_l, k_new, v_new, cache_inputs, spec):
+    def update(self, k_pool, v_pool, k_new, v_new, cache_inputs, spec):
         # k_new (B, KV, S_act, D); slot_mapping (B, S_act) flat slot per token
-        slots = cache_inputs["slot_mapping"].astype(jnp.int32)
-        slots = jnp.where(slots < 0, k_cache_l.shape[0], slots)  # drop padding
-        store = k_cache_l.dtype
+        layer = cache_inputs["layer_idx"].astype(jnp.int32)
+        slots = cache_inputs["slot_mapping"].astype(jnp.int32).reshape(-1)
+        slots = jnp.where(slots < 0, k_pool.shape[1], slots)  # drop padding
+        store = k_pool.dtype
         if self.k_scale != 1.0:
             k_new = k_new / jnp.asarray(self.k_scale, k_new.dtype)
         if self.v_scale != 1.0:
@@ -408,19 +419,22 @@ class BlockKVLayout:
         k_vals = jnp.swapaxes(k_new, 1, 2).astype(store)  # (B, S_act, KV, D)
         v_vals = jnp.swapaxes(v_new, 1, 2).astype(store)
         flat = (-1, k_vals.shape[-2], k_vals.shape[-1])
-        k_cache_l = k_cache_l.at[slots.reshape(-1)].set(k_vals.reshape(flat), mode="drop")
-        v_cache_l = v_cache_l.at[slots.reshape(-1)].set(v_vals.reshape(flat), mode="drop")
-        return k_cache_l, v_cache_l
+        k_pool = k_pool.at[layer, slots].set(k_vals.reshape(flat), mode="drop")
+        v_pool = v_pool.at[layer, slots].set(v_vals.reshape(flat), mode="drop")
+        return k_pool, v_pool
 
-    def read(self, k_cache_l, v_cache_l, cache_inputs, spec):
+    def read(self, k_pool, v_pool, cache_inputs, spec):
         # block_table (B, max_blocks) -> flat slots (B, max_blocks*block_size)
+        layer = cache_inputs["layer_idx"].astype(jnp.int32)
         bt = cache_inputs["block_table"].astype(jnp.int32)
         B, NB = bt.shape
         offs = jnp.arange(self.block_size, dtype=jnp.int32)
         slots = (bt[:, :, None] * self.block_size + offs[None, None, :]).reshape(B, -1)
+        # holes (negative table entries) clip onto slot 0; kv_pos hides them
+        slots = jnp.clip(slots, 0, k_pool.shape[1] - 1)
         compute = spec.compute_dtype
-        kk = jnp.take(k_cache_l, slots, axis=0, mode="clip").astype(compute)
-        vv = jnp.take(v_cache_l, slots, axis=0, mode="clip").astype(compute)
+        kk = k_pool[layer, slots].astype(compute)  # (B, W, KV, D)
+        vv = v_pool[layer, slots].astype(compute)
         if self.k_scale != 1.0:
             kk = kk * jnp.asarray(self.k_scale, compute)
         if self.v_scale != 1.0:

@@ -10,10 +10,15 @@ Structure of one forward (reference: model_base.py:1264 ``get_model_output``):
   residual -> rmsnorm -> MLP -> residual] -> final rmsnorm -> last-token gather
   -> lm_head -> padded-logit mask -> on-device sampler.
 
-The layer stack runs as ONE ``lax.scan`` over layer-stacked params and cache
-(kvcache/kv_cache.py layout): a single compiled layer body regardless of depth,
-which keeps XLA compile times flat as models grow. Heterogeneous stacks (e.g.
-interleaved sliding-window layers) pass per-layer scalars through the scan xs.
+The layer stack runs as ONE ``lax.scan`` over layer-stacked params: a single
+compiled layer body regardless of depth, which keeps XLA compile times flat as
+models grow. Heterogeneous stacks (e.g. interleaved sliding-window layers) pass
+per-layer scalars through the scan xs. Where the KV cache lives in that scan
+depends on its layout (kvcache/kv_cache.py): the paged pool is the scan's
+CARRY — one donated buffer written at (layer, slot) in place and read by the
+paged kernels at (layer, block); the contiguous decode path reads the old
+stack and commits fresh rows once after the scan; only what is left (contiguous
+prefill, ring, MLA) rides the scan as per-layer xs/ys slices.
 """
 
 from __future__ import annotations
@@ -399,8 +404,8 @@ def attention_block(
     hidden: jax.Array,  # (B, S, hidden)
     cos: jax.Array,
     sin: jax.Array,
-    k_cache_l: jax.Array,  # contiguous: (B, KV, W, D) view; block: (slots, KV, D)
-    v_cache_l: jax.Array,
+    k_cache_l: jax.Array,  # contiguous: (B, KV, W, D) view; block: the WHOLE
+    v_cache_l: jax.Array,  # (L, slots, KV, D) pool, addressed at ``layer_idx``
     position_ids: jax.Array,  # (B, S)
     cache_spec,  # KVCacheSpec | BlockKVCacheSpec
     attend_to_cache: bool,
@@ -412,7 +417,7 @@ def attention_block(
     use_rope: Optional[jax.Array] = None,
     defer_write: bool = False,
     qkv_stacked=None,  # (w_s (L,H,T), b_s|None) + stacked_layer_idx: in-scan kernel
-    layer_idx=None,  # GLOBAL layer index (per-layer KV-quant scale rows)
+    layer_idx=None,  # GLOBAL layer index (KV-quant scale rows; the paged pool's layer)
     stacked_layer_idx=None,  # segment-local index into the stacked weights
     tkg_stacked=None,  # (k_s, v_s, kv_len): stacked-cache fused decode kernel
     spec_window=None,  # (k_sp, v_sp, win_pos, slot): draft-window scratch
@@ -776,6 +781,7 @@ def attention_block(
                 with jax.named_scope("attn.core"):
                     ctx = attn_kernels.sharded_ragged_paged_call(
                         policy, q, new_k, new_v, bt, rids[0], position_ids[0],
+                        layer_idx,
                         block_size=layout.block_size,
                         scale=arch.attention_scale,
                         k_scale=layout.k_scale,
@@ -839,6 +845,7 @@ def attention_block(
             with jax.named_scope("attn.core"):
                 ctx = attn_kernels.sharded_paged_prefill_call(
                     policy, q, new_k, new_v, ci["block_table"], position_ids,
+                    layer_idx,
                     block_size=layout.block_size,
                     scale=arch.attention_scale,
                     k_scale=layout.k_scale,
@@ -876,6 +883,7 @@ def attention_block(
             with jax.named_scope("attn.core"):
                 ctx = attn_kernels.sharded_paged_decode_call(
                     policy, q, new_k, new_v, ci["block_table"], position_ids,
+                    layer_idx,
                     block_size=layout.block_size,
                     scale=arch.attention_scale,
                     k_scale=layout.k_scale,
@@ -1554,7 +1562,17 @@ def run_decoder_layers(
     layer_replacements: Optional[Tuple[jax.Array, jax.Array]] = None,
     spec_window_inputs: Optional[Tuple[jax.Array, jax.Array]] = None,
 ):
-    """Scan the layer stack. Cache slices ride the scan as xs/ys.
+    """Scan the layer stack.
+
+    Where the cache rides: the paged pool (``BlockKVLayout``) is the scan's
+    CARRY beside the hidden state — every layer writes its rows at
+    ``(layer, slot)`` and the paged kernels read at ``(layer, block)``, so
+    the donated pool stays one buffer from the program's input to its
+    aliased output and is never an xs or a ys (a pool slice as xs/ys cost
+    five whole-pool passes a step: two copies, two slices, two stackings).
+    The contiguous decode path emits fresh rows as ys and commits once after
+    the scan (``defer``); the other layouts hand per-layer cache slices
+    through as xs/ys.
 
     ``spec_window_inputs`` (win_pos (B, W), slot ()): engaged when the cache
     pytree carries ``k_spec``/``v_spec`` scratch stacks (the fused-speculation
@@ -1586,6 +1604,7 @@ def run_decoder_layers(
 
     # bucket re-windowing slices the cache S dim — meaningless for the paged
     # pool and for the ring layout (its S dim is slots, not positions)
+    paged = isinstance(layout, BlockKVLayout)
     windowable = not isinstance(layout, (BlockKVLayout, WindowKVLayout))
     # deferred cache writes (decode hot path): the scan emits only fresh K/V
     # rows; they commit in ONE scatter on the stacked cache below — carrying
@@ -1777,6 +1796,7 @@ def run_decoder_layers(
     )
 
     ks, vs, hs = [], [], []
+    k_pool, v_pool = (cache["k"], cache["v"]) if paged else (None, None)
     off = 0
     for seg in segments:
         # kernel-stacked weights: keep the big MLP/QKV weights OUT of the
@@ -1786,12 +1806,17 @@ def run_decoder_layers(
         seg, mlp_st, qkv_st = _extract_stacked_weights(arch, seg)
         n_seg = jax.tree_util.tree_leaves(seg)[0].shape[0]
 
-        def body(h, xs, mlp_st=mlp_st, qkv_st=qkv_st, seg_off=off,
+        def body(carry, xs, mlp_st=mlp_st, qkv_st=qkv_st, seg_off=off,
                  tkg_st=None):
             # xs carries the GLOBAL layer index (for per-layer KV-quant scale
-            # rows, kv_cache._scale_for); the per-SEGMENT stacked kernel
-            # weights index with the segment-local offset
+            # rows, kv_cache._scale_for, and the paged pool's layer); the
+            # per-SEGMENT stacked kernel weights index with the segment-local
+            # offset
             lp, kl, vl, ksp, vsp, inj, li, repl = xs
+            if paged:
+                h, kl, vl = carry  # the whole pool, addressed at ``li``
+            else:
+                h = carry
             li_local = li - jnp.int32(seg_off)
             spec_win = None
             if ksp is not None:
@@ -1807,11 +1832,15 @@ def run_decoder_layers(
             if repl is not None:
                 rv, rm = repl
                 h = jnp.where(rm > 0, rv.astype(h.dtype), h)
+            if paged:
+                return (h, nk, nv), (h if collect_hidden else None)
             return h, ((nk, nv, h) if collect_hidden else (nk, nv))
 
-        with jax.named_scope("layers"):
-            k_seg = jax.lax.slice_in_dim(cache["k"], off, off + n_seg, axis=0)
-            v_seg = jax.lax.slice_in_dim(cache["v"], off, off + n_seg, axis=0)
+        k_seg = v_seg = None
+        if not paged:
+            with jax.named_scope("layers"):
+                k_seg = jax.lax.slice_in_dim(cache["k"], off, off + n_seg, axis=0)
+                v_seg = jax.lax.slice_in_dim(cache["v"], off, off + n_seg, axis=0)
         ksp_seg = vsp_seg = None
         if spec_mode:
             ksp_seg = jax.lax.slice_in_dim(cache["k_spec"], off, off + n_seg, axis=0)
@@ -1836,14 +1865,23 @@ def run_decoder_layers(
         xs = (seg, k_seg, v_seg, ksp_seg, vsp_seg, inj_seg,
               off + jnp.arange(n_seg, dtype=jnp.int32), repl_seg)
         with jax.named_scope("layers"):
-            hidden, ys = jax.lax.scan(body, hidden, xs)
+            if paged:
+                (hidden, k_pool, v_pool), seg_h = jax.lax.scan(
+                    body, (hidden, k_pool, v_pool), xs
+                )
+            else:
+                hidden, ys = jax.lax.scan(body, hidden, xs)
         off += n_seg
-        if collect_hidden:
+        if paged:
+            hs.append(seg_h)
+        elif collect_hidden:
             ks.append(ys[0]); vs.append(ys[1]); hs.append(ys[2])
         else:
             ks.append(ys[0]); vs.append(ys[1])
     cat = (lambda xs: xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=0))
-    if spec_mode:
+    if paged:
+        new_cache = {"k": k_pool, "v": v_pool}
+    elif spec_mode:
         # full cache untouched; the scratch stacks carry this step's rows and
         # the whole window commits once, after the draft scan (fused.py)
         new_cache = {

@@ -679,8 +679,9 @@ def sharded_fused_decode_call(
 
 
 def paged_decode_kernel_supported(q_shape, cache_shape, block_size) -> bool:
+    """``cache_shape`` is the stacked pool's (L, total_slots, KV, D)."""
     B, H, Sq, D = q_shape
-    total_slots, KV = cache_shape[0], cache_shape[1]
+    total_slots, KV = cache_shape[1], cache_shape[2]
     if H % KV or Sq != 1 or total_slots % block_size:
         return False
     if mode.interpret():
@@ -691,10 +692,138 @@ def paged_decode_kernel_supported(q_shape, cache_shape, block_size) -> bool:
     return D % 8 == 0 and block_size % 8 == 0 and KV <= 16
 
 
+#: table entries whose blocks one grid step of the paged decode kernel
+#: fetches at once: (2 buffers) x K,V x 8 x (128, KV, 128) bf16 blocks of a
+#: GQA model is a few MiB of VMEM, and a row of <= 1024 tokens is one step
+PAGED_DECODE_PAGES_PER_STEP = 8
+
+
+def _reset_softmax_state(m_ref, l_ref, acc_ref):
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+
+
+def _write_paged_decode_output(o_ref, l_ref, acc_ref, v_scale, KV, G):
+    l = jnp.maximum(l_ref[:, 0], 1e-20)
+    o_ref[0] = (
+        (acc_ref[:] * v_scale / l[:, None])
+        .reshape(KV, G, acc_ref.shape[-1])
+        .astype(o_ref.dtype)
+    )
+
+
 def _paged_decode_kernel(
-    bt_ref, qp_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+    li_ref, bt_ref, qp_ref, q_ref, k_hbm, v_hbm, o_ref,
+    m_ref, l_ref, acc_ref, k_buf, v_buf, sem, slot_ref,
+    *, scale, v_scale, n_rows, n_chunks, pages, KV, G, block_size, compute_dtype,
+):
+    """Grid (row, chunk of ``pages`` table entries). The pool stays in HBM;
+    each step that has live blocks waits for its own (started one live step
+    earlier, into the other buffer), starts the NEXT live step's — the same
+    row's next chunk, or the next row's first — and computes, so a block's
+    HBM latency is paid behind the previous step's compute and all blocks of
+    a step are in flight together. Steps past a row's position touch
+    neither the pool nor the state.
+
+    A block arrives as its (block_size * KV, D) rows, token-major with the kv
+    heads interleaved (row = token * KV + head): ONE dot of all H query rows
+    against it scores every head at once, and the mask keeps, for a query
+    row, the columns of its own kv head (the others' exp is an exact 0, so
+    they add nothing to l or acc). That spends KV x the MXU work a per-head
+    dot needs — nothing beside the block's DMA at decode widths — and never
+    pulls a head's rows out from between the others'."""
+    b, c = pl.program_id(0), pl.program_id(1)
+    layer = li_ref[0]
+    span = pages * block_size  # positions one chunk covers
+    rows = block_size * KV  # pool rows of one block
+
+    def page_copies(row, chunk, slot):
+        """[(live, K copy, V copy)] of one step's table entries: a block is
+        fetched iff it is allocated and starts at or before the position."""
+        out = []
+        for p in range(pages):
+            entry = chunk * pages + p
+            blk = bt_ref[row, entry]
+            live = (blk >= 0) & (entry * block_size <= qp_ref[row])
+            src = pl.ds(pl.multiple_of(jnp.maximum(blk, 0) * rows, rows), rows)
+            out.append((
+                live,
+                pltpu.make_async_copy(
+                    k_hbm.at[layer, src], k_buf.at[slot, p], sem.at[slot, 0, p]
+                ),
+                pltpu.make_async_copy(
+                    v_hbm.at[layer, src], v_buf.at[slot, p], sem.at[slot, 1, p]
+                ),
+            ))
+        return out
+
+    def start(row, chunk, slot):
+        for live, k_copy, v_copy in page_copies(row, chunk, slot):
+            @pl.when(live)
+            def _():
+                k_copy.start()
+                v_copy.start()
+
+    @pl.when((b == 0) & (c == 0))
+    def _():
+        slot_ref[0] = 0
+        start(0, 0, 0)
+
+    @pl.when(c == 0)
+    def _():
+        _reset_softmax_state(m_ref, l_ref, acc_ref)
+
+    # a row's first chunk always runs (it hands the chain of prefetches on,
+    # even for a row with nothing to read); later ones while they hold
+    # positions the row has reached
+    @pl.when((c == 0) | (c * span <= qp_ref[b]))
+    def _():
+        slot = slot_ref[0]
+        q_pos = qp_ref[b]
+        same_row = (c + 1 < n_chunks) & ((c + 1) * span <= q_pos)
+        nxt_row = jnp.where(same_row, b, b + 1)
+        nxt_chunk = jnp.where(same_row, c + 1, 0)
+
+        @pl.when(nxt_row < n_rows)
+        def _():
+            start(nxt_row, nxt_chunk, 1 - slot)
+
+        q = q_ref[0].reshape(KV * G, q_ref.shape[-1])  # row = head * G + g
+        col = jax.lax.broadcasted_iota(jnp.int32, (KV * G, rows), 1)
+        q_head = jax.lax.broadcasted_iota(jnp.int32, (KV * G, rows), 0) // G
+        own_head = (col % KV) == q_head
+        token = col // KV
+        for p, (live, k_copy, v_copy) in enumerate(page_copies(b, c, slot)):
+            @pl.when(live)
+            def _(p=p, k_copy=k_copy, v_copy=v_copy):
+                k_copy.wait()
+                v_copy.wait()
+                k = k_buf[slot, p].astype(compute_dtype)  # (block_size * KV, D)
+                v = v_buf[slot, p].astype(compute_dtype)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale  # (H, block_size * KV)
+                kv_pos = (c * pages + p) * block_size + token
+                mask = own_head & (kv_pos <= q_pos)
+                _online_softmax_step(s, mask, m_ref, l_ref, acc_ref, v)
+
+        slot_ref[0] = 1 - slot
+
+    @pl.when(c == n_chunks - 1)
+    def _():
+        _write_paged_decode_output(o_ref, l_ref, acc_ref, v_scale, KV, G)
+
+
+def _paged_decode_narrow_kernel(
+    li_ref, bt_ref, qp_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     *, scale, v_scale, n_blocks, KV, G, block_size, compute_dtype,
 ):
+    """Heads narrower than a lane tile (D = 64, 96): one (block_size, KV, D)
+    BlockSpec block per (row, table entry) grid step, pipelined by Pallas —
+    Mosaic cannot slice such a pool in HBM for a copy of the kernel's own."""
+    del li_ref  # consumed by the cache index maps
     bi = pl.program_id(1)
     b = pl.program_id(0)
     q_pos = qp_ref[b]
@@ -702,9 +831,7 @@ def _paged_decode_kernel(
 
     @pl.when(bi == 0)
     def _():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        _reset_softmax_state(m_ref, l_ref, acc_ref)
 
     # skip unallocated blocks and blocks entirely past the decode position
     @pl.when((bt >= 0) & (bi * block_size <= q_pos))
@@ -712,7 +839,7 @@ def _paged_decode_kernel(
         kv_pos = bi * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_size), 1
         )
-        base_mask = kv_pos <= q_pos
+        mask = jnp.broadcast_to(kv_pos <= q_pos, (G, block_size))
         # one cache-block read serves every kv head (the block's last two
         # dims are the FULL (KV, D) tail — Mosaic-valid for any KV)
         for kv in range(KV):
@@ -722,27 +849,22 @@ def _paged_decode_kernel(
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
             ) * scale  # (G, block_size)
-            mask = jnp.broadcast_to(base_mask, (G, block_size))
             _online_softmax_step(
                 s, mask, m_ref, l_ref, acc_ref, v, sl=slice(kv * G, (kv + 1) * G)
             )
 
     @pl.when(bi == n_blocks - 1)
     def _():
-        l = jnp.maximum(l_ref[:, 0], 1e-20)
-        o_ref[0] = (
-            (acc_ref[:] * v_scale / l[:, None])
-            .reshape(KV, G, acc_ref.shape[-1])
-            .astype(o_ref.dtype)
-        )
+        _write_paged_decode_output(o_ref, l_ref, acc_ref, v_scale, KV, G)
 
 
 def paged_attention_decode(
     q,  # (B, H, 1, D)
-    k_cache,  # (total_slots, KV, D) — one layer's slice of the paged pool
-    v_cache,  # (total_slots, KV, D)
+    k_cache,  # (L, total_slots, KV, D) — the WHOLE layer-stacked paged pool
+    v_cache,  # (L, total_slots, KV, D)
     block_table,  # (B, NB) int32 block ids in logical token order; <0 = hole
     q_pos,  # (B, 1) int32 decode positions
+    layer_idx,  # scalar/1-elt int32 — the layer of the stack to read
     *,
     block_size: int,
     scale: Optional[float] = None,
@@ -752,68 +874,96 @@ def paged_attention_decode(
     """Decode attention reading K/V **through the block table** — no
     materialized (B, KV, W, D) gather in HBM (the round-1 XLA path's
     O(table-width) traffic; reference analog: NKI block-TKG kernel,
-    attention_base.py:50-162). The table rides scalar prefetch (SMEM) and the
-    BlockSpec index maps address cache blocks directly; each grid step reads
-    a (block_size, KV, D) block ONCE for all kv heads (full-tail blocks keep
-    Mosaic's tiling constraints satisfied for any per-shard KV count).
-    Prefix-cached blocks are just table entries — nothing special. fp8 scaled
-    caches fold ``k_scale`` into the softmax scale and ``v_scale`` into the
-    output normalization (exact, since both are per-tensor)."""
+    attention_base.py:50-162). Prefix-cached blocks are just table entries —
+    nothing special. fp8 scaled caches fold ``k_scale`` into the softmax
+    scale and ``v_scale`` into the output normalization (exact, since both
+    are per-tensor).
+
+    The pool operand is the whole (L, slots, KV, D) stack and the layer is one
+    more prefetched scalar: inside the decoder's layer scan a Pallas operand
+    on a per-layer slice of the pool materializes the slice (and made the
+    scan copy the pool). A block is ONE copy for all kv heads.
+
+    At lane-wide heads (D a multiple of 128) the pool stays in HBM and the
+    kernel fetches the blocks itself (``_paged_decode_kernel``): the live
+    blocks of up to ``PAGED_DECODE_PAGES_PER_STEP`` table entries fly
+    together, one step ahead of the compute, through the pool's
+    (L, slots * KV, D) view — the same bytes (XLA makes the reshape a
+    bitcast), moved as 4 KiB tiles where the (.., KV, D) view moves KV-row
+    tiles (512 B at KV = 2), and from HBM the tile count is what a copy's
+    time goes by: 36 launches over 333 live blocks took 59 ms with one
+    (block_size, KV, D) BlockSpec block a step, 52 ms with eight of those in
+    flight, 7.0 ms with eight on this view (PERF.md, PR 27). Narrower heads
+    keep the BlockSpec form (``_paged_decode_narrow_kernel``)."""
     B, H, Sq, D = q.shape
     assert Sq == 1, "paged decode kernel is single-position"
-    KV = k_cache.shape[1]
+    L, slots, KV = k_cache.shape[:3]
     G = H // KV
     NB = block_table.shape[1]
     scale = (D ** -0.5 if scale is None else scale) * k_scale
-    compute_dtype = q.dtype
-
-    qf = q.reshape(B, KV, G, D)
+    static = dict(
+        scale=scale, v_scale=v_scale, KV=KV, G=G, block_size=block_size,
+        compute_dtype=q.dtype,
+    )
+    q_spec = pl.BlockSpec((1, KV, G, D), lambda b, i, *_: (b, 0, 0, 0))
+    state = [  # the running (m, l, acc) of one row, all heads
+        pltpu.VMEM((KV * G, 1), jnp.float32),
+        pltpu.VMEM((KV * G, 1), jnp.float32),
+        pltpu.VMEM((KV * G, D), jnp.float32),
+    ]
     bt = block_table.astype(jnp.int32)
-    qp = q_pos[:, 0].astype(jnp.int32)
+    if D % 128 == 0:
+        pages = min(PAGED_DECODE_PAGES_PER_STEP, NB)
+        n_chunks = -(-NB // pages)
+        if n_chunks * pages != NB:  # entries past the table are holes
+            bt = jnp.pad(bt, ((0, 0), (0, n_chunks * pages - NB)), constant_values=-1)
+        k_cache = k_cache.reshape(L, slots * KV, D)
+        v_cache = v_cache.reshape(L, slots * KV, D)
+        kernel = functools.partial(
+            _paged_decode_kernel, n_rows=B, n_chunks=n_chunks, pages=pages, **static
+        )
+        grid = (B, n_chunks)
+        pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+        buf = (2, pages, block_size * KV, D)  # [buffer, table entry] blocks
+        scratch = state + [
+            pltpu.VMEM(buf, k_cache.dtype),
+            pltpu.VMEM(buf, v_cache.dtype),
+            pltpu.SemaphoreType.DMA((2, 2, pages)),  # [buffer, K | V, entry]
+            pltpu.SMEM((1,), jnp.int32),  # the buffer the current step reads
+        ]
+    else:
+        kernel = functools.partial(_paged_decode_narrow_kernel, n_blocks=NB, **static)
+        grid = (B, NB)
 
-    kernel = functools.partial(
-        _paged_decode_kernel,
-        scale=scale,
-        v_scale=v_scale,
-        n_blocks=NB,
-        KV=KV,
-        G=G,
-        block_size=block_size,
-        compute_dtype=compute_dtype,
-    )
+        def cache_index(b, bi, li_ref, bt_ref, qp_ref):
+            # unallocated/future blocks clamp to block 0 — the kernel masks them out
+            return li_ref[0], jnp.maximum(bt_ref[b, bi], 0), 0, 0
 
-    def cache_index(b, bi, bt_ref, qp_ref):
-        # unallocated/future blocks clamp to block 0 — the kernel masks them out
-        return jnp.maximum(bt_ref[b, bi], 0), 0, 0
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, NB),
-        in_specs=[
-            pl.BlockSpec((1, KV, G, D), lambda b, bi, *_: (b, 0, 0, 0)),
-            pl.BlockSpec((block_size, KV, D), cache_index),
-            pl.BlockSpec((block_size, KV, D), cache_index),
-        ],
-        out_specs=pl.BlockSpec((1, KV, G, D), lambda b, bi, *_: (b, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((KV * G, 1), jnp.float32),
-            pltpu.VMEM((KV * G, 1), jnp.float32),
-            pltpu.VMEM((KV * G, D), jnp.float32),
-        ],
-    )
+        pool_spec = pl.BlockSpec((None, block_size, KV, D), cache_index)
+        scratch = state
     out = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=grid,
+            in_specs=[q_spec, pool_spec, pool_spec],
+            out_specs=q_spec,
+            scratch_shapes=scratch,
+        ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
         name="paged_attention_decode",
         interpret=mode.interpret(),
-    )(bt, qp, qf, k_cache, v_cache)
+    )(
+        jnp.asarray(layer_idx, jnp.int32).reshape(1), bt, q_pos[:, 0].astype(jnp.int32),
+        q.reshape(B, KV, G, D), k_cache, v_cache,
+    )
     return out.reshape(B, H, 1, D)
 
 
 def paged_prefill_kernel_supported(q_shape, cache_shape, block_size) -> bool:
+    """``cache_shape`` is the stacked pool's (L, total_slots, KV, D)."""
     B, H, Sq, D = q_shape
-    total_slots, KV = cache_shape[0], cache_shape[1]
+    total_slots, KV = cache_shape[1], cache_shape[2]
     G = H // KV if H % KV == 0 else 0
     if not G or total_slots % block_size:
         return False
@@ -823,9 +973,10 @@ def paged_prefill_kernel_supported(q_shape, cache_shape, block_size) -> bool:
 
 
 def _paged_prefill_kernel(
-    bt_ref, qs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+    li_ref, bt_ref, qs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     *, scale, v_scale, n_blocks, KV, G, block_q, block_size, compute_dtype,
 ):
+    del li_ref  # consumed by the cache index maps
     qi, bi = pl.program_id(1), pl.program_id(2)
     b = pl.program_id(0)
     q_start = qs_ref[b]
@@ -879,10 +1030,11 @@ def _paged_prefill_kernel(
 
 def paged_attention_prefill(
     q,  # (B, H, Sq, D) — the active chunk/suffix queries
-    k_cache,  # (total_slots, KV, D) — paged pool, chunk already written
-    v_cache,  # (total_slots, KV, D)
+    k_cache,  # (L, total_slots, KV, D) — stacked paged pool, chunk already written
+    v_cache,  # (L, total_slots, KV, D)
     block_table,  # (B, NB) int32 block ids in logical token order; <0 = hole
     q_pos,  # (B, Sq) int32 — affine per row (chunk start + arange)
+    layer_idx,  # scalar/1-elt int32 — the layer of the stack to read
     *,
     block_size: int,
     scale: Optional[float] = None,
@@ -898,9 +1050,10 @@ def paged_attention_prefill(
     blocks are just table entries. The chunk's own K/V must already be
     scattered into the pool (BlockKVLayout.update runs first), so new tokens
     attend earlier tokens of the same chunk through the table like the
-    reference's contexted prefill."""
+    reference's contexted prefill. The pool is the whole layer stack, read at
+    ``layer_idx`` (see ``paged_attention_decode``)."""
     B, H, Sq, D = q.shape
-    KV = k_cache.shape[1]
+    KV = k_cache.shape[2]
     G = H // KV
     NB = block_table.shape[1]
     scale = (D ** -0.5 if scale is None else scale) * k_scale
@@ -911,6 +1064,7 @@ def paged_attention_prefill(
     qf = q.reshape(B, KV, G, Sq, D)
     bt = block_table.astype(jnp.int32)
     qs = q_pos[:, 0].astype(jnp.int32)
+    li = jnp.asarray(layer_idx, jnp.int32).reshape(1)
 
     kernel = functools.partial(
         _paged_prefill_kernel,
@@ -924,18 +1078,18 @@ def paged_attention_prefill(
         compute_dtype=compute_dtype,
     )
 
-    def cache_index(b, qi, bi, bt_ref, qs_ref):
-        return jnp.maximum(bt_ref[b, bi], 0), 0, 0
+    def cache_index(b, qi, bi, li_ref, bt_ref, qs_ref):
+        return li_ref[0], jnp.maximum(bt_ref[b, bi], 0), 0, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, Sq // block_q, NB),
         in_specs=[
             pl.BlockSpec(
                 (1, KV, G, block_q, D), lambda b, qi, bi, *_: (b, 0, 0, qi, 0)
             ),
-            pl.BlockSpec((block_size, KV, D), cache_index),
-            pl.BlockSpec((block_size, KV, D), cache_index),
+            pl.BlockSpec((None, block_size, KV, D), cache_index),
+            pl.BlockSpec((None, block_size, KV, D), cache_index),
         ],
         out_specs=pl.BlockSpec(
             (1, KV, G, block_q, D), lambda b, qi, bi, *_: (b, 0, 0, qi, 0)
@@ -952,16 +1106,17 @@ def paged_attention_prefill(
         out_shape=jax.ShapeDtypeStruct((B, KV, G, Sq, D), q.dtype),
         name="paged_attention_prefill",
         interpret=mode.interpret(),
-    )(bt, qs, qf, k_cache, v_cache)
+    )(li, bt, qs, qf, k_cache, v_cache)
     return out.reshape(B, H, Sq, D)
 
 
 def sharded_paged_prefill_call(
-    policy, q, k_cache, v_cache, block_table, q_pos,
+    policy, q, k_cache, v_cache, block_table, q_pos, layer_idx,
     *, block_size, scale=None, k_scale=1.0, v_scale=1.0,
 ):
-    """Paged prefill under GSPMD (see sharded_paged_decode_call): cache and q
-    shard over kv heads on tp; table and positions are replicated."""
+    """Paged prefill under GSPMD (see sharded_paged_decode_call): the stacked
+    pool and q shard over kv heads on tp; table, positions and the layer
+    index are replicated."""
     from jax.sharding import PartitionSpec as P
 
     fn = functools.partial(
@@ -973,7 +1128,7 @@ def sharded_paged_prefill_call(
     )
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
-        return fn(q, k_cache, v_cache, block_table, q_pos)
+        return fn(q, k_cache, v_cache, block_table, q_pos, layer_idx)
     if policy.q[0] is not None or policy.q[2] is not None:
         return None  # batch/seq-sharded prefill (DP/CP) -> XLA path
     shard_fn = jax.shard_map(
@@ -981,24 +1136,25 @@ def sharded_paged_prefill_call(
         mesh=mesh,
         in_specs=(
             P(*policy.q),
-            P(None, policy.q[1], None),
-            P(None, policy.q[1], None),
+            P(None, None, policy.q[1], None),
+            P(None, None, policy.q[1], None),
             P(None, None),
             P(None, None),
+            P(),
         ),
         out_specs=P(*policy.q),
         check_vma=False,
     )
-    return shard_fn(q, k_cache, v_cache, block_table, q_pos)
+    return shard_fn(q, k_cache, v_cache, block_table, q_pos, layer_idx)
 
 
 def sharded_paged_decode_call(
-    policy, q, k_cache, v_cache, block_table, q_pos,
+    policy, q, k_cache, v_cache, block_table, q_pos, layer_idx,
     *, block_size, scale=None, k_scale=1.0, v_scale=1.0,
 ):
-    """Paged decode under GSPMD: cache + q shard over kv-heads on tp, the
-    block table and positions are replicated host metadata. Returns None when
-    the mesh layout shards anything the kernel can't see locally."""
+    """Paged decode under GSPMD: the stacked pool + q shard over kv-heads on
+    tp, the block table, positions and the layer index are replicated. Returns
+    None when the mesh layout shards anything the kernel can't see locally."""
     from jax.sharding import PartitionSpec as P
 
     fn = functools.partial(
@@ -1010,8 +1166,8 @@ def sharded_paged_decode_call(
     )
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
-        return fn(q, k_cache, v_cache, block_table, q_pos)
-    # block pool layer slice is (slots, KV, D) sharded on heads only
+        return fn(q, k_cache, v_cache, block_table, q_pos, layer_idx)
+    # the block pool is (L, slots, KV, D), sharded on heads only
     if policy.q[0] is not None or policy.q[2] is not None:
         return None  # batch/seq-sharded decode (DP/flash-decode) -> XLA path
     shard_fn = jax.shard_map(
@@ -1019,15 +1175,16 @@ def sharded_paged_decode_call(
         mesh=mesh,
         in_specs=(
             P(*policy.q),
-            P(None, policy.q[1], None),
-            P(None, policy.q[1], None),
+            P(None, None, policy.q[1], None),
+            P(None, None, policy.q[1], None),
             P(None, None),
             P(None, None),
+            P(),
         ),
         out_specs=P(*policy.q),
         check_vma=False,
     )
-    return shard_fn(q, k_cache, v_cache, block_table, q_pos)
+    return shard_fn(q, k_cache, v_cache, block_table, q_pos, layer_idx)
 
 
 # ---------------------------------------------------------------------------

@@ -8,9 +8,10 @@ kernel shape (PAPERS.md) that lets the serving engine issue one dispatch
 per step instead of separate CTE + TKG programs.
 
 Relationship to the per-row kernels (flash_attention.py):
-  - same cache addressing: the block table rides scalar prefetch and the
-    BlockSpec index maps pull (block_size, KV, D) pool blocks directly —
-    no materialized (R, KV, W, D) gather in HBM.
+  - same cache addressing: the block table and the layer index ride scalar
+    prefetch and the BlockSpec index maps pull (block_size, KV, D) blocks
+    straight out of the (L, slots, KV, D) pool — no materialized
+    (R, KV, W, D) gather in HBM, no per-layer slice of the pool.
   - same softmax state machine: `_online_softmax_step` is shared, and a
     fully-masked block update is an exact no-op on the running (m, l, acc)
     state (s == NEG_INF everywhere -> m_new == m_prev, corr == 1, p == 0).
@@ -50,9 +51,10 @@ from nxdi_tpu.ops.kernels.flash_attention import (
 
 def ragged_paged_kernel_supported(q_shape, cache_shape, block_size) -> bool:
     """Same Mosaic envelope as the per-row paged prefill kernel, plus the
-    packed layout's B == 1 (the batch dim is folded into the token stream)."""
+    packed layout's B == 1 (the batch dim is folded into the token stream).
+    ``cache_shape`` is the stacked pool's (L, total_slots, KV, D)."""
     B, H, T, D = q_shape
-    total_slots, KV = cache_shape[0], cache_shape[1]
+    total_slots, KV = cache_shape[1], cache_shape[2]
     if B != 1 or H % KV or total_slots % block_size:
         return False
     if mode.interpret():
@@ -61,11 +63,12 @@ def ragged_paged_kernel_supported(q_shape, cache_shape, block_size) -> bool:
 
 
 def _ragged_kernel(
-    bt_ref, tmin_ref, tmax_ref, rid_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
-    m_ref, l_ref, acc_ref,
+    li_ref, bt_ref, tmin_ref, tmax_ref, rid_ref, qp_ref, q_ref, k_ref, v_ref,
+    o_ref, m_ref, l_ref, acc_ref,
     *, scale, v_scale, n_rows, n_blocks, KV, G, block_q, block_size,
     compute_dtype,
 ):
+    del li_ref  # consumed by the cache index maps
     qi, j = pl.program_id(0), pl.program_id(1)
     rj = j // n_blocks  # the row this step serves
     bj = j % n_blocks  # the row's logical cache block
@@ -117,11 +120,12 @@ def _ragged_kernel(
 
 def ragged_paged_attention(
     q,  # (1, H, T, D) — the packed mixed-batch queries
-    k_cache,  # (total_slots, KV, D) — paged pool, this step's rows written
-    v_cache,  # (total_slots, KV, D)
+    k_cache,  # (L, total_slots, KV, D) — stacked pool, this step's rows written
+    v_cache,  # (L, total_slots, KV, D)
     block_tables,  # (R, NB) int32 block ids per row in logical order; <0 = hole
     row_ids,  # (T,) int32 — owning row per packed token; -1 = padding
     q_pos,  # (T,) int32 — position within the row per packed token
+    layer_idx,  # scalar/1-elt int32 — the layer of the stack to read
     *,
     block_size: int,
     scale: Optional[float] = None,
@@ -138,7 +142,7 @@ def ragged_paged_attention(
     so a tile over one row's chunk pays that row's blocks only."""
     B, H, T, D = q.shape
     assert B == 1, "ragged kernel takes the packed (1, H, T, D) layout"
-    KV = k_cache.shape[1]
+    KV = k_cache.shape[2]
     G = H // KV
     R, NB = block_tables.shape
     scale = (D ** -0.5 if scale is None else scale) * k_scale
@@ -151,6 +155,7 @@ def ragged_paged_attention(
     bt = block_tables.astype(jnp.int32)
     rid = row_ids.astype(jnp.int32)
     qp = q_pos.astype(jnp.int32)
+    li = jnp.asarray(layer_idx, jnp.int32).reshape(1)
     # per-tile live row range for the block skip; an all-padding tile gets
     # an empty range (min > max) and touches no blocks at all
     rid2 = rid.reshape(nq, block_q)
@@ -171,12 +176,12 @@ def ragged_paged_attention(
         compute_dtype=compute_dtype,
     )
 
-    def cache_index(qi, j, bt_ref, tmin_ref, tmax_ref):
+    def cache_index(qi, j, li_ref, bt_ref, tmin_ref, tmax_ref):
         # holes/skipped steps clamp to block 0 — the kernel masks them out
-        return jnp.maximum(bt_ref[j // NB, j % NB], 0), 0, 0
+        return li_ref[0], jnp.maximum(bt_ref[j // NB, j % NB], 0), 0, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(nq, R * NB),
         in_specs=[
             pl.BlockSpec((block_q, 1), lambda qi, j, *_: (qi, 0)),
@@ -184,8 +189,8 @@ def ragged_paged_attention(
             pl.BlockSpec(
                 (1, KV, G, block_q, D), lambda qi, j, *_: (0, 0, 0, qi, 0)
             ),
-            pl.BlockSpec((block_size, KV, D), cache_index),
-            pl.BlockSpec((block_size, KV, D), cache_index),
+            pl.BlockSpec((None, block_size, KV, D), cache_index),
+            pl.BlockSpec((None, block_size, KV, D), cache_index),
         ],
         out_specs=pl.BlockSpec(
             (1, KV, G, block_q, D), lambda qi, j, *_: (0, 0, 0, qi, 0)
@@ -202,17 +207,17 @@ def ragged_paged_attention(
         out_shape=jax.ShapeDtypeStruct((1, KV, G, T, D), q.dtype),
         name="ragged_paged_attention",
         interpret=mode.interpret(),
-    )(bt, tile_min, tile_max, rid[:, None], qp[:, None], qf, k_cache, v_cache)
+    )(li, bt, tile_min, tile_max, rid[:, None], qp[:, None], qf, k_cache, v_cache)
     return out.reshape(1, H, T, D)
 
 
 def sharded_ragged_paged_call(
-    policy, q, k_cache, v_cache, block_tables, row_ids, q_pos,
+    policy, q, k_cache, v_cache, block_tables, row_ids, q_pos, layer_idx,
     *, block_size, scale=None, k_scale=1.0, v_scale=1.0,
 ):
     """Ragged paged attention under GSPMD (see sharded_paged_prefill_call):
-    cache and q shard over kv heads on tp; tables and token tags are
-    replicated host metadata."""
+    the stacked pool and q shard over kv heads on tp; tables, token tags and
+    the layer index are replicated."""
     from jax.sharding import PartitionSpec as P
 
     fn = functools.partial(
@@ -224,7 +229,7 @@ def sharded_ragged_paged_call(
     )
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
-        return fn(q, k_cache, v_cache, block_tables, row_ids, q_pos)
+        return fn(q, k_cache, v_cache, block_tables, row_ids, q_pos, layer_idx)
     if policy.q[0] is not None or policy.q[2] is not None:
         return None  # batch/seq-sharded packed stream (DP/CP) -> XLA path
     shard_fn = jax.shard_map(
@@ -232,13 +237,14 @@ def sharded_ragged_paged_call(
         mesh=mesh,
         in_specs=(
             P(*policy.q),
-            P(None, policy.q[1], None),
-            P(None, policy.q[1], None),
+            P(None, None, policy.q[1], None),
+            P(None, None, policy.q[1], None),
             P(None, None),
             P(None),
             P(None),
+            P(),
         ),
         out_specs=P(*policy.q),
         check_vma=False,
     )
-    return shard_fn(q, k_cache, v_cache, block_tables, row_ids, q_pos)
+    return shard_fn(q, k_cache, v_cache, block_tables, row_ids, q_pos, layer_idx)

@@ -16,6 +16,8 @@ compilation cache is off around the compiles (an entry written for a
 described chip cannot be read back without one).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -112,9 +114,13 @@ def test_flash_decode(width, one_chip, mosaic):
     )
 
 
+POOL_LAYERS = 4  # the paged kernels take the whole stack and a layer index
+LAYER = ((1,), I32)
+
+
 def _pool(w):
     n_blocks = SLOTS * (SEQ // BLOCK) + SLOTS
-    return ((n_blocks * BLOCK, w["KV"], w["D"]), BF16)
+    return ((POOL_LAYERS, n_blocks * BLOCK, w["KV"], w["D"]), BF16)
 
 
 @pytest.mark.parametrize("width", WIDTH_IDS)
@@ -124,10 +130,10 @@ def test_paged_prefill(width, one_chip, mosaic):
     pool = _pool(w)
     assert fa.paged_prefill_kernel_supported(q[0], pool[0], BLOCK)
     _compile(
-        lambda q, k, v, bt, pos: fa.paged_attention_prefill(
-            q, k, v, bt, pos, block_size=BLOCK
+        lambda q, k, v, bt, pos, li: fa.paged_attention_prefill(
+            q, k, v, bt, pos, li, block_size=BLOCK
         ),
-        one_chip, q, pool, pool, ((1, SEQ // BLOCK), I32), ((1, PROMPT), I32),
+        one_chip, q, pool, pool, ((1, SEQ // BLOCK), I32), ((1, PROMPT), I32), LAYER,
     )
 
 
@@ -138,10 +144,10 @@ def test_paged_decode(width, one_chip, mosaic):
     pool = _pool(w)
     assert fa.paged_decode_kernel_supported(q[0], pool[0], BLOCK)
     _compile(
-        lambda q, k, v, bt, pos: fa.paged_attention_decode(
-            q, k, v, bt, pos, block_size=BLOCK
+        lambda q, k, v, bt, pos, li: fa.paged_attention_decode(
+            q, k, v, bt, pos, li, block_size=BLOCK
         ),
-        one_chip, q, pool, pool, ((SLOTS, SEQ // BLOCK), I32), ((SLOTS, 1), I32),
+        one_chip, q, pool, pool, ((SLOTS, SEQ // BLOCK), I32), ((SLOTS, 1), I32), LAYER,
     )
 
 
@@ -155,11 +161,11 @@ def test_ragged_paged(width, one_chip, mosaic):
     pool = _pool(w)
     assert ragged_paged_kernel_supported(q[0], pool[0], BLOCK)
     _compile(
-        lambda q, k, v, bt, rid, pos: ragged_paged_attention(
-            q, k, v, bt, rid, pos, block_size=BLOCK
+        lambda q, k, v, bt, rid, pos, li: ragged_paged_attention(
+            q, k, v, bt, rid, pos, li, block_size=BLOCK
         ),
         one_chip, q, pool, pool,
-        ((SLOTS + 1, SEQ // BLOCK), I32), ((T,), I32), ((T,), I32),
+        ((SLOTS + 1, SEQ // BLOCK), I32), ((T,), I32), ((T,), I32), LAYER,
     )
 
 
@@ -241,3 +247,145 @@ def test_kernel_bytes_do_not_depend_on_the_call_path(one_chip, mosaic):
         assert lower() == from_another_caller()
     finally:
         jax.config.update("jax_include_full_tracebacks_in_locations", was)
+
+
+# ---------------------------------------------------------------------------
+# Whole step programs over the paged pool: the pool stays in ONE place
+# ---------------------------------------------------------------------------
+
+# Qwen2.5-3B-Instruct's widths (benchmark/configs/qwen25-3b.json), its 36
+# layers (the layer scan compiles ONE layer body), and the serving stack of the
+# `qwen25-3b` cells: 64 decode rows, window 4096, 448 blocks of 128 = a
+# (36, 57344, 2, 128) bf16 pool per side, 1.97 GiB for K and V together
+QWEN25_3B = dict(
+    model_type="qwen2", hidden_size=2048, intermediate_size=11008,
+    num_hidden_layers=36, num_attention_heads=16, num_key_value_heads=2,
+    vocab_size=151936, rms_norm_eps=1e-6, rope_theta=1000000.0,
+    max_position_embeddings=32768, tie_word_embeddings=True, hidden_act="silu",
+)
+ROWS, WINDOW, POOL_BLOCKS, CTE_BUCKET = 64, 4096, 448, 256
+
+_HLO_LINE = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ([a-z0-9]+\[[0-9,]*\])\S* ([a-z][a-z\-]*)\(")
+_MOVERS = ("copy", "dynamic-slice", "dynamic-update-slice")
+
+
+def _paged_app(config, devices, **tpu_kwargs):
+    """An un-loaded paged serving app of ``config`` on ``devices`` (described
+    or real): wrappers built, nothing placed, so its programs lower from
+    shapes alone."""
+    from nxdi_tpu.config import OnDeviceSamplingConfig, TpuConfig
+    from nxdi_tpu.models.registry import get_family
+    from nxdi_tpu.parallel.mesh import mesh_from_config
+    from nxdi_tpu.runtime.application import TpuModelForCausalLM
+
+    family, cfg_cls = get_family(config["model_type"])
+    tc = TpuConfig(
+        tp_degree=1, dtype="bfloat16",
+        on_device_sampling_config=OnDeviceSamplingConfig(),
+        is_block_kv_layout=True, telemetry="off", **tpu_kwargs,
+    )
+    app = TpuModelForCausalLM(
+        "<shapes>", cfg_cls(tc, load_config=lambda: dict(config)), model_family=family
+    )
+    app.mesh = mesh_from_config(tc, devices=devices)
+    app._build_wrappers()
+    return app
+
+
+def _pool_movers(hlo_text, pool_shape):
+    """Instructions of the optimised HLO that copy, slice or stack something
+    of the pool's shape or of one layer's slice of it."""
+    L, S, KV, D = pool_shape
+    shapes = {f"bf16[{L},{S},{KV},{D}]", f"bf16[1,{S},{KV},{D}]", f"bf16[{S},{KV},{D}]"}
+    found = []
+    for line in hlo_text.splitlines():
+        m = _HLO_LINE.match(line)
+        if not m or m.group(2) not in shapes:
+            continue
+        name, _, opcode = m.groups()
+        if opcode in _MOVERS or (opcode == "fusion" and any(w in name for w in _MOVERS)):
+            found.append(f"{opcode} {name} {m.group(2)}")
+    return found
+
+
+@pytest.mark.parametrize(
+    "tag", ["token_generation_model", "context_encoding_model"], ids=["tkg64", "cte256"]
+)
+def test_paged_step_program_keeps_the_pool_in_place(tag, topo, mosaic):
+    """The real token-generation program (64 rows) and CTE[256] of a paged
+    app at `qwen25-3b` widths: no pool-sized ``temp``, no copy, slice or
+    stacking of the pool or of a layer's slice in the optimised HLO, and the
+    output pool aliased to the donated input. With the pool as the layer
+    scan's xs/ys this read: temp 1.97 GiB, two ``copy`` of the pool, two
+    ``dynamic-slice`` and two ``dynamic-update-slice`` fusions a layer."""
+    app = _paged_app(
+        QWEN25_3B, topo.devices[:1],
+        batch_size=ROWS, ctx_batch_size=1, tkg_batch_size=ROWS, seq_len=WINDOW,
+        max_context_length=CTE_BUCKET, context_encoding_buckets=[CTE_BUCKET],
+        pa_block_size=BLOCK, pa_num_blocks=POOL_BLOCKS,
+        attn_kernel_enabled=True, attn_block_tkg_kernel_enabled=True,
+    )
+    cache = app._cache_struct()
+    pool_shape = cache["k"].shape
+    assert pool_shape == (36, POOL_BLOCKS * BLOCK, 2, 128)
+    pool_bytes = 2 * 2 * 36 * POOL_BLOCKS * BLOCK * 2 * 128  # K and V, bf16
+
+    (compiled,) = app.models[tag].aot_compile(app.build_params_struct(), cache).values()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < pool_bytes // 8, memory
+    assert memory.alias_size_in_bytes >= pool_bytes, memory
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the compiled program"
+    assert _pool_movers(text, pool_shape) == []
+
+
+def _scans(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans(sub)
+
+
+def test_layer_scan_carries_the_pool_in_every_paged_program():
+    """CPU backend, no topology: in the jaxpr of every program of a paged app
+    (context encoding, prefix/chunked prefill, token generation, mixed) the
+    layer scan has the K and V pools among its CARRY and no xs or ys operand
+    of the pool's shape (nor of a per-segment stack of its layers)."""
+    tiny = dict(
+        model_type="llama", hidden_size=64, intermediate_size=128,
+        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2,
+        vocab_size=256, rms_norm_eps=1e-5, rope_theta=10000.0,
+        max_position_embeddings=128, tie_word_embeddings=False, hidden_act="silu",
+    )
+    app = _paged_app(
+        tiny, jax.devices()[:1],
+        batch_size=3, ctx_batch_size=1, tkg_batch_size=3, seq_len=64,
+        max_context_length=32, pa_block_size=8, pa_num_blocks=32,
+        is_prefix_caching=True, mixed_dispatch=True,
+    )
+    cache = app._cache_struct()
+    pool = cache["k"].shape
+    params = app.build_params_struct()
+    seen = set()
+    for tag, wrapper in app.models.items():
+        for key, prog in wrapper._programs.items():
+            with jax.set_mesh(app.mesh):
+                jaxpr = jax.make_jaxpr(prog._fn)(params, cache, wrapper._example_for_key(key))
+            layer_scans = [
+                e for e in _scans(jaxpr.jaxpr)
+                if any(getattr(v.aval, "shape", None) == pool for v in e.invars)
+            ]
+            assert len(layer_scans) == 1, (tag, key, len(layer_scans))
+            (scan,) = layer_scans
+            n_consts, n_carry = scan.params["num_consts"], scan.params["num_carry"]
+            carry = scan.invars[n_consts:n_consts + n_carry]
+            xs, ys = scan.invars[n_consts + n_carry:], scan.outvars[n_carry:]
+            assert [v.aval.shape for v in carry].count(pool) == 2, (tag, key)
+            assert [v.aval.shape for v in scan.outvars[:n_carry]].count(pool) == 2
+            for v in list(xs) + list(ys):
+                shape = getattr(v.aval, "shape", ())
+                assert shape[1:] != pool[1:], (tag, key, shape)
+            seen.add(tag)
+    assert {"context_encoding_model", "token_generation_model", "mixed_model"} <= seen, seen
+    assert len(seen) >= 4, seen  # + the prefix/chunked prefill program

@@ -4,6 +4,7 @@
 On CPU the kernels run in interpreter mode; semantics must match
 ops/attention.py to float tolerance on every mask variant."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -77,17 +78,39 @@ def test_decode_kernel_sliding_window():
 from nxdi_tpu.kvcache.kv_cache import BlockKVCacheSpec, BlockKVLayout  # noqa: E402
 from nxdi_tpu.ops.kernels.flash_attention import paged_attention_decode  # noqa: E402
 
+# the paged kernels take the WHOLE (L, slots, KV, D) pool and a layer index:
+# every case builds a 3-layer pool and reads layer 1, against a reference
+# computed on ``pool[1]`` alone
+LAYERS, LAYER = 3, 1
 
+
+def _gathered_window(pool_l, bt, bs):
+    """One layer's (slots, KV, D) pool gathered through the block table:
+    (B, KV, W, D) rows in table order and their positions, holes poisoned —
+    the per-layer reference, independent of BlockKVLayout."""
+    B, NB = bt.shape
+    offs = jnp.arange(bs, dtype=jnp.int32)
+    slots = (bt[:, :, None] * bs + offs[None, None, :]).reshape(B, -1)
+    rows = jnp.swapaxes(jnp.take(pool_l, slots, axis=0, mode="clip"), 1, 2)
+    W = NB * bs
+    kv_pos = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32)[None, :], (B, W))
+    valid = jnp.repeat(bt >= 0, bs, axis=1)
+    return rows, jnp.where(valid, kv_pos, jnp.int32(2**30))
+
+
+@pytest.mark.parametrize("D", [16, 128], ids=["d16", "d128"])
 @pytest.mark.parametrize("H,KV", [(4, 4), (8, 2)])
-def test_paged_decode_kernel_matches_gathered_read(H, KV):
-    """Kernel reading through a scrambled block table (with holes) must equal
-    the XLA gather path (BlockKVLayout.read + attention)."""
-    B, D, block_size, num_blocks = 2, 16, 8, 12
-    NB = 4  # table width per row
+def test_paged_decode_kernel_matches_gathered_read(H, KV, D):
+    """Kernel reading layer 1 of the pool through a scrambled block table
+    (with holes) must equal the XLA gather path (BlockKVLayout.read at layer 1
+    + attention), which must equal the plain gather of ``pool[1]``. At D=128
+    the kernel takes its blocks through the pool's (L, slots * KV, D) view and
+    scores all heads in one dot; at D=16 head by head."""
+    B, block_size, num_blocks = 2, 8, 12
     total = num_blocks * block_size
     rng = np.random.default_rng(3)
-    k_cache = jnp.asarray(rng.standard_normal((total, KV, D)), jnp.float32)
-    v_cache = jnp.asarray(rng.standard_normal((total, KV, D)), jnp.float32)
+    k_cache = jnp.asarray(rng.standard_normal((LAYERS, total, KV, D)), jnp.float32)
+    v_cache = jnp.asarray(rng.standard_normal((LAYERS, total, KV, D)), jnp.float32)
     q = jnp.asarray(rng.standard_normal((B, H, 1, D)), jnp.float32)
     # row 0: 3 live blocks (scrambled), 1 hole; row 1: 2 live blocks
     bt = jnp.array([[7, 2, 9, -1], [11, 0, -1, -1]], jnp.int32)
@@ -98,13 +121,47 @@ def test_paged_decode_kernel_matches_gathered_read(H, KV):
         num_layers=1, num_blocks=num_blocks, block_size=block_size,
         num_kv_heads=KV, head_dim=D, dtype="float32",
     )
-    kk, vv, kv_pos = layout.read(k_cache, v_cache, {"block_table": bt}, spec)
-    expected = attention_with_positions(q, kk, vv, q_pos, kv_pos)
+    ci = {"block_table": bt, "layer_idx": jnp.int32(LAYER)}
+    kk, vv, kv_pos = layout.read(k_cache, v_cache, ci, spec)
+    kk_ref, pos_ref = _gathered_window(k_cache[LAYER], bt, block_size)
+    vv_ref, _ = _gathered_window(v_cache[LAYER], bt, block_size)
+    np.testing.assert_array_equal(np.asarray(kk), np.asarray(kk_ref))
+    np.testing.assert_array_equal(np.asarray(vv), np.asarray(vv_ref))
+    np.testing.assert_array_equal(np.asarray(kv_pos), np.asarray(pos_ref))
+    expected = attention_with_positions(q, kk_ref, vv_ref, q_pos, pos_ref)
 
     actual = paged_attention_decode(
-        q, k_cache, v_cache, bt, q_pos, block_size=block_size
+        q, k_cache, v_cache, bt, q_pos, LAYER, block_size=block_size
     )
-    np.testing.assert_allclose(np.asarray(actual), np.asarray(expected), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(actual), np.asarray(expected), atol=5e-5)
+
+
+def test_paged_decode_kernel_rows_end_in_different_steps():
+    """A 20-entry table is three steps of eight entries a row: rows of 1, 8,
+    9, 17 and 20 live blocks end in their first, second and third step, so a
+    block fetch started in one row's last live step is waited for in the next
+    row's first, with the two buffers swapping on live steps only."""
+    KV, G, D, bs, NB, blocks = 2, 2, 128, 8, 20, 64
+    live = [1, 20, 8, 9, 17, 3]
+    B = len(live)
+    rng = np.random.default_rng(5)
+    k_cache = jnp.asarray(rng.standard_normal((LAYERS, blocks * bs, KV, D)), jnp.float32)
+    v_cache = jnp.asarray(rng.standard_normal((LAYERS, blocks * bs, KV, D)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((B, KV * G, 1, D)), jnp.float32)
+    perm = rng.permutation(blocks)
+    bt = np.full((B, NB), -1, np.int32)
+    q_pos = np.zeros((B, 1), np.int32)
+    at = 0
+    for r, n in enumerate(live):
+        bt[r, :n] = perm[at:at + n]
+        at += n
+        q_pos[r, 0] = (n - 1) * bs + int(rng.integers(0, bs))
+    bt, q_pos = jnp.asarray(bt), jnp.asarray(q_pos)
+    kk, kv_pos = _gathered_window(k_cache[LAYER], bt, bs)
+    vv, _ = _gathered_window(v_cache[LAYER], bt, bs)
+    expected = attention_with_positions(q, kk, vv, q_pos, kv_pos)
+    actual = paged_attention_decode(q, k_cache, v_cache, bt, q_pos, LAYER, block_size=bs)
+    np.testing.assert_allclose(np.asarray(actual), np.asarray(expected), atol=5e-5)
 
 
 def test_paged_decode_kernel_scaled_fp8_folding():
@@ -113,15 +170,16 @@ def test_paged_decode_kernel_scaled_fp8_folding():
     B, H, KV, D, block_size, num_blocks = 1, 4, 2, 16, 8, 6
     total = num_blocks * block_size
     rng = np.random.default_rng(4)
-    k_raw = rng.standard_normal((total, KV, D)).astype(np.float32)
-    v_raw = rng.standard_normal((total, KV, D)).astype(np.float32)
+    k_raw = rng.standard_normal((LAYERS, total, KV, D)).astype(np.float32)
+    v_raw = rng.standard_normal((LAYERS, total, KV, D)).astype(np.float32)
     q = jnp.asarray(rng.standard_normal((B, H, 1, D)), jnp.float32)
     bt = jnp.array([[3, 1, -1]], jnp.int32)
     q_pos = jnp.array([[13]], jnp.int32)
     k_scale, v_scale = 2.5, 0.75
 
     expected = paged_attention_decode(
-        q, jnp.asarray(k_raw), jnp.asarray(v_raw), bt, q_pos, block_size=block_size
+        q, jnp.asarray(k_raw), jnp.asarray(v_raw), bt, q_pos, LAYER,
+        block_size=block_size,
     )
     actual = paged_attention_decode(
         q,
@@ -129,6 +187,7 @@ def test_paged_decode_kernel_scaled_fp8_folding():
         jnp.asarray(v_raw / v_scale),
         bt,
         q_pos,
+        LAYER,
         block_size=block_size,
         k_scale=k_scale,
         v_scale=v_scale,
@@ -212,8 +271,8 @@ from nxdi_tpu.ops.kernels import paged_attention_prefill  # noqa: E402
 
 
 def _paged_pool(rng, total_slots, KV, D):
-    k = jnp.asarray(rng.standard_normal((total_slots, KV, D)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((total_slots, KV, D)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((LAYERS, total_slots, KV, D)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((LAYERS, total_slots, KV, D)), jnp.float32)
     return k, v
 
 
@@ -222,7 +281,7 @@ def test_paged_prefill_matches_gathered_read(H, KV):
     """Bit-parity with the XLA path: materialized block-table gather +
     attention_with_positions over the gathered window."""
     rng = np.random.default_rng(0)
-    B, Sq, D, bs, NB = 2, 16, 16, 8, 6
+    B, Sq, D, bs = 2, 16, 16, 8
     total = 64
     k_cache, v_cache = _paged_pool(rng, total, KV, D)
     q = jnp.asarray(rng.standard_normal((B, H, Sq, D)), jnp.float32)
@@ -231,19 +290,13 @@ def test_paged_prefill_matches_gathered_read(H, KV):
     chunk_start = 2 * bs  # suffix begins after the 2-block prefix
     q_pos = chunk_start + jnp.tile(jnp.arange(Sq, dtype=jnp.int32), (B, 1))
 
-    # golden: gather the table window, causal mask on logical positions
-    offs = jnp.arange(bs, dtype=jnp.int32)
-    slots = (bt[:, :, None] * bs + offs[None, None, :]).reshape(B, -1)
-    kk = jnp.swapaxes(jnp.take(k_cache, slots, axis=0, mode="clip"), 1, 2)
-    vv = jnp.swapaxes(jnp.take(v_cache, slots, axis=0, mode="clip"), 1, 2)
-    W = NB * bs
-    kv_pos = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32)[None, :], (B, W))
-    valid = jnp.repeat(bt >= 0, bs, axis=1)
-    kv_pos = jnp.where(valid, kv_pos, jnp.int32(2**30))
+    # golden: gather layer 1's table window, causal mask on logical positions
+    kk, kv_pos = _gathered_window(k_cache[LAYER], bt, bs)
+    vv, _ = _gathered_window(v_cache[LAYER], bt, bs)
     expected = attention_with_positions(q, kk, vv, q_pos, kv_pos)
 
     actual = paged_attention_prefill(
-        q, k_cache, v_cache, bt, q_pos, block_size=bs, block_q=8
+        q, k_cache, v_cache, bt, q_pos, LAYER, block_size=bs, block_q=8
     )
     np.testing.assert_allclose(np.asarray(actual), np.asarray(expected), atol=2e-5)
 
@@ -257,10 +310,53 @@ def test_paged_prefill_fp8_scale_folding():
     bt = jnp.asarray([[2, 0, -1, -1]], jnp.int32)
     q_pos = bs + jnp.tile(jnp.arange(Sq, dtype=jnp.int32), (B, 1))
     expected = paged_attention_prefill(
-        q, k_cache * 2.0, v_cache * 0.5, bt, q_pos, block_size=bs, block_q=8
+        q, k_cache * 2.0, v_cache * 0.5, bt, q_pos, LAYER, block_size=bs, block_q=8
     )
     actual = paged_attention_prefill(
-        q, k_cache, v_cache, bt, q_pos, block_size=bs, block_q=8,
+        q, k_cache, v_cache, bt, q_pos, LAYER, block_size=bs, block_q=8,
         k_scale=2.0, v_scale=0.5,
     )
     np.testing.assert_allclose(np.asarray(actual), np.asarray(expected), atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# The stacked pool and the layer index (decode + prefill)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_paged_kernels_read_the_layer_the_index_names(kernel):
+    """For L = 3 the kernel at layer l equals the per-layer reference on
+    ``pool[l]`` — with the index a python int, and TRACED as a layer scan's
+    xs hands it over (the serving path: the pool closed over whole, no slice
+    of it taken)."""
+    rng = np.random.default_rng(11)
+    B, H, KV, D, bs = 2, 4, 2, 16, 8
+    k_cache, v_cache = _paged_pool(rng, 64, KV, D)
+    bt = jnp.asarray([[3, 5, 0, -1], [7, 1, -1, -1]], jnp.int32)
+    if kernel == "decode":
+        q = jnp.asarray(rng.standard_normal((B, H, 1, D)), jnp.float32)
+        q_pos = jnp.array([[19], [12]], jnp.int32)
+        call = lambda li: paged_attention_decode(  # noqa: E731
+            q, k_cache, v_cache, bt, q_pos, li, block_size=bs
+        )
+    else:
+        q = jnp.asarray(rng.standard_normal((B, H, 8, D)), jnp.float32)
+        q_pos = bs + jnp.tile(jnp.arange(8, dtype=jnp.int32), (B, 1))
+        call = lambda li: paged_attention_prefill(  # noqa: E731
+            q, k_cache, v_cache, bt, q_pos, li, block_size=bs, block_q=8
+        )
+
+    def reference(layer):
+        kk, kv_pos = _gathered_window(k_cache[layer], bt, bs)
+        vv, _ = _gathered_window(v_cache[layer], bt, bs)
+        return np.asarray(attention_with_positions(q, kk, vv, q_pos, kv_pos))
+
+    refs = [reference(layer) for layer in range(LAYERS)]
+    assert not np.allclose(refs[0], refs[1], atol=1e-3)  # layers do differ
+    for layer in range(LAYERS):
+        np.testing.assert_allclose(np.asarray(call(layer)), refs[layer], atol=2e-5)
+    _, scanned = jax.lax.scan(
+        lambda c, li: (c, call(li)), 0, jnp.arange(LAYERS, dtype=jnp.int32)
+    )
+    np.testing.assert_allclose(np.asarray(scanned), np.stack(refs), atol=2e-5)

@@ -31,7 +31,7 @@ def _s(*shape, dtype=F32):
 
 Q1 = _s(B, H, 1, D)  # decode queries
 QS = _s(1, H, S, D)  # prefill queries
-POOL = _s(NB * BLOCK, KV, D)
+POOL = _s(L, NB * BLOCK, KV, D)  # the whole layer-stacked paged pool
 CACHE = _s(B, KV, S, D)
 ROW = _s(B, KV, 1, D)
 X = _s(8, HID)
@@ -56,17 +56,20 @@ KERNELS = {
          _s(B, 1, dtype=I32), _s(1, dtype=I32)),
     ),
     "paged_attention_decode": (
-        lambda q, k, v, bt, pos: fa.paged_attention_decode(q, k, v, bt, pos, block_size=BLOCK),
-        (Q1, POOL, POOL, _s(B, NB, dtype=I32), _s(B, 1, dtype=I32)),
+        lambda q, k, v, bt, pos, li: fa.paged_attention_decode(
+            q, k, v, bt, pos, li, block_size=BLOCK),
+        (Q1, POOL, POOL, _s(B, NB, dtype=I32), _s(B, 1, dtype=I32), _s(1, dtype=I32)),
     ),
     "paged_attention_prefill": (
-        lambda q, k, v, bt, pos: fa.paged_attention_prefill(q, k, v, bt, pos, block_size=BLOCK),
-        (QS, POOL, POOL, _s(1, NB, dtype=I32), _s(1, S, dtype=I32)),
+        lambda q, k, v, bt, pos, li: fa.paged_attention_prefill(
+            q, k, v, bt, pos, li, block_size=BLOCK),
+        (QS, POOL, POOL, _s(1, NB, dtype=I32), _s(1, S, dtype=I32), _s(1, dtype=I32)),
     ),
     "ragged_paged_attention": (
-        lambda q, k, v, bt, rid, pos: rpa.ragged_paged_attention(
-            q, k, v, bt, rid, pos, block_size=BLOCK),
-        (QS, POOL, POOL, _s(B, NB, dtype=I32), _s(S, dtype=I32), _s(S, dtype=I32)),
+        lambda q, k, v, bt, rid, pos, li: rpa.ragged_paged_attention(
+            q, k, v, bt, rid, pos, li, block_size=BLOCK),
+        (QS, POOL, POOL, _s(B, NB, dtype=I32), _s(S, dtype=I32), _s(S, dtype=I32),
+         _s(1, dtype=I32)),
     ),
     "kv_commit_rows": (
         kv_commit.kv_commit_rows,

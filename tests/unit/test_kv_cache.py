@@ -1,5 +1,6 @@
 """KV cache tests (reference analog: test/unit/modules/kvcache)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -109,35 +110,77 @@ def test_seq_id_routed_update_and_read():
 
 
 def test_block_layout_scatter_and_gather():
+    """The layout takes the whole (L, slots, KV, D) pool and writes and reads
+    it at ``layer_idx``; layer 1 of 3 here."""
     layout = BlockKVLayout(block_size=4)
     spec = make_spec()  # dtype fields reused; shape comes from the array
-    pool = jnp.zeros((16, 2, 4))  # 4 blocks x 4 slots
+    pool = jnp.zeros((3, 16, 2, 4))  # 3 layers x (4 blocks x 4 slots)
     k_new = jnp.arange(2 * 2 * 3 * 4, dtype=jnp.float32).reshape(2, 2, 3, 4)
     ci = {
         "position_ids": jnp.array([[0, 1, 2], [0, 1, 2]], jnp.int32),
         # row0 -> block 2 (slots 8..), row1 -> block 0 (slots 0..)
         "slot_mapping": jnp.array([[8, 9, 10], [0, 1, 2]], jnp.int32),
         "block_table": jnp.array([[2, -1], [0, -1]], jnp.int32),
+        "layer_idx": jnp.int32(1),
     }
     k_l, v_l = layout.update(pool, pool, k_new, k_new, ci, spec)
     k_np = np.asarray(k_l)
-    assert np.allclose(k_np[8], np.asarray(k_new)[0, :, 0])  # (KV, D) at slot 8
-    assert np.allclose(k_np[2], np.asarray(k_new)[1, :, 2])
+    assert k_np.shape == (3, 16, 2, 4)
+    assert np.allclose(k_np[1, 8], np.asarray(k_new)[0, :, 0])  # (KV, D) at slot 8
+    assert np.allclose(k_np[1, 2], np.asarray(k_new)[1, :, 2])
     kk, _, kv_pos = layout.read(k_l, v_l, ci, spec)
     assert kk.shape == (2, 2, 8, 4)  # 2 table entries x block_size
     assert np.allclose(np.asarray(kk)[0, :, 0], np.asarray(k_new)[0, :, 0])
     # unallocated second block: kv positions pushed out of causal range
     assert np.all(np.asarray(kv_pos)[:, 4:] >= 2**29)
+    # the other layers' reads see none of it
+    kk0, _, _ = layout.read(k_l, v_l, dict(ci, layer_idx=jnp.int32(0)), spec)
+    assert np.all(np.asarray(kk0) == 0)
 
 
 def test_block_layout_negative_slots_dropped():
     layout = BlockKVLayout(block_size=4)
     spec = make_spec()
-    pool = jnp.zeros((8, 2, 4))
+    pool = jnp.zeros((3, 8, 2, 4))
     k_new = jnp.ones((1, 2, 2, 4))
     ci = {
         "position_ids": jnp.array([[0, 1]], jnp.int32),
         "slot_mapping": jnp.array([[-1, -1]], jnp.int32),
+        "layer_idx": jnp.int32(1),
     }
     k_l, _ = layout.update(pool, pool, k_new, k_new, ci, spec)
     assert np.all(np.asarray(k_l) == 0)
+
+
+def test_block_layout_update_touches_one_layer_only():
+    """``update`` at layer 1 leaves layers 0 and 2 bit-identical, lands the
+    live rows at (1, slot), and drops the rows whose slot is negative — also
+    with the layer TRACED, as the layer scan hands it over."""
+    layout = BlockKVLayout(block_size=4)
+    spec = make_spec()
+    rng = np.random.default_rng(0)
+    k_pool = jnp.asarray(rng.standard_normal((3, 16, 2, 4)), jnp.float32)
+    v_pool = jnp.asarray(rng.standard_normal((3, 16, 2, 4)), jnp.float32)
+    k_new = jnp.asarray(rng.standard_normal((2, 2, 3, 4)), jnp.float32)
+    v_new = jnp.asarray(rng.standard_normal((2, 2, 3, 4)), jnp.float32)
+    ci = {
+        "position_ids": jnp.array([[0, 1, 2], [0, 1, 2]], jnp.int32),
+        "slot_mapping": jnp.array([[8, 9, -1], [-1, 1, 2]], jnp.int32),
+    }
+
+    def update(layer):
+        return layout.update(
+            k_pool, v_pool, k_new, v_new, dict(ci, layer_idx=layer), spec
+        )
+
+    for got_k, got_v in (update(jnp.int32(1)), jax.jit(update)(jnp.int32(1))):
+        want_k, want_v = np.array(k_pool), np.array(v_pool)
+        for b, t, slot in ((0, 0, 8), (0, 1, 9), (1, 1, 1), (1, 2, 2)):
+            want_k[1, slot] = np.asarray(k_new)[b, :, t]
+            want_v[1, slot] = np.asarray(v_new)[b, :, t]
+        np.testing.assert_array_equal(np.asarray(got_k), want_k)
+        np.testing.assert_array_equal(np.asarray(got_v), want_v)
+        for layer in (0, 2):
+            np.testing.assert_array_equal(
+                np.asarray(got_k)[layer], np.asarray(k_pool)[layer]
+            )

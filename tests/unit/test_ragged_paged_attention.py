@@ -14,6 +14,7 @@ isolation they check fails at O(1), not at 1e-7."""
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from nxdi_tpu.ops.kernels import (
@@ -24,9 +25,14 @@ from nxdi_tpu.ops.kernels import (
 )
 
 
+# the kernels take the WHOLE (L, slots, KV, D) pool and a layer index: every
+# case builds a 3-layer pool and reads layer 1
+LAYERS, LAYER = 3, 1
+
+
 def _pool(rng, total_slots, KV, D):
-    k = jnp.asarray(rng.standard_normal((total_slots, KV, D)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((total_slots, KV, D)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((LAYERS, total_slots, KV, D)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((LAYERS, total_slots, KV, D)), jnp.float32)
     return k, v
 
 
@@ -67,7 +73,7 @@ def test_ragged_mixed_batch_bitwise_per_row(H, KV):
     assert ragged_paged_kernel_supported(q.shape, k_cache.shape, bs)
 
     out = ragged_paged_attention(
-        q, k_cache, v_cache, bt, row_ids, q_pos, block_size=bs, block_q=8
+        q, k_cache, v_cache, bt, row_ids, q_pos, LAYER, block_size=bs, block_q=8
     )
 
     for r, (positions, _) in enumerate(rows):
@@ -76,11 +82,12 @@ def test_ragged_mixed_batch_bitwise_per_row(H, KV):
         pos_row = jnp.asarray([positions], jnp.int32)
         if len(positions) == 1:
             expected = paged_attention_decode(
-                q_row, k_cache, v_cache, bt[r : r + 1], pos_row, block_size=bs
+                q_row, k_cache, v_cache, bt[r : r + 1], pos_row, LAYER,
+                block_size=bs,
             )
         else:
             expected = paged_attention_prefill(
-                q_row, k_cache, v_cache, bt[r : r + 1], pos_row,
+                q_row, k_cache, v_cache, bt[r : r + 1], pos_row, LAYER,
                 block_size=bs, block_q=8,
             )
         np.testing.assert_allclose(
@@ -102,10 +109,10 @@ def test_ragged_row_at_bucket_edge():
     rows = [(list(range(8, 16)), [2, 6, -1])]
     q, row_ids, q_pos, bt, spans = _pack(T, H, D, rows, rng)
     out = ragged_paged_attention(
-        q, k_cache, v_cache, bt, row_ids, q_pos, block_size=bs, block_q=8
+        q, k_cache, v_cache, bt, row_ids, q_pos, LAYER, block_size=bs, block_q=8
     )
     expected = paged_attention_prefill(
-        q, k_cache, v_cache, bt, jnp.asarray([rows[0][0]], jnp.int32),
+        q, k_cache, v_cache, bt, jnp.asarray([rows[0][0]], jnp.int32), LAYER,
         block_size=bs, block_q=8,
     )
     np.testing.assert_array_equal(np.asarray(out), np.asarray(expected))
@@ -123,13 +130,13 @@ def test_ragged_all_decode_rows():
     ]
     q, row_ids, q_pos, bt, spans = _pack(T, H, D, rows, rng)
     out = ragged_paged_attention(
-        q, k_cache, v_cache, bt, row_ids, q_pos, block_size=bs, block_q=8
+        q, k_cache, v_cache, bt, row_ids, q_pos, LAYER, block_size=bs, block_q=8
     )
     for r, (positions, _) in enumerate(rows):
         idx = jnp.asarray(spans[r])
         expected = paged_attention_decode(
             q[:, :, idx, :], k_cache, v_cache, bt[r : r + 1],
-            jnp.asarray([positions], jnp.int32), block_size=bs,
+            jnp.asarray([positions], jnp.int32), LAYER, block_size=bs,
         )
         np.testing.assert_array_equal(
             np.asarray(out[:, :, idx, :]), np.asarray(expected)
@@ -146,13 +153,13 @@ def test_ragged_empty_tail_is_inert():
     rows = [([9], [1, 0]), ([3], [2, -1])]
     q, row_ids, q_pos, bt, spans = _pack(T, H, D, rows, rng)
     out = ragged_paged_attention(
-        q, k_cache, v_cache, bt, row_ids, q_pos, block_size=bs, block_q=4
+        q, k_cache, v_cache, bt, row_ids, q_pos, LAYER, block_size=bs, block_q=4
     )
     for r, (positions, _) in enumerate(rows):
         idx = jnp.asarray(spans[r])
         expected = paged_attention_decode(
             q[:, :, idx, :], k_cache, v_cache, bt[r : r + 1],
-            jnp.asarray([positions], jnp.int32), block_size=bs,
+            jnp.asarray([positions], jnp.int32), LAYER, block_size=bs,
         )
         np.testing.assert_allclose(
             np.asarray(out[:, :, idx, :]), np.asarray(expected),
@@ -170,13 +177,58 @@ def test_ragged_fp8_scale_folding():
     rows = [(list(range(0, 6)), [2, -1]), ([8], [3, 0])]
     q, row_ids, q_pos, bt, spans = _pack(T, H, D, rows, rng)
     expected = ragged_paged_attention(
-        q, k_cache * 2.0, v_cache * 0.5, bt, row_ids, q_pos,
+        q, k_cache * 2.0, v_cache * 0.5, bt, row_ids, q_pos, LAYER,
         block_size=bs, block_q=8,
     )
     actual = ragged_paged_attention(
-        q, k_cache, v_cache, bt, row_ids, q_pos,
+        q, k_cache, v_cache, bt, row_ids, q_pos, LAYER,
         block_size=bs, block_q=8, k_scale=2.0, v_scale=0.5,
     )
     np.testing.assert_allclose(
         np.asarray(actual), np.asarray(expected), atol=2e-5
     )
+
+
+def test_ragged_reads_the_layer_the_index_names():
+    """For L = 3 the packed launch at layer l equals, row by row, plain
+    attention over ``pool[l]`` gathered through that row's table — with the
+    index a python int and TRACED as a layer scan's xs hands it over."""
+    from nxdi_tpu.ops.attention import attention_with_positions
+
+    rng = np.random.default_rng(5)
+    H, KV, T, D, bs = 4, 2, 16, 16, 8
+    k_cache, v_cache = _pool(rng, 64, KV, D)
+    rows = [(list(range(8, 14)), [3, 5, -1]), ([17], [6, 2, 0]), ([2], [1, -1, -1])]
+    q, row_ids, q_pos, bt, spans = _pack(T, H, D, rows, rng)
+
+    def call(li):
+        return ragged_paged_attention(
+            q, k_cache, v_cache, bt, row_ids, q_pos, li, block_size=bs, block_q=8
+        )
+
+    def reference(layer):
+        out = np.zeros((1, H, T, D), np.float32)
+        offs = np.arange(bs)
+        for r, (positions, table) in enumerate(rows):
+            table = np.asarray(table)
+            slots = (np.maximum(table, 0)[:, None] * bs + offs[None, :]).reshape(-1)
+            kk = jnp.swapaxes(k_cache[layer][slots], 0, 1)[None]  # (1, KV, W, D)
+            vv = jnp.swapaxes(v_cache[layer][slots], 0, 1)[None]
+            kv_pos = np.where(
+                np.repeat(table >= 0, bs), np.arange(slots.size), 2**30
+            ).astype(np.int32)[None]
+            idx = np.asarray(spans[r])
+            out[:, :, idx, :] = np.asarray(attention_with_positions(
+                q[:, :, idx, :], kk, vv, jnp.asarray([positions], jnp.int32),
+                jnp.asarray(kv_pos),
+            ))
+        return out
+
+    refs = [reference(layer) for layer in range(LAYERS)]
+    assert not np.allclose(refs[0], refs[1], atol=1e-3)  # layers do differ
+    for layer in range(LAYERS):
+        np.testing.assert_allclose(np.asarray(call(layer)), refs[layer], atol=2e-5)
+    _, scanned = jax.lax.scan(
+        lambda c, li: (c, call(li)), 0, jnp.arange(LAYERS, dtype=jnp.int32)
+    )
+    np.testing.assert_allclose(np.asarray(scanned), np.stack(refs), atol=2e-5)
