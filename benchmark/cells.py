@@ -64,8 +64,10 @@ def plugin_path(kind: str, name: str) -> str:
     return os.path.join(BENCH_DIR, PLUGIN_DIRS[kind][0], f"{name}.py")
 
 
-def load_plugin(kind: str, name: str):
-    """The function of the by-name file ``<kind dir>/<name>.py``."""
+def load_plugin(kind: str, name: str, function: Optional[str] = None):
+    """The function of the by-name file ``<kind dir>/<name>.py``. ``function``
+    asks for a second one the file MAY define beside it (a reference's
+    ``routing_margins``): None where it does not."""
     if not NAME_RE.match(name):
         raise ValueError(f"bad {kind} name {name!r}")
     path = plugin_path(kind, name)
@@ -76,6 +78,8 @@ def load_plugin(kind: str, name: str):
         raise FileNotFoundError(f"{kind} {name!r}: no file {path}")
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
+    if function is not None:
+        return getattr(module, function, None)
     return getattr(module, PLUGIN_DIRS[kind][1])
 
 

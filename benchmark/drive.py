@@ -98,7 +98,8 @@ def drive(
                 on_token=lambda _req, _tok, times=times: times.append(clock()),
                 arrival_s=due,
             )
-            s = Served(next_i, due, clock(), len(o.prompt), o.max_new, req, token_times=times)
+            s = Served(next_i, due, clock(), len(o.prompt), o.max_new, req, token_times=times,
+                       prompt=o.prompt)
             res.served.append(s)
             by_id[req.request_id] = s
             next_i += 1

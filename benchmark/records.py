@@ -42,6 +42,7 @@ class Served:
     fault: Optional[str] = None  # why it counts as failed, if it does
     #: when each output token reached the streaming callback (``on_token``)
     token_times: List[float] = field(default_factory=list)
+    prompt: Sequence[int] = ()  # as the generator drew it (the reference reads this one)
 
 
 def fault_of(served: Served, vocab: int) -> Optional[str]:
@@ -101,13 +102,43 @@ class RunRecords:
         """Every gap between two consecutive output tokens of one stream, both
         inside ``[t_open, t_host_end]``: all streams, finished or still running,
         and nothing of the ramp before the window or the drain after it."""
-        gaps = []
+        return [b - a for a, b in self._token_pairs()]
+
+    def _token_pairs(self):
+        """``(a, b)``: the times of two consecutive tokens of one clean stream,
+        both inside ``[t_open, t_host_end]``."""
         for s in self.served:
             if s.fault is not None:
                 continue
             inside = [t for t in s.token_times if self.t_open <= t <= self.t_host_end]
-            gaps.extend(b - a for a, b in zip(inside, inside[1:]))
-        return gaps
+            yield from zip(inside, inside[1:])
+
+    def gap_modes(self, buckets: Sequence[int]) -> List[dict]:
+        """The token gaps by the engine step that ended them: a decode-only
+        step (``decode``) or one that also ran a prefill of a prompt bucket
+        (``cte<bucket>``). A gap tail is a percentile of these few modes, so
+        each comes with its count, its share of all gaps and its range, in
+        ascending order of its median gap; ``other`` holds what no step of the
+        window's records ends (none, as a rule)."""
+        import bisect
+
+        steps = sorted((r for r in self.steps if r.decode is not None), key=lambda r: r.t_end)
+        ends = [r.t_end for r in steps]
+        buckets = sorted(buckets)
+        found: Dict[str, List[float]] = {}
+        for a, b in self._token_pairs():
+            k = bisect.bisect_left(ends, b)
+            label = "other"
+            if k < len(steps) and steps[k].t_start <= b:
+                tokens = sum(p["tokens"] for p in steps[k].prefills)
+                label = "decode" if not steps[k].prefills else "cte%d" % next(
+                    (bk for bk in buckets if bk >= tokens), buckets[-1])
+            found.setdefault(label, []).append(b - a)
+        total = sum(len(v) for v in found.values())
+        modes = [{"mode": k, "gaps": len(v), "share_pct": 100.0 * len(v) / total,
+                  "lo_ms": 1e3 * min(v), "median_ms": 1e3 * median(v), "hi_ms": 1e3 * max(v)}
+                 for k, v in found.items()]
+        return sorted(modes, key=lambda m: m["median_ms"])
 
     def decode_only_steps(self) -> list:
         """Steps that ran one token-generation dispatch and no prefill."""

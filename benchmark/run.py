@@ -5,12 +5,15 @@
 
 Finds its chips first and fails without a TPU (no CPU fall-back); builds the
 cell's app with weights drawn on the device from ``--seed``; warms the cell's
-own programs; checks the app against the configuration's plain reference;
-drives the cell's traffic through ``InferenceEngine`` for ``--seconds``; prints
-progress on earlier lines and, as the LAST line of standard output, one JSON
-object with ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``
-(and ``breakdown`` in a traced run). ``--trace 0`` reports the cell's
-end-to-end metrics, ``--trace 1`` its per-layer metrics.
+own programs; drives the cell's traffic through ``InferenceEngine`` for
+``--seconds``; then, the window closed and the device's peak memory read, holds
+what the window served to the configuration's plain reference
+(``correctness.py``); prints progress on earlier lines and, as the LAST line of
+standard output, one JSON object with ``correct``, ``attempted``, ``failed``,
+``metrics``, ``device`` (``breakdown`` in a traced run) and, last, ``compared``:
+every number that decided ``correct`` beside its limit, which are also the last
+lines of standard error. ``--trace 0`` reports the cell's end-to-end metrics,
+``--trace 1`` its per-layer metrics.
 """
 
 from __future__ import annotations
@@ -160,10 +163,10 @@ def main(argv=None) -> int:
 
 
 def prepare(cell, seed: int, devices, say):
-    """Set-up up to the window: the loaded, warmed, checked app and its engine."""
+    """Set-up up to the window: the loaded, warmed app and its engine."""
     import jax
 
-    from benchmark import cells, correctness, costs, serving_app, traffic_gen
+    from benchmark import costs, serving_app, traffic_gen
     from nxdi_tpu.runtime.application import enable_persistent_cache
     from nxdi_tpu.serving import InferenceEngine, SchedulerConfig
 
@@ -198,14 +201,32 @@ def prepare(cell, seed: int, devices, say):
         say(f"  STRATEGY FAULT: {f}")
 
     engine = InferenceEngine(app, SchedulerConfig(num_slots=bench["slots"]))
-    reference = cells.load_plugin("reference", bench["reference"])
-    t = time.perf_counter()
-    checked = correctness.check(app, engine, cell.config, reference, seed, say)
-    say(f"correctness check took {time.perf_counter() - t:.1f}s")
     return SimpleNamespace(
-        app=app, engine=engine, device=device, checked=checked, faults=faults,
-        compiles=compiles,
+        app=app, engine=engine, device=device, faults=faults, compiles=compiles, buckets=buckets,
     )
+
+
+def compare(prep, cell, seed: int, finished, devices, say):
+    """After the window: the program's probe logits, then the device's peak
+    memory, then (the program's cache and engine let go) the reference over
+    the probe and over a sample of ``finished``. ``(check result, peak bytes)``."""
+    from benchmark import cells, correctness
+
+    bench, vocab = cell.config["benchmark"], cell.config["vocab_size"]
+    t = time.perf_counter()
+    probe_got = correctness.program_probe(prep.app, correctness.probe_prompt(seed, vocab), vocab)
+    stats = [d.memory_stats() or {} for d in devices[: cell.chips]]
+    peak = int(max(s.get("peak_bytes_in_use", 0) for s in stats))
+    params = prep.app.params
+    prep.engine = prep.app.kv_cache = None  # the reference runs beside the weights alone
+    checked = correctness.check(
+        params, cell.config, cells.load_plugin("reference", bench["reference"]), seed,
+        probe_got, correctness.sample_served(finished, seed), say,
+        routing_margins=cells.load_plugin("reference", bench["reference"], "routing_margins"),
+    )
+    say(f"comparison with the reference took {time.perf_counter() - t:.1f}s (after the window, "
+        f"outside set-up)")
+    return checked, peak
 
 
 def measure(prep, cell, seed: int, seconds: float, trace: bool, say):
@@ -287,8 +308,14 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices, say) -> dict
         say(f"  FAULT: the flight recorder dropped {flight.records_dropped} records")
     for label, xs in (("ttft", run.metric_of_ok("ttft_s")), ("token gaps", run.token_gaps())):
         ladder = ", ".join(f"p{p:g} {1e3 * (percentile(xs, p) or 0):.2f}"
-                           for p in (50, 90, 92.5, 95, 97.5, 99, 99.5, 100))
+                           for p in (50, 90, 92.5, 95, 97.5, 98, 98.5, 99, 99.5, 100))
         say(f"samples: {label} {len(xs)} (ms: {ladder})")
+    above = 0.0  # a gap tail is a percentile of a few modes: which, and how far inside
+    for m in reversed(run.gap_modes(prep.buckets)):
+        say(f"gap mode {m['mode']}: {m['gaps']} gaps = {m['share_pct']:.3f} % of all (with the modes "
+            f"above it {above + m['share_pct']:.3f} %), {m['lo_ms']:.2f} / {m['median_ms']:.2f} / "
+            f"{m['hi_ms']:.2f} ms (least / median / most)")
+        above += m["share_pct"]
 
     device_out = dict(prep.device)
     breakdown = None
@@ -323,13 +350,17 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices, say) -> dict
             if (kind == "per_layer") == bool(trace):
                 metrics[name] = {"value": float(value), "unit": entry["unit"]}
 
-    stats = [d.memory_stats() or {} for d in devices[: cell.chips]]
-    device_out["memory_peak_bytes"] = int(max(s.get("peak_bytes_in_use", 0) for s in stats))
-    correct = bool(
-        prep.checked["ok"] and not prep.faults and not failed and not in_window
-        and not res.ran_dry and not flight.records_dropped
-        and (not trace or run.trace is not None)
-    )
+    # what else a sound run has none of, each a number compared with the limit 0
+    none_of = {
+        "failed_requests": len(failed), "compilations_in_window": len(in_window),
+        "strategy_faults": len(prep.faults), "backlog_ran_dry": int(res.ran_dry),
+        "flight_records_dropped": flight.records_dropped,
+        "trace_unread": int(trace and run.trace is None),
+    }
+    checked, device_out["memory_peak_bytes"] = compare(prep, cell, seed, population, devices, say)
+    compared = dict(checked["compared"])
+    compared.update({name: {"value": value, "limit": 0} for name, value in none_of.items()})
+    correct = bool(checked["ok"] and not any(none_of.values()))
     line = {
         "correct": correct,
         "attempted": len(population),
@@ -339,6 +370,10 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices, say) -> dict
     }
     if breakdown is not None:
         line["breakdown"] = breakdown
+    line["compared"] = compared  # last in the line, and the last lines of standard error
+    for name, c in compared.items():
+        print(f"compared {name} = {c['value']} limit {c['limit']}", file=sys.stderr)
+    print(f"correct = {correct}", file=sys.stderr, flush=True)
     return line
 
 

@@ -1,11 +1,14 @@
 """A configuration file -> the loaded serving app and its engine.
 
-The app is the program's own ``TpuModelForCausalLM`` with the serving stack of
-``chip_smoke.paged_tpu_config`` (paged KV, flash prefill + paged decode
-kernels, greedy sampling on the device). Two things are the benchmark's: the
-weights, drawn on the device from the seed in one jitted call with the app's
-own shardings, and the empty KV pool, made sharded instead of whole on the
-first device. Neither reads a checkpoint.
+The app is the program's own application class for the family (the family
+module's ``APPLICATION_CLS`` where it has one, else ``TpuModelForCausalLM``)
+with the serving stack of ``chip_smoke.paged_tpu_config`` (paged KV, flash
+prefill + paged decode kernels, greedy sampling on the device). Two things are
+the benchmark's: the weights, drawn on the device from the seed in one jitted
+call with the app's own shardings, and the empty cache: whatever tree the app
+declares (``_cache_struct()``: a k/v pool, a latent pool, a window ring, state
+beside the pool), made sharded instead of whole on the first device. Neither
+reads a checkpoint.
 """
 
 from __future__ import annotations
@@ -58,6 +61,14 @@ def tpu_config_of(config: dict, buckets: Sequence[int], flight_records: int):
     )
 
 
+def leaf_kind(path) -> str:
+    """``norm``, ``bias`` or ``weight``, from a parameter leaf's tree path."""
+    keys = [getattr(p, "key", None) for p in path]
+    if any(isinstance(k, str) and k.endswith("norm") for k in keys):
+        return "norm"
+    return "bias" if keys[-1] == "b" else "weight"
+
+
 def seeded_params(struct, shardings, seed: int):
     """The whole parameter tree from ``seed`` in ONE jitted call, each leaf in
     the dtype and sharding it is served in. Norm weights are 1, biases normal
@@ -67,13 +78,7 @@ def seeded_params(struct, shardings, seed: int):
 
     paths, treedef = jax.tree_util.tree_flatten_with_path(struct)
 
-    def kind(path) -> str:
-        keys = [getattr(p, "key", None) for p in path]
-        if any(isinstance(k, str) and k.endswith("norm") for k in keys):
-            return "norm"
-        return "bias" if keys[-1] == "b" else "weight"
-
-    kinds = [kind(path) for path, _ in paths]
+    kinds = [leaf_kind(path) for path, _ in paths]
     shapes = [s for _, s in paths]
 
     def make(key):
@@ -90,14 +95,34 @@ def seeded_params(struct, shardings, seed: int):
     return jax.jit(make, out_shardings=shardings)(jax.random.key(seed % (2**63)))
 
 
-def build_app(config: dict, buckets: Sequence[int], seed: int, flight_records: int = 1 << 17):
-    """The un-loaded app of ``config`` (call ``.load()`` on it)."""
+def empty_cache(struct, specs, mesh):
+    """Zeros for every leaf of ``struct`` (a tree of ``ShapeDtypeStruct``: the
+    app's ``_cache_struct()``), each in its own shape and dtype, sharded as
+    ``specs`` (a tree of ``PartitionSpec`` with the same keys) says on
+    ``mesh``, in ONE jitted call: no leaf is ever whole on one device."""
     import jax
     import jax.numpy as jnp
 
+    from nxdi_tpu.parallel.layers import sharding_tree
+
+    def zeros():
+        return jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype), struct)
+
+    return jax.jit(zeros, out_shardings=sharding_tree(specs, mesh))()
+
+
+def application_class(family):
+    """The program's convention (``cli/inference_demo.py``): a family with an
+    application class of its own names it ``APPLICATION_CLS`` in its module."""
+    from nxdi_tpu.runtime.application import TpuModelForCausalLM
+
+    return getattr(family, "APPLICATION_CLS", TpuModelForCausalLM)
+
+
+def build_app(config: dict, buckets: Sequence[int], seed: int, flight_records: int = 1 << 17):
+    """The un-loaded app of ``config`` (call ``.load()`` on it)."""
     from nxdi_tpu.models.registry import get_family
     from nxdi_tpu.parallel.layers import sharding_tree
-    from nxdi_tpu.runtime.application import TpuModelForCausalLM
 
     published = {k: v for k, v in config.items() if k not in BENCHMARK_KEYS}
     family, cfg_cls = get_family(config["model_type"])
@@ -105,7 +130,7 @@ def build_app(config: dict, buckets: Sequence[int], seed: int, flight_records: i
         tpu_config_of(config, buckets, flight_records), load_config=lambda: dict(published)
     )
 
-    class SeededApp(TpuModelForCausalLM):
+    class SeededApp(application_class(family)):
         def build_params(self):
             return seeded_params(
                 self.build_params_struct(),
@@ -116,13 +141,7 @@ def build_app(config: dict, buckets: Sequence[int], seed: int, flight_records: i
         def init_cache_host(self):
             # the program's own makes the whole pool on the first device and
             # shards it afterwards, which a four-chip pool does not survive
-            spec = self._cache_spec()
-            shardings = sharding_tree(self.cache_partition_specs(), self.mesh)
-            zeros = jax.jit(
-                lambda: {k: jnp.zeros(spec.shape, spec.store_dtype) for k in ("k", "v")},
-                out_shardings=shardings,
-            )
-            return zeros()
+            return empty_cache(self._cache_struct(), self.cache_partition_specs(), self.mesh)
 
     return SeededApp(f"<seeded:{config['name']}>", inference_config, model_family=family)
 

@@ -27,6 +27,8 @@ import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 METRIC_LINE = re.compile(r"\] +(?:end_to_end|per_layer) (\S+) = (\S+) ")
+COMPARED_LINE = re.compile(r"\] +compared (\S+) = (\S+) \(limit")
+MODE_LINE = re.compile(r"\] gap mode (\S+): \d+ gaps = (\S+) % of all \(with the modes above it (\S+) %\)")
 
 
 def spread(values) -> float:
@@ -43,6 +45,14 @@ def read_run(stdout: str) -> dict:
     except (IndexError, ValueError):
         last = None
     found = {m.group(1): float(m.group(2)) for ln in lines for m in [METRIC_LINE.search(ln)] if m}
+    for ln in lines:  # what decided ``correct``, and the gap tail's modes, as further rows
+        m = COMPARED_LINE.search(ln)
+        if m and m.group(2) != "None":
+            found[f"compared.{m.group(1)}"] = float(m.group(2))
+        m = MODE_LINE.search(ln)
+        if m:
+            found[f"gap_mode.{m.group(1)}.share_pct"] = float(m.group(2))
+            found[f"gap_mode.{m.group(1)}.with_above_pct"] = float(m.group(3))
     return {"line": last, "metrics": found}
 
 

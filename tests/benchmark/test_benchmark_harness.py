@@ -22,6 +22,10 @@ if ROOT not in sys.path:
 from benchmark import cells, costs, records, traffic_gen, trace_reduce  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+if HERE not in sys.path:
+    sys.path.insert(0, HERE)
+from toys import quiet_run, served_by, toy_config, toy_steady_cell  # noqa: E402
+
 BIG_SEED = 2**31 + 12345  # the driver's seeds do not fit 32 signed bits
 
 
@@ -243,20 +247,10 @@ def test_reducer_on_the_recorded_v5e_trace():
 # -- the references against the app, and the last line ------------------------
 
 def _toy(model_type, tied, tp=1):
-    return dict(
-        name="toy", model_type=model_type, hidden_size=64, intermediate_size=128,
-        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2, vocab_size=256,
-        max_position_embeddings=4096, rms_norm_eps=1e-6, rope_theta=1e6,
-        tie_word_embeddings=tied, sliding_window=None, use_sliding_window=False, reduced=[],
-        a_later_family_key={"experts": 8},  # no list in the harness knows this one
-        source="nowhere", deployment="toy", assumed={},
-        benchmark=dict(
-            chips=tp, tp=tp, reference="dense_decoder", cost_model="dense_decoder", seq_len=512, slots=4, ctx_batch_size=1,
-            pa_block_size=128, pa_num_blocks=24, logit_tolerance=0.05,
-            attention_strategies={"context_encoding_model": "cte_flash_kernel",
-                                  "token_generation_model": "tkg_paged_kernel"},
-        ),
-    )
+    cfg = toy_config(model_type, tie_word_embeddings=tied,
+                     a_later_family_key={"experts": 8})  # no list in the harness knows this one
+    cfg["benchmark"].update(chips=tp, tp=tp)
+    return cfg
 
 
 @pytest.mark.parametrize("model_type,tied,tp", [("qwen2", True, 1), ("mistral", False, 2)])
@@ -277,9 +271,11 @@ def test_reference_agrees_with_the_app_at_a_toy_size(model_type, tied, tp):
     assert serving_app.strategy_faults(app, {"token_generation_model": "tkg_two_part_xla"})
     engine = InferenceEngine(app, SchedulerConfig(num_slots=4))
     reference = cells.load_plugin("reference", "dense_decoder")
-    said = []
-    assert correctness.check(app, engine, cfg, reference, 3, said.append)["ok"], said
+    samples = correctness.sample_served(served_by(engine, 3), 3, tokens=120)
+    got = correctness.program_probe(app, correctness.probe_prompt(3, 256), 256)
     assert app.kv_cache is not None and getattr(app, "_logit_probe", None) is None
+    said = []
+    assert correctness.check(app.params, cfg, reference, 3, got, samples, said.append)["ok"], said
 
     def wrong(params, config, ids):
         if model_type == "qwen2":
@@ -290,7 +286,7 @@ def test_reference_agrees_with_the_app_at_a_toy_size(model_type, tied, tp):
             return reference(params, config, ids)
         return reference(params, dict(config, num_hidden_layers=1), ids)
 
-    assert not correctness.check(app, engine, cfg, wrong, 3, said.append)["ok"]
+    assert not correctness.check(app.params, cfg, wrong, 3, got, samples, said.append)["ok"]
 
 
 def test_run_cell_prints_the_contract_line(monkeypatch):
@@ -298,28 +294,16 @@ def test_run_cell_prints_the_contract_line(monkeypatch):
     TPU ``main`` refuses; ``run_cell`` is what it calls after the device check."""
     import jax
 
-    import nxdi_tpu.runtime.application as application
     from benchmark import run as bench_run
 
-    # a run owns its process; a test does not: leave this worker's JAX as it was
-    # (no persistent compile cache for the tests that follow, no listener left on)
-    monkeypatch.setattr(application, "enable_persistent_cache", lambda: "(off in tests)")
-    monkeypatch.setattr(jax.monitoring, "register_event_duration_secs_listener", lambda cb: None)
-    monkeypatch.setattr(costs, "peaks_of", lambda kind: {"bf16_flops_per_s": 1.0,
-                                                         "hbm_bytes_per_s": 1.0})
-    traffic = cells.read_json(cells.traffic_path("chat-steady"))
-    traffic.update(rate_per_s=3.0, drain_cap_s=30)
-    traffic["prompt_len"] = dict(traffic["prompt_len"], hi=200)
-    traffic["output_len"] = dict(traffic["output_len"], median=8, lo=2, hi=12)
+    quiet_run(monkeypatch)
     manifest = cells.load_manifest()
-    cell = cells.Cell(
-        "toy.chat-steady", "toy", _toy("qwen2", True), "chat-steady", traffic, 1,
-        [dict(m, workloads=None) for m in manifest["end_to_end"]],
-        [m for m in manifest["per_layer"]],
-    )
+    cell = toy_steady_cell(_toy("qwen2", True))
     said = []
     line = bench_run.run_cell(cell, BIG_SEED, 3.0, False, jax.devices()[:1], said.append)
-    assert sorted(line) == ["attempted", "correct", "device", "failed", "metrics"]
+    assert list(line) == ["correct", "attempted", "failed", "metrics", "device", "compared"]
+    assert list(line["compared"])[:3] == ["probe_mse", "probe_diff", "served_gap"]  # each number beside its limit
+    assert all(sorted(c) == ["limit", "value"] and c["value"] <= c["limit"] for c in line["compared"].values())
     assert sorted(line["device"]) == ["count", "kind", "memory_peak_bytes", "platform"]
     assert line["device"]["platform"] == "cpu"  # names its device: never read as a chip's
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] == 9
@@ -333,19 +317,15 @@ def test_run_cell_prints_the_contract_line(monkeypatch):
 def test_sweep_runs_a_window_per_rate_and_refuses_off_a_tpu(monkeypatch):
     import jax
 
-    import nxdi_tpu.runtime.application as application
     from benchmark import run as bench_run
     from benchmark import sweep
 
     assert sweep.main(["--config", "qwen25-3b", "--traffic", "chat-steady", "--rates", "1"]) \
         == bench_run.EXIT_NO_DEVICE
-    monkeypatch.setattr(application, "enable_persistent_cache", lambda: "(off in tests)")
-    monkeypatch.setattr(jax.monitoring, "register_event_duration_secs_listener", lambda cb: None)
-    monkeypatch.setattr(costs, "peaks_of", lambda kind: {})
-    traffic = cells.read_json(cells.traffic_path("chat-steady"))
-    traffic.update(drain_cap_s=30, prompt_len=dict(traffic["prompt_len"], hi=200),
-                   output_len=dict(traffic["output_len"], median=6, lo=2, hi=8))
-    cell = cells.Cell("toy.chat-steady", "toy", _toy("qwen2", True), "chat-steady", traffic, 1, [], [])
+    quiet_run(monkeypatch)
+    cell = toy_steady_cell(_toy("qwen2", True))
+    cell.traffic["output_len"] = dict(cell.traffic["output_len"], median=6, hi=8)
+    traffic = cell.traffic
     said = []
     prep = bench_run.prepare(cell, 5, jax.devices()[:1], said.append)
     rows = sweep.sweep(prep, cell, [4.0, 2.0], 5, 2.0, said.append)
