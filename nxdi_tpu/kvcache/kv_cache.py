@@ -113,6 +113,9 @@ class BlockKVCacheSpec:
     head_dim: int
     dtype: str = "bfloat16"
     quant_dtype: Optional[str] = None
+    # the MLA latent pool: ``k`` rows are the rotated rope key (padded to a
+    # lane tile, ops/mla.py), ``v`` rows the normed latent; None = same as k
+    v_head_dim: Optional[int] = None
 
     @property
     def store_dtype(self):
@@ -134,11 +137,16 @@ class BlockKVCacheSpec:
     def shape(self) -> Tuple[int, ...]:
         return (self.num_layers, self.total_slots, self.num_kv_heads, self.head_dim)
 
+    @property
+    def shape_v(self) -> Tuple[int, ...]:
+        d = self.v_head_dim if self.v_head_dim is not None else self.head_dim
+        return self.shape[:-1] + (d,)
+
 
 def init_block_kv_cache(spec: BlockKVCacheSpec) -> Dict[str, jax.Array]:
     return {
         "k": jnp.zeros(spec.shape, dtype=spec.store_dtype),
-        "v": jnp.zeros(spec.shape, dtype=spec.store_dtype),
+        "v": jnp.zeros(spec.shape_v, dtype=spec.store_dtype),
     }
 
 
@@ -418,9 +426,12 @@ class BlockKVLayout:
             v_new = v_new / jnp.asarray(self.v_scale, v_new.dtype)
         k_vals = jnp.swapaxes(k_new, 1, 2).astype(store)  # (B, S_act, KV, D)
         v_vals = jnp.swapaxes(v_new, 1, 2).astype(store)
-        flat = (-1, k_vals.shape[-2], k_vals.shape[-1])
-        k_pool = k_pool.at[layer, slots].set(k_vals.reshape(flat), mode="drop")
-        v_pool = v_pool.at[layer, slots].set(v_vals.reshape(flat), mode="drop")
+        k_pool = k_pool.at[layer, slots].set(
+            k_vals.reshape((-1,) + k_vals.shape[-2:]), mode="drop"
+        )
+        v_pool = v_pool.at[layer, slots].set(
+            v_vals.reshape((-1,) + v_vals.shape[-2:]), mode="drop"
+        )
         return k_pool, v_pool
 
     def read(self, k_pool, v_pool, cache_inputs, spec):

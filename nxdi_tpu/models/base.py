@@ -1097,9 +1097,13 @@ def decoder_layer(
     stacked_layer_idx=None,  # segment-local index into the stacked weights
     tkg_stacked=None,  # (k_s, v_s, kv_len): stacked-cache fused decode kernel
     spec_window=None,  # (k_sp, v_sp, win_pos, slot): draft-window scratch
+    moe_tally=None,  # list gaining a routed layer's held-pair count (ops/moe.py)
+    moe_stacked=None,  # (gate, up, down): the segment's stacked expert weights
 ):
     if stacked_layer_idx is None:
         stacked_layer_idx = layer_idx
+    if moe_stacked is not None:
+        moe_stacked = (*moe_stacked, stacked_layer_idx)
     # per-layer rope selection (gemma3 local/global thetas): cos/sin arrive
     # stacked (2, B, S, D) and the layer flag picks one inside the scan body
     if "use_local_rope" in lp:
@@ -1125,6 +1129,8 @@ def decoder_layer(
         extra["stacked_layer_idx"] = stacked_layer_idx
         extra["tkg_stacked"] = tkg_stacked
         extra["spec_window"] = spec_window
+    else:
+        extra["layer_idx"] = layer_idx  # the paged latent pool's layer
     attn_out, (nk, nv) = attn_block_fn(
         arch, lp["attn"], h, cos, sin, k_cache_l, v_cache_l,
         position_ids, cache_spec, attend_to_cache, policy, layout, cache_inputs,
@@ -1135,7 +1141,7 @@ def decoder_layer(
         # pre-norms off the SAME residual input, one residual add
         h_mlp = _norm(arch, hidden, lp["post_attention_layernorm"])
         if arch.moe is not None and "moe" in lp:
-            ff = moe_ops.moe_block(arch, arch.moe, lp["moe"], h_mlp, policy.hidden)
+            ff = moe_ops.moe_block(arch, arch.moe, lp["moe"], h_mlp, policy.hidden, moe_tally, moe_stacked)
         else:
             ff = mlp_block(arch, lp["mlp"], h_mlp, adapter_ids, mlp_stacked, stacked_layer_idx, policy=policy)
         hidden = hidden + (attn_out + ff) * arch.residual_multiplier
@@ -1154,7 +1160,7 @@ def decoder_layer(
         # per-layer MoE-vs-dense decided by the params structure so segmented
         # stacks (deepseek-V3 first_k_dense_replace, minimax) mix both
         if arch.moe is not None and "moe" in lp:
-            ff = moe_ops.moe_block(arch, arch.moe, lp["moe"], h, policy.hidden)
+            ff = moe_ops.moe_block(arch, arch.moe, lp["moe"], h, policy.hidden, moe_tally, moe_stacked)
         else:
             ff = mlp_block(arch, lp["mlp"], h, adapter_ids, mlp_stacked, stacked_layer_idx, policy=policy)
         ff = _norm(arch, ff, lp["post_feedforward_layernorm"])
@@ -1163,7 +1169,7 @@ def decoder_layer(
         hidden = hidden + attn_out * arch.residual_multiplier
         h = _norm(arch, hidden, lp["post_attention_layernorm"])
         if arch.moe is not None and "moe" in lp:
-            hidden = hidden + moe_ops.moe_block(arch, arch.moe, lp["moe"], h, policy.hidden) * arch.residual_multiplier
+            hidden = hidden + moe_ops.moe_block(arch, arch.moe, lp["moe"], h, policy.hidden, moe_tally, moe_stacked) * arch.residual_multiplier
         else:
             hidden = hidden + mlp_block(arch, lp["mlp"], h, adapter_ids, mlp_stacked, stacked_layer_idx, policy=policy) * arch.residual_multiplier
     hidden = constrain(hidden, policy.hidden)
@@ -1508,9 +1514,28 @@ def _extract_stacked_weights(arch: DecoderArch, seg):
     """Pull the layer-stacked MLP / fused-QKV weights out of a segment pytree
     when their Pallas kernels are enabled, so the scan does not slice them
     per layer (see run_decoder_layers). Returns (seg', mlp_stacked,
-    qkv_stacked) — stacked entries are None when the kernel is off or the
-    segment has no such weights (e.g. a MoE segment)."""
-    mlp_st = qkv_st = None
+    qkv_stacked, moe_stacked) — stacked entries are None when the kernel is
+    off or the segment has no such weights (e.g. a MoE segment).
+
+    ``moe_stacked``: a routed segment's plain expert weights under sparse
+    dispatch. The TPU's grouped matmul is such a kernel too (ops/moe.py
+    ``_grouped_matmul``): it takes the whole stack and the layer's index."""
+    mlp_st = qkv_st = moe_st = None
+    names = ("gate_proj", "up_proj", "down_proj")
+    moe = seg.get("moe") if isinstance(seg, dict) else None
+    if (
+        arch.moe is not None
+        and arch.moe.dispatch == "sparse"
+        and not arch.moe.per_phase_hybrid
+        and isinstance(moe, dict)
+        and isinstance(moe.get("experts"), dict)
+        and all(  # plain weights: a quantized leaf is dequantized a layer at a time
+            isinstance(moe["experts"].get(k), dict) and "w" in moe["experts"][k] for k in names
+        )
+    ):
+        experts = {k: dict(moe["experts"][k]) for k in names}
+        moe_st = tuple(experts[k].pop("w") for k in names)
+        seg = {**seg, "moe": {**moe, "experts": {**moe["experts"], **experts}}}
     if (
         arch.mlp_kernel_enabled
         and isinstance(seg, dict)
@@ -1539,7 +1564,7 @@ def _extract_stacked_weights(arch: DecoderArch, seg):
         qkv_st = (qp.pop("w"), qp.pop("b", None))
         attn["qkv_proj"] = qp
         seg = {**seg, "attn": attn}
-    return seg, mlp_st, qkv_st
+    return seg, mlp_st, qkv_st, moe_st
 
 
 def run_decoder_layers(
@@ -1561,8 +1586,14 @@ def run_decoder_layers(
     layer_injections: Optional[jax.Array] = None,  # (L, B, S, hidden) or None
     layer_replacements: Optional[Tuple[jax.Array, jax.Array]] = None,
     spec_window_inputs: Optional[Tuple[jax.Array, jax.Array]] = None,
+    moe_held_tally: Optional[list] = None,
 ):
     """Scan the layer stack.
+
+    ``moe_held_tally`` (paged pool only): a list that gains ONE int32 pair:
+    the (row, expert) pairs of this forward that fell on held experts, summed
+    over the routed layers, and the number of routed layers — it rides the
+    scan's carry beside the pool.
 
     Where the cache rides: the paged pool (``BlockKVLayout``) is the scan's
     CARRY beside the hidden state — every layer writes its rows at
@@ -1638,7 +1669,7 @@ def run_decoder_layers(
     def _step(h, lp, kl, vl, cos_, sin_, pos_, ci_, ad_, layout_=None,
               windowable_=None, defer_=None, mlp_stacked=None,
               qkv_stacked=None, layer_idx=None, stacked_layer_idx=None,
-              tkg_stacked=None, spec_window=None):
+              tkg_stacked=None, spec_window=None, moe_tally=None, moe_stacked=None):
         """One decoder layer with the bucket's static KV window applied.
         ``layout_``/``windowable_``/``defer_`` override the stack-wide
         defaults for the interleaved-window unit scan (ring slices use the
@@ -1653,7 +1684,8 @@ def run_decoder_layers(
             dfr = True
         stk = dict(mlp_stacked=mlp_stacked, qkv_stacked=qkv_stacked,
                    layer_idx=layer_idx, stacked_layer_idx=stacked_layer_idx,
-                   tkg_stacked=tkg_stacked, spec_window=spec_window)
+                   tkg_stacked=tkg_stacked, spec_window=spec_window,
+                   moe_tally=moe_tally, moe_stacked=moe_stacked)
         if (win_ok and kv_window is not None and kv_window < kl.shape[2]
                 and attend_to_cache and tkg_stacked is None):
             k_win, v_win = kl[:, :, :kv_window], vl[:, :, :kv_window]
@@ -1797,23 +1829,29 @@ def run_decoder_layers(
 
     ks, vs, hs = [], [], []
     k_pool, v_pool = (cache["k"], cache["v"]) if paged else (None, None)
+    count_held = paged and moe_held_tally is not None
+    held_pairs = jnp.zeros((2,), jnp.int32)  # [held pairs, routed layers]
     off = 0
     for seg in segments:
         # kernel-stacked weights: keep the big MLP/QKV weights OUT of the
         # scanned xs (a pallas operand on a scan slice materializes a full
         # per-layer weight copy) — the kernels index the stacked arrays via
         # scalar-prefetched layer index instead
-        seg, mlp_st, qkv_st = _extract_stacked_weights(arch, seg)
+        seg, mlp_st, qkv_st, moe_st = _extract_stacked_weights(arch, seg)
         n_seg = jax.tree_util.tree_leaves(seg)[0].shape[0]
 
-        def body(carry, xs, mlp_st=mlp_st, qkv_st=qkv_st, seg_off=off,
+        def body(carry, xs, mlp_st=mlp_st, qkv_st=qkv_st, moe_st=moe_st, seg_off=off,
                  tkg_st=None):
             # xs carries the GLOBAL layer index (for per-layer KV-quant scale
             # rows, kv_cache._scale_for, and the paged pool's layer); the
             # per-SEGMENT stacked kernel weights index with the segment-local
             # offset
             lp, kl, vl, ksp, vsp, inj, li, repl = xs
-            if paged:
+            held = layer_tally = None
+            if count_held:
+                h, kl, vl, held = carry
+                layer_tally = []
+            elif paged:
                 h, kl, vl = carry  # the whole pool, addressed at ``li``
             else:
                 h = carry
@@ -1823,15 +1861,20 @@ def run_decoder_layers(
                 spec_win = (ksp, vsp) + spec_window_inputs
             h, nk, nv = _step(
                 h, lp, kl, vl, cos, sin, position_ids, cache_inputs,
-                adapter_ids, mlp_stacked=mlp_st, qkv_stacked=qkv_st,
+                adapter_ids, mlp_stacked=mlp_st, qkv_stacked=qkv_st, moe_stacked=moe_st,
                 layer_idx=li, stacked_layer_idx=li_local, tkg_stacked=tkg_st,
-                spec_window=spec_win,
+                spec_window=spec_win, moe_tally=layer_tally,
             )
             if inj is not None:
                 h = h + inj.astype(h.dtype)
             if repl is not None:
                 rv, rm = repl
                 h = jnp.where(rm > 0, rv.astype(h.dtype), h)
+            if count_held:
+                held = held + jnp.stack(
+                    [sum(layer_tally, jnp.int32(0)), jnp.int32(len(layer_tally))]
+                )
+                return (h, nk, nv, held), (h if collect_hidden else None)
             if paged:
                 return (h, nk, nv), (h if collect_hidden else None)
             return h, ((nk, nv, h) if collect_hidden else (nk, nv))
@@ -1865,7 +1908,11 @@ def run_decoder_layers(
         xs = (seg, k_seg, v_seg, ksp_seg, vsp_seg, inj_seg,
               off + jnp.arange(n_seg, dtype=jnp.int32), repl_seg)
         with jax.named_scope("layers"):
-            if paged:
+            if count_held:
+                (hidden, k_pool, v_pool, held_pairs), seg_h = jax.lax.scan(
+                    body, (hidden, k_pool, v_pool, held_pairs), xs
+                )
+            elif paged:
                 (hidden, k_pool, v_pool), seg_h = jax.lax.scan(
                     body, (hidden, k_pool, v_pool), xs
                 )
@@ -1879,6 +1926,8 @@ def run_decoder_layers(
         else:
             ks.append(ys[0]); vs.append(ys[1])
     cat = (lambda xs: xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=0))
+    if count_held:
+        moe_held_tally.append(held_pairs)
     if paged:
         new_cache = {"k": k_pool, "v": v_pool}
     elif spec_mode:
@@ -2118,6 +2167,7 @@ def causal_lm_forward(
     if tensor_capture and "embeds" in tensor_capture:
         captured["embeds"] = hidden
     layer_hiddens = None
+    held_tally = None
     if tensor_capture and "layer_hiddens" in tensor_capture and not aux_hidden_indices:
         aux_hidden_indices = ()  # falsy: don't emit aux_hidden output
         hidden, new_cache, layer_hiddens = run_decoder_layers(
@@ -2143,6 +2193,16 @@ def causal_lm_forward(
         if tensor_capture and "layer_hiddens" in tensor_capture:
             captured["layer_hiddens"] = layer_hiddens
     else:
+        if (
+            arch.moe is not None
+            and arch.moe.held_experts is not None
+            and attend_to_cache
+            and input_ids.shape[1] == 1
+            and isinstance(layout, BlockKVLayout)
+        ):
+            # token generation of one chip's share of an expert-parallel
+            # layer: count the (row, expert) pairs that fell on held experts
+            held_tally = []
         hidden, new_cache = run_decoder_layers(
             arch, params["layers"], hidden, cos, sin, cache,
             position_ids, cache_spec, attend_to_cache, kv_window=kv_window,
@@ -2151,6 +2211,7 @@ def causal_lm_forward(
             layer_injections=layer_injections,
             layer_replacements=layer_replacements,
             spec_window_inputs=spec_window_inputs,
+            moe_held_tally=held_tally,
         )
     if tensor_replacement and "hidden" in tensor_replacement:
         hidden = jnp.where(
@@ -2192,6 +2253,11 @@ def causal_lm_forward(
         logits = sampling_ops.mask_padded_logits(logits, arch.vocab_pad)
 
     outputs: Dict[str, jax.Array] = {}
+    if held_tally:
+        # two scalars beside the tokens, in the same fetch (serving/engine.py
+        # _decode_single -> StepRecord.moe_held_pairs); batch-padding rows
+        # are rows the expert layer computed, and are in the count
+        outputs["moe_held_pairs"], outputs["moe_routed_layers"] = held_tally[0]
     if tensor_capture:
         if "hidden" in tensor_capture:
             captured["hidden"] = pre_norm_hidden

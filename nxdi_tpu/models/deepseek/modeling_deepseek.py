@@ -10,6 +10,19 @@ choice — see the ops/mla.py docstring). V3 routing semantics live in
 ops/moe.py:route_topk (sigmoid_routing / n_group / topk_group /
 correction_bias); the dense-head + MoE-tail layer mix rides the segmented
 layer scan (models/base.py run_decoder_layers).
+
+``pangu_ultra_moe`` (openPangu-Ultra-MoE) is the same stack with four norms a
+layer (``sandwich_norm``: the block outputs are normed before the residual
+add, under the published names ``pre_mlp_layernorm``/``post_mlp_layernorm``),
+a sigmoid router without selection bias or groups, plain RoPE in rotate-half
+order, and no multi-token-prediction module in the served forward pass:
+:class:`PanguUltraMoeInferenceConfig` states those, the code below branches
+on what the config says.
+
+One chip's share of an expert-parallel deployment (``n_routed_experts_total``
+beside ``n_routed_experts``): the router keeps the published width, the tree
+holds ``n_routed_experts`` experts from ``first_routed_expert`` on
+(ops/moe.py ``MoEArch.held_experts``).
 """
 
 from __future__ import annotations
@@ -60,6 +73,26 @@ class DeepseekInferenceConfig(dense.DenseInferenceConfig):
                 setattr(self, k, v)
 
 
+class PanguUltraMoeInferenceConfig(DeepseekInferenceConfig):
+    """openPangu-Ultra-MoE (``model_type: pangu_ultra_moe``): what its
+    ``config.json`` leaves to the modeling code. The router scores with a
+    sigmoid and selects without a correction bias or groups (renormalised
+    top k x ``routed_scaling_factor``); RoPE channels are in rotate-half order
+    (no conversion-time permutation); the multi-token-prediction module
+    (``num_nextn_predict_layers``) is no part of the served forward pass."""
+
+    def add_derived_config(self):
+        for k, v in {
+            "scoring_func": "sigmoid",
+            "router_correction_bias": False,
+            "rope_interleave": False,
+            "sandwich_norm": True,
+        }.items():
+            if not hasattr(self, k):
+                setattr(self, k, v)
+        super().add_derived_config()
+
+
 def _yarn_mscale(scale: float, mscale: float) -> float:
     if scale <= 1:
         return 1.0
@@ -67,11 +100,6 @@ def _yarn_mscale(scale: float, mscale: float) -> float:
 
 
 def _mla_arch(config: InferenceConfig) -> MLAArch:
-    if config.tpu_config.is_block_kv_layout:
-        raise ValueError(
-            "MLA does not support the block KV layout yet: the latent cache "
-            "needs asymmetric k/v slot widths the block pool lacks"
-        )
     tp = config.tpu_config.tp_degree
     H = config.num_attention_heads
     if H % tp != 0:
@@ -107,9 +135,12 @@ def _moe_arch(config: InferenceConfig) -> Optional[MoEArch]:
     routed_scaling_factor. Shared experts are n_shared_experts plain
     (ungated) MLPs of moe_intermediate_size each, fused here into one wide
     shared MLP."""
-    E = getattr(config, "n_routed_experts", None)
-    if not E:
+    held = getattr(config, "n_routed_experts", None)
+    if not held:
         return None
+    # a share: ``n_routed_experts`` counts the experts held here, the router
+    # keeps the published width
+    E = getattr(config, "n_routed_experts_total", None) or held
     scoring = getattr(config, "scoring_func", "sigmoid")
     if scoring not in ("sigmoid", "softmax"):
         raise ValueError(f"deepseek scoring_func {scoring!r} not supported")
@@ -124,10 +155,14 @@ def _moe_arch(config: InferenceConfig) -> Optional[MoEArch]:
         n_group=getattr(config, "n_group", None),
         topk_group=getattr(config, "topk_group", None),
         routed_scaling=float(getattr(config, "routed_scaling_factor", 1.0)),
-        correction_bias=scoring == "sigmoid",
+        correction_bias=(
+            scoring == "sigmoid" and bool(getattr(config, "router_correction_bias", True))
+        ),
         shared_expert_intermediate_size=(
             n_shared * config.moe_intermediate_size if n_shared else None
         ),
+        held_experts=held if held != E else None,
+        first_held=int(getattr(config, "first_routed_expert", 0) or 0),
         **moe_parallel_fields(config.tpu_config, E),
     )
 
@@ -145,7 +180,10 @@ def build_arch(config: InferenceConfig, **overrides) -> DecoderArch:
     moe = _moe_arch(config)
     if moe is not None and _first_k_dense(config) >= config.num_hidden_layers:
         moe = None  # every layer is dense — no MoE layer exists in the model
-    kwargs = dict(mla=_mla_arch(config), moe=moe)
+    kwargs = dict(
+        mla=_mla_arch(config), moe=moe,
+        sandwich_norm=bool(getattr(config, "sandwich_norm", False)),
+    )
     kwargs.update(overrides)
     return dense.build_arch(config, **kwargs)
 
@@ -189,27 +227,19 @@ def _moe_layer(state_dict, pre, cast, moe: MoEArch):
                 return state_dict[k]
         raise KeyError(name)
 
+    held = range(moe.first_held, moe.first_held + moe.experts_here)
+
+    def stacked(proj):
+        return {"w": cast(np.stack([
+            np.asarray(get(f"{pre}mlp.experts.{j}.{proj}.weight")).T for j in held
+        ]))}
+
     out: Dict[str, Any] = {
         "router": {"w": cast(get(pre + "mlp.gate.weight")).T},
         "experts": {
-            "gate_proj": {
-                "w": cast(np.stack([
-                    np.asarray(get(f"{pre}mlp.experts.{j}.gate_proj.weight")).T
-                    for j in range(moe.num_experts)
-                ]))
-            },
-            "up_proj": {
-                "w": cast(np.stack([
-                    np.asarray(get(f"{pre}mlp.experts.{j}.up_proj.weight")).T
-                    for j in range(moe.num_experts)
-                ]))
-            },
-            "down_proj": {
-                "w": cast(np.stack([
-                    np.asarray(get(f"{pre}mlp.experts.{j}.down_proj.weight")).T
-                    for j in range(moe.num_experts)
-                ]))
-            },
+            "gate_proj": stacked("gate_proj"),
+            "up_proj": stacked("up_proj"),
+            "down_proj": stacked("down_proj"),
         },
     }
     if moe.correction_bias:
@@ -279,6 +309,10 @@ def convert_hf_state_dict(
             "post_attention_layernorm": cast(get(pre + "post_attention_layernorm.weight")),
             "attn": attn,
         }
+        if arch.sandwich_norm:
+            # the published names, onto the slots models/base.py reads
+            layer["pre_feedforward_layernorm"] = cast(get(pre + "pre_mlp_layernorm.weight"))
+            layer["post_feedforward_layernorm"] = cast(get(pre + "post_mlp_layernorm.weight"))
         if arch.moe is not None and i >= _first_k_dense(config):
             layer["moe"] = _moe_layer(state_dict, pre, cast, arch.moe)
         else:
@@ -340,16 +374,22 @@ def param_specs(config: InferenceConfig):
         )
 
     mla_specs = stack(mla_param_specs(arch.mla))
+
+    def finish(layer_specs):
+        layer_specs["attn"] = mla_specs
+        if arch.sandwich_norm:
+            layer_specs["pre_feedforward_layernorm"] = P()
+            layer_specs["post_feedforward_layernorm"] = P()
+        return layer_specs
+
     segs = _segment_archs(config, arch)
     specs = dense.param_specs_for(arch)
     if segs is None:
-        specs["layers"]["attn"] = mla_specs
+        finish(specs["layers"])
         return specs
     seg_specs = []
     for seg_arch in segs:
-        seg = decoder_param_specs(seg_arch)["layers"]
-        seg["attn"] = mla_specs
-        seg_specs.append(seg)
+        seg_specs.append(finish(decoder_param_specs(seg_arch)["layers"]))
     specs["layers"] = seg_specs
     return specs
 
@@ -357,21 +397,28 @@ def param_specs(config: InferenceConfig):
 def param_shape_struct(config: InferenceConfig):
     from nxdi_tpu.config import to_jax_dtype
 
+    import jax
+
     arch = build_arch(config)
+    dt = to_jax_dtype(arch.dtype)
+
+    def finish(layers, seg_arch):
+        layers["attn"] = mla_shape_struct(
+            seg_arch.mla, seg_arch.hidden_size, seg_arch.num_layers, dt
+        )
+        if arch.sandwich_norm:
+            norm = jax.ShapeDtypeStruct((seg_arch.num_layers, seg_arch.hidden_size), dt)
+            layers["pre_feedforward_layernorm"] = norm
+            layers["post_feedforward_layernorm"] = norm
+        return layers
+
     struct = dense.param_shape_struct(config, arch)
     segs = _segment_archs(config, arch)
     if segs is None:
-        struct["layers"]["attn"] = mla_shape_struct(
-            arch.mla, arch.hidden_size, arch.num_layers, to_jax_dtype(arch.dtype)
-        )
+        finish(struct["layers"], arch)
         return struct
-    seg_structs = []
-    for seg_arch in segs:
-        seg_cfg_struct = dense.param_shape_struct(config, seg_arch)["layers"]
-        seg_cfg_struct["attn"] = mla_shape_struct(
-            seg_arch.mla, seg_arch.hidden_size, seg_arch.num_layers,
-            to_jax_dtype(seg_arch.dtype),
-        )
-        seg_structs.append(seg_cfg_struct)
-    struct["layers"] = seg_structs
+    struct["layers"] = [
+        finish(dense.param_shape_struct(config, seg_arch)["layers"], seg_arch)
+        for seg_arch in segs
+    ]
     return struct

@@ -30,6 +30,10 @@ _REGISTRY: Dict[str, Tuple[str, str]] = {
     "gpt_oss": ("nxdi_tpu.models.gpt_oss.modeling_gpt_oss", "GptOssInferenceConfig"),
     "deepseek_v3": ("nxdi_tpu.models.deepseek.modeling_deepseek", "DeepseekInferenceConfig"),
     "deepseek": ("nxdi_tpu.models.deepseek.modeling_deepseek", "DeepseekInferenceConfig"),
+    "pangu_ultra_moe": (
+        "nxdi_tpu.models.deepseek.modeling_deepseek",
+        "PanguUltraMoeInferenceConfig",
+    ),
     "llama4": ("nxdi_tpu.models.llama4.modeling_llama4", "Llama4InferenceConfig"),
     "llama4_text": ("nxdi_tpu.models.llama4.modeling_llama4", "Llama4InferenceConfig"),
     "llava": ("nxdi_tpu.models.llava.modeling_llava", "LlavaInferenceConfig"),

@@ -713,33 +713,31 @@ def _write_paged_decode_output(o_ref, l_ref, acc_ref, v_scale, KV, G):
     )
 
 
-def _paged_decode_kernel(
-    li_ref, bt_ref, qp_ref, q_ref, k_hbm, v_hbm, o_ref,
-    m_ref, l_ref, acc_ref, k_buf, v_buf, sem, slot_ref,
-    *, scale, v_scale, n_rows, n_chunks, pages, KV, G, block_size, compute_dtype,
+def _paged_block_chain(
+    b, c, bt_ref, qp_ref, layer, pools, bufs, sem, slot_ref,
+    *, n_rows, n_chunks, pages, block_size, rows, reset, prepare, block,
 ):
-    """Grid (row, chunk of ``pages`` table entries). The pool stays in HBM;
-    each step that has live blocks waits for its own (started one live step
-    earlier, into the other buffer), starts the NEXT live step's — the same
-    row's next chunk, or the next row's first — and computes, so a block's
-    HBM latency is paid behind the previous step's compute and all blocks of
-    a step are in flight together. Steps past a row's position touch
-    neither the pool nor the state.
+    """The chain of block copies of a paged decode kernel with grid (row,
+    chunk of ``pages`` table entries), shared by ``_paged_decode_kernel`` and
+    ``mla_decode._mla_paged_decode_kernel``. The pools stay in HBM; each step
+    that has live blocks waits for its own (started one live step earlier,
+    into the other buffer), starts the NEXT live step's — the same row's next
+    chunk, or the next row's first — and computes, so a block's HBM latency
+    is paid behind the previous step's compute and all blocks of a step are
+    in flight together. Steps past a row's position touch neither the pools
+    nor the state.
 
-    A block arrives as its (block_size * KV, D) rows, token-major with the kv
-    heads interleaved (row = token * KV + head): ONE dot of all H query rows
-    against it scores every head at once, and the mask keeps, for a query
-    row, the columns of its own kv head (the others' exp is an exact 0, so
-    they add nothing to l or acc). That spends KV x the MXU work a per-head
-    dot needs — nothing beside the block's DMA at decode widths — and never
-    pulls a head's rows out from between the others'."""
-    b, c = pl.program_id(0), pl.program_id(1)
-    layer = li_ref[0]
+    ``pools``: HBM refs (L, pool rows, width), one block being ``rows`` of
+    them; ``bufs``: their VMEM buffers (2, pages, rows, width); ``sem``: DMA
+    semaphores (2, len(pools), pages); ``slot_ref``: SMEM (1,), the buffer
+    the current step reads. ``reset()`` runs at a row's first chunk, ``ctx =
+    prepare()`` once in a step that computes, ``block(ctx, p, slot, q_pos)``
+    on table entry ``p`` of the chunk once its copies have landed. ``b, c``:
+    the grid step (``pl.program_id`` of both axes)."""
     span = pages * block_size  # positions one chunk covers
-    rows = block_size * KV  # pool rows of one block
 
     def page_copies(row, chunk, slot):
-        """[(live, K copy, V copy)] of one step's table entries: a block is
+        """[(live, one copy a pool)] of one step's table entries: a block is
         fetched iff it is allocated and starts at or before the position."""
         out = []
         for p in range(pages):
@@ -749,21 +747,21 @@ def _paged_decode_kernel(
             src = pl.ds(pl.multiple_of(jnp.maximum(blk, 0) * rows, rows), rows)
             out.append((
                 live,
-                pltpu.make_async_copy(
-                    k_hbm.at[layer, src], k_buf.at[slot, p], sem.at[slot, 0, p]
-                ),
-                pltpu.make_async_copy(
-                    v_hbm.at[layer, src], v_buf.at[slot, p], sem.at[slot, 1, p]
-                ),
+                [
+                    pltpu.make_async_copy(
+                        hbm.at[layer, src], buf.at[slot, p], sem.at[slot, i, p]
+                    )
+                    for i, (hbm, buf) in enumerate(zip(pools, bufs))
+                ],
             ))
         return out
 
     def start(row, chunk, slot):
-        for live, k_copy, v_copy in page_copies(row, chunk, slot):
+        for live, copies in page_copies(row, chunk, slot):
             @pl.when(live)
             def _():
-                k_copy.start()
-                v_copy.start()
+                for copy in copies:
+                    copy.start()
 
     @pl.when((b == 0) & (c == 0))
     def _():
@@ -772,7 +770,7 @@ def _paged_decode_kernel(
 
     @pl.when(c == 0)
     def _():
-        _reset_softmax_state(m_ref, l_ref, acc_ref)
+        reset()
 
     # a row's first chunk always runs (it hands the chain of prefetches on,
     # even for a row with nothing to read); later ones while they hold
@@ -789,27 +787,60 @@ def _paged_decode_kernel(
         def _():
             start(nxt_row, nxt_chunk, 1 - slot)
 
+        ctx = prepare()
+        for p, (live, copies) in enumerate(page_copies(b, c, slot)):
+            @pl.when(live)
+            def _(p=p, copies=copies):
+                for copy in copies:
+                    copy.wait()
+                block(ctx, p, slot, q_pos)
+
+        slot_ref[0] = 1 - slot
+
+
+def _paged_decode_kernel(
+    li_ref, bt_ref, qp_ref, q_ref, k_hbm, v_hbm, o_ref,
+    m_ref, l_ref, acc_ref, k_buf, v_buf, sem, slot_ref,
+    *, scale, v_scale, n_rows, n_chunks, pages, KV, G, block_size, compute_dtype,
+):
+    """Grid (row, chunk of ``pages`` table entries); ``_paged_block_chain``
+    brings the blocks.
+
+    A block arrives as its (block_size * KV, D) rows, token-major with the kv
+    heads interleaved (row = token * KV + head): ONE dot of all H query rows
+    against it scores every head at once, and the mask keeps, for a query
+    row, the columns of its own kv head (the others' exp is an exact 0, so
+    they add nothing to l or acc). That spends KV x the MXU work a per-head
+    dot needs — nothing beside the block's DMA at decode widths — and never
+    pulls a head's rows out from between the others'."""
+    b, c = pl.program_id(0), pl.program_id(1)
+    layer = li_ref[0]
+    rows = block_size * KV  # pool rows of one block
+
+    def prepare():
         q = q_ref[0].reshape(KV * G, q_ref.shape[-1])  # row = head * G + g
         col = jax.lax.broadcasted_iota(jnp.int32, (KV * G, rows), 1)
         q_head = jax.lax.broadcasted_iota(jnp.int32, (KV * G, rows), 0) // G
-        own_head = (col % KV) == q_head
-        token = col // KV
-        for p, (live, k_copy, v_copy) in enumerate(page_copies(b, c, slot)):
-            @pl.when(live)
-            def _(p=p, k_copy=k_copy, v_copy=v_copy):
-                k_copy.wait()
-                v_copy.wait()
-                k = k_buf[slot, p].astype(compute_dtype)  # (block_size * KV, D)
-                v = v_buf[slot, p].astype(compute_dtype)
-                s = jax.lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ) * scale  # (H, block_size * KV)
-                kv_pos = (c * pages + p) * block_size + token
-                mask = own_head & (kv_pos <= q_pos)
-                _online_softmax_step(s, mask, m_ref, l_ref, acc_ref, v)
+        return q, (col % KV) == q_head, col // KV
 
-        slot_ref[0] = 1 - slot
+    def block(ctx, p, slot, q_pos):
+        q, own_head, token = ctx
+        k = k_buf[slot, p].astype(compute_dtype)  # (block_size * KV, D)
+        v = v_buf[slot, p].astype(compute_dtype)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # (H, block_size * KV)
+        kv_pos = (c * pages + p) * block_size + token
+        mask = own_head & (kv_pos <= q_pos)
+        _online_softmax_step(s, mask, m_ref, l_ref, acc_ref, v)
+
+    _paged_block_chain(
+        b, c, bt_ref, qp_ref, layer, (k_hbm, v_hbm), (k_buf, v_buf), sem, slot_ref,
+        n_rows=n_rows, n_chunks=n_chunks, pages=pages, block_size=block_size, rows=rows,
+        reset=lambda: _reset_softmax_state(m_ref, l_ref, acc_ref),
+        prepare=prepare, block=block,
+    )
 
     @pl.when(c == n_chunks - 1)
     def _():

@@ -104,6 +104,33 @@ class MoEArch:
     topk_group: Optional[int] = None
     routed_scaling: float = 1.0
     correction_bias: bool = False
+    # ONE CHIP'S SHARE of an expert-parallel layer, run without its exchange
+    # (a chip of a decode pool that shares each layer by experts): the router
+    # keeps its ``num_experts`` outputs and its top k, the parameter tree
+    # holds ``held_experts`` of them, ``[first_held, first_held + held)``, and
+    # the layer returns the partial sum of its own experts plus the shared
+    # expert. None = every expert is held (the whole layer).
+    held_experts: Optional[int] = None
+    first_held: int = 0
+
+    def __post_init__(self):
+        if self.held_experts is None:
+            return
+        if not 0 < self.held_experts <= self.num_experts - self.first_held or self.first_held < 0:
+            raise ValueError(
+                f"held experts [{self.first_held}, {self.first_held + self.held_experts}) "
+                f"do not lie among the router's {self.num_experts}"
+            )
+        if self.ep or self.hybrid_ep:
+            raise ValueError(
+                "a share of an expert layer (held_experts) is what ONE chip of an "
+                "expert-parallel pool holds; it does not combine with an expert mesh axis"
+            )
+
+    @property
+    def experts_here(self) -> int:
+        """Experts in the parameter tree."""
+        return self.num_experts if self.held_experts is None else self.held_experts
 
 
 def ep_policy(tp_degree: int, num_experts: int) -> bool:
@@ -353,6 +380,24 @@ def _expert_act(moe: MoEArch, gate: jax.Array, up: jax.Array) -> jax.Array:
     return ACT_FNS[moe.hidden_act](gate) * up
 
 
+def _grouped_matmul(xs, w, group_sizes, layer, precision):
+    """``ragged_dot`` of sorted rows against the experts' weights. ``w`` is
+    one layer's ``(E, in, out)`` (``layer`` None) or a segment's layer-stacked
+    ``(L, E, in, out)``: the stack is then handed over WHOLE, as L*E groups of
+    which only layer ``layer``'s have rows. The TPU's grouped matmul is a
+    kernel and takes its operand as a buffer: given a layer's slice of the
+    stack it has the slice materialised first (0.5 GiB a matrix at 16 experts
+    of 7680 x 2048), where the whole stack is read in place and the empty
+    groups cost no tile."""
+    if layer is not None:
+        L, E = w.shape[:2]
+        w = w.reshape((L * E,) + w.shape[2:])
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((L * E,), group_sizes.dtype), group_sizes, (layer * E,)
+        )
+    return jax.lax.ragged_dot(xs, w, group_sizes, precision=precision)
+
+
 def _sparse_expert_ffn(
     moe: MoEArch,
     ew: Dict[str, Any],
@@ -362,6 +407,7 @@ def _sparse_expert_ffn(
     e_lo,  # scalar: first expert id held locally
     e_count: int,  # number of experts held locally
     down_bias_on=1.0,  # 0/1 gate so replicated down biases aren't double-psummed
+    layer=None,  # the weights in ``ew`` are layer-stacked; this layer's index
 ) -> jax.Array:
     """Grouped-matmul expert FFN over the locally-held expert/intermediate
     shard. Returns the PARTIAL combined output (T, H) — callers psum over the
@@ -369,12 +415,14 @@ def _sparse_expert_ffn(
 
     The ragged_dot grouped matmul wants rows sorted by group; rows routed to
     non-local experts sort to a tail past ``sum(group_sizes)`` whose output is
-    unspecified-but-finite — their combine weight is zeroed so they never
-    contribute."""
+    unspecified — they are dropped at the combine and never contribute."""
     T, H = xt.shape
     K = moe.top_k
     N = T * K
-    hp = jax.lax.Precision.HIGHEST
+    # float32 inputs multiply exactly only at HIGHEST; bf16 x bf16 products
+    # are exact in the float32 accumulator at any precision, and the TPU's
+    # grouped matmul (a Mosaic kernel) refuses HIGHEST on bf16 operands
+    hp = jax.lax.Precision.HIGHEST if xt.dtype == jnp.float32 else None
 
     flat_e = idx.reshape(N)
     flat_t = jnp.repeat(jnp.arange(T, dtype=jnp.int32), K)
@@ -394,17 +442,18 @@ def _sparse_expert_ffn(
     group_sizes = jnp.bincount(se, length=e_count).astype(jnp.int32)
 
     se_c = jnp.minimum(se, e_count - 1)  # clipped for bias gathers
-    gate = jax.lax.ragged_dot(xs, ew["gate_proj"]["w"], group_sizes, precision=hp)
-    up = jax.lax.ragged_dot(xs, ew["up_proj"]["w"], group_sizes, precision=hp)
+    gate = _grouped_matmul(xs, ew["gate_proj"]["w"], group_sizes, layer, hp)
+    up = _grouped_matmul(xs, ew["up_proj"]["w"], group_sizes, layer, hp)
     if moe.expert_bias:
         gate = gate + ew["gate_proj"]["b"][se_c]
         up = up + ew["up_proj"]["b"][se_c]
     inner = _expert_act(moe, gate, up)
-    rows = jax.lax.ragged_dot(inner, ew["down_proj"]["w"], group_sizes, precision=hp)
+    rows = _grouped_matmul(inner, ew["down_proj"]["w"], group_sizes, layer, hp)
     if moe.expert_bias:
         rows = rows + (ew["down_proj"]["b"][se_c] * down_bias_on).astype(rows.dtype)
 
-    rows = rows * comb[:, None].astype(rows.dtype)
+    # the tail's rows are whatever the kernel left there (0 x NaN is NaN)
+    rows = jnp.where((se < e_count)[:, None], rows * comb[:, None].astype(rows.dtype), 0)
     # un-sort back to (T, K) slots, then reduce over K — deterministic combine
     unsorted = jnp.zeros((N, H), rows.dtype).at[order].set(rows)
     return jnp.sum(unsorted.reshape(T, K, H), axis=1)
@@ -431,6 +480,7 @@ def _sparse_moe(
     weights: jax.Array,  # (B, S, K) f32
     idx: jax.Array,  # (B, S, K) i32
     hidden_spec: P,
+    layer=None,  # the weights are layer-stacked (L, E, ..); this layer's index
 ) -> jax.Array:
     """Dispatch the sparse expert FFN, sharded over the mesh when one is in
     scope. Token (dp/cp) axes stay data-parallel; expert/intermediate shards
@@ -440,34 +490,39 @@ def _sparse_moe(
     i_axes = _inter_dim_axes(moe)
     mesh = jax.sharding.get_abstract_mesh()
 
-    def local(ex, xb, wb, ib):
+    def local(ex, xb, wb, ib, layer=None):
         B, S, H = xb.shape
         if e_axes:
-            e_count = ex["gate_proj"]["w"].shape[0]
+            e_count = ex["gate_proj"]["w"].shape[-3]
             e_lo = jax.lax.axis_index(e_axes) * e_count
         else:
-            e_count = moe.num_experts
-            e_lo = 0
+            e_count = moe.experts_here
+            e_lo = moe.first_held
         if i_axes:
             down_on = (jax.lax.axis_index(i_axes) == 0).astype(jnp.float32)
         else:
             down_on = 1.0
         out = _sparse_expert_ffn(
             moe, ex, xb.reshape(B * S, H), wb.reshape(B * S, -1),
-            ib.reshape(B * S, -1), e_lo, e_count, down_on,
+            ib.reshape(B * S, -1), e_lo, e_count, down_on, layer,
         )
         out = jax.lax.psum(out, AXIS_MP)
         return out.reshape(B, S, H)
 
-    if mesh is None or mesh.empty or not set(AXIS_MP).issubset(mesh.axis_names):
+    if (
+        mesh is None or mesh.empty or not set(AXIS_MP).issubset(mesh.axis_names)
+        # a share on a one-chip model-parallel world: nothing to exchange
+        or (moe.held_experts is not None and all(mesh.shape[a] == 1 for a in AXIS_MP))
+    ):
         return _sparse_expert_ffn(
             moe,
             experts,
             x.reshape(-1, x.shape[-1]),
             weights.reshape(-1, moe.top_k),
             idx.reshape(-1, moe.top_k),
-            0,
-            moe.num_experts,
+            moe.first_held,
+            moe.experts_here,
+            layer=layer,
         ).reshape(x.shape)
 
     tok_spec = _strip_mp_axes(hidden_spec)
@@ -475,10 +530,11 @@ def _sparse_moe(
              tok_spec[1] if len(tok_spec) > 1 else None, None)
     e = _axes_entry(e_axes)
     i = _axes_entry(i_axes)
+    stack = () if layer is None else (None,)  # the leading layer axis
     w_specs = {
-        "gate_proj": {"w": P(e, None, i)},
-        "up_proj": {"w": P(e, None, i)},
-        "down_proj": {"w": P(e, i, None)},
+        "gate_proj": {"w": P(*stack, e, None, i)},
+        "up_proj": {"w": P(*stack, e, None, i)},
+        "down_proj": {"w": P(*stack, e, i, None)},
     }
     if moe.expert_bias:
         w_specs["gate_proj"]["b"] = P(e, i)
@@ -487,29 +543,43 @@ def _sparse_moe(
     fn = jax.shard_map(
         local,
         mesh=mesh,
-        in_specs=(w_specs, tok2, tok2, tok2),
+        in_specs=(w_specs, tok2, tok2, tok2) + (() if layer is None else (P(),)),
         out_specs=tok2,
         check_vma=False,
     )
-    return fn(experts, x, weights, idx)
+    return fn(experts, x, weights, idx, *(() if layer is None else (layer,)))
 
 
 def moe_block(
-    arch, moe: MoEArch, p: Dict[str, Any], x: jax.Array, hidden_spec: Optional[P] = None
+    arch, moe: MoEArch, p: Dict[str, Any], x: jax.Array, hidden_spec: Optional[P] = None,
+    held_tally: Optional[list] = None, stacked_experts=None,
 ) -> jax.Array:
     """MoE feed-forward: (B, S, H) -> (B, S, H).
 
-    Param leaves: router.w (H, E); experts.{gate,up}_proj.w (E, H, I),
-    experts.down_proj.w (E, I, H); optional shared_expert mlp.
+    Param leaves: router.w (H, E); experts.{gate,up}_proj.w (E_here, H, I),
+    experts.down_proj.w (E_here, I, H); optional shared_expert mlp. E is the
+    router's width, E_here the experts this program holds (``MoEArch
+    .held_experts``; all of them unless the layer is one chip's share).
+
+    ``held_tally``: a list that gains this layer's count (int32 scalar) of
+    (row, expert) pairs routed to a held expert — the step program's counter
+    ``moe_held_pairs`` (models/base.py run_decoder_layers).
+
+    ``stacked_experts`` (sparse dispatch inside the layer scan): ``(gate, up,
+    down, layer)``, the segment's layer-stacked expert weights ``(L, E_here,
+    ..)`` kept OUT of the scan's xs, and this layer's index among them; ``p``
+    then has no ``experts.*.w`` (``_grouped_matmul`` says why).
     """
     from nxdi_tpu.ops.quantization import materialize_weight as mat_w
 
     B, S, H = x.shape
     xt = x.reshape(B * S, H)
+    lo, n_here = moe.first_held, moe.experts_here
 
-    router_logits = xt.astype(jnp.float32) @ p["router"]["w"].astype(jnp.float32)
-    if moe.router_bias:
-        router_logits = router_logits + p["router"]["b"].astype(jnp.float32)
+    with jax.named_scope("moe.route"):
+        router_logits = xt.astype(jnp.float32) @ p["router"]["w"].astype(jnp.float32)
+        if moe.router_bias:
+            router_logits = router_logits + p["router"]["b"].astype(jnp.float32)
 
     # per-phase hybrid: decode programs read the EP-heavy duplicated copy
     p_experts = p["experts"]
@@ -517,7 +587,19 @@ def moe_block(
         p_experts = p["experts_tkg"]
 
     if moe.dispatch == "sparse":
-        top_vals, top_idx = route_topk(router_logits, moe, p["router"])
+        with jax.named_scope("moe.route"):
+            top_vals, top_idx = route_topk(router_logits, moe, p["router"])
+            if held_tally is not None:
+                held_tally.append(
+                    jnp.sum((top_idx >= lo) & (top_idx < lo + n_here), dtype=jnp.int32)
+                )
+        layer = None
+        if stacked_experts is not None:
+            *stacked, layer = stacked_experts
+            p_experts = {
+                k: {**p_experts[k], "w": w}
+                for k, w in zip(("gate_proj", "up_proj", "down_proj"), stacked)
+            }
         experts = {
             "gate_proj": {"w": mat_w(p_experts["gate_proj"], x.dtype)},
             "up_proj": {"w": mat_w(p_experts["up_proj"], x.dtype)},
@@ -526,16 +608,22 @@ def moe_block(
         if moe.expert_bias:
             for k in experts:
                 experts[k]["b"] = p_experts[k]["b"]
-        out = _sparse_moe(
-            moe,
-            experts,
-            x,
-            top_vals.reshape(B, S, moe.top_k),
-            top_idx.reshape(B, S, moe.top_k),
-            hidden_spec if hidden_spec is not None else P(),
-        ).reshape(B * S, H)
+        with jax.named_scope("moe.experts"):
+            out = _sparse_moe(
+                moe,
+                experts,
+                x,
+                top_vals.reshape(B, S, moe.top_k),
+                top_idx.reshape(B, S, moe.top_k),
+                hidden_spec if hidden_spec is not None else P(),
+                layer,
+            ).reshape(B * S, H)
     else:
         weights = route(router_logits, moe, p["router"]).astype(x.dtype)  # (T, E)
+        if held_tally is not None:
+            held_tally.append(jnp.sum(weights[:, lo: lo + n_here] != 0, dtype=jnp.int32))
+        if moe.held_experts is not None:
+            weights = weights[:, lo: lo + n_here]  # the held experts' columns
         # dense dispatch: all experts on all tokens, combine contracted over E.
         # mat_w dequantizes low-bit expert weights in the einsum's operand read.
         gate = jnp.einsum("th,ehi->eti", xt, mat_w(p_experts["gate_proj"], x.dtype))
@@ -565,14 +653,15 @@ def moe_block(
 
         act = ACT_FNS[moe.hidden_act]
         sp = p["shared_expert"]
-        shared = (
-            act(xt @ mat_w(sp["gate_proj"], x.dtype)) * (xt @ mat_w(sp["up_proj"], x.dtype))
-        ) @ mat_w(sp["down_proj"], x.dtype)
-        if moe.shared_expert_gated:
-            shared = jax.nn.sigmoid(
-                xt.astype(jnp.float32) @ p["shared_expert_gate"]["w"].astype(jnp.float32)
-            ).astype(shared.dtype) * shared
-        out = out + shared
+        with jax.named_scope("moe.shared"):
+            shared = (
+                act(xt @ mat_w(sp["gate_proj"], x.dtype)) * (xt @ mat_w(sp["up_proj"], x.dtype))
+            ) @ mat_w(sp["down_proj"], x.dtype)
+            if moe.shared_expert_gated:
+                shared = jax.nn.sigmoid(
+                    xt.astype(jnp.float32) @ p["shared_expert_gate"]["w"].astype(jnp.float32)
+                ).astype(shared.dtype) * shared
+            out = out + shared
 
     return out.reshape(B, S, H)
 
@@ -598,13 +687,14 @@ def moe_shape_struct(moe: MoEArch, hidden_size: int, num_layers: int, dtype) -> 
     def s(*shape):
         return jax.ShapeDtypeStruct((num_layers,) + shape, dtype)
 
-    E, H, I = moe.num_experts, hidden_size, moe.intermediate_size
+    # the router scores all E experts; the tree holds EH of them (a share)
+    E, EH, H, I = moe.num_experts, moe.experts_here, hidden_size, moe.intermediate_size
     struct: Dict[str, Any] = {
         "router": {"w": s(H, E)},
         "experts": {
-            "gate_proj": {"w": s(E, H, I)},
-            "up_proj": {"w": s(E, H, I)},
-            "down_proj": {"w": s(E, I, H)},
+            "gate_proj": {"w": s(EH, H, I)},
+            "up_proj": {"w": s(EH, H, I)},
+            "down_proj": {"w": s(EH, I, H)},
         },
     }
     if moe.router_bias:
@@ -613,9 +703,9 @@ def moe_shape_struct(moe: MoEArch, hidden_size: int, num_layers: int, dtype) -> 
         # f32 regardless of model dtype (selection-precision critical)
         struct["router"]["e_bias"] = jax.ShapeDtypeStruct((num_layers, E), jnp.float32)
     if moe.expert_bias:
-        struct["experts"]["gate_proj"]["b"] = s(E, I)
-        struct["experts"]["up_proj"]["b"] = s(E, I)
-        struct["experts"]["down_proj"]["b"] = s(E, H)
+        struct["experts"]["gate_proj"]["b"] = s(EH, I)
+        struct["experts"]["up_proj"]["b"] = s(EH, I)
+        struct["experts"]["down_proj"]["b"] = s(EH, H)
     if moe.per_phase_hybrid:
         import copy
 

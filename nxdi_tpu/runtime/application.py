@@ -293,15 +293,16 @@ class ApplicationBase:
         return (sum(not w for w in pat), sum(bool(w) for w in pat))
 
     def cache_partition_specs(self):
-        if self.tpu_config.is_block_kv_layout:
-            return block_kv_cache_partition_spec()
         arch = self.family.build_arch(self.config)
         if getattr(arch, "mla", None) is not None:
-            # MLA latent cache has ONE shared kv head; nothing to shard on the
-            # head axis — replicate (sequence sharding comes with flash decode)
+            # MLA latent cache, contiguous or paged, has ONE shared kv head;
+            # nothing to shard on the head axis — replicate (sequence sharding
+            # comes with flash decode)
             from jax.sharding import PartitionSpec as P
 
             return {"k": P(), "v": P()}
+        if self.tpu_config.is_block_kv_layout:
+            return block_kv_cache_partition_spec()
         specs = dict(kv_cache_partition_spec(self.tpu_config))
         if self._interleaved_window_split(arch) is not None:
             specs["k_win"] = specs["k"]
@@ -386,14 +387,23 @@ class ApplicationBase:
         # even when the target runs window_sized_kv
         tc = config.tpu_config
         if tc.is_block_kv_layout:
+            heads, k_dim, v_dim = arch.num_kv_heads, arch.head_dim, None
+            if getattr(arch, "mla", None) is not None:
+                # the paged LATENT pool: one row a token, the rope key (padded
+                # to a lane tile) in ``k`` and the normed latent in ``v``
+                from nxdi_tpu.ops.mla import paged_latent_widths
+
+                heads = 1
+                k_dim, v_dim = paged_latent_widths(arch.mla)
             return BlockKVCacheSpec(
                 num_layers=arch.num_layers,
                 num_blocks=tc.pa_num_blocks,
                 block_size=tc.pa_block_size,
-                num_kv_heads=arch.num_kv_heads,
-                head_dim=arch.head_dim,
+                num_kv_heads=heads,
+                head_dim=k_dim,
                 dtype=arch.dtype,
                 quant_dtype=(tc.kv_quant_config.dtype if tc.kv_quant_config else None),
+                v_head_dim=v_dim,
             )
         max_len = tc.seq_len
         split = self._interleaved_window_split(arch, config=config)

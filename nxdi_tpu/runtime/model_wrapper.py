@@ -615,6 +615,10 @@ class ModelWrapper:
         ``batch_np``: input_ids (b, s), position_ids (b, s), last_token_index
         (b,), sampling_params (b, 3). b may be smaller than the compiled batch.
         Returns (outputs, new_cache) with outputs still on device (async).
+        ``keep_batch_padding`` (true): per-row outputs keep the compiled
+        batch's rows, the first b being the caller's. A slice on the device
+        is one small program per b: a caller that walks through every row
+        count (the engine's ramp) reads the first b rows on the host instead.
         """
         tel = self.telemetry
         phase = self._phase
@@ -749,12 +753,15 @@ class ModelWrapper:
                     real_tokens=orig_b * s,
                     padded_tokens=self.batch_size * pad_s,
                 )
-            outputs = self._slice_batch_padding(outputs, orig_b)
+            keep = bool(batch_np.get("keep_batch_padding"))
+            if not keep:
+                outputs = self._slice_batch_padding(outputs, orig_b)
         if tel is not None and tel.sentinel is not None and "logit_stats" in outputs:
             # numerics sentinel: the compiled-in (B, 5) health readout is
             # recorded AFTER batch-padding rows are sliced away (padding
             # repeats row 0 — double-counting it would skew the series)
-            tel.sentinel.observe(self.tag, bucket, outputs["logit_stats"])
+            stats = outputs["logit_stats"]
+            tel.sentinel.observe(self.tag, bucket, stats[:orig_b] if keep else stats)
         return outputs, new_cache
 
     def _phase(self, name: str):

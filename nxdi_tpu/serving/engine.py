@@ -278,6 +278,9 @@ class InferenceEngine:
         )
         self._rng = StepRngSchedule(seed)
         self._tkg = app.models[TAG_TOKEN_GENERATION]
+        # counters of a model that holds a share of its experts; made on the
+        # first step whose program returns the count (none for other models)
+        self._moe_held_pairs = self._moe_routed_layer_steps = self._moe_routed_layers = None
         self._can_continue_prefill = TAG_PREFIX_PREFILL in app.models
         # n>1 sibling forks also start their tail prefill mid-prompt, so
         # the scheduler may only fork when a continuation path is compiled
@@ -1354,11 +1357,20 @@ class InferenceEngine:
             TAG_TOKEN_GENERATION,
             lambda: self.app.forward(
                 ids, pos, last_token_index=last, sampling_params=sampling,
-                submodel=TAG_TOKEN_GENERATION, **kwargs,
+                submodel=TAG_TOKEN_GENERATION, keep_batch_padding=True, **kwargs,
             ),
         )
         with phase("fetch"):
+            # read only for someone who keeps it
+            kept = self.flight is not None or self.telemetry is not None
+            held = out.get("moe_held_pairs") if kept else None
+            if held is not None:
+                held.copy_to_host_async()  # rides the tokens' fetch
             toks = self._tokens_of(out)
+        if held is not None:
+            if self._moe_routed_layers is None:  # the same number every step
+                self._moe_routed_layers = int(out["moe_routed_layers"])
+            self._note_moe_held_pairs(int(held), self._moe_routed_layers)
         dt = (clock() - t0) if clock else None
         with phase("emit"):
             for (slot, req), tok in zip(rows, toks):
@@ -1647,6 +1659,28 @@ class InferenceEngine:
                 }
             ),
         }
+
+    def _note_moe_held_pairs(self, pairs: int, routed_layers: int) -> None:
+        """A share of an expert-parallel model counts, inside its
+        token-generation program, the (row, expert) pairs routed to the
+        experts it holds (models/base.py causal_lm_forward)."""
+        if self.flight is not None:
+            self.flight.note_moe_held_pairs(pairs, routed_layers)
+        if self.telemetry is None:
+            return
+        if self._moe_held_pairs is None:
+            r = self.telemetry.registry
+            self._moe_held_pairs = r.counter(
+                "nxdi_moe_held_pairs_total",
+                "(row, expert) pairs of token generation routed to held experts, "
+                "summed over the routed layers (batch-padding rows included)",
+            )
+            self._moe_routed_layer_steps = r.counter(
+                "nxdi_moe_routed_layers_steps_total",
+                "routed layers x token-generation steps behind nxdi_moe_held_pairs_total",
+            )
+        self._moe_held_pairs.inc(pairs)
+        self._moe_routed_layer_steps.inc(routed_layers)
 
     def _tokens_of(self, outputs) -> np.ndarray:
         # shared with the HF adapter (ops/sampling.py): ONE extraction rule,

@@ -72,7 +72,7 @@ class StepRecord:
         "step", "t_start", "t_end", "admitted", "prefills", "decode",
         "mixed", "preempted", "retired", "programs", "kv_blocks_free",
         "queue_depth", "slots_busy", "dispatch_s", "host_s", "faults",
-        "phases", "open_phase",
+        "phases", "open_phase", "moe_held_pairs", "moe_routed_layers",
     )
 
     def __init__(self, step: int, t_start: float):
@@ -113,6 +113,13 @@ class StepRecord:
         self.phases: Dict[str, float] = {}
         #: the innermost phase open right now (Telemetry.phase keeps it)
         self.open_phase = None
+        #: (row, expert) pairs of this step's token generation that fell on
+        #: experts this program holds, summed over its routed layers, as the
+        #: step program counted them (batch-padding rows included); None for
+        #: a model whose program returns no such count
+        self.moe_held_pairs: Optional[int] = None
+        #: the routed layers those pairs were summed over
+        self.moe_routed_layers: Optional[int] = None
 
     @property
     def wall_s(self) -> float:
@@ -154,6 +161,8 @@ class StepRecord:
             "kv_blocks_free": self.kv_blocks_free,
             "queue_depth": self.queue_depth,
             "slots_busy": self.slots_busy,
+            "moe_held_pairs": self.moe_held_pairs,
+            "moe_routed_layers": self.moe_routed_layers,
         }
 
 
@@ -320,6 +329,12 @@ class FlightRecorder:
         rec = self.current
         if rec is not None and rec.decode is not None:
             rec.decode["tokens_emitted"] = int(tokens)
+
+    def note_moe_held_pairs(self, pairs: int, routed_layers: int) -> None:
+        rec = self.current
+        if rec is not None:
+            rec.moe_held_pairs = (rec.moe_held_pairs or 0) + int(pairs)
+            rec.moe_routed_layers = int(routed_layers)
 
     def record_mixed(
         self,

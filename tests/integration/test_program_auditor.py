@@ -306,8 +306,8 @@ def test_moe_tpxep_budget_violation_detected(monkeypatch):
 
     orig = ops_moe._sparse_moe
 
-    def wasteful(moe, experts, x, weights, idx, hidden_spec):
-        out = orig(moe, experts, x, weights, idx, hidden_spec)
+    def wasteful(moe, experts, x, weights, idx, hidden_spec, layer=None):
+        out = orig(moe, experts, x, weights, idx, hidden_spec, layer)
         mesh = jax.sharding.get_abstract_mesh()
         world = 1
         for a in AXIS_MP:

@@ -16,6 +16,7 @@ compilation cache is off around the compiles (an entry written for a
 described chip cannot be read back without one).
 """
 
+import os
 import re
 
 import jax
@@ -389,3 +390,120 @@ def test_layer_scan_carries_the_pool_in_every_paged_program():
             seen.add(tag)
     assert {"context_encoding_model", "token_generation_model", "mixed_model"} <= seen, seen
     assert len(seen) >= 4, seen  # + the prefix/chunked prefill program
+
+
+# -- the paged LATENT pool (PR 30): openPangu-Ultra-MoE's widths as the cell
+# `pangu-ultra-moe-ep16.reason-saturated` serves them: one dense + four expert
+# layers, 16 of 256 experts held, 128 rows, 2560 blocks of 128 = a
+# (5, 327680, 1, 128) rope pool and a (5, 327680, 1, 512) latent pool, 1.95 GiB
+def _pangu_share():
+    import json
+
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "benchmark", "configs",
+                        "pangu-ultra-moe-ep16.json")
+    with open(path) as f:
+        body = json.load(f)
+    own = ("name", "source", "deployment", "reduced", "published", "published_why", "assumed", "benchmark")
+    return {k: v for k, v in body.items() if k not in own}, body["benchmark"]
+
+
+def test_mla_paged_decode(one_chip, mosaic):
+    """The absorbed latent decode kernel at the published widths: 128 heads
+    against a 512-wide latent block and a rope block of one lane tile."""
+    from nxdi_tpu.ops.kernels import mla_decode
+
+    rows, table = 16, 32
+
+    def fn(q_lat, q_rot, k_pool, c_pool, bt, pos, layer):
+        return mla_decode.mla_paged_decode(
+            q_lat, q_rot, k_pool, c_pool, bt, pos, layer, block_size=BLOCK, scale=192 ** -0.5)
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    assert mla_decode.mla_paged_decode_supported((rows, 128, 512), (5, 64 * BLOCK, 1, 128),
+                                                 (5, 64 * BLOCK, 1, 512), BLOCK)
+    _compile(
+        fn, one_chip, ((rows, 128, 512), bf16), ((rows, 128, 64), bf16),
+        ((5, 64 * BLOCK, 1, 128), bf16), ((5, 64 * BLOCK, 1, 512), bf16),
+        ((rows, table), i32), ((rows,), i32), ((1,), i32),
+    )
+
+
+def test_latent_step_program_keeps_the_pool_in_place(topo, mosaic):
+    """The real token-generation program (128 rows) at the configuration's
+    widths: the latent pool aliased from the donated input to the output, no
+    pool-sized ``temp`` (the gathered-block XLA decode reads 2.6 GiB), no copy
+    or slice of either pool, the absorbed kernel in the program, and the held
+    experts on the default sparse dispatch with their layer-stacked weights
+    read in place: a scan that sliced a layer's experts out for the grouped
+    matmul materialised 0.5 GiB a matrix (``temp`` 506 MiB)."""
+    config, b = _pangu_share()
+    app = _paged_app(
+        config, topo.devices[:1],
+        batch_size=b["slots"], ctx_batch_size=1, tkg_batch_size=b["slots"], seq_len=b["seq_len"],
+        max_context_length=256, context_encoding_buckets=[256],
+        pa_block_size=BLOCK, pa_num_blocks=b["pa_num_blocks"],
+        attn_kernel_enabled=True, attn_block_tkg_kernel_enabled=True, **b.get("tpu_config", {}),
+    )
+    cache = app._cache_struct()
+    slots = b["pa_num_blocks"] * BLOCK
+    assert cache["k"].shape == (5, slots, 1, 128) and cache["v"].shape == (5, slots, 1, 512)
+    pool_bytes = 5 * slots * (128 + 512) * 2
+
+    (compiled,) = app.models["token_generation_model"].aot_compile(
+        app.build_params_struct(), cache).values()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < pool_bytes // 8, memory
+    assert memory.alias_size_in_bytes >= pool_bytes, memory
+    assert memory.argument_size_in_bytes < 11.5 * 2 ** 30, memory
+    text = compiled.as_text()
+    assert "mla_paged_decode" in text and "tpu_custom_call" in text
+    assert _pool_movers(text, cache["k"].shape) == [] and _pool_movers(text, cache["v"].shape) == []
+    (prog,) = app.models["token_generation_model"]._programs.values()
+    assert set(prog.attention_strategies) == {"tkg_mla_paged_kernel"}
+    assert app.tpu_config.moe_dispatch == "sparse" and "ragged-dot" in text
+    e, h, i = config["n_routed_experts"], config["hidden_size"], config["moe_intermediate_size"]
+    sliced = [ln.strip()[:160] for ln in text.splitlines()
+              if re.match(rf"\s*(?:ROOT )?%?[\w.\-]+ = bf16\[{e},({h},{i}|{i},{h})\]", ln)]
+    assert sliced == [], sliced  # no layer's experts as a buffer of their own
+
+
+def test_layer_scan_carries_the_latent_pool():
+    """CPU backend: in both programs of a paged ``pangu_ultra_moe`` app each
+    segment's layer scan (dense head, expert tail) has the rope pool and the
+    latent pool among its CARRY, never as xs or ys; token generation carries
+    the held-pair count beside them."""
+    toy = dict(
+        model_type="pangu_ultra_moe", hidden_size=64, intermediate_size=128, num_hidden_layers=3,
+        first_k_dense_replace=1, num_attention_heads=4, num_key_value_heads=4, vocab_size=256,
+        q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        moe_intermediate_size=32, n_routed_experts=4, n_routed_experts_total=16,
+        n_shared_experts=1, num_experts_per_tok=2, routed_scaling_factor=2.5, rms_norm_eps=1e-5,
+        rope_theta=10000.0, max_position_embeddings=128, tie_word_embeddings=False,
+    )
+    app = _paged_app(
+        toy, jax.devices()[:1],
+        batch_size=3, ctx_batch_size=1, tkg_batch_size=3, seq_len=64,
+        max_context_length=32, pa_block_size=8, pa_num_blocks=32,
+    )
+    cache = app._cache_struct()
+    k_pool, c_pool = cache["k"].shape, cache["v"].shape
+    assert k_pool == (3, 256, 1, 128) and c_pool == (3, 256, 1, 32)  # the rope key in a lane tile
+    params = app.build_params_struct()
+    for tag in ("context_encoding_model", "token_generation_model"):
+        wrapper = app.models[tag]
+        for key, prog in wrapper._programs.items():
+            with jax.set_mesh(app.mesh):
+                jaxpr = jax.make_jaxpr(prog._fn)(params, cache, wrapper._example_for_key(key))
+            layer_scans = [
+                e for e in _scans(jaxpr.jaxpr)
+                if any(getattr(v.aval, "shape", None) == c_pool for v in e.invars)
+            ]
+            assert len(layer_scans) == 2, (tag, key, len(layer_scans))  # one a segment
+            for scan in layer_scans:
+                n_consts, n_carry = scan.params["num_consts"], scan.params["num_carry"]
+                carry = [v.aval.shape for v in scan.invars[n_consts:n_consts + n_carry]]
+                assert carry.count(k_pool) == 1 and carry.count(c_pool) == 1, (tag, carry)
+                assert ((2,) in carry) == (tag == "token_generation_model"), (tag, carry)
+                for v in list(scan.invars[n_consts + n_carry:]) + list(scan.outvars[n_carry:]):
+                    shape = getattr(v.aval, "shape", ())
+                    assert shape[1:] not in (k_pool[1:], c_pool[1:]), (tag, key, shape)
