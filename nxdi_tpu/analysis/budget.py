@@ -143,8 +143,9 @@ def _moe_budget(
 
     **TPxEP meshes** (an explicit ``moe_ep_degree`` or a
     ``hybrid_sharding_config``) get EXACT derived counts instead of the old
-    generous flat budget: the sparse MoE path (ops/moe.py ``_sparse_moe``)
-    dispatches tokens by a LOCAL gather inside ``shard_map`` (every shard
+    generous flat budget: a sharded layer always takes the sorted form
+    (ops/moe.py ``expert_form``, ``_sparse_moe``), which dispatches tokens by
+    a LOCAL gather inside ``shard_map`` (every shard
     holds the replicated token stream) and combines with **one psum over
     the (ep[, epx], tp) world** per layer body — so the budget is one
     all-reduce per body (plus one for the always-on shared expert), and
@@ -154,7 +155,7 @@ def _moe_budget(
     compiled arch — a regime typo must blow past the budget, not raise it.
 
     Regimes WITHOUT declared degrees (full-world EP from the family
-    builder's ``ep_policy``, expert-internal TP, dense dispatch) keep the
+    builder's ``ep_policy``, expert-internal TP) keep the
     flat allowance: GSPMD owns their lowering and its collective pattern is
     not pinned by this repo's code.
     """
@@ -173,12 +174,11 @@ def _moe_budget(
         ep_degree = tc.moe_ep_degree
         regime = f"hybrid TPxEP (moe_ep_degree={ep_degree})"
 
-    sparse = getattr(tc, "moe_dispatch", "sparse") == "sparse"
-    if ep_degree is not None and sparse:
+    if ep_degree is not None:
         tp_inner = max(world // ep_degree, 1)
         n_ar = 1
         why = (
-            f"MoE {regime} x tp={tp_inner}: sparse dispatch is a local "
+            f"MoE {regime} x tp={tp_inner}: sorted dispatch is a local "
             "gather; combine is ONE psum over the (ep, tp) world"
         )
         if getattr(moe, "shared_expert_intermediate_size", None):

@@ -91,7 +91,6 @@ def setup_run_parser(parser: argparse.ArgumentParser) -> None:
                         "the whole model-parallel axis, so intermediate "
                         "degrees (e.g. tp=8 mlp-cp=2) have no mesh sub-axis "
                         "to land on and are rejected loudly")
-    p.add_argument("--moe-dispatch", default="sparse", choices=["sparse", "dense"])
     p.add_argument("--sequence-parallel-enabled", action="store_true")
     p.add_argument("--flash-decoding-enabled", action="store_true")
     p.add_argument("--vocab-parallel", type=int, choices=[0, 1], default=None,
@@ -329,7 +328,6 @@ def create_tpu_config(args):
         ),
         moe_tp_degree=args.moe_tp_degree,
         mlp_cp_degree=args.mlp_cp_degree,
-        moe_dispatch=args.moe_dispatch,
         sequence_parallel_enabled=args.sequence_parallel_enabled,
         flash_decoding_enabled=args.flash_decoding_enabled,
         logical_nc_config=args.logical_nc_config,

@@ -1017,14 +1017,6 @@ class TpuConfig:
         if isinstance(hsc, dict):
             hsc = HybridShardingConfig(**hsc)
         self.hybrid_sharding_config = hsc
-        # "sparse" = ragged_dot grouped matmul over routed tokens (default);
-        # "dense" = all experts compute all tokens (reference ExpertMLPs
-        # non-blockwise mode; kept as an A/B and debugging fallback)
-        self.moe_dispatch = kwargs.pop("moe_dispatch", "sparse")
-        if self.moe_dispatch not in ("sparse", "dense"):
-            raise ValueError(
-                f"moe_dispatch must be 'sparse' or 'dense', got {self.moe_dispatch!r}"
-            )
         self.world_size = kwargs.pop("world_size", None)
         if self.world_size is None:
             self.world_size = self.tp_degree * self.pp_degree

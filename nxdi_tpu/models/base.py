@@ -1510,28 +1510,30 @@ def _interleaved_window_scan(
     return hidden, new_cache
 
 
-def _extract_stacked_weights(arch: DecoderArch, seg):
+def _extract_stacked_weights(arch: DecoderArch, seg, rows: int):
     """Pull the layer-stacked MLP / fused-QKV weights out of a segment pytree
     when their Pallas kernels are enabled, so the scan does not slice them
     per layer (see run_decoder_layers). Returns (seg', mlp_stacked,
     qkv_stacked, moe_stacked) — stacked entries are None when the kernel is
     off or the segment has no such weights (e.g. a MoE segment).
 
-    ``moe_stacked``: a routed segment's plain expert weights under sparse
-    dispatch. The TPU's grouped matmul is such a kernel too (ops/moe.py
-    ``_grouped_matmul``): it takes the whole stack and the layer's index."""
+    ``moe_stacked``: a routed segment's plain expert weights where the expert
+    layer takes its sorted form at this program's ``rows`` (B x S;
+    ops/moe.py ``expert_form``). The TPU's grouped matmul is such a kernel
+    too (``_grouped_matmul``): it takes the whole stack and the layer's
+    index. The dense form's einsums read a layer's slice of the xs in place."""
     mlp_st = qkv_st = moe_st = None
     names = ("gate_proj", "up_proj", "down_proj")
     moe = seg.get("moe") if isinstance(seg, dict) else None
     if (
         arch.moe is not None
-        and arch.moe.dispatch == "sparse"
         and not arch.moe.per_phase_hybrid
         and isinstance(moe, dict)
         and isinstance(moe.get("experts"), dict)
         and all(  # plain weights: a quantized leaf is dequantized a layer at a time
             isinstance(moe["experts"].get(k), dict) and "w" in moe["experts"][k] for k in names
         )
+        and moe_ops.expert_form(arch.moe, rows) == "sorted"
     ):
         experts = {k: dict(moe["experts"][k]) for k in names}
         moe_st = tuple(experts[k].pop("w") for k in names)
@@ -1837,7 +1839,9 @@ def run_decoder_layers(
         # scanned xs (a pallas operand on a scan slice materializes a full
         # per-layer weight copy) — the kernels index the stacked arrays via
         # scalar-prefetched layer index instead
-        seg, mlp_st, qkv_st, moe_st = _extract_stacked_weights(arch, seg)
+        seg, mlp_st, qkv_st, moe_st = _extract_stacked_weights(
+            arch, seg, hidden.shape[0] * hidden.shape[1]
+        )
         n_seg = jax.tree_util.tree_leaves(seg)[0].shape[0]
 
         def body(carry, xs, mlp_st=mlp_st, qkv_st=qkv_st, moe_st=moe_st, seg_off=off,

@@ -62,7 +62,6 @@ class Qwen3NextArch:
     moe_intermediate_size: int = 0
     shared_expert_intermediate_size: int = 0
     norm_topk_prob: bool = True
-    moe_dispatch: str = "sparse"
     tie_word_embeddings: bool = False
     dtype: str = "float32"
 
@@ -166,7 +165,6 @@ def build_arch(config: InferenceConfig, **overrides) -> Qwen3NextArch:
         moe_intermediate_size=config.moe_intermediate_size,
         shared_expert_intermediate_size=config.shared_expert_intermediate_size,
         norm_topk_prob=bool(config.norm_topk_prob),
-        moe_dispatch=getattr(config.tpu_config, "moe_dispatch", "sparse"),
         tie_word_embeddings=getattr(config, "tie_word_embeddings", False),
         dtype=dtype_name(config.tpu_config.dtype),
     )
@@ -395,7 +393,6 @@ def _moe_arch(arch: Qwen3NextArch):
         intermediate_size=arch.moe_intermediate_size,
         hidden_act="silu",
         norm_topk_prob=arch.norm_topk_prob,
-        dispatch=arch.moe_dispatch,
         shared_expert_intermediate_size=arch.shared_expert_intermediate_size,
         shared_expert_gated=True,
     )

@@ -7,17 +7,22 @@ NKI blockwise-matmul kernels. TPU-native the same structure is:
   - **Router**: one replicated linear -> scoring (softmax / sigmoid /
     grouped-top-k for deepseek-V3) -> top-k -> (optional) renormalize, exactly
     HF's semantics so logits match the CPU golden.
-  - **Experts, sparse dispatch (default)**: tokens are sorted by their routed
-    expert and run through ``jax.lax.ragged_dot`` — XLA's grouped matmul, the
-    MXU-native equivalent of the reference's blockwise NKI expert kernels
-    (ExpertMLPsV2 block dispatch). FLOPs scale with ``T x top_k``, not with
-    ``T x num_experts``; at 128-expert/top-8 scale that is 16x fewer expert
-    FLOPs than dense dispatch. Static shapes throughout: the sort, the group
-    sizes, and the combine are all fixed-(T*K) arrays, so the path jits/scans
-    cleanly.
-  - **Experts, dense dispatch (fallback)**: every expert runs on every token
-    with a zero combine weight for unselected experts. No sort, no
-    gather/scatter; kept for A/B testing via ``moe_dispatch="dense"``.
+  - **Experts, two forms of one computation** (same operands, float32
+    accumulation and combine weights; :func:`expert_form` chooses between
+    them per compiled program, from static shapes, at trace time):
+      * **sorted**: the (row, expert) pairs are sorted by expert and run
+        through ``jax.lax.ragged_dot`` — XLA's grouped matmul, the MXU-native
+        equivalent of the reference's blockwise NKI expert kernels
+        (ExpertMLPsV2 block dispatch). FLOPs scale with ``T x top_k``, not
+        with ``T x num_experts``; at 128-expert/top-8 scale that is 16x fewer
+        expert FLOPs than the dense form. Static shapes throughout: the sort,
+        the group sizes, and the combine are all fixed-(T*K) arrays (every
+        sorted pair goes through the grouped matmul, routed here or not), so
+        the path jits/scans cleanly.
+      * **dense**: every held expert runs on every row with a zero combine
+        weight where the router did not pick it. No sort, no gather/scatter,
+        and the einsums stream the weights at the HBM rate; the form of a
+        chip's SHARE of few held experts once every one of them expects a row.
   - **Parallelism**: three regimes over the (ep, tp) mesh axes (parallel/mesh
     AXIS_MP = the full model-parallel world):
       * full-EP (``ep=True``, default when the world divides the expert
@@ -29,10 +34,10 @@ NKI blockwise-matmul kernels. TPU-native the same structure is:
         while each expert's intermediate shards over ``tp`` — the reference's
         moe_v2.py:135-161 TPxEP process-group factorization. Attention and
         dense layers keep sharding over the full world via AXIS_MP.
-    The sparse path runs under ``shard_map`` (GSPMD cannot partition a
-    ragged_dot over its group dim); each shard computes its local experts /
-    intermediate slice and one psum over (ep, tp) produces the combined
-    output — the reference's EP dispatch AR/RS collectives
+    A sharded layer is always sorted, under ``shard_map`` (GSPMD cannot
+    partition a ragged_dot over its group dim); each shard computes its local
+    experts / intermediate slice and one psum over (ep, tp) produces the
+    combined output — the reference's EP dispatch AR/RS collectives
     (attention_base.py:179 EPDispatchOption).
 """
 
@@ -46,6 +51,16 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from nxdi_tpu.parallel.mesh import AXIS_EP, AXIS_EPX, AXIS_MP, AXIS_TP
+
+
+#: the two forms of the local expert computation (expert_form)
+EXPERT_FORMS = ("dense", "sorted")
+
+# Expert-form trace: moe_block appends the form each traced expert layer took.
+# The choice is STATIC per program, so recording at trace time is exact;
+# model_wrapper snapshots it per (submodel, bucket) beside the attention
+# strategies (models/base.py _STRATEGY_TRACE) and counts it into the registry.
+_FORM_TRACE: list = []
 
 
 @dataclass(frozen=True)
@@ -72,8 +87,10 @@ class MoEArch:
     # mirroring the reference's preshard-hook duplication.
     per_phase_hybrid: bool = False
     phase: str = "prefill"
-    # "sparse" (ragged_dot grouped matmul) or "dense" (all experts, all tokens)
-    dispatch: str = "sparse"
+    # None: the layer chooses its form per program (expert_form). "sorted" /
+    # "dense" pin one — what a TEST sets to put the two forms side by side;
+    # no family builder and no option sets it
+    dispatch: Optional[str] = None
     # shared (always-on) experts, qwen2-moe/llama4 style
     shared_expert_intermediate_size: Optional[int] = None
     shared_expert_gated: bool = False  # sigmoid(gate(x)) scaling on shared out
@@ -114,6 +131,8 @@ class MoEArch:
     first_held: int = 0
 
     def __post_init__(self):
+        if self.dispatch not in (None,) + EXPERT_FORMS:
+            raise ValueError(f"dispatch pins one of {EXPERT_FORMS} or is None, got {self.dispatch!r}")
         if self.held_experts is None:
             return
         if not 0 < self.held_experts <= self.num_experts - self.first_held or self.first_held < 0:
@@ -140,8 +159,8 @@ def ep_policy(tp_degree: int, num_experts: int) -> bool:
 
 
 def moe_parallel_fields(tc, num_experts: int) -> Dict[str, Any]:
-    """MoEArch constructor kwargs for the parallel/dispatch knobs, derived from
-    the :class:`TpuConfig` — shared by every MoE family builder."""
+    """MoEArch constructor kwargs for the parallel knobs, derived from the
+    :class:`TpuConfig` — shared by every MoE family builder."""
     hsc = getattr(tc, "hybrid_sharding_config", None)
     if hsc is not None:
         if num_experts % hsc.moe_tkg_ep_degree:
@@ -149,12 +168,7 @@ def moe_parallel_fields(tc, num_experts: int) -> Dict[str, Any]:
                 f"moe_tkg_ep_degree ({hsc.moe_tkg_ep_degree}) must divide the "
                 f"expert count ({num_experts})"
             )
-        return {
-            "ep": False,
-            "hybrid_ep": True,
-            "per_phase_hybrid": True,
-            "dispatch": getattr(tc, "moe_dispatch", "sparse"),
-        }
+        return {"ep": False, "hybrid_ep": True, "per_phase_hybrid": True}
     hybrid = bool(getattr(tc, "moe_ep_degree", None) and tc.moe_ep_degree > 1)
     if hybrid and num_experts % tc.moe_ep_degree != 0:
         raise ValueError(
@@ -164,7 +178,6 @@ def moe_parallel_fields(tc, num_experts: int) -> Dict[str, Any]:
     return {
         "ep": (not hybrid) and ep_policy(tc.tp_degree, num_experts),
         "hybrid_ep": hybrid,
-        "dispatch": getattr(tc, "moe_dispatch", "sparse"),
     }
 
 
@@ -353,10 +366,9 @@ def route_topk(
     return top_vals, top_idx
 
 
-def route(router_logits: jax.Array, moe: MoEArch, p_router=None) -> jax.Array:
-    """Router logits (T, E) -> dense combine weights (T, E), zero for
-    unselected experts (used by the dense-dispatch path)."""
-    top_vals, top_idx = route_topk(router_logits, moe, p_router)
+def _dense_combine_weights(top_vals: jax.Array, top_idx: jax.Array, moe: MoEArch) -> jax.Array:
+    """Top-k (weights, ids) (T, K) -> combine weights (T, E), zero where the
+    router did not pick the expert (the dense form's)."""
     return jnp.sum(
         jax.nn.one_hot(top_idx, moe.num_experts, dtype=top_vals.dtype)
         * top_vals[..., None],
@@ -365,8 +377,49 @@ def route(router_logits: jax.Array, moe: MoEArch, p_router=None) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Expert compute — sparse (ragged_dot) and dense dispatch
+# Expert compute — the sorted (ragged_dot) and the dense form, and what chooses
 # ---------------------------------------------------------------------------
+
+
+def _no_exchange(moe: MoEArch, mesh) -> bool:
+    """The layer computes on this chip alone: no mesh in scope, or a share on
+    a one-chip model-parallel world (nothing to exchange)."""
+    return (
+        mesh is None or mesh.empty or not set(AXIS_MP).issubset(mesh.axis_names)
+        or (moe.held_experts is not None and all(mesh.shape[a] == 1 for a in AXIS_MP))
+    )
+
+
+def expert_form(moe: MoEArch, rows: int) -> str:
+    """The form (one of ``EXPERT_FORMS``) of the local expert computation in a
+    program that hands the layer ``rows`` rows (B x S: a model's
+    token-generation program and each of its prefill buckets choose apart),
+    under the mesh in scope.
+
+    Dense where the layer computes with no exchange and both hold:
+
+    (a) ``rows * top_k >= num_experts``: every held expert expects at least
+        one row, so both forms stream all the held weights and the dense one
+        reads nothing extra (one row over 256 experts stays sorted and reads
+        only the experts that were hit);
+    (b) ``experts_here <= 2 * top_k``: the dense form multiplies ``rows *
+        experts_here`` row-experts where the sorted one sends ``rows * top_k``
+        sorted pairs through the grouped matmul, routed here or not; at no
+        more than twice the rows, with no sort, gather or scatter and its
+        einsums at the HBM rate, it won weight-bound and compute-bound alike
+        (PERF.md section 6, PR 31).
+
+    Under an expert or intermediate mesh axis the sorted form inside
+    ``shard_map`` stays (its collectives are what analysis/budget.py budgets).
+    A whole published layer (8 experts top 2, 60 top 4, 128 top 8, 256 top 8)
+    fails (b) and stays sorted at every row count."""
+    if moe.dispatch is not None:
+        return moe.dispatch
+    if not _no_exchange(moe, jax.sharding.get_abstract_mesh()):
+        return "sorted"
+    if rows * moe.top_k >= moe.num_experts and moe.experts_here <= 2 * moe.top_k:
+        return "dense"
+    return "sorted"
 
 
 def _expert_act(moe: MoEArch, gate: jax.Array, up: jax.Array) -> jax.Array:
@@ -509,11 +562,7 @@ def _sparse_moe(
         out = jax.lax.psum(out, AXIS_MP)
         return out.reshape(B, S, H)
 
-    if (
-        mesh is None or mesh.empty or not set(AXIS_MP).issubset(mesh.axis_names)
-        # a share on a one-chip model-parallel world: nothing to exchange
-        or (moe.held_experts is not None and all(mesh.shape[a] == 1 for a in AXIS_MP))
-    ):
+    if _no_exchange(moe, mesh):
         return _sparse_expert_ffn(
             moe,
             experts,
@@ -563,12 +612,16 @@ def moe_block(
 
     ``held_tally``: a list that gains this layer's count (int32 scalar) of
     (row, expert) pairs routed to a held expert — the step program's counter
-    ``moe_held_pairs`` (models/base.py run_decoder_layers).
+    ``moe_held_pairs`` (models/base.py run_decoder_layers). Taken from the
+    router's top k ahead of the expert computation: the same pairs in either
+    form.
 
-    ``stacked_experts`` (sparse dispatch inside the layer scan): ``(gate, up,
+    ``stacked_experts`` (the sorted form inside the layer scan): ``(gate, up,
     down, layer)``, the segment's layer-stacked expert weights ``(L, E_here,
     ..)`` kept OUT of the scan's xs, and this layer's index among them; ``p``
-    then has no ``experts.*.w`` (``_grouped_matmul`` says why).
+    then has no ``experts.*.w`` (``_grouped_matmul`` says why). Under the
+    dense form the weights ride the xs: an einsum reads a layer's slice in
+    place.
     """
     from nxdi_tpu.ops.quantization import materialize_weight as mat_w
 
@@ -580,19 +633,22 @@ def moe_block(
         router_logits = xt.astype(jnp.float32) @ p["router"]["w"].astype(jnp.float32)
         if moe.router_bias:
             router_logits = router_logits + p["router"]["b"].astype(jnp.float32)
+        top_vals, top_idx = route_topk(router_logits, moe, p["router"])  # (T, K)
+        if held_tally is not None:
+            held_tally.append(
+                jnp.sum((top_idx >= lo) & (top_idx < lo + n_here), dtype=jnp.int32)
+            )
 
     # per-phase hybrid: decode programs read the EP-heavy duplicated copy
     p_experts = p["experts"]
     if moe.per_phase_hybrid and moe.phase == "decode" and "experts_tkg" in p:
         p_experts = p["experts_tkg"]
 
-    if moe.dispatch == "sparse":
-        with jax.named_scope("moe.route"):
-            top_vals, top_idx = route_topk(router_logits, moe, p["router"])
-            if held_tally is not None:
-                held_tally.append(
-                    jnp.sum((top_idx >= lo) & (top_idx < lo + n_here), dtype=jnp.int32)
-                )
+    # weights held out of the scan's xs are the sorted form's (the caller asked
+    # expert_form with this program's rows: models/base.py _extract_stacked_weights)
+    form = "sorted" if stacked_experts is not None else expert_form(moe, B * S)
+    _FORM_TRACE.append(form)
+    if form == "sorted":
         layer = None
         if stacked_experts is not None:
             *stacked, layer = stacked_experts
@@ -608,7 +664,7 @@ def moe_block(
         if moe.expert_bias:
             for k in experts:
                 experts[k]["b"] = p_experts[k]["b"]
-        with jax.named_scope("moe.experts"):
+        with jax.named_scope("moe.experts.sorted"):
             out = _sparse_moe(
                 moe,
                 experts,
@@ -619,34 +675,33 @@ def moe_block(
                 layer,
             ).reshape(B * S, H)
     else:
-        weights = route(router_logits, moe, p["router"]).astype(x.dtype)  # (T, E)
-        if held_tally is not None:
-            held_tally.append(jnp.sum(weights[:, lo: lo + n_here] != 0, dtype=jnp.int32))
-        if moe.held_experts is not None:
-            weights = weights[:, lo: lo + n_here]  # the held experts' columns
-        # dense dispatch: all experts on all tokens, combine contracted over E.
-        # mat_w dequantizes low-bit expert weights in the einsum's operand read.
-        gate = jnp.einsum("th,ehi->eti", xt, mat_w(p_experts["gate_proj"], x.dtype))
-        up = jnp.einsum("th,ehi->eti", xt, mat_w(p_experts["up_proj"], x.dtype))
-        if moe.llama4_router:
-            # llama4 scales the expert INPUT by the sigmoid score. gate/up are
-            # linear and bias-free on this path, so scaling their OUTPUTS before
-            # the activation is identical (act(s*g(x)) where s*g(x) = g(s*x)) —
-            # avoids materializing an (E, T, H) scaled-input tensor
-            se = jnp.swapaxes(weights, 0, 1)[:, :, None].astype(gate.dtype)  # (E, T, 1)
-            gate = gate * se
-            up = up * se
-        if moe.expert_bias:
-            gate = gate + p_experts["gate_proj"]["b"][:, None, :]
-            up = up + p_experts["up_proj"]["b"][:, None, :]
-        inner = _expert_act(moe, gate, up)  # (E, T, I)
-        expert_out = jnp.einsum("eti,eih->eth", inner, mat_w(p_experts["down_proj"], x.dtype))
-        if moe.expert_bias:
-            expert_out = expert_out + p_experts["down_proj"]["b"][:, None, :]
-        if moe.llama4_router:
-            out = jnp.sum(expert_out, axis=0)  # input already carries the score
-        else:
-            out = jnp.einsum("te,eth->th", weights, expert_out)  # psum over E under EP
+        with jax.named_scope("moe.experts.dense"):
+            weights = _dense_combine_weights(top_vals, top_idx, moe).astype(x.dtype)  # (T, E)
+            if moe.held_experts is not None:
+                weights = weights[:, lo: lo + n_here]  # the held experts' columns
+            # all held experts on all rows, combine contracted over E. mat_w
+            # dequantizes low-bit expert weights in the einsum's operand read.
+            gate = jnp.einsum("th,ehi->eti", xt, mat_w(p_experts["gate_proj"], x.dtype))
+            up = jnp.einsum("th,ehi->eti", xt, mat_w(p_experts["up_proj"], x.dtype))
+            if moe.llama4_router:
+                # llama4 scales the expert INPUT by the sigmoid score. gate/up are
+                # linear and bias-free on this path, so scaling their OUTPUTS before
+                # the activation is identical (act(s*g(x)) where s*g(x) = g(s*x)) —
+                # avoids materializing an (E, T, H) scaled-input tensor
+                se = jnp.swapaxes(weights, 0, 1)[:, :, None].astype(gate.dtype)  # (E, T, 1)
+                gate = gate * se
+                up = up * se
+            if moe.expert_bias:
+                gate = gate + p_experts["gate_proj"]["b"][:, None, :]
+                up = up + p_experts["up_proj"]["b"][:, None, :]
+            inner = _expert_act(moe, gate, up)  # (E, T, I)
+            expert_out = jnp.einsum("eti,eih->eth", inner, mat_w(p_experts["down_proj"], x.dtype))
+            if moe.expert_bias:
+                expert_out = expert_out + p_experts["down_proj"]["b"][:, None, :]
+            if moe.llama4_router:
+                out = jnp.sum(expert_out, axis=0)  # input already carries the score
+            else:
+                out = jnp.einsum("te,eth->th", weights, expert_out)  # psum over E under EP
 
     if moe.shared_expert_intermediate_size:
         from nxdi_tpu.models.base import ACT_FNS

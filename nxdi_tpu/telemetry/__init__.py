@@ -51,6 +51,7 @@ Metric catalog (labels in parens):
 ``nxdi_serve_slots_busy``             gauge
 ``nxdi_serve_preemptions_total``      counter
 ``nxdi_program_lowerings_total``      counter    (phase: warmup|serving)
+``nxdi_moe_expert_form_programs_total`` counter  (submodel, form: dense|sorted)
 ``nxdi_program_mfu_pct``              gauge      (submodel, bucket, steps)
 ``nxdi_program_hbm_bw_pct``           gauge      (submodel, bucket, steps)
 ``nxdi_roofline_gap_ratio``           gauge      (submodel, bucket, steps)
@@ -470,6 +471,12 @@ class Telemetry:
             "program lowerings by phase (serving = post-seal retrace!)",
             ("phase",),
         )
+        self.expert_form_programs = r.counter(
+            "nxdi_moe_expert_form_programs_total",
+            "lowered programs with an expert layer, by the form it chose from "
+            "its shapes (ops/moe.py expert_form)",
+            ("submodel", "form"),
+        )
         # roofline gauges, set by the cost-observatory attachment
         # (analysis/costs.attach_cost_gauges) from measured-mean / CostSheet
         self.program_mfu_pct = r.gauge(
@@ -661,6 +668,9 @@ class Telemetry:
 
     def record_lowering(self, label: str, post_seal: bool) -> None:
         self.lowerings_total.inc(phase="serving" if post_seal else "warmup")
+
+    def record_expert_form(self, submodel: str, form: str) -> None:
+        self.expert_form_programs.inc(submodel=submodel, form=form)
 
     def attach_flight(self, recorder) -> None:
         """Adopt an engine's :class:`~nxdi_tpu.telemetry.flight.FlightRecorder`:

@@ -5,6 +5,7 @@ the paged latent pool in the absorbed form, logits against the plain
 reference's full forward pass (``benchmark/references/mla_moe_decoder.py``:
 float32, non-absorbed, nothing of the program's)."""
 
+import dataclasses
 import importlib.util
 import os
 
@@ -43,7 +44,23 @@ def _reference():
     return module
 
 
-def _app(seed=0, **tpu):
+class _PinnedForm:
+    """The family, with the expert layer's form pinned on every arch it builds
+    (``MoEArch.dispatch``: what a test sets to put the two forms side by side;
+    None leaves the layer its own choice)."""
+
+    def __init__(self, form):
+        self.form = form
+
+    def __getattr__(self, name):
+        return getattr(family, name)
+
+    def build_arch(self, config, **overrides):
+        arch = family.build_arch(config, **overrides)
+        return dataclasses.replace(arch, moe=dataclasses.replace(arch.moe, dispatch=self.form))
+
+
+def _app(seed=0, expert_form=None, **tpu):
     kwargs = dict(
         tp_degree=1, dtype="float32", seq_len=64, max_context_length=32, batch_size=2,
         ctx_batch_size=1, tkg_batch_size=2, is_block_kv_layout=True, pa_block_size=BLOCK,
@@ -66,7 +83,7 @@ def _app(seed=0, **tpu):
             ]
             return jax.tree_util.tree_unflatten(treedef, leaves)
 
-    app = App("<seeded>", config, model_family=family)
+    app = App("<seeded>", config, model_family=_PinnedForm(expert_form))
     app.load()
     return app
 
@@ -76,11 +93,13 @@ P1 = [7, 13, 21, 4, 33]
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
-@pytest.mark.parametrize("dispatch", ["sparse", "dense"])
+@pytest.mark.parametrize("dispatch", ["sorted", "dense", None], ids=str)
 def test_prefill_then_paged_absorbed_decode_matches_the_reference(dispatch, kernel):
     """Two rows of unequal length in scrambled blocks: every prefill and every
-    decode step's logits against the reference over the whole sequence."""
-    app = _app(moe_dispatch=dispatch, attn_block_tkg_kernel_enabled=kernel)
+    decode step's logits against the reference over the whole sequence, with
+    each form of the expert layer pinned and with the layer's own choice (the
+    32-row prefill of 4 held experts dense, the 2-row decode sorted)."""
+    app = _app(expert_form=dispatch, attn_block_tkg_kernel_enabled=kernel)
     ref = _reference()
     mgr = BlockSpaceManager(24, BLOCK)
     mgr.ensure_capacity(99, 3 * BLOCK)  # burn blocks: tables are not contiguous
@@ -118,6 +137,13 @@ def test_prefill_then_paged_absorbed_decode_matches_the_reference(dispatch, kern
     want = "tkg_mla_paged_kernel" if kernel else "tkg_mla_paged_xla"
     tkg = app.models["token_generation_model"]
     assert all(want in p.attention_strategies for p in tkg._programs.values())
+    forms = {tag: {f for p in m._programs.values() for f in p.expert_forms}
+             for tag, m in app.models.items()}
+    assert forms == {"context_encoding_model": {dispatch or "dense"},
+                     "token_generation_model": {dispatch or "sorted"}}
+    counted = app.telemetry.expert_form_programs
+    assert {tuple(counted.labels_of(key).values()) for key in counted.series()} == {
+        (tag, f) for tag, fs in forms.items() for f in fs}
 
 
 def test_the_held_pair_count_is_the_reference_routers():

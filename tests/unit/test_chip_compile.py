@@ -428,18 +428,23 @@ def test_mla_paged_decode(one_chip, mosaic):
     )
 
 
-def test_latent_step_program_keeps_the_pool_in_place(topo, mosaic):
-    """The real token-generation program (128 rows) at the configuration's
-    widths: the latent pool aliased from the donated input to the output, no
-    pool-sized ``temp`` (the gathered-block XLA decode reads 2.6 GiB), no copy
-    or slice of either pool, the absorbed kernel in the program, and the held
-    experts on the default sparse dispatch with their layer-stacked weights
-    read in place: a scan that sliced a layer's experts out for the grouped
-    matmul materialised 0.5 GiB a matrix (``temp`` 506 MiB)."""
+@pytest.mark.parametrize("rows, form", [(None, "dense"), (1, "sorted")], ids=["the-cells-128-rows", "one-row"])
+def test_latent_step_program_keeps_the_pool_in_place(topo, mosaic, rows, form):
+    """The real token-generation program (the cell's 128 rows) at the
+    configuration's widths: the latent pool aliased from the donated input to
+    the output, no pool-sized ``temp`` (the gathered-block XLA decode reads
+    2.6 GiB), no copy or slice of either pool, the absorbed kernel in the
+    program, and the 16 held experts in the form the layer chose from its
+    shapes: DENSE (128 rows x top 8 >= 256 and 16 <= 2 x 8), einsums that read
+    a layer's slice of the scan's xs in place, no grouped matmul. The same
+    share at ONE row stays sorted, with the layer-stacked weights handed to
+    the grouped matmul whole: a scan that sliced a layer's experts out for it
+    materialised 0.5 GiB a matrix (``temp`` 506 MiB)."""
     config, b = _pangu_share()
+    rows = rows or b["slots"]
     app = _paged_app(
         config, topo.devices[:1],
-        batch_size=b["slots"], ctx_batch_size=1, tkg_batch_size=b["slots"], seq_len=b["seq_len"],
+        batch_size=rows, ctx_batch_size=1, tkg_batch_size=rows, seq_len=b["seq_len"],
         max_context_length=256, context_encoding_buckets=[256],
         pa_block_size=BLOCK, pa_num_blocks=b["pa_num_blocks"],
         attn_kernel_enabled=True, attn_block_tkg_kernel_enabled=True, **b.get("tpu_config", {}),
@@ -457,14 +462,26 @@ def test_latent_step_program_keeps_the_pool_in_place(topo, mosaic):
     assert memory.argument_size_in_bytes < 11.5 * 2 ** 30, memory
     text = compiled.as_text()
     assert "mla_paged_decode" in text and "tpu_custom_call" in text
-    assert _pool_movers(text, cache["k"].shape) == [] and _pool_movers(text, cache["v"].shape) == []
+    movers = _pool_movers(text, cache["k"].shape) + _pool_movers(text, cache["v"].shape)
+    if rows == 1:  # ONE row's write is a dynamic-update-slice of the carried pool, in place
+        movers = [m for m in movers if not m.startswith("dynamic-update-slice ")]
+    assert movers == []
     (prog,) = app.models["token_generation_model"]._programs.values()
     assert set(prog.attention_strategies) == {"tkg_mla_paged_kernel"}
-    assert app.tpu_config.moe_dispatch == "sparse" and "ragged-dot" in text
+    assert app.models["token_generation_model"].arch.moe.dispatch is None, "nothing pinned"
+    assert prog.expert_forms == (form,)
+    assert f"moe.experts.{form}" in text and ("ragged-dot" in text) == (form == "sorted")
     e, h, i = config["n_routed_experts"], config["hidden_size"], config["moe_intermediate_size"]
-    sliced = [ln.strip()[:160] for ln in text.splitlines()
-              if re.match(rf"\s*(?:ROOT )?%?[\w.\-]+ = bf16\[{e},({h},{i}|{i},{h})\]", ln)]
-    assert sliced == [], sliced  # no layer's experts as a buffer of their own
+    # no layer's experts as a buffer of their own. Inside a fused computation
+    # an instruction is a value, not a buffer: the dense form's einsum has its
+    # layer's slice of the (4, 16, ..) stack there, read in place as it computes
+    sliced, fused = [], False
+    for ln in text.splitlines():
+        if ln.endswith("{") and not ln.startswith(" "):
+            fused = ln.startswith("%fused_computation")
+        elif not fused and re.match(rf"\s*(?:ROOT )?%?[\w.\-]+ = bf16\[{e},({h},{i}|{i},{h})\]", ln):
+            sliced.append(ln.strip()[:160])
+    assert sliced == [], sliced
 
 
 def test_layer_scan_carries_the_latent_pool():

@@ -54,7 +54,11 @@ def _uncut_reference(p, x):
     return routed.reshape(x.shape), swiglu(xt, p["shared_expert"]).reshape(x.shape)
 
 
-@pytest.mark.parametrize("dispatch", ["sparse", "dense"])
+#: each form pinned, and the layer's own choice (None)
+FORMS = ["sorted", "dense", None]
+
+
+@pytest.mark.parametrize("dispatch", FORMS, ids=str)
 @pytest.mark.parametrize("held", [4, 1], ids=["4-shares-of-4", "16-shares-of-1"])
 def test_the_shares_add_up_to_the_uncut_layer(held, dispatch):
     p = _params(5)
@@ -73,7 +77,7 @@ def test_the_shares_add_up_to_the_uncut_layer(held, dispatch):
     np.testing.assert_allclose(np.asarray(total + shared), np.asarray(whole), rtol=2e-5, atol=2e-6)
 
 
-@pytest.mark.parametrize("dispatch", ["sparse", "dense"])
+@pytest.mark.parametrize("dispatch", FORMS, ids=str)
 def test_holding_every_expert_is_bit_identical_to_the_whole_layer(dispatch):
     p = _params(7)
     x = jnp.asarray(np.random.default_rng(8).standard_normal((3, 5, H)), jnp.bfloat16)
@@ -106,8 +110,29 @@ def test_a_share_that_cannot_be_is_refused(fields):
         dataclasses.replace(WHOLE, **fields)
 
 
+@pytest.mark.parametrize("rows", [1, 7, 24])
+def test_the_held_pair_tally_is_the_same_integer_in_either_form(rows):
+    """``moe_held_pairs`` counts the router's top k once, ahead of the expert
+    computation: a seeded batch reads the same integer under both pinned forms
+    and under the layer's own choice, in float32 and after a cast to bf16."""
+    share = dataclasses.replace(WHOLE, held_experts=4, first_held=8)
+    p = _share(_params(11), 8, 4)
+    x = jnp.asarray(np.random.default_rng(12).standard_normal((1, rows, H)), jnp.float32)
+    counts = set()
+    for dtype in (jnp.float32, jnp.bfloat16):
+        # the router reads float32 either way: same logits, same top k
+        pd = {**jax.tree_util.tree_map(lambda w: w.astype(dtype), p), "router": p["router"]}
+        for form in FORMS:
+            tally = []
+            moe_block(None, dataclasses.replace(share, dispatch=form), pd, x, held_tally=tally)
+            counts.add(int(tally[0]))
+    scores = jax.nn.sigmoid(x.reshape(-1, H) @ p["router"]["w"])
+    chosen = np.asarray(jax.lax.top_k(scores, K)[1])
+    assert counts == {int(((chosen >= 8) & (chosen < 12)).sum())}
+
+
 # -- the layer-stacked expert weights, whole, with the layer's index (what the
-# layer scan hands sparse dispatch: models/base.py _extract_stacked_weights)
+# layer scan hands the sorted form: models/base.py _extract_stacked_weights)
 L = 3
 
 
@@ -121,7 +146,7 @@ def _stacked(seed):
 @pytest.mark.parametrize("layer", range(L))
 def test_the_stacked_weights_and_a_layer_index_give_the_layers_own_result(layer, held, lo):
     layers, stack = _stacked(20)
-    moe = dataclasses.replace(WHOLE, held_experts=held, first_held=lo)
+    moe = dataclasses.replace(WHOLE, held_experts=held, first_held=lo, dispatch="sorted")
     if held is not None:
         stack = tuple(w[:, lo: lo + held] for w in stack)
     x = jnp.asarray(np.random.default_rng(9).standard_normal((2, 6, H)), jnp.float32)
@@ -142,7 +167,7 @@ def test_the_stacked_weights_and_a_layer_index_give_the_layers_own_result(layer,
     assert int(pairs) == int(tally[0])
 
 
-def test_the_layer_scan_keeps_plain_expert_weights_of_sparse_dispatch_out_of_its_xs():
+def test_the_layer_scan_keeps_plain_expert_weights_of_the_sorted_form_out_of_its_xs():
     from types import SimpleNamespace
 
     from nxdi_tpu.models.base import _extract_stacked_weights
@@ -150,19 +175,26 @@ def test_the_layer_scan_keeps_plain_expert_weights_of_sparse_dispatch_out_of_its
     layers, stack = _stacked(30)
     seg = {"moe": jax.tree_util.tree_map(lambda *w: jnp.stack(w), *layers), "attn": {}}
     arch = SimpleNamespace(moe=WHOLE, mlp_kernel_enabled=False, qkv_kernel_enabled=False)
-    rest, mlp_st, qkv_st, moe_st = _extract_stacked_weights(arch, seg)
+    rest, mlp_st, qkv_st, moe_st = _extract_stacked_weights(arch, seg, 12)
     assert mlp_st is None and qkv_st is None
     for got, want in zip(moe_st, stack):
         assert got.shape == want.shape == (L, E) + want.shape[2:]
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert all(rest["moe"]["experts"][k] == {} for k in ("gate_proj", "up_proj", "down_proj"))
     assert set(rest["moe"]) == set(seg["moe"]) and "w" in seg["moe"]["experts"]["gate_proj"]
-    # dense dispatch reads a layer's slice in place (an einsum operand): left in the xs
+    # the dense form reads a layer's slice in place (an einsum operand): left in the
+    # xs, be it pinned or what a share of 4 (<= 2 x top 2) chooses once 8 rows x 2 >= 16
     dense = SimpleNamespace(moe=dataclasses.replace(WHOLE, dispatch="dense"),
                             mlp_kernel_enabled=False, qkv_kernel_enabled=False)
-    assert _extract_stacked_weights(dense, seg)[3] is None
+    assert _extract_stacked_weights(dense, seg, 12)[3] is None
+    share = SimpleNamespace(moe=dataclasses.replace(WHOLE, held_experts=4, first_held=8),
+                            mlp_kernel_enabled=False, qkv_kernel_enabled=False)
+    held = {**seg, "moe": {**seg["moe"], "experts": jax.tree_util.tree_map(
+        lambda w: w[:, 8:12], seg["moe"]["experts"])}}
+    assert _extract_stacked_weights(share, held, 8)[3] is None
+    assert _extract_stacked_weights(share, held, 7)[3][0].shape[:2] == (L, 4)
     # a quantized leaf is dequantized a layer at a time: left in the xs
     quant = {**seg, "moe": {**seg["moe"], "experts": {
         k: {"qw": v["w"].astype(jnp.int8), "scale": v["w"][..., :1, :]}
         for k, v in seg["moe"]["experts"].items()}}}
-    assert _extract_stacked_weights(arch, quant)[3] is None
+    assert _extract_stacked_weights(arch, quant, 12)[3] is None

@@ -1,6 +1,6 @@
-"""Sparse (ragged_dot) MoE dispatch — equivalence with dense dispatch, FLOP
-scaling in top_k (not num_experts), routing variants, and the hybrid TPxEP
-sharding plan.
+"""The expert layer's two forms — sorted (ragged_dot) against dense, and what
+chooses between them (``expert_form``) — FLOP scaling in top_k (not
+num_experts), routing variants, and the hybrid TPxEP sharding plan.
 
 Reference behaviors being matched: blockwise expert dispatch in
 modules/moe_v2.py:23-132 (ExpertMLPsV2), TPxEP process groups (:135-161), and
@@ -17,6 +17,7 @@ import pytest
 
 from nxdi_tpu.ops.moe import (
     MoEArch,
+    expert_form,
     expert_parallel_specs,
     moe_block,
     moe_parallel_fields,
@@ -67,13 +68,95 @@ BASE = dict(num_experts=8, top_k=2, intermediate_size=32)
 def test_sparse_matches_dense(variant):
     rng = np.random.default_rng(0)
     H = 16
-    sparse = MoEArch(**BASE, dispatch="sparse", **variant)
+    sparse = MoEArch(**BASE, dispatch="sorted", **variant)
     dense = MoEArch(**BASE, dispatch="dense", **variant)
     p = _params(rng, sparse, H)
     x = jnp.asarray(rng.standard_normal((2, 5, H)), jnp.float32)
     out_s = moe_block(None, sparse, p, x)
     out_d = moe_block(None, dense, p, x)
     np.testing.assert_allclose(np.asarray(out_s), np.asarray(out_d), atol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "held, rows, chosen",
+    [(None, 10, "sorted"), (4, 10, "dense"), (4, 1, "sorted")],
+    ids=["whole-layer", "share-of-4", "share-at-one-row"],
+)
+def test_the_layers_own_choice_equals_both_pinned_forms(held, rows, chosen):
+    """``dispatch=None``: the layer chooses from its shapes, records what it
+    chose, and gives what either pinned form gives."""
+    from nxdi_tpu.ops import moe as moe_ops
+
+    rng = np.random.default_rng(4)
+    H = 16
+    own = MoEArch(**BASE, held_experts=held, first_held=0 if held is None else 2)
+    p = _params(rng, own, H)
+    if held is not None:
+        p["experts"] = jax.tree_util.tree_map(lambda w: w[2: 2 + held], p["experts"])
+    x = jnp.asarray(rng.standard_normal((1, rows, H)), jnp.float32)
+    assert expert_form(own, rows) == chosen
+    moe_ops._FORM_TRACE.clear()
+    out = moe_block(None, own, p, x)
+    assert moe_ops._FORM_TRACE == [chosen]
+    for form in moe_ops.EXPERT_FORMS:
+        pinned = moe_block(None, dataclasses.replace(own, dispatch=form), p, x)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(pinned), atol=1e-5)
+
+
+# (rows, held experts or None = the whole layer, top k, router width E)
+_CELL = [(rows, 16, 8, 256) for rows in (128, 256, 512, 1024)]
+_WHOLE = [(rows, None, k, e) for e, k in ((8, 2), (60, 4), (128, 8), (256, 8))
+          for rows in (1, 16, 128, 1024, 8192)]
+
+
+@pytest.mark.parametrize(
+    "rows, held, top_k, experts, want",
+    [(*c, "dense") for c in _CELL]
+    + [(1, 16, 8, 256, "sorted"), (16, 16, 8, 256, "sorted")]
+    + [(*c, "sorted") for c in _WHOLE]
+    + [(128, 17, 8, 256, "sorted"), (32, 16, 8, 256, "dense"), (31, 16, 8, 256, "sorted")],
+    ids=lambda v: str(v),
+)
+def test_expert_form_table(rows, held, top_k, experts, want):
+    """The rule in rows, held experts, top k and router width alone: the routed
+    cell's four programs (a share of 16 of 256, top 8) are dense; the same
+    share at 1 and 16 rows, and a whole published layer at every row count,
+    are sorted."""
+    moe = MoEArch(num_experts=experts, top_k=top_k, intermediate_size=8, held_experts=held)
+    assert expert_form(moe, rows) == want
+    for form in ("dense", "sorted"):  # a pin is a pin
+        assert expert_form(dataclasses.replace(moe, dispatch=form), rows) == form
+
+
+@pytest.mark.parametrize("fields", [dict(ep=True), dict(hybrid_ep=True), dict()],
+                         ids=["expert-axis", "hybrid-axes", "intermediate-axis"])
+def test_a_layer_under_a_mesh_with_exchange_is_sorted(fields):
+    """Under an expert or intermediate mesh axis the sorted form inside
+    ``shard_map`` stays, whatever the shapes; a share on a one-chip
+    model-parallel world has nothing to exchange and chooses by the rule."""
+    from nxdi_tpu.parallel.mesh import build_mesh
+
+    few = MoEArch(num_experts=8, top_k=4, intermediate_size=8, **fields)  # 8 <= 2 x 4
+    assert expert_form(few, 128) == "dense"  # no mesh in scope
+    mesh = build_mesh(tp_degree=8, ep_degree=2) if fields.get("hybrid_ep") else build_mesh(tp_degree=8)
+    with jax.set_mesh(mesh):
+        assert expert_form(few, 128) == "sorted"
+    share = MoEArch(num_experts=256, top_k=8, intermediate_size=8, held_experts=16)
+    with jax.set_mesh(build_mesh(tp_degree=1)):
+        assert expert_form(share, 128) == "dense"
+        assert expert_form(share, 1) == "sorted"
+
+
+def test_a_pin_is_one_of_the_two_forms():
+    with pytest.raises(ValueError, match="dispatch"):
+        MoEArch(**BASE, dispatch="sparse")
+
+
+def test_moe_dispatch_is_no_option_of_the_tpu_config():
+    from nxdi_tpu.config import TpuConfig
+
+    with pytest.raises(ValueError, match="Unknown TpuConfig arguments.*moe_dispatch"):
+        TpuConfig(tp_degree=1, moe_dispatch="dense")
 
 
 def _expert_matmul_flops(moe: MoEArch, H=32, T=8):
@@ -121,8 +204,9 @@ def _expert_matmul_flops(moe: MoEArch, H=32, T=8):
 def test_sparse_flops_scale_with_topk_not_experts():
     """Decode-shaped MoE: dense dispatch pays E/top_k x the expert FLOPs; the
     sparse path's grouped-matmul work is fixed at T*top_k rows as E grows."""
-    small = dataclasses.replace(MoEArch(**BASE), num_experts=8)
-    big = dataclasses.replace(MoEArch(**BASE), num_experts=64)
+    # about the sorted form: pinned (8 experts at top 4 would choose dense)
+    small = dataclasses.replace(MoEArch(**BASE, dispatch="sorted"), num_experts=8)
+    big = dataclasses.replace(MoEArch(**BASE, dispatch="sorted"), num_experts=64)
     f_small, r_small = _expert_matmul_flops(small)
     f_big, r_big = _expert_matmul_flops(big)
     assert r_small == 3 and r_big == 3  # gate/up/down all grouped
@@ -175,10 +259,9 @@ def test_hybrid_tpxep_specs():
     class TC:
         tp_degree = 8
         moe_ep_degree = 2
-        moe_dispatch = "sparse"
 
     fields = moe_parallel_fields(TC, 8)
-    assert fields == {"ep": False, "hybrid_ep": True, "dispatch": "sparse"}
+    assert fields == {"ep": False, "hybrid_ep": True}
     moe = MoEArch(**BASE, **fields)
     specs = expert_parallel_specs(moe)
     from jax.sharding import PartitionSpec as P
@@ -189,7 +272,6 @@ def test_hybrid_tpxep_specs():
     class TC2:
         tp_degree = 8
         moe_ep_degree = None
-        moe_dispatch = "sparse"
 
     moe2 = MoEArch(**BASE, **moe_parallel_fields(TC2, 8))
     assert moe2.ep and not moe2.hybrid_ep
@@ -212,7 +294,6 @@ def test_per_phase_hybrid_specs_and_duplication():
     class TC:
         tp_degree = 8
         moe_ep_degree = None
-        moe_dispatch = "sparse"
         hybrid_sharding_config = HybridShardingConfig(
             moe_cte_ep_degree=2, moe_tkg_ep_degree=8
         )
@@ -249,7 +330,6 @@ def test_per_phase_hybrid_block_matches_both_phases():
     class TC:
         tp_degree = 8
         moe_ep_degree = None
-        moe_dispatch = "sparse"
         hybrid_sharding_config = HybridShardingConfig(
             moe_cte_ep_degree=2, moe_tkg_ep_degree=8
         )
