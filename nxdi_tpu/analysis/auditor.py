@@ -170,7 +170,7 @@ def audit_wrapper(
     program-independent passes once (audit_application threads a single
     dict through every wrapper).
     """
-    from nxdi_tpu.models import base as base_mod
+    from nxdi_tpu.ops import attention_select
 
     if shared is None:
         shared = {}
@@ -213,10 +213,10 @@ def audit_wrapper(
         try:
             example = wrapper._example_for_key(key)
             with jax.set_mesh(wrapper._mesh):
-                base_mod._STRATEGY_TRACE.clear()
+                attention_select._STRATEGY_TRACE.clear()
                 traced = prog.jitted.trace(ps, cs, example)
                 lowered = traced.lower()
-                strategies = tuple(base_mod._STRATEGY_TRACE) or tuple(
+                strategies = tuple(attention_select._STRATEGY_TRACE) or tuple(
                     prog.attention_strategies
                 )
                 if reuse_compiled and prog._compiled is not None:

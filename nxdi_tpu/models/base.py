@@ -41,6 +41,7 @@ from nxdi_tpu.kvcache.kv_cache import (
     KVCacheSpec,
 )
 from nxdi_tpu.ops import attention as attn_ops
+from nxdi_tpu.ops import attention_select as attn_select
 from nxdi_tpu.ops import kernels as attn_kernels
 from nxdi_tpu.ops import moe as moe_ops
 from nxdi_tpu.ops import quantization as quant_ops
@@ -80,18 +81,6 @@ def xielu(x: jax.Array, alpha_p: jax.Array, alpha_n: jax.Array) -> jax.Array:
     pos = alpha_p * xf * xf + beta * xf
     neg = (jnp.expm1(jnp.minimum(xf, eps)) - xf) * alpha_n + beta * xf
     return jnp.where(xf > 0, pos, neg).astype(x.dtype)
-
-# Attention-strategy trace: attention_block appends the strategy each traced
-# attention body actually chose (kernel vs XLA fallback). Strategy decisions
-# are STATIC (flags, shapes, mesh layout), so recording at trace time is
-# exact — the analog of the reference's FlashAttentionStrategy logging
-# (attention_base.py:165,1330); model_wrapper snapshots this per
-# (submodel, bucket) so silent kernel fallbacks are visible and assertable.
-_STRATEGY_TRACE: list = []
-
-
-def _record_strategy(name: str) -> None:
-    _STRATEGY_TRACE.append(name)
 
 
 @dataclass(frozen=True)
@@ -498,10 +487,10 @@ def attention_block(
                         "qkv_kernel_enabled: fused projection shape is not "
                         "kernel-eligible; disable the flag"
                     )
-                _record_strategy("qkv_fused_kernel")
+                attn_select._record_strategy("qkv_fused_kernel")
             else:
                 qkv = _linear(hidden, pq, aq, ac, adapter_ids)
-                _record_strategy("qkv_fused_matmul")
+                attn_select._record_strategy("qkv_fused_matmul")
             # undo the per-rank interleave on the LOGICAL view: rank blocks are
             # head blocks in order, so regrouping by rank reassembles q/k/v
             tp = arch.fused_qkv_tp
@@ -598,14 +587,23 @@ def attention_block(
         # starting at 0, so the contiguous layout may take its slice-write
         # fast path instead of a B*S-row scatter (kv_cache.py update)
         ci["prefill_from_zero"] = True
-    if spec_window is not None and attend_to_cache:
-        # fused-speculation draft window (one commit per WINDOW): write the
-        # fresh row into scratch column `slot`, then attend [old cache with
-        # every window position masked] + [scratch] — rows written by earlier
-        # draft steps are visible at their true positions, unwritten columns
-        # sit at future positions the causal mask hides. Numerically this
-        # attends exactly the same (position, value) set as the per-step
-        # commit path; only the two-part summation split differs.
+    # which attention computes this call: ops/attention_select.py. It raises
+    # where no strategy computes a term the call needs (a bidirectional image
+    # span under prefix-cached prefill, a window under mixed dispatch)
+    site = attn_select.site_of(
+        arch, layout, policy, ci, q.shape, k.shape, k_cache_l, cache_spec.compute_dtype,
+        attend_to_cache=attend_to_cache, deferred=defer_write and attend_to_cache,
+        layer_flags=(window_enabled is not None, use_rope is not None),
+        stacked=tkg_stacked is not None and stacked_layer_idx is not None,
+        spec_window=spec_window is not None,
+    )
+    name = attn_select.select(site)
+
+    # -- the write
+    if site.phase == "spec_window":
+        # rows written by earlier draft steps are visible at their true
+        # positions, unwritten columns sit at future positions the causal mask
+        # hides: the same (position, value) set as the per-step commit path
         k_sp, v_sp, win_pos, slot = spec_window
         with jax.named_scope("kv.write"):
             k_sp = jax.lax.dynamic_update_slice(
@@ -614,390 +612,181 @@ def attention_block(
             v_sp = jax.lax.dynamic_update_slice(
                 v_sp, v.astype(v_sp.dtype), (0, 0, slot, 0)
             )
-        with jax.named_scope("attn.core"):
-            kk, vv, kv_pos = layout.read(k_cache_l, v_cache_l, ci, cache_spec)
-            kk = constrain(kk, policy.cache_kv)
-            vv = constrain(vv, policy.cache_kv)
-            kv_pos = jnp.where(kv_pos >= win_pos[:, :1], jnp.int32(2 ** 30), kv_pos)
-            _record_strategy("tkg_spec_window_xla")
-            ctx = attn_ops.attention_two_part(
-                q, kk, vv, k_sp, v_sp, position_ids, kv_pos, win_pos,
-                scale=arch.attention_scale,
-                softmax_dtype=jnp.float32,
-                sliding_window=arch.sliding_window,
-                chunk_size=arch.chunk_size,
-                sink=p_attn.get("sink") if arch.attention_sink else None,
-                sliding_window_enabled=window_enabled,
-                chunk_enabled=use_rope,
-                logit_softcap=arch.attn_logit_softcap,
-            )
-        ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * Dv)
-        out = _o_proj(ctx)
-        return out, (k_sp, v_sp)
-    # run_decoder_layers is the single authority on eligibility; the mask
-    # check repeats here only because tree-verify programs statically carry
-    # attn_mask in their cache inputs
-    defer = defer_write and attend_to_cache and ci.get("attn_mask") is None
-    if defer:
-        # OLD cache; this step's slots are masked below and the fresh rows
-        # appended — no per-layer full-cache write-back
-        with jax.named_scope("attn.core"):
-            kk, vv, kv_pos = layout.read(k_cache_l, v_cache_l, ci, cache_spec)
-            kk = constrain(kk, policy.cache_kv)
-            vv = constrain(vv, policy.cache_kv)
-            store = cache_spec.store_dtype
-            array_scales = getattr(layout, "has_array_scales", lambda: False)()
-            if store != k.dtype or getattr(layout, "k_scale", 1.0) != 1.0 or array_scales:
-                # quantized cache: round-trip the fresh rows through the store
-                # dtype/scale so this step's numerics match the non-deferred
-                # path (which attends the quantize->dequantize'd row) exactly
-                if array_scales:
-                    ks = layout._scale_for("k", ci, stacked=False)
-                    vs = layout._scale_for("v", ci, stacked=False)
-                else:
-                    ks = getattr(layout, "k_scale", 1.0)
-                    vs = getattr(layout, "v_scale", 1.0)
-                clip = getattr(ContiguousKVLayout, "clip_to_store")
-                k_att = (clip(k / ks, store).astype(store).astype(k.dtype) * ks).astype(k.dtype)
-                v_att = (clip(v / vs, store).astype(store).astype(v.dtype) * vs).astype(v.dtype)
-            else:
-                k_att, v_att = k, v
-        # STACKED fused TKG kernel (round-4): reads the OLD cache straight
-        # from the (L, B, KV, S, D) stack via a scalar-prefetched layer
-        # index — no per-layer cache slice ever materializes for the pallas
-        # operand (the tax that made the per-layer kernel lose in round 3)
-        if (
-            tkg_stacked is not None
-            and S == 1
-            and stacked_layer_idx is not None
-            and window_enabled is None
-            and use_rope is None
-            and ci.get("write_positions") is None
-        ):
-            k_s, v_s, kv_len_s = tkg_stacked
-            with jax.named_scope("attn.core"):
-                ctx = attn_kernels.sharded_fused_decode_stacked_call(
-                    policy, q, k_s, v_s, k, v, position_ids, stacked_layer_idx,
-                    scale=arch.attention_scale,
-                    sliding_window=arch.sliding_window,
-                    chunk_size=arch.chunk_size,
-                    kv_len=kv_len_s,
-                )
-            if ctx is not None:
-                _record_strategy("tkg_fused_kernel_stacked")
-                ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * Dv)
-                out = _o_proj(ctx)
-                return out, (k, v)
-        # fused TKG kernel: strict-causal online softmax over the old cache
-        # merged with the fresh row in ONE pallas pass — the kernel that
-        # COMPOSES with deferred writes (reference: fused TKG kernels,
-        # attention_base.py:1419-1994); two_part attention is the XLA fallback
-        if (
-            arch.attn_tkg_kernel_enabled
-            and S == 1
-            and isinstance(layout, ContiguousKVLayout)  # ring kv_pos wraps
-            and arch.v_head_dim is None
-            and not arch.attention_sink
-            and arch.attn_logit_softcap is None
-            and window_enabled is None
-            and use_rope is None
-            and ci.get("write_positions") is None
-            and attn_kernels.fused_decode_kernel_supported(q.shape, kk.shape)
-        ):
-            with jax.named_scope("attn.core"):
-                ctx = attn_kernels.sharded_fused_decode_call(
-                    policy, q, kk, vv, k_att, v_att, position_ids, kv_pos,
-                    scale=arch.attention_scale,
-                    sliding_window=arch.sliding_window,
-                    chunk_size=arch.chunk_size,
-                )
-            if ctx is not None:
-                _record_strategy("tkg_fused_kernel")
-                ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * Dv)
-                out = _o_proj(ctx)
-                return out, (k, v)
-        with jax.named_scope("attn.core"):
-            _record_strategy("tkg_two_part_xla")
-            wpos = ci.get("write_positions", position_ids).astype(jnp.int32)
-            hit = jnp.any(kv_pos[:, None, :] == wpos[:, :, None], axis=1)
-            kv_pos = jnp.where(hit, jnp.int32(2 ** 30), kv_pos)
-            ctx = attn_ops.attention_two_part(
-                q, kk, vv, k_att, v_att, position_ids, kv_pos, wpos,
-                scale=arch.attention_scale,
-                softmax_dtype=jnp.float32,
-                sliding_window=arch.sliding_window,
-                chunk_size=arch.chunk_size,
-                sink=p_attn.get("sink") if arch.attention_sink else None,
-                sliding_window_enabled=window_enabled,
-                chunk_enabled=use_rope,
-                logit_softcap=arch.attn_logit_softcap,
-            )
-        ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * Dv)
-        out = _o_proj(ctx)
-        return out, (k, v)  # fresh rows only; committed after the scan
-
-    with jax.named_scope("kv.write"):
-        new_k, new_v = layout.update(k_cache_l, v_cache_l, k, v, ci, cache_spec)
-
-    if attend_to_cache:
-        if ci and ci.get("bidir_spans") is not None and S > 1:
-            # a cache-attending multi-token prefill (prefix caching / chunked
-            # prefill) cannot honor the bidirectional image-span mask: span
-            # ids restart per chunk, so same-image tokens in the cached
-            # prefix could never match — reject at trace time instead of
-            # silently computing causal-only attention
-            raise NotImplementedError(
-                "bidirectional image attention (gemma3-vision) does not "
-                "compose with prefix-cached/chunked prefill; disable "
-                "prefix caching for this model"
-            )
-        # mixed ragged dispatch (serving one-dispatch step): the packed
-        # token stream carries per-token (row, position) tags and one
-        # combined per-row block table, so prefill chunks and decode rows
-        # share this single attention call — the chunk/fresh rows are
-        # already scattered into the pool (update above), exactly like the
-        # per-row paged paths below
-        mixed_rids = ci.get("mixed_row_ids")
-        if mixed_rids is not None and S > 1:
-            rids = mixed_rids.astype(jnp.int32)  # (1, S); -1 = padding
-            R = ci["last_token_index"].shape[0]  # rows per step (static)
-            bt = ci["block_table"].reshape(R, -1)  # (R, Wt) per-row tables
-            if (
-                isinstance(layout, BlockKVLayout)
-                and arch.v_head_dim is None
-                and arch.attn_kernel_enabled
-                and ci.get("attn_mask") is None
-                and ci.get("write_positions") is None
-                and not arch.attention_sink
-                and arch.attn_logit_softcap is None
-                and arch.sliding_window is None
-                and arch.chunk_size is None
-                and window_enabled is None
-                and use_rope is None
-                and attn_kernels.ragged_paged_kernel_supported(
-                    q.shape, new_k.shape, layout.block_size
-                )
-            ):
-                with jax.named_scope("attn.core"):
-                    ctx = attn_kernels.sharded_ragged_paged_call(
-                        policy, q, new_k, new_v, bt, rids[0], position_ids[0],
-                        layer_idx,
-                        block_size=layout.block_size,
-                        scale=arch.attention_scale,
-                        k_scale=layout.k_scale,
-                        v_scale=layout.v_scale,
-                    )
-                if ctx is not None:
-                    _record_strategy("mixed_ragged_kernel")
-                    ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * D)
-                    out = _o_proj(ctx)
-                    return out, (new_k, new_v)
-            # XLA fallback: gather the combined window and rebuild the
-            # ragged causal mask from the token tags — kv col g serves row
-            # g // row_width at in-row position g % row_width; holes carry
-            # the layout's poisoned 2**30 position
-            with jax.named_scope("attn.core"):
-                kk, vv, kv_pos = layout.read(new_k, new_v, ci, cache_spec)
-                kk = constrain(kk, policy.cache_kv)
-                vv = constrain(vv, policy.cache_kv)
-                W = kk.shape[2]
-                row_width = W // R
-                g = jnp.arange(W, dtype=jnp.int32)
-                kv_row = g // row_width
-                kv_in = g % row_width
-                live = kv_pos[0] < jnp.int32(2 ** 30)
-                mask = (
-                    (rids[:, :, None] == kv_row[None, None, :])
-                    & (kv_in[None, None, :] <= position_ids[:, :, None])
-                    & live[None, None, :]
-                )
-                _record_strategy("mixed_ragged_xla")
-                ctx = attn_ops.grouped_attention(
-                    q, kk, vv, mask,
-                    scale=arch.attention_scale, softmax_dtype=jnp.float32,
-                )
-            ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * Dv)
-            out = _o_proj(ctx)
-            return out, (new_k, new_v)
-        # prefix-cache / chunked-prefill CTE through the block table: the
-        # chunk is already scattered into the pool (update above), so the
-        # kernel reads prefix + chunk in token order without materializing
-        # the (B, KV, W, D) gather (reference: NKI block-CTE kernels,
-        # attention_base.py:909,1083)
-        if (
-            isinstance(layout, BlockKVLayout)
-            and arch.v_head_dim is None
-            and arch.attn_kernel_enabled
-            and S > 1
-            and "block_table" in ci
-            and ci.get("attn_mask") is None
-            and ci.get("write_positions") is None
-            and not arch.attention_sink
-            and arch.attn_logit_softcap is None
-            and arch.sliding_window is None
-            and arch.chunk_size is None
-            and window_enabled is None
-            and use_rope is None
-            and attn_kernels.paged_prefill_kernel_supported(
-                q.shape, new_k.shape, layout.block_size
-            )
-        ):
-            with jax.named_scope("attn.core"):
-                ctx = attn_kernels.sharded_paged_prefill_call(
-                    policy, q, new_k, new_v, ci["block_table"], position_ids,
-                    layer_idx,
-                    block_size=layout.block_size,
-                    scale=arch.attention_scale,
-                    k_scale=layout.k_scale,
-                    v_scale=layout.v_scale,
-                )
-            if ctx is not None:
-                _record_strategy("cte_paged_kernel")
-                ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * D)
-                with jax.named_scope("attn.out"):
-                    out = _linear(
-                        ctx, p_attn["o_proj"], arch.act_quant, arch.act_clamp, adapter_ids
-                    )
-                return out, (new_k, new_v)
-        # paged decode: read K/V straight through the block table inside the
-        # kernel — skips the materialized O(table-width) gather of
-        # BlockKVLayout.read (reference: NKI block-TKG kernel,
-        # attention_base.py:50-162)
-        if (
-            isinstance(layout, BlockKVLayout)
-            and arch.v_head_dim is None
-            and arch.attn_block_tkg_kernel_enabled
-            and S == 1
-            and "block_table" in ci
-            and ci.get("attn_mask") is None
-            and not arch.attention_sink
-            and arch.attn_logit_softcap is None
-            and arch.sliding_window is None
-            and arch.chunk_size is None
-            and window_enabled is None
-            and use_rope is None
-            and attn_kernels.paged_decode_kernel_supported(
-                q.shape, new_k.shape, layout.block_size
-            )
-        ):
-            with jax.named_scope("attn.core"):
-                ctx = attn_kernels.sharded_paged_decode_call(
-                    policy, q, new_k, new_v, ci["block_table"], position_ids,
-                    layer_idx,
-                    block_size=layout.block_size,
-                    scale=arch.attention_scale,
-                    k_scale=layout.k_scale,
-                    v_scale=layout.v_scale,
-                )
-            if ctx is not None:
-                _record_strategy("tkg_paged_kernel")
-                ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * D)
-                with jax.named_scope("attn.out"):
-                    out = _linear(
-                        ctx, p_attn["o_proj"], arch.act_quant, arch.act_clamp, adapter_ids
-                    )
-                return out, (new_k, new_v)
-        with jax.named_scope("attn.core"):
-            kk, vv, kv_pos = layout.read(new_k, new_v, ci, cache_spec)
-            kk = constrain(kk, policy.cache_kv)
-            vv = constrain(vv, policy.cache_kv)
-        mask_override = ci.get("attn_mask")
-        if mask_override is not None:
-            # explicit (B, S, W) mask — tree-attention verify passes
-            # (speculation/token_tree.py) where causal-by-position is wrong.
-            # Sink/softcap still apply; window/chunk masks cannot compose with
-            # an override (applications reject those combinations up front).
-            with jax.named_scope("attn.core"):
-                W = kk.shape[2]
-                _record_strategy("attn_mask_override_xla")
-                ctx = attn_ops.grouped_attention(
-                    q, kk, vv, mask_override[:, :, :W],
-                    scale=arch.attention_scale, softmax_dtype=jnp.float32,
-                    sink=p_attn.get("sink") if arch.attention_sink else None,
-                    logit_softcap=arch.attn_logit_softcap,
-                )
-            ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * Dv)
-            out = _o_proj(ctx)
-            return out, (new_k, new_v)
-        with jax.named_scope("attn.core"):
-            ctx = None
-            if (
-                arch.attn_tkg_kernel_enabled
-                and arch.v_head_dim is None
-                and not arch.attention_sink
-                and arch.attn_logit_softcap is None
-                and window_enabled is None
-                and use_rope is None
-                and attn_kernels.decode_kernel_supported(q.shape, kk.shape)
-            ):
-                ctx = attn_kernels.sharded_kernel_call(
-                    policy, q, kk, vv, position_ids, kv_pos,
-                    decode=True,
-                    scale=arch.attention_scale,
-                    sliding_window=arch.sliding_window,
-                    chunk_size=arch.chunk_size,
-                )
-            _record_strategy("tkg_xla" if ctx is None else "tkg_kernel")
-            if ctx is None:
-                ctx = attn_ops.attention_with_positions(
-                    q, kk, vv, position_ids, kv_pos,
-                    scale=arch.attention_scale,
-                    softmax_dtype=jnp.float32,
-                    sliding_window=arch.sliding_window,
-                    chunk_size=arch.chunk_size,
-                    sink=p_attn.get("sink") if arch.attention_sink else None,
-                    sliding_window_enabled=window_enabled,
-                    chunk_enabled=use_rope,
-                    logit_softcap=arch.attn_logit_softcap,
-                )
+        k_read, v_read, written = k_cache_l, v_cache_l, (k_sp, v_sp)
+    elif site.deferred:  # the fresh rows alone go back, committed after the scan
+        k_read, v_read, written = k_cache_l, v_cache_l, (k, v)
     else:
-        # gemma3-vision: image-span tokens attend each other BIDIRECTIONALLY
-        # during prefill (HF token_type_ids_mask_function OR-ed into both the
-        # full and sliding masks); spans are derived in-graph from input_ids
-        # (causal_lm_forward), so only the CTE program pays for it
-        with jax.named_scope("attn.core"):
-            bidir = ci.get("bidir_spans") if ci else None
-            extra_or = None
-            if bidir is not None and S > 1:
-                extra_or = (bidir[:, None, :] == bidir[:, :, None]) & (
-                    bidir[:, :, None] > 0
-                )
-            ctx = None
-            if (
-                arch.attn_kernel_enabled
-                and arch.v_head_dim is None
-                and not arch.attention_sink
-                and arch.attn_logit_softcap is None
-                and window_enabled is None
-                and use_rope is None
-                and extra_or is None
-                and attn_kernels.prefill_kernel_supported(q.shape, k.shape)
-            ):
-                ctx = attn_kernels.sharded_kernel_call(
-                    policy, q, k, v, position_ids, position_ids,
-                    decode=False,
-                    scale=arch.attention_scale,
-                    sliding_window=arch.sliding_window,
-                    chunk_size=arch.chunk_size,
-                )
-            _record_strategy("cte_xla" if ctx is None else "cte_flash_kernel")
-            if ctx is None:
-                ctx = attn_ops.attention_with_positions(
-                    q, k, v, position_ids, position_ids,
-                    scale=arch.attention_scale,
-                    softmax_dtype=jnp.float32,
-                    sliding_window=arch.sliding_window,
-                    chunk_size=arch.chunk_size,
-                    sink=p_attn.get("sink") if arch.attention_sink else None,
-                    sliding_window_enabled=window_enabled,
-                    chunk_enabled=use_rope,
-                    logit_softcap=arch.attn_logit_softcap,
-                    extra_or_mask=extra_or,
-                )
+        with jax.named_scope("kv.write"):
+            written = layout.update(k_cache_l, v_cache_l, k, v, ci, cache_spec)
+        k_read, v_read = written
 
+    # -- one core per strategy name, each (B, H, S, Dv)
+    sink = p_attn.get("sink") if arch.attention_sink else None
+    # the kernels take the static window and chunk; ops/attention.py every term
+    static_terms = dict(
+        scale=arch.attention_scale,
+        sliding_window=arch.sliding_window,
+        chunk_size=arch.chunk_size,
+    )
+    all_terms = dict(
+        static_terms, softmax_dtype=jnp.float32, sink=sink,
+        sliding_window_enabled=window_enabled, chunk_enabled=use_rope,
+        logit_softcap=arch.attn_logit_softcap,
+    )
+    if site.phase == "mixed":
+        # mixed ragged dispatch (serving one-dispatch step): the packed token
+        # stream carries per-token (row, position) tags and one combined
+        # per-row block table, so prefill chunks and decode rows share this
+        # single attention call
+        rids = ci["mixed_row_ids"].astype(jnp.int32)  # (1, S); -1 = padding
+        R = ci["last_token_index"].shape[0]  # rows per step (static)
+
+    def read():
+        kk, vv, kv_pos = layout.read(k_read, v_read, ci, cache_spec)
+        return constrain(kk, policy.cache_kv), constrain(vv, policy.cache_kv), kv_pos
+
+    def attended():
+        """What a flat core attends: the fresh rows alone (context encoding:
+        O(S^2), not O(S * max_len)) or the written cache's window."""
+        return read() if attend_to_cache else (k, v, position_ids)
+
+    def fresh_as_stored():
+        store = cache_spec.store_dtype
+        array_scales = getattr(layout, "has_array_scales", lambda: False)()
+        if store == k.dtype and getattr(layout, "k_scale", 1.0) == 1.0 and not array_scales:
+            return k, v
+        # quantized cache: round-trip the fresh rows through the store
+        # dtype/scale so this step's numerics match the non-deferred
+        # path (which attends the quantize->dequantize'd row) exactly
+        if array_scales:
+            ks = layout._scale_for("k", ci, stacked=False)
+            vs = layout._scale_for("v", ci, stacked=False)
+        else:
+            ks = getattr(layout, "k_scale", 1.0)
+            vs = getattr(layout, "v_scale", 1.0)
+        clip = ContiguousKVLayout.clip_to_store
+        k_att = (clip(k / ks, store).astype(store).astype(k.dtype) * ks).astype(k.dtype)
+        v_att = (clip(v / vs, store).astype(store).astype(v.dtype) * vs).astype(v.dtype)
+        return k_att, v_att
+
+    def spec_window_xla():
+        kk, vv, kv_pos = read()
+        kv_pos = jnp.where(kv_pos >= win_pos[:, :1], jnp.int32(2 ** 30), kv_pos)
+        return attn_ops.attention_two_part(
+            q, kk, vv, k_sp, v_sp, position_ids, kv_pos, win_pos, **all_terms
+        )
+
+    def fused_kernel_stacked():  # why: run_decoder_layers, use_stacked_tkg
+        k_s, v_s, kv_len_s = tkg_stacked
+        return attn_kernels.sharded_fused_decode_stacked_call(
+            policy, q, k_s, v_s, k, v, position_ids, stacked_layer_idx,
+            kv_len=kv_len_s, **static_terms,
+        )
+
+    def fused_kernel():
+        # strict-causal online softmax over the old cache merged with the
+        # fresh row in ONE pallas pass — the kernel that COMPOSES with
+        # deferred writes (reference: fused TKG kernels,
+        # attention_base.py:1419-1994); two_part attention is the XLA form
+        kk, vv, kv_pos = read()
+        k_att, v_att = fresh_as_stored()
+        return attn_kernels.sharded_fused_decode_call(
+            policy, q, kk, vv, k_att, v_att, position_ids, kv_pos, **static_terms
+        )
+
+    def two_part_xla():
+        kk, vv, kv_pos = read()
+        k_att, v_att = fresh_as_stored()
+        wpos = ci.get("write_positions", position_ids).astype(jnp.int32)
+        hit = jnp.any(kv_pos[:, None, :] == wpos[:, :, None], axis=1)
+        kv_pos = jnp.where(hit, jnp.int32(2 ** 30), kv_pos)
+        return attn_ops.attention_two_part(
+            q, kk, vv, k_att, v_att, position_ids, kv_pos, wpos, **all_terms
+        )
+
+    def paged(call, table, *tags):
+        # the rows are in the pool (the write above): the kernels read them
+        # through the block table in token order, without BlockKVLayout.read's
+        # gather (reference: NKI block kernels, attention_base.py:50-162, 909)
+        return call(
+            policy, q, k_read, v_read, table, *tags, layer_idx,
+            block_size=layout.block_size, scale=arch.attention_scale,
+            k_scale=layout.k_scale, v_scale=layout.v_scale,
+        )
+
+    def ragged_xla():
+        # gather the combined window and rebuild the ragged causal mask from
+        # the token tags — kv col g serves row g // row_width at in-row
+        # position g % row_width; holes carry the layout's poisoned 2**30
+        kk, vv, kv_pos = read()
+        W = kk.shape[2]
+        row_width = W // R
+        g = jnp.arange(W, dtype=jnp.int32)
+        mask = (
+            (rids[:, :, None] == (g // row_width)[None, None, :])
+            & ((g % row_width)[None, None, :] <= position_ids[:, :, None])
+            & (kv_pos[0] < jnp.int32(2 ** 30))[None, None, :]
+        )
+        return attn_ops.grouped_attention(
+            q, kk, vv, mask, scale=arch.attention_scale, softmax_dtype=jnp.float32
+        )
+
+    def mask_override_xla():
+        # explicit (B, S, W) mask — tree-attention verify passes
+        # (speculation/token_tree.py) where causal-by-position is wrong
+        kk, vv, _ = read()
+        return attn_ops.grouped_attention(
+            q, kk, vv, ci["attn_mask"][:, :, : kk.shape[2]],
+            scale=arch.attention_scale, softmax_dtype=jnp.float32,
+            sink=sink, logit_softcap=arch.attn_logit_softcap,
+        )
+
+    def flat_kernel():
+        kk, vv, kv_pos = attended()
+        return attn_kernels.sharded_kernel_call(
+            policy, q, kk, vv, position_ids, kv_pos, decode=attend_to_cache, **static_terms
+        )
+
+    def positions_xla():
+        extra_or = None
+        if "bidir" in site.needs:
+            # gemma3-vision: image-span tokens attend each other BIDIRECTIONALLY
+            # during prefill (HF token_type_ids_mask_function OR-ed into both the
+            # full and sliding masks); spans are derived in-graph from input_ids
+            # (causal_lm_forward), so only the CTE program pays for it
+            bidir = ci["bidir_spans"]
+            extra_or = (bidir[:, None, :] == bidir[:, :, None]) & (bidir[:, :, None] > 0)
+        kk, vv, kv_pos = attended()
+        return attn_ops.attention_with_positions(
+            q, kk, vv, position_ids, kv_pos, extra_or_mask=extra_or, **all_terms
+        )
+
+    cores = {
+        "tkg_spec_window_xla": spec_window_xla,
+        "tkg_fused_kernel_stacked": fused_kernel_stacked,
+        "tkg_fused_kernel": fused_kernel,
+        "tkg_two_part_xla": two_part_xla,
+        "mixed_ragged_kernel": lambda: paged(
+            attn_kernels.sharded_ragged_paged_call,
+            ci["block_table"].reshape(R, -1), rids[0], position_ids[0],
+        ),
+        "mixed_ragged_xla": ragged_xla,
+        "cte_paged_kernel": lambda: paged(
+            attn_kernels.sharded_paged_prefill_call, ci["block_table"], position_ids
+        ),
+        "tkg_paged_kernel": lambda: paged(
+            attn_kernels.sharded_paged_decode_call, ci["block_table"], position_ids
+        ),
+        "attn_mask_override_xla": mask_override_xla,
+        "tkg_kernel": flat_kernel,
+        "tkg_xla": positions_xla,
+        "cte_flash_kernel": flat_kernel,
+        "cte_xla": positions_xla,
+    }
+    with jax.named_scope("attn.core"):
+        ctx = cores[name]()
     ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * Dv)
-    out = _o_proj(ctx)
-    return out, (new_k, new_v)
+    return _o_proj(ctx), written
 
 
 def mlp_block(
@@ -1056,7 +845,7 @@ def mlp_block(
                     f"mlp_kernel_enabled: MLP shape (act={arch.hidden_act!r}) is "
                     "not kernel-eligible; disable the flag"
                 )
-            _record_strategy("mlp_fused_kernel")
+            attn_select._record_strategy("mlp_fused_kernel")
             return out
         aq, ac = arch.act_quant, arch.act_clamp
         if arch.hidden_act == "xielu":
@@ -1117,20 +906,15 @@ def decoder_layer(
         # per-layer scalar riding the scan xs: EAGLE drafts feed the fc output
         # straight into attention for their first layer (no input norm)
         h = jnp.where(lp["input_norm_skip"], hidden, h)
+    extra = dict(layer_idx=layer_idx)  # the paged pool's layer, KV-quant scale rows
     if arch.mla is not None:
         from nxdi_tpu.ops.mla import mla_attention_block as attn_block_fn
     else:
         attn_block_fn = attention_block
-    extra = {}
-    if attn_block_fn is attention_block:
-        extra["defer_write"] = defer_write
-        extra["qkv_stacked"] = qkv_stacked
-        extra["layer_idx"] = layer_idx
-        extra["stacked_layer_idx"] = stacked_layer_idx
-        extra["tkg_stacked"] = tkg_stacked
-        extra["spec_window"] = spec_window
-    else:
-        extra["layer_idx"] = layer_idx  # the paged latent pool's layer
+        extra.update(
+            defer_write=defer_write, qkv_stacked=qkv_stacked, tkg_stacked=tkg_stacked,
+            stacked_layer_idx=stacked_layer_idx, spec_window=spec_window,
+        )
     attn_out, (nk, nv) = attn_block_fn(
         arch, lp["attn"], h, cos, sin, k_cache_l, v_cache_l,
         position_ids, cache_spec, attend_to_cache, policy, layout, cache_inputs,
@@ -1639,21 +1423,41 @@ def run_decoder_layers(
     # pool and for the ring layout (its S dim is slots, not positions)
     paged = isinstance(layout, BlockKVLayout)
     windowable = not isinstance(layout, (BlockKVLayout, WindowKVLayout))
+    spec_mode = "k_spec" in cache
+    # Heterogeneous stacks (deepseek-V3 first_k_dense_replace, minimax) arrive
+    # as a LIST of layer-stacked segments — e.g. [dense-MLP head, MoE rest] —
+    # each scanned over its static slice of the cache. Homogeneous models pass
+    # the single stacked pytree unchanged.
+    segments = (
+        list(layer_params) if isinstance(layer_params, (list, tuple)) else [layer_params]
+    )
+    # what the attention table (ops/attention_select.py) will answer this
+    # stack's layers, asked once for the stack's two decisions
+    stack_site = attn_select.site_of(
+        arch, layout, policy, cache_inputs,
+        (position_ids.shape[0], arch.num_attention_heads, position_ids.shape[1], arch.head_dim),
+        None,
+        jax.ShapeDtypeStruct(cache["k"].shape[0 if paged else 1:], cache["k"].dtype),
+        cache_spec.compute_dtype,
+        attend_to_cache=attend_to_cache, deferred=True, stacked=True, spec_window=spec_mode,
+        layer_flags=tuple(
+            any(isinstance(sg, dict) and flag in sg for sg in segments)
+            for flag in ("use_sliding_window", "use_rope")
+        ),
+    )
     # deferred cache writes (decode hot path): the scan emits only fresh K/V
     # rows; they commit in ONE scatter on the stacked cache below — carrying
     # full cache slices through the scan as ys round-trips the whole cache
     # per layer (measured ~6x the pure-attention cost on v5e)
-    # (the TKG kernel no longer disables defer: the fused decode kernel in
-    # attention_block implements two-part attention in one pallas pass, and
-    # ineligible layer shapes fall back to the XLA two_part path per layer)
-    defer = (
-        attend_to_cache
-        and arch.pp_degree == 1
-        and arch.mla is None
-        and isinstance(layout, ContiguousKVLayout)
-        and (cache_inputs or {}).get("attn_mask") is None
+    defer = attn_select.defers(stack_site)
+    # stacked-cache fused TKG kernel (round-4): the kernel reads the OLD cache
+    # from the full stack via scalar-prefetched layer index, so the scan's
+    # per-layer cache slices are never pallas operands (round-3's slice-copy
+    # tax) and the kv_window slice is skipped: where a layer would not take
+    # it, skipping the slice would regress the XLA path to the full cache
+    use_stacked_tkg = (
+        defer and attn_select.select(stack_site, record=False) == "tkg_fused_kernel_stacked"
     )
-    spec_mode = "k_spec" in cache
     if spec_mode and (
         not attend_to_cache
         or arch.pp_degree > 1
@@ -1708,9 +1512,6 @@ def run_decoder_layers(
         return h, nk, nv
 
     if arch.pp_degree > 1:
-        segments_chk = (
-            list(layer_params) if isinstance(layer_params, (list, tuple)) else [layer_params]
-        )
         if layer_injections is not None:
             raise NotImplementedError(
                 "deepstack layer injections are not supported under "
@@ -1723,18 +1524,13 @@ def run_decoder_layers(
             )
         # deferred commit applies under pp too (stage-local in-place commit
         # each tick; see _pipelined_decoder_layers) — decode-shaped only
+        # (unquantized, unrouted cache rows written at their own positions: the
+        # stage-local commit kernel's terms)
         defer_pp = (
-            attend_to_cache
-            and arch.mla is None
-            and isinstance(layout, ContiguousKVLayout)
-            and not getattr(layout, "route_by_seq_id", False)
-            and getattr(layout, "k_scale", 1.0) == 1.0
-            and getattr(layout, "v_scale", 1.0) == 1.0
-            and not getattr(layout, "has_array_scales", lambda: False)()
-            and cache["k"].dtype == cache_spec.compute_dtype  # no quant store
-            and position_ids.shape[1] == 1
-            and (cache_inputs or {}).get("attn_mask") is None
-            and (cache_inputs or {}).get("write_positions") is None
+            defer
+            and stack_site.phase == "decode"
+            and stack_site.raw_cache
+            and "write_positions" not in stack_site.needs
         )
         # Heterogeneous segment stacks (deepseek-V3 first_k_dense + MoE rest,
         # minimax) pipeline as MULTI-LAP virtual stages: each segment runs one
@@ -1744,7 +1540,7 @@ def run_decoder_layers(
         # Cost: one bubble set per segment.
         pks, pvs, phs = [], [], []
         off_pp = 0
-        for seg in segments_chk:
+        for seg in segments:
             n_seg = jax.tree_util.tree_leaves(seg)[0].shape[0]
             if n_seg % arch.pp_degree:
                 raise ValueError(
@@ -1787,47 +1583,6 @@ def run_decoder_layers(
             cache_spec, _step, defer, layout, policy, cache_inputs,
             adapter_ids, collect_hidden, layer_injections,
         )
-
-    # Heterogeneous stacks (deepseek-V3 first_k_dense_replace, minimax) arrive
-    # as a LIST of layer-stacked segments — e.g. [dense-MLP head, MoE rest] —
-    # each scanned over its static slice of the cache. Homogeneous models pass
-    # the single stacked pytree unchanged.
-    segments = (
-        list(layer_params) if isinstance(layer_params, (list, tuple)) else [layer_params]
-    )
-    # stacked-cache fused TKG kernel eligibility (round-4): the kernel reads
-    # the OLD cache from the full stack via scalar-prefetched layer index, so
-    # the scan's per-layer cache slices are never pallas operands (round-3's
-    # slice-copy tax). Conditions mirror the deferred-commit contract.
-    _has_layer_flags = any(
-        isinstance(sg, dict)
-        and any(k in sg for k in ("use_sliding_window", "use_rope", "use_local_rope"))
-        for sg in segments
-    )
-    use_stacked_tkg = (
-        arch.attn_tkg_kernel_enabled
-        and defer
-        and not spec_mode
-        and position_ids.shape[1] == 1
-        # flash decoding (KV-S sharded) and per-layer window/rope flags fall
-        # back per layer inside attention_block — skipping the kv_window
-        # slice for them would regress the XLA path to the full cache
-        and policy.cache_kv[2] is None
-        and not _has_layer_flags
-        and arch.v_head_dim is None
-        and not arch.attention_sink
-        and arch.attn_logit_softcap is None
-        and not getattr(layout, "route_by_seq_id", False)
-        and getattr(layout, "k_scale", 1.0) == 1.0
-        and getattr(layout, "v_scale", 1.0) == 1.0
-        and not getattr(layout, "has_array_scales", lambda: False)()
-        and cache["k"].dtype == cache_spec.compute_dtype
-        and (cache_inputs or {}).get("write_positions") is None
-        and attn_kernels.fused_decode_kernel_supported(
-            (position_ids.shape[0], arch.num_attention_heads, 1, arch.head_dim),
-            cache["k"].shape[1:],
-        )
-    )
 
     ks, vs, hs = [], [], []
     k_pool, v_pool = (cache["k"], cache["v"]) if paged else (None, None)

@@ -4,9 +4,10 @@ The TPU-native replacements for the reference's NKI attention kernels
 (SURVEY §2.9: external ``attention_isa_kernel`` CTE flash,
 ``attention_tkg_fwd_isa_kernel`` decode, in-repo sliding-window flash
 ``modules/sliding_window/attention.py:234``). Same role as there: an
-*optimization* behind a flag (``attn_kernel_enabled``), never a semantic
-change — ops/attention.py stays the always-available XLA fallback with
-identical mask semantics.
+*optimization*, never a semantic change. Which call takes which kernel is one
+row each of ``ops/attention_select.py TABLE`` (its flag, the mask terms it
+computes, its shape and sharding predicates); ops/attention.py is the XLA row
+that computes every term.
 
 Design notes (vs the reference's 128-partition NKI tiling):
   - grid = (batch*q_heads, S_q/block_q, S_kv/block_k); the kv dim is the
@@ -520,8 +521,8 @@ def sharded_fused_decode_stacked_call(
     policy, q, k_cache_s, v_cache_s, k_new, v_new, q_pos, layer_idx,
     *, scale=None, sliding_window=None, chunk_size=None, kv_len=None,
 ):
-    """Stacked fused decode under GSPMD. Returns None when the KV sequence
-    dim is sharded (flash decoding) — callers fall back."""
+    """Stacked fused decode under GSPMD (the KV sequence dim unsharded: the
+    attention table's sharding predicate)."""
     from jax.sharding import PartitionSpec as P
 
     fn = functools.partial(
@@ -535,8 +536,6 @@ def sharded_fused_decode_stacked_call(
     if mesh is None or mesh.empty:
         return fn(q, k_cache_s, v_cache_s, k_new, v_new, q_pos, layer_idx)
     kv_spec = policy.cache_kv
-    if kv_spec[2] is not None:
-        return None  # KV sequence sharded (flash decoding) -> XLA path
     q_spec = P(*policy.q)
     fresh_spec = P(*policy.kv)
     cache_spec = P(None, *kv_spec)
@@ -641,8 +640,7 @@ def sharded_fused_decode_call(
     policy, q, k_cache, v_cache, k_new, v_new, q_pos, kv_pos,
     *, scale=None, sliding_window=None, chunk_size=None, kv_len=None,
 ):
-    """Fused deferred-write decode under GSPMD (see sharded_kernel_call).
-    Returns None when the KV sequence dim is sharded (flash decoding)."""
+    """Fused deferred-write decode under GSPMD (see sharded_kernel_call)."""
     from jax.sharding import PartitionSpec as P
 
     fn = functools.partial(
@@ -656,8 +654,6 @@ def sharded_fused_decode_call(
     if mesh is None or mesh.empty:
         return fn(q, k_cache, v_cache, k_new, v_new, q_pos, kv_pos)
     kv_spec = policy.cache_kv
-    if kv_spec[2] is not None:
-        return None  # KV sequence sharded (flash decoding) -> XLA path
     q_spec = P(*policy.q)
     fresh_spec = P(*policy.kv)
     qp_spec = P(policy.q[0], policy.q[2])
@@ -1160,8 +1156,6 @@ def sharded_paged_prefill_call(
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return fn(q, k_cache, v_cache, block_table, q_pos, layer_idx)
-    if policy.q[0] is not None or policy.q[2] is not None:
-        return None  # batch/seq-sharded prefill (DP/CP) -> XLA path
     shard_fn = jax.shard_map(
         fn,
         mesh=mesh,
@@ -1184,8 +1178,7 @@ def sharded_paged_decode_call(
     *, block_size, scale=None, k_scale=1.0, v_scale=1.0,
 ):
     """Paged decode under GSPMD: the stacked pool + q shard over kv-heads on
-    tp, the block table, positions and the layer index are replicated. Returns
-    None when the mesh layout shards anything the kernel can't see locally."""
+    tp, the block table, positions and the layer index are replicated."""
     from jax.sharding import PartitionSpec as P
 
     fn = functools.partial(
@@ -1199,8 +1192,6 @@ def sharded_paged_decode_call(
     if mesh is None or mesh.empty:
         return fn(q, k_cache, v_cache, block_table, q_pos, layer_idx)
     # the block pool is (L, slots, KV, D), sharded on heads only
-    if policy.q[0] is not None or policy.q[2] is not None:
-        return None  # batch/seq-sharded decode (DP/flash-decode) -> XLA path
     shard_fn = jax.shard_map(
         fn,
         mesh=mesh,
@@ -1237,9 +1228,9 @@ def sharded_kernel_call(
     submodel's :class:`ShardingPolicy`; attention is head-local so no in-shard
     collectives are needed. CP's q-sequence sharding is fine — GSPMD shards
     are contiguous slices, so per-shard positions stay affine and each shard's
-    start is its own ``row[0]``. Returns None only when the policy shards the
-    KV sequence dim (flash decoding needs a cross-shard softmax) — the caller
-    falls back to ops/attention.py."""
+    start is its own ``row[0]``. A policy that shards the KV sequence dim
+    (flash decoding needs a cross-shard softmax) is the XLA rows': the
+    attention table never selects this call for it."""
     from jax.sharding import PartitionSpec as P
 
     fn = functools.partial(
@@ -1253,8 +1244,6 @@ def sharded_kernel_call(
         return fn(q, k, v, q_pos, kv_pos)
 
     kv_spec = policy.cache_kv if decode else policy.kv
-    if kv_spec[2] is not None:
-        return None  # KV sequence sharded (flash decoding) -> XLA path
     q_spec = P(*policy.q)
     qp_spec = P(policy.q[0], policy.q[2])  # (B, Sq) follows q's batch/seq axes
     kp_spec = P(kv_spec[0], None)

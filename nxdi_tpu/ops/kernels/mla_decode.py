@@ -162,15 +162,13 @@ def sharded_mla_paged_decode_call(
 ):
     """The kernel under GSPMD: queries shard over heads on the policy's head
     axis, the pools (one shared row a token), the table, the positions and the
-    layer are replicated. None when the policy shards batch or sequence."""
+    layer are replicated."""
     from jax.sharding import PartitionSpec as P
 
     fn = functools.partial(mla_paged_decode, block_size=block_size, scale=scale)
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return fn(q_lat, q_rot, k_pool, c_pool, block_table, q_pos, layer_idx)
-    if policy.q[0] is not None or policy.q[2] is not None:
-        return None  # batch/seq-sharded decode (DP / flash decoding) -> XLA path
     heads = P(None, policy.q[1], None)
     return jax.shard_map(
         fn,

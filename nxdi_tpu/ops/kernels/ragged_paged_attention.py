@@ -230,8 +230,6 @@ def sharded_ragged_paged_call(
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return fn(q, k_cache, v_cache, block_tables, row_ids, q_pos, layer_idx)
-    if policy.q[0] is not None or policy.q[2] is not None:
-        return None  # batch/seq-sharded packed stream (DP/CP) -> XLA path
     shard_fn = jax.shard_map(
         fn,
         mesh=mesh,

@@ -38,6 +38,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from nxdi_tpu.ops import attention as attn_ops
+from nxdi_tpu.ops import attention_select
 from nxdi_tpu.ops.norms import rms_norm
 from nxdi_tpu.ops.rope import apply_rotary_pos_emb
 from nxdi_tpu.parallel.mesh import AXIS_MP
@@ -100,29 +101,19 @@ def absorbed_decode_xla(q_lat, q_rot, k_rot_all, c_all, q_pos, kv_pos, scale):
     return jnp.einsum("bhw,bwr->bhr", p, c_all)
 
 
-def _expanded_core(arch, mla, qq, kk, v, position_ids, kv_pos, policy, fresh: bool):
-    """Non-absorbed attention over per-head keys and values. Over ``fresh``
-    rows (context encoding) the flash prefill kernel, with the values padded
-    to the qk width (one head width is all it knows); else XLA."""
-    from nxdi_tpu.models.base import _record_strategy
-    from nxdi_tpu.ops import kernels as attn_kernels
+def _expanded_core(name, mla, qq, kk, v, position_ids, kv_pos, policy):
+    """Non-absorbed attention over per-head keys and values: the flash prefill
+    kernel, with the values padded to the qk width (one head width is all it
+    knows), where the attention table chose it; else XLA."""
+    if name == "cte_flash_kernel":
+        from nxdi_tpu.ops import kernels as attn_kernels
 
-    B, H, S, qk = qq.shape
-    if (
-        arch.attn_kernel_enabled
-        and fresh
-        and S > 1
-        and attn_kernels.prefill_kernel_supported(qq.shape, kk.shape)
-    ):
-        v_wide = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, qk - mla.v_head_dim)))
+        v_wide = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, qq.shape[-1] - mla.v_head_dim)))
         ctx = attn_kernels.sharded_kernel_call(
             policy, qq, kk, v_wide, position_ids, kv_pos, decode=False,
             scale=mla.softmax_scale,
         )
-        if ctx is not None:
-            _record_strategy("cte_flash_kernel")
-            return ctx[..., : mla.v_head_dim]
-    _record_strategy("cte_xla" if fresh else "tkg_mla_xla")
+        return ctx[..., : mla.v_head_dim]
     mask = attn_ops.causal_mask_from_positions(position_ids, kv_pos)
     return attn_ops.grouped_attention(
         qq, kk, v, mask, scale=mla.softmax_scale, softmax_dtype=jnp.float32
@@ -150,7 +141,7 @@ def mla_attention_block(
     layer_idx=None,  # GLOBAL layer index: the paged pool's layer
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     from nxdi_tpu.kvcache.kv_cache import BlockKVLayout
-    from nxdi_tpu.models.base import _linear, _record_strategy
+    from nxdi_tpu.models.base import _linear
 
     mla: MLAArch = arch.mla
     B, S, _ = hidden.shape
@@ -198,7 +189,15 @@ def mla_attention_block(
         with jax.named_scope("attn.out"):
             return _linear(ctx, p_attn["o_proj"], aq, ac)
 
-    if paged and attend_to_cache and S == 1 and "block_table" in ci:
+    # which attention computes this call (ops/attention_select.py): the
+    # absorbed form over the paged pool for one token, else the expanded one
+    site = attention_select.site_of(
+        arch, layout, policy, ci, (B, H, S, mla.qk_head_dim), (B, H, S, mla.qk_head_dim),
+        new_k, cache_spec.compute_dtype, attend_to_cache=attend_to_cache, v_cache=new_v,
+    )
+    name = attention_select.select(site)
+
+    if site.mla == "absorbed":
         # -- token generation over the paged latent pool, ABSORBED: the query
         # goes into the latent space (q_nope @ W_UK), scores and the weighted
         # sum run over the cached 512 + 64-wide rows as they lie, and only the
@@ -209,23 +208,16 @@ def mla_attention_block(
         with jax.named_scope("attn.q_absorb"):
             q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk).astype(dt)
         q_pos = position_ids[:, 0].astype(jnp.int32)
-        o_lat = None
         with jax.named_scope("attn.core"):
-            if arch.attn_block_tkg_kernel_enabled:
+            if name == "tkg_mla_paged_kernel":
                 from nxdi_tpu.ops.kernels import mla_decode
 
-                if mla_decode.mla_paged_decode_supported(
-                    q_lat.shape, new_k.shape, new_v.shape, layout.block_size
-                ):
-                    o_lat = mla_decode.sharded_mla_paged_decode_call(
-                        policy, q_lat, q_rot[:, :, 0], new_k, new_v,
-                        ci["block_table"], q_pos, layer_idx,
-                        block_size=layout.block_size, scale=mla.softmax_scale,
-                    )
-            if o_lat is not None:
-                _record_strategy("tkg_mla_paged_kernel")
+                o_lat = mla_decode.sharded_mla_paged_decode_call(
+                    policy, q_lat, q_rot[:, :, 0], new_k, new_v,
+                    ci["block_table"], q_pos, layer_idx,
+                    block_size=layout.block_size, scale=mla.softmax_scale,
+                )
             else:
-                _record_strategy("tkg_mla_paged_xla")
                 k_all, c_all, kv_pos = layout.read(new_k, new_v, ci, cache_spec)
                 o_lat = absorbed_decode_xla(
                     q_lat, q_rot[:, :, 0], k_all[:, 0], c_all[:, 0], q_pos, kv_pos,
@@ -254,9 +246,7 @@ def mla_attention_block(
         kk = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rot_all, (B, H, W, rope_d))], axis=-1
         )
-        ctx = _expanded_core(
-            arch, mla, qq, kk, v, position_ids, kv_pos, policy, fresh=not attend_to_cache
-        )
+        ctx = _expanded_core(name, mla, qq, kk, v, position_ids, kv_pos, policy)
 
     ctx = jnp.swapaxes(ctx, 1, 2).reshape(B, S, H * mla.v_head_dim)
     return o_proj(ctx), (new_k, new_v)

@@ -59,7 +59,7 @@ EXPERT_FORMS = ("dense", "sorted")
 # Expert-form trace: moe_block appends the form each traced expert layer took.
 # The choice is STATIC per program, so recording at trace time is exact;
 # model_wrapper snapshots it per (submodel, bucket) beside the attention
-# strategies (models/base.py _STRATEGY_TRACE) and counts it into the registry.
+# strategies (ops/attention_select.py _STRATEGY_TRACE) and counts it into the registry.
 _FORM_TRACE: list = []
 
 

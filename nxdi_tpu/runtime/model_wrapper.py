@@ -31,6 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from nxdi_tpu.kvcache.kv_cache import BlockKVLayout, ContiguousKVLayout
 from nxdi_tpu.models.base import causal_lm_forward
+from nxdi_tpu.ops import attention_select
 from nxdi_tpu.runtime import autobucketing, faults
 from nxdi_tpu.runtime.padding import pad_with_first_batchline
 
@@ -216,15 +217,14 @@ class _AutoLayoutProgram:
         """The ONE lowering path — AOT artifact (`compile`) and lazy
         first-call (`__call__`) both come through here, so required-strategy
         verification and retrace-guard recording provably run on both."""
-        from nxdi_tpu.models import base as base_mod
         from nxdi_tpu.ops import moe as moe_ops
 
         if self.retrace_guard is not None:
             self.retrace_guard.record(self.label)
-        base_mod._STRATEGY_TRACE.clear()
+        attention_select._STRATEGY_TRACE.clear()
         moe_ops._FORM_TRACE.clear()
         lowered = self.jitted.lower(*args)
-        self._snap_strategies(base_mod)
+        self._snap_strategies()
         self._snap_expert_forms(moe_ops)
         return lowered
 
@@ -235,12 +235,12 @@ class _AutoLayoutProgram:
             for form in self.expert_forms:
                 self.telemetry.record_expert_form(self.label.partition("[")[0], form)
 
-    def _snap_strategies(self, base_mod):
-        if not base_mod._STRATEGY_TRACE:
+    def _snap_strategies(self):
+        if not attention_select._STRATEGY_TRACE:
             # jaxpr-tracing cache hit: the python body (and its recording)
             # did not re-run — keep the strategies from the first lowering
             return
-        self.attention_strategies = tuple(base_mod._STRATEGY_TRACE)
+        self.attention_strategies = tuple(attention_select._STRATEGY_TRACE)
         logging.getLogger("nxdi_tpu").info(
             "%s attention strategies: %s",
             self.label,
