@@ -197,6 +197,10 @@ def _print_timeline(records: List[dict], last: int) -> None:
             )
         elif dec is not None:
             prog = f"{dec['submodel']}[steps={dec['steps']}]"
+            if r.get("chained"):
+                prog += " chained"  # dispatched ahead of the previous collect
+            if r.get("overrun_tokens"):
+                prog += f" overrun={r['overrun_tokens']}"
             if dec["padding_rows"]:
                 prog += f" pad={dec['padding_rows']}"
             toks = dec.get("tokens_emitted")

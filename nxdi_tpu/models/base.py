@@ -2014,7 +2014,7 @@ def causal_lm_forward(
     outputs: Dict[str, jax.Array] = {}
     if held_tally:
         # two scalars beside the tokens, in the same fetch (serving/engine.py
-        # _decode_single -> StepRecord.moe_held_pairs); batch-padding rows
+        # _collect_decode -> StepRecord.moe_held_pairs); batch-padding rows
         # are rows the expert layer computed, and are in the count
         outputs["moe_held_pairs"], outputs["moe_routed_layers"] = held_tally[0]
     if tensor_capture:

@@ -287,6 +287,17 @@ class _AutoLayoutProgram:
         return self._compiled(params, cache, batch)
 
 
+@jax.jit
+def decode_next_ids(prev_tokens, prev_rows, host_ids):
+    """``input_ids`` of a decode step whose previous step's tokens are still
+    on the device: row i takes ``prev_tokens[prev_rows[i]]``, or its
+    ``host_ids`` row where ``prev_rows[i] < 0`` (a row that joined since: its
+    last token came from its prefill's fetch). A program of its own ahead of
+    the token-generation program, whose name and time it must not carry."""
+    took = prev_tokens[jnp.maximum(prev_rows, 0), :1].astype(host_ids.dtype)
+    return jnp.where(prev_rows[:, None] >= 0, took, host_ids)
+
+
 #: Telemetry.phase of a wrapper with no (or disabled) telemetry
 _NO_PHASE = contextlib.nullcontext()
 
@@ -636,6 +647,11 @@ class ModelWrapper:
         batch's rows, the first b being the caller's. A slice on the device
         is one small program per b: a caller that walks through every row
         count (the engine's ramp) reads the first b rows on the host instead.
+        ``prev_tokens`` + ``prev_rows``: the ``tokens`` output of the previous
+        dispatch, left on the device with its batch padding, and per row the
+        row of it that holds this row's input id (``-1``: ``input_ids`` does);
+        see :func:`decode_next_ids`. Everything else of the batch depends on
+        counts, not on token values, and stays host-built.
         """
         tel = self.telemetry
         phase = self._phase
@@ -687,6 +703,9 @@ class ModelWrapper:
                 dtype=np.float32,
             )
             extra = self._layout_inputs(batch_np, b, s, pad_s, position_ids)
+            prev_rows = batch_np.get("prev_rows")
+            if prev_rows is not None:  # padded below like every other row input
+                extra["prev_rows"] = np.asarray(prev_rows, dtype=np.int32)
             if self.lora_enabled:
                 extra["adapter_ids"] = np.asarray(
                     batch_np.get("adapter_ids", np.zeros((b,))), dtype=np.int32
@@ -734,6 +753,11 @@ class ModelWrapper:
                 "sampling_params": jnp.asarray(sampling_params),
             }
             device_batch.update({k: jnp.asarray(v) for k, v in extra.items()})
+            if prev_rows is not None:
+                device_batch["input_ids"] = decode_next_ids(
+                    batch_np["prev_tokens"], device_batch.pop("prev_rows"),
+                    device_batch["input_ids"],
+                )
             if self.needs_rng:
                 rng = batch_np.get("rng")
                 if rng is None:

@@ -24,6 +24,15 @@ stack):
    for the next admission (the new request overwrites the line from
    position 0, so a dirty slot is safe by construction).
 
+The plain decode step is two halves, ``_dispatch_decode`` and
+``_collect_decode``. Where nothing needs a step's tokens on the host inside
+that step (``_may_chain``), the engine leaves the dispatched program in
+flight and, on the next step, dispatches step N+1 BEFORE it collects step N:
+the next step's ids stay on the device (``decode_next_ids``), and scheduling,
+packing, padding and the puts run beside the program instead of between two
+of them. Otherwise the same two halves run the other way round (collect,
+then dispatch), which is the order every step had before.
+
 Preemption: when the paged pool cannot grow a running decode, the
 scheduler evicts the youngest request back to WAITING (blocks freed); on
 re-admission the engine re-prefills ``prompt + generated`` and the CTE's
@@ -92,6 +101,22 @@ logger = logging.getLogger("nxdi_tpu")
 ENGINE_FAULT_PREFIX = "engine step failed"
 
 _NO_SPAN = contextlib.nullcontext()
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """One token-generation dispatch whose tokens are not on the host yet."""
+
+    #: (slot, request, the request's ``preemptions`` at dispatch): a row is
+    #: still its request's at collect only if the request is RUNNING and was
+    #: not requeued since
+    rows: List[Tuple[int, Request, int]]
+    outputs: dict  # on the device, batch padding kept; ``tokens`` already on its way
+    t0: float  # telemetry clock at dispatch (0.0 without telemetry)
+    record: object  # the dispatching step's StepRecord, or None
+    #: a row's token in flight is its last by ``max_new_tokens``: the next
+    #: step collects before it schedules
+    ends_a_row: bool
 
 
 class InferenceEngine:
@@ -282,6 +307,33 @@ class InferenceEngine:
         # first step whose program returns the count (none for other models)
         self._moe_held_pairs = self._moe_routed_layer_steps = self._moe_routed_layers = None
         self._can_continue_prefill = TAG_PREFIX_PREFILL in app.models
+        #: the decode dispatch not collected yet (None: nothing in flight)
+        self._inflight: Optional[_InFlight] = None
+        self._t_collected = 0.0  # telemetry clock of the last collect
+        # what of the configuration lets a dispatch stay in flight over the
+        # step boundary: the tokens are sampled on the device and are all the
+        # host reads of the step (no logits, captured tensors or logit_stats),
+        # and the plain decode step is the only decode path compiled
+        self._chain_compiled = (
+            tc.on_device_sampling_config is not None
+            and not tc.output_logits
+            and getattr(tc, "tensor_capture_config", None) is None
+            and not (self.mixed or self.device_loop)
+            and not getattr(app, "multistep_supported", False)
+        )
+        self._chained_steps = self._overrun_tokens = None
+        if tel is not None:
+            self._chained_steps = tel.registry.counter(
+                "nxdi_decode_chained_steps_total",
+                "decode steps dispatched before the previous step's tokens were collected",
+            )
+            self._overrun_tokens = tel.registry.counter(
+                "nxdi_decode_overrun_tokens_total",
+                "tokens dispatched for a row that had left its slot by their "
+                "collect (an EOS the host could not foresee), dropped unemitted",
+            )
+            self._chained_steps.inc(0)
+            self._overrun_tokens.inc(0)
         # n>1 sibling forks also start their tail prefill mid-prompt, so
         # the scheduler may only fork when a continuation path is compiled
         self.scheduler.can_fork = self.paged and (
@@ -589,6 +641,8 @@ class InferenceEngine:
 
     # -- the engine loop ----------------------------------------------------
     def has_work(self) -> bool:
+        if self._inflight is not None:
+            return True  # its tokens are some request's; a step collects them
         if self._handoffs:
             # a parked handoff waits on the ROUTER's ack, not on a step —
             # only unparked occupants and queued work keep the loop hot
@@ -676,6 +730,15 @@ class InferenceEngine:
         marker so the router fails THAT request over individually."""
         fc = self.fault_config
         clock = self.telemetry.clock if self.telemetry is not None else None
+        # a dispatch in flight: the tokens the device has made are emitted
+        # before their rows requeue (the replay starts after them); if the
+        # fault took them too they are dropped, and the replay of
+        # prompt + generated makes them again
+        try:
+            self._drain(finished)
+        except Exception as e:  # noqa: BLE001 — the fault being recovered
+            logger.warning("dropped the decode in flight: %s", e)
+            self._inflight = None
         victims = [r for r in self.scheduler.slots if r is not None]
         logger.warning(
             "engine step fault (%s), recovering %d running request(s): %s",
@@ -732,9 +795,17 @@ class InferenceEngine:
 
     def _step_split(self, finished: List[RequestOutput]) -> None:
         """The classic two-phase step: per-request prefill dispatches, then
-        one batched decode dispatch."""
+        one batched decode dispatch; the previous step's decode is collected
+        first or after it (``_may_chain``)."""
         phase = self._phase
         preempted: List[Request] = []
+        flight = self._inflight
+        if flight is not None and (flight.ends_a_row or not self._may_chain()):
+            # a finish by max_new_tokens is known before its token is here:
+            # collected first, it frees the slot and the blocks for this
+            # step's admission, and every dispatch has the rows and the rng
+            # draw it has in the synchronous order
+            self._drain(finished)
         with phase("schedule"):
             if self.role == "decode" and self.scheduler.waiting:
                 # a decode-role engine compiles no prefill program: anything
@@ -755,13 +826,14 @@ class InferenceEngine:
         for req in prefills:
             self._prefill_chunk(req, finished)
         with phase("schedule"):
-            rows = self.scheduler.decodable()
-            if self._handoffs and rows:
-                # parked prefill-role requests hold their slot/chain for
-                # export; they never join a decode batch
-                rows = [
-                    (s, r) for s, r in rows if r.request_id not in self._handoffs
-                ]
+            rows = self._decodable()
+        if rows and self._inflight is not None and not self._growth_fits(rows):
+            # the pool cannot grow these rows without a preemption, and a
+            # victim's replay starts from the tokens the host holds: collect
+            # first, then preempt as ever
+            self._drain(finished)
+            with phase("schedule"):
+                rows = self._decodable()
         if rows:
             with phase("kv"):
                 rows, preempted = self.scheduler.ensure_decode_capacity(rows)
@@ -781,12 +853,25 @@ class InferenceEngine:
                 self._decode_multistep(rows, steps, finished)
             else:
                 self._decode_single(rows, finished)
+        else:
+            self._drain(finished)  # nothing to dispatch ahead of it
         # a preemption-only step still made progress (the freed blocks are
         # what lets the NEXT step admit) — only a true no-op step may trip
         # the stall guard in run()
         self._progress = (
             bool(prefills) or bool(rows) or bool(preempted) or bool(finished)
+            or flight is not None
         )
+
+    def _decodable(self) -> List[Tuple[int, Request]]:
+        rows = self.scheduler.decodable()
+        if self._handoffs and rows:
+            # parked prefill-role requests hold their slot/chain for
+            # export; they never join a decode batch
+            rows = [
+                (s, r) for s, r in rows if r.request_id not in self._handoffs
+            ]
+        return rows
 
     def _step_mixed(self, finished: List[RequestOutput]) -> None:
         """One-dispatch mixed step: pack this step's prefill chunks and
@@ -1008,7 +1093,7 @@ class InferenceEngine:
         kept: List[Tuple[int, Request]] = []
         for slot, req in rows:
             try:
-                self._cow_for_write(req, req.total_len - 1, req.total_len)
+                self._cow_for_write(req, req.dispatched_len - 1, req.dispatched_len)
                 kept.append((slot, req))
             except RuntimeError:
                 logger.info(
@@ -1335,21 +1420,93 @@ class InferenceEngine:
         if self._tkg.needs_rng:
             kwargs["rng"] = self._rng.next()
 
+    def _may_chain(self) -> bool:
+        """Whether a decode dispatch may stay in flight over the step
+        boundary, from what the engine sees now; no option selects it. Not
+        when something reads the step's outputs on the host inside the step:
+        a synchronous dispatch (``detail="full"``, a profiler's post hooks),
+        an input snapshot, the numerics sentinel's ``logit_stats`` and
+        replays; and not where the configuration rules it out
+        (``_chain_compiled``)."""
+        if not self._chain_compiled or self.sentinel is not None:
+            return False
+        tkg, tel = self._tkg, self.telemetry
+        if tkg.post_hooks or tkg.snapshot_hook is not None:
+            return False
+        return not (tel is not None and tel.enabled and tel.sync_dispatch)
+
+    def _growth_fits(self, rows: List[Tuple[int, Request]]) -> bool:
+        """Whether the pool grows (and copies on write) every row's next
+        position with no preemption."""
+        mgr = self.block_manager
+        if mgr is None or mgr.num_free_blocks() >= len(rows):
+            return True  # a row takes one block at the most: a new one, or a copy
+        bs = mgr.block_size
+        need = 0
+        for _, r in rows:
+            new = mgr.blocks_needed(r.request_id, r.dispatched_len)
+            need += new
+            table = mgr._tables.get(r.request_id)
+            bi = (r.dispatched_len - 1) // bs
+            if not new and table and bi < len(table) and mgr._refs[table[bi]] > 1:
+                need += 1  # a shared block is copied before the write
+        return need <= mgr.num_free_blocks()
+
+    def _drain(self, finished: List[RequestOutput]) -> None:
+        """Collect the decode in flight, if any."""
+        flight, self._inflight = self._inflight, None
+        if flight is not None:
+            self._collect_decode(flight, finished)
+
     def _decode_single(
         self, rows: List[Tuple[int, Request]], finished: List[RequestOutput]
     ) -> None:
+        """The plain decode step: dispatch this step's program, collect the
+        previous step's tokens. With nothing left in flight (the chain is
+        not kept, or was drained earlier in the step) there is nothing to
+        collect after the dispatch; where the chain may not start either,
+        this step's own tokens are collected at once: the same two halves
+        in the synchronous order."""
+        prev = self._inflight
+        self._inflight = self._dispatch_decode(rows, prev)
+        if prev is not None:
+            self._collect_decode(prev, finished)
+        elif not self._may_chain():
+            self._drain(finished)
+
+    def _dispatch_decode(
+        self, rows: List[Tuple[int, Request]], prev: Optional[_InFlight]
+    ) -> _InFlight:
+        """Dispatch half: pack, pad, put and enqueue one token-generation
+        program for ``rows`` and start its tokens' copy to the host. A row
+        that is in ``prev`` (its token is dispatched, not yet emitted) takes
+        its input id from ``prev``'s tokens on the device; positions, block
+        table, slot mapping, sampling rows and rng depend on counts only
+        (``Request.dispatched_len``) and are host-built."""
         phase = self._phase
+        fl = self.flight
         with phase("pack"):
             B = len(rows)
-            ids = np.array([[r.generated[-1]] for _, r in rows], dtype=np.int32)
-            pos = np.array([[r.total_len - 1] for _, r in rows], dtype=np.int32)
-            kwargs = self._layout_kwargs(rows)
-            self._maybe_rng(kwargs)
+            batch = self._layout_kwargs(rows)
+            if prev is not None:
+                row_of = {id(r): i for i, (_, r, _) in enumerate(prev.rows)}
+                batch["prev_tokens"] = prev.outputs["tokens"]
+                batch["prev_rows"] = np.array(
+                    [row_of[id(r)] if r.pending else -1 for _, r in rows],
+                    dtype=np.int32,
+                )
+            ids = np.array(
+                [[0 if r.pending else r.generated[-1]] for _, r in rows],
+                dtype=np.int32,
+            )
+            pos = np.array([[r.dispatched_len - 1] for _, r in rows], dtype=np.int32)
+            self._maybe_rng(batch)
             last = np.zeros((B,), dtype=np.int32)
             sampling = SamplingParams.rows_tensor([r.params for _, r in rows])
-            if self.flight is not None:
-                self.flight.record_decode(
-                    TAG_TOKEN_GENERATION, 1, rows, self.tpu_config.tkg_batch_size
+            if fl is not None:
+                fl.record_decode(
+                    TAG_TOKEN_GENERATION, 1, rows, self.tpu_config.tkg_batch_size,
+                    chained=prev is not None,
                 )
         clock = self.telemetry.clock if self.telemetry is not None else None
         t0 = clock() if clock else 0.0
@@ -1357,31 +1514,77 @@ class InferenceEngine:
             TAG_TOKEN_GENERATION,
             lambda: self.app.forward(
                 ids, pos, last_token_index=last, sampling_params=sampling,
-                submodel=TAG_TOKEN_GENERATION, keep_batch_padding=True, **kwargs,
+                submodel=TAG_TOKEN_GENERATION, keep_batch_padding=True, **batch,
             ),
         )
+        for key in ("tokens", "moe_held_pairs"):
+            if key in out:  # the copies start now and ride behind the program
+                out[key].copy_to_host_async()
+        if prev is not None and self._chained_steps is not None:
+            self._chained_steps.inc()
+        for _, r in rows:
+            r.pending += 1
+        return _InFlight(
+            rows=[(slot, r, r.preemptions) for slot, r in rows],
+            outputs=out, t0=t0, record=fl.current if fl is not None else None,
+            ends_a_row=any(r.remaining <= 0 for _, r in rows),
+        )
+
+    def _collect_decode(
+        self, flight: _InFlight, finished: List[RequestOutput]
+    ) -> None:
+        """Collect half: wait for the flight's tokens, emit them, retire what
+        finished. A row whose request left its slot since the dispatch (it
+        finished on an EOS the host could not foresee, or was requeued) has
+        its token dropped: never emitted, never counted. Its KV write was
+        harmless: it landed in a block the request still owned at dispatch,
+        at the position after its last token, which no reader attends to (a
+        later owner of the block, or a follower that forks the cached chain,
+        reads a position only after writing it itself, and the prefix cache
+        holds the request's committed positions only); and the device runs
+        programs in order, so a block freed on the host at the finish and
+        handed to a later dispatch cannot be written under that dispatch. A
+        recurrent-state slot an overrun step advanced is rewritten by the
+        next admission's context-encoding pass, which computes the state from
+        its inputs alone (the families' ``is_decode=False`` branch)."""
+        phase = self._phase
+        out = flight.outputs
+        kept = self.flight is not None or self.telemetry is not None
         with phase("fetch"):
-            # read only for someone who keeps it
-            kept = self.flight is not None or self.telemetry is not None
+            toks = self._tokens_of(out)
             held = out.get("moe_held_pairs") if kept else None
             if held is not None:
-                held.copy_to_host_async()  # rides the tokens' fetch
-            toks = self._tokens_of(out)
+                if self._moe_routed_layers is None:  # the same number every step
+                    self._moe_routed_layers = int(out["moe_routed_layers"])
+                held = int(held)
         if held is not None:
-            if self._moe_routed_layers is None:  # the same number every step
-                self._moe_routed_layers = int(out["moe_routed_layers"])
-            self._note_moe_held_pairs(int(held), self._moe_routed_layers)
-        dt = (clock() - t0) if clock else None
+            self._note_moe_held_pairs(held, self._moe_routed_layers, flight.record)
+        clock = self.telemetry.clock if self.telemetry is not None else None
+        dt = None
+        if clock:
+            # a token's time: since its dispatch, or since the collect before
+            # it where that came later (the chain's period)
+            now = clock()
+            dt = now - max(flight.t0, self._t_collected)
+            self._t_collected = now
         with phase("emit"):
-            for (slot, req), tok in zip(rows, toks):
+            emitted = 0
+            for (slot, req, epoch), tok in zip(flight.rows, toks):
+                if req.state != RUNNING or req.preemptions != epoch:
+                    continue
+                req.pending -= 1
                 if req.span is not None:
                     req.span.tokens(1, dt)
                 req.emit(int(tok))
+                emitted += 1
                 reason = req.check_finish()
                 if reason:
                     self._finish(req, reason, finished)
+            overrun = len(flight.rows) - emitted
+            if overrun and self._overrun_tokens is not None:
+                self._overrun_tokens.inc(overrun)
             if self.flight is not None:
-                self.flight.note_decode_tokens(len(rows))
+                self.flight.note_decode_tokens(emitted, overrun, flight.record)
 
     def _decode_multistep(
         self,
@@ -1660,12 +1863,14 @@ class InferenceEngine:
             ),
         }
 
-    def _note_moe_held_pairs(self, pairs: int, routed_layers: int) -> None:
+    def _note_moe_held_pairs(self, pairs: int, routed_layers: int, record) -> None:
         """A share of an expert-parallel model counts, inside its
         token-generation program, the (row, expert) pairs routed to the
-        experts it holds (models/base.py causal_lm_forward)."""
+        experts it holds (models/base.py causal_lm_forward). ``record``: the
+        step that dispatched the program (the count arrives with its
+        collect, which may be a step later)."""
         if self.flight is not None:
-            self.flight.note_moe_held_pairs(pairs, routed_layers)
+            self.flight.note_moe_held_pairs(pairs, routed_layers, record)
         if self.telemetry is None:
             return
         if self._moe_held_pairs is None:

@@ -92,6 +92,13 @@ class Request:
 
         self.state = WAITING
         self.generated: List[int] = []
+        #: tokens dispatched for this request and not yet emitted: a decode
+        #: program the engine has enqueued and not collected (at most one
+        #: between steps). The request's next position and block growth
+        #: (``dispatched_len``) and its budget (``remaining``) count them;
+        #: leaving the slot (retire, preempt) zeroes it, and the engine drops
+        #: such a token at its collect
+        self.pending = 0
         #: committed tokens of the (re)prefill replay (chunked-prefill
         #: progress); complete when it reaches ``prefill_target``, which the
         #: scheduler pins to ``len(seq_tokens)`` at placement time (the
@@ -148,8 +155,14 @@ class Request:
         return len(self.prompt) + len(self.generated)
 
     @property
+    def dispatched_len(self) -> int:
+        """``total_len`` with the tokens in flight counted: the next decode
+        feeds (and writes the KV of) position ``dispatched_len - 1``."""
+        return self.total_len + self.pending
+
+    @property
     def remaining(self) -> int:
-        return self.params.max_new_tokens - len(self.generated)
+        return self.params.max_new_tokens - len(self.generated) - self.pending
 
     @property
     def prefill_done(self) -> bool:

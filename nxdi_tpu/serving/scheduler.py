@@ -462,7 +462,7 @@ class Scheduler:
         self, rows: List[Tuple[int, Request]]
     ) -> Tuple[List[Tuple[int, Request]], List[Request]]:
         """Grow each row's block table to cover its next KV write (the fed
-        token's position = ``total_len - 1``). On pool exhaustion one running
+        token's position = ``dispatched_len - 1``). On pool exhaustion one running
         request is preempted per ``preempt_policy`` (possibly a row in
         ``rows``, possibly the grower itself) and growth retries — oldest
         requests are processed first, so under the youngest/FCFS tie-break
@@ -474,7 +474,7 @@ class Scheduler:
         for slot, req in sorted(rows, key=lambda sr: sr[1]._admit_seq):
             while req.state == RUNNING:  # may flip if evicted as a victim
                 try:
-                    self.block_manager.ensure_capacity(req.request_id, req.total_len)
+                    self.block_manager.ensure_capacity(req.request_id, req.dispatched_len)
                     kept.append((slot, req))
                     break
                 except RuntimeError:
@@ -587,6 +587,7 @@ class Scheduler:
         self.slots[req.slot] = None
         req.slot = None
         req.state = PREEMPTED
+        req.pending = 0  # an in-flight token is dropped at its collect; the replay makes it again
         if self.block_manager is not None:
             # the victim's committed blocks enter the cache instead of
             # dropping: its recompute-resume (and any shared-prompt peer)
@@ -614,6 +615,7 @@ class Scheduler:
         if req.slot is not None:
             self.slots[req.slot] = None
             req.slot = None
+        req.pending = 0  # a token dispatched past the finish is dropped at its collect
         if self.block_manager is not None:
             if reason != "error":
                 self._cache_insert(req)

@@ -17,9 +17,11 @@ ring buffer, carrying
   the work happens, on ``Telemetry.clock``; ``other_s = wall - sum(phases)``
   is the time under no phase, so nothing is lost silently. ``fetch`` is the
   one phase in which the host waits for the device's tokens, so
-  ``host_s = wall - phases["fetch"]`` is the time this engine kept the
-  device without work to wait for — what overlapping the host with the
-  device (ROADMAP D1) can win back. ``dispatch_s`` is the wrapper's own
+  ``host_s = wall - phases["fetch"]`` is the host's own work in the step.
+  On a step in the synchronous order the device has nothing to do
+  meanwhile; on a ``chained`` step (its decode dispatched before the
+  previous step's tokens were collected: serving/engine.py) that work runs
+  beside the previous program. ``dispatch_s`` is the wrapper's own
   ``pad`` + ``enqueue`` time per program dispatch (the
   ``nxdi_dispatch_seconds`` path feeds it via ``Telemetry.record_dispatch``,
   ONE timing source): at ``detail="basic"`` the time to hand the device its
@@ -73,6 +75,7 @@ class StepRecord:
         "mixed", "preempted", "retired", "programs", "kv_blocks_free",
         "queue_depth", "slots_busy", "dispatch_s", "host_s", "faults",
         "phases", "open_phase", "moe_held_pairs", "moe_routed_layers",
+        "chained", "overrun_tokens",
     )
 
     def __init__(self, step: int, t_start: float):
@@ -120,6 +123,12 @@ class StepRecord:
         self.moe_held_pairs: Optional[int] = None
         #: the routed layers those pairs were summed over
         self.moe_routed_layers: Optional[int] = None
+        #: this step's decode was dispatched before the previous step's
+        #: tokens were collected (its input ids never left the device)
+        self.chained = False
+        #: tokens this step's decode made for rows that had left their slot
+        #: by the collect (an unforeseen EOS), dropped unemitted
+        self.overrun_tokens = 0
 
     @property
     def wall_s(self) -> float:
@@ -163,6 +172,8 @@ class StepRecord:
             "slots_busy": self.slots_busy,
             "moe_held_pairs": self.moe_held_pairs,
             "moe_routed_layers": self.moe_routed_layers,
+            "chained": self.chained,
+            "overrun_tokens": self.overrun_tokens,
         }
 
 
@@ -305,8 +316,10 @@ class FlightRecorder:
         rows,
         batch: int,
         tokens_emitted: Optional[int] = None,
+        chained: bool = False,
     ) -> None:
         if self.current is not None:
+            self.current.chained = chained
             self.current.decode = {
                 "submodel": submodel,
                 "steps": steps,
@@ -323,15 +336,18 @@ class FlightRecorder:
                 "tokens_emitted": tokens_emitted,
             }
 
-    def note_decode_tokens(self, tokens: int) -> None:
-        """Fill the open step's decode record with the real emitted-token
-        count once the host has unpacked the dispatch."""
-        rec = self.current
+    def note_decode_tokens(self, tokens: int, overrun: int = 0, rec=None) -> None:
+        """Fill a step's decode record with the real emitted-token count once
+        the host has unpacked the dispatch: the open step's, or ``rec``, the
+        step that dispatched it (a chained decode is collected a step later,
+        its record already in the ring)."""
+        rec = rec or self.current
         if rec is not None and rec.decode is not None:
             rec.decode["tokens_emitted"] = int(tokens)
+            rec.overrun_tokens = int(overrun)
 
-    def note_moe_held_pairs(self, pairs: int, routed_layers: int) -> None:
-        rec = self.current
+    def note_moe_held_pairs(self, pairs: int, routed_layers: int, rec=None) -> None:
+        rec = rec or self.current
         if rec is not None:
             rec.moe_held_pairs = (rec.moe_held_pairs or 0) + int(pairs)
             rec.moe_routed_layers = int(routed_layers)

@@ -335,3 +335,53 @@ def test_flightrec_cli_end_to_end(tmp_path, capsys):
     assert main(["--inspect", str(breach_files[0])]) == 0
     inspected = capsys.readouterr().out
     assert "trigger:   slo_breach" in inspected
+
+
+# ---------------------------------------------------------------------------
+# the decode chain in the journal (ISSUE 33)
+# ---------------------------------------------------------------------------
+
+def test_chained_steps_and_overrun_tokens_are_journalled_and_rendered(
+    tiny_hf_llama, capsys
+):
+    """``StepRecord.chained`` / ``overrun_tokens`` ride ``to_dict`` (so every
+    bundle and the JSON timeline), the two registry counters agree with the
+    records, and ``cli.flightrec``'s table marks both."""
+    from nxdi_tpu.cli.flightrec import _print_timeline
+
+    hf_model, hf_cfg = tiny_hf_llama
+    app = _build_app(hf_model, hf_cfg, telemetry="basic")
+    probe = InferenceEngine(app, SchedulerConfig(num_slots=2))
+    probe.add_request(P1, SamplingParams(max_new_tokens=10))
+    free = probe.run()[0].token_ids
+    j = next(j for j in range(2, len(free)) if free[j] not in free[:j])
+    reg = app.telemetry.registry
+    chained0 = reg.get("nxdi_decode_chained_steps_total").value()
+
+    engine = InferenceEngine(app, SchedulerConfig(num_slots=2))
+    engine.add_request(P0, SamplingParams(max_new_tokens=12))
+    engine.add_request(P1, SamplingParams(max_new_tokens=10, eos_token_ids=(free[j],)))
+    outs = engine.run()
+    assert sorted(o.finish_reason for o in outs) == ["eos", "length"]
+
+    records = engine.flight.snapshot_records()
+    dicts = [r.to_dict() for r in records]
+    json.dumps(dicts)
+    chained = [d for d in dicts if d["chained"]]
+    assert chained and all(d["decode"] is not None for d in chained)
+    assert sum(d["overrun_tokens"] for d in dicts) == 1
+    assert reg.get("nxdi_decode_chained_steps_total").value() - chained0 == len(chained)
+    assert reg.get("nxdi_decode_overrun_tokens_total").value() == 1
+    # every decode record knows what its own dispatch emitted, though the
+    # count arrived a step later
+    for d in dicts:
+        if d["decode"] is not None:
+            assert d["decode"]["tokens_emitted"] == len(d["decode"]["rows"]) - d["overrun_tokens"]
+    # phases still cover each step with no instant under two of them
+    for r in records:
+        assert r.other_s > -1e-9 and r.host_s == pytest.approx(r.wall_s - r.phases.get("fetch", 0.0))
+    _print_timeline(dicts, 50)
+    table = capsys.readouterr().out
+    assert table.count(" chained") == len(chained) and " overrun=1" in table
+    prom = app.telemetry.prometheus_text()
+    assert "nxdi_decode_chained_steps_total" in prom and "nxdi_decode_overrun_tokens_total" in prom

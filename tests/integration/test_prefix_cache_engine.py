@@ -328,3 +328,55 @@ def test_prefix_cache_requires_paged_layout(tiny_hf_llama):
     )
     with pytest.raises(ValueError, match="paged"):
         InferenceEngine(app, SchedulerConfig(num_slots=2, prefix_cache=True))
+
+
+def test_a_follower_of_a_chain_cached_past_an_overrun_gets_the_same_tokens(tiny_hf_llama):
+    """ISSUE 33: a request that ends on an EOS the host could not foresee has
+    one more decode in flight; that step wrote KV at the position after its
+    last token, in its own block, and only its COMMITTED positions entered the
+    radix tree at retirement. A follower whose prompt is the finished
+    sequence and more forks that chain and must produce what it produces on
+    an engine without the cache."""
+    hf_model, hf_cfg = tiny_hf_llama
+
+    def run(cache_on):
+        # both apps compile the prefix-prefill program (one decode window); the
+        # radix tree is on in one engine only
+        app, eng = _paged_engine(
+            hf_model, hf_cfg, cache_on,
+            app_kw=dict(pa_block_size=4, pa_num_blocks=48, is_prefix_caching=True,
+                        max_context_length=48),
+        )
+        free = eng.add_request(PROMPTS[0], SamplingParams(max_new_tokens=14))
+        toks = eng.run()[0].token_ids
+        # an EOS after the 9th new token or later: >= 5 full blocks of 4 committed
+        j = next(j for j in range(8, len(toks)) if toks[j] not in toks[:j])
+        eng2 = InferenceEngine(
+            app, SchedulerConfig(num_slots=3, prefix_cache=cache_on)
+        )
+        other = eng2.add_request(PROMPTS[1], SamplingParams(max_new_tokens=20))
+        first = eng2.add_request(
+            PROMPTS[0], SamplingParams(max_new_tokens=14, eos_token_ids=(toks[j],))
+        )
+        outs = []
+        while first.state != "FINISHED":
+            outs += eng2.step()
+        # learnt of the EOS with the row dispatched once more
+        assert any(r is first for _, r, _ in eng2._inflight.rows)
+        follower = eng2.add_request(
+            PROMPTS[0] + first.generated + [9, 4, 21], SamplingParams(max_new_tokens=8)
+        )
+        outs += eng2.run()
+        got = {o.request_id: o for o in outs}
+        assert got[first.request_id].finish_reason == "eos"
+        assert got[first.request_id].token_ids == toks[: j + 1]
+        assert free.request_id != first.request_id
+        return ([got[r.request_id].token_ids for r in (other, first, follower)], eng2, app)
+
+    off, _, _ = run(False)
+    on, eng, app = run(True)
+    assert on == off
+    pc = eng.scheduler.prefix_cache
+    assert pc.hits_n >= 1 and pc.tokens_saved_n >= 20, "the follower forked the finished chain"
+    assert app.telemetry.registry.get("nxdi_decode_overrun_tokens_total").value() == 1
+    assert eng.block_manager.num_free_blocks() == 48  # cached blocks are reclaimable
