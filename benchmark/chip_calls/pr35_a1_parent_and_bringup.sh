@@ -1,0 +1,15 @@
+#!/bin/bash
+# PR 35, call a1 (one chip): chiprun --chips 1 --timeout 1800 -- bash benchmark/chip_calls/pr35_a1_parent_and_bringup.sh
+# (1) the PARENT (git archive of 3664d80 in _checkout/parent, this PR's benchmark files laid over it, as the
+# driver does) asked for the new cell: it must fail at once, not hang; (2) the new cell once, TRACED, from the
+# working tree: does it come up, what does it read.
+out=$PWD/chiprun_out/pr35/${TAG:-a1}; mkdir -p $out
+cell=mimo-v2-flash-ep16.longctx-saturated
+seed=${SEED:-2147484301}
+( cd _checkout/parent && t=$(date +%s) && timeout 300 python3 benchmark/run.py --workload $cell --seed $seed --seconds 51 --trace 0 \
+    > $out/parent.out 2> $out/parent.err; echo "PARENT on $cell rc=$? after $(( $(date +%s) - t )) s: $(tail -2 $out/parent.err | cut -c1-300 | tr '\n' ' ')" )
+t=$(date +%s)
+python3 benchmark/run.py --workload $cell --seed $seed --seconds ${SECONDS_:-51} --trace ${TRACE:-1} > $out/run.out 2> $out/run.err
+echo "run.py $cell rc=$? after $(( $(date +%s) - t )) s: $(tail -1 $out/run.out | cut -c1-6000)"
+grep -v "^\[bench.*request" $out/run.out | grep "gap mode\|per_layer\|end_to_end\|compil\|memory\|strateg\|setup\|correct" | head -80 | cut -c1-400
+tail -30 $out/run.err | cut -c1-400

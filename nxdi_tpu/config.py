@@ -1282,16 +1282,28 @@ class TpuConfig:
                     f"exceed seq_len ({self.seq_len}) — the ring layout would "
                     "address slots the cache does not have"
                 )
+            if self.is_block_kv_layout:
+                # under the block layout a window layer's rows are no option:
+                # a family whose architecture has window layers beside full
+                # ones keeps them as ring rows a SLOT beside the pool by itself
+                # (models/mimo_v2); a ring the user sizes is the contiguous
+                # layout's
+                raise ValueError(
+                    "window_sized_kv is a user-set ring of the contiguous "
+                    "layout; under is_block_kv_layout leave it off: a family "
+                    "with window layers beside full ones keeps their rows per "
+                    "slot beside the block pool by itself, and a model whose "
+                    "every layer is a window layer has no paged ring yet"
+                )
             if (
-                self.is_block_kv_layout
-                or self.is_medusa
+                self.is_medusa
                 or self.is_prefix_caching
                 or self.is_chunked_prefill
                 or self.flash_decoding_enabled
             ):
                 raise ValueError(
                     "window_sized_kv composes with contiguous decode (and "
-                    "linear speculation) only: paged/medusa/prefix modes "
+                    "linear speculation) only: medusa/prefix modes "
                     "assume position-addressed cache slots, which the ring "
                     "layout does not provide"
                 )

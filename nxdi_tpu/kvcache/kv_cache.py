@@ -116,6 +116,10 @@ class BlockKVCacheSpec:
     # the MLA latent pool: ``k`` rows are the rotated rope key (padded to a
     # lane tile, ops/mla.py), ``v`` rows the normed latent; None = same as k
     v_head_dim: Optional[int] = None
+    # lane tiles a key row is kept as, each a pool row of ``head_dim`` in a
+    # layer of its own (BlockKVLayout, KEY TILES): the key pool has
+    # ``key_tiles * num_layers`` layers, the value pool ``num_layers``
+    key_tiles: int = 1
 
     @property
     def store_dtype(self):
@@ -135,12 +139,13 @@ class BlockKVCacheSpec:
 
     @property
     def shape(self) -> Tuple[int, ...]:
-        return (self.num_layers, self.total_slots, self.num_kv_heads, self.head_dim)
+        layers = self.key_tiles * self.num_layers
+        return (layers, self.total_slots, self.num_kv_heads, self.head_dim)
 
     @property
     def shape_v(self) -> Tuple[int, ...]:
         d = self.v_head_dim if self.v_head_dim is not None else self.head_dim
-        return self.shape[:-1] + (d,)
+        return (self.num_layers,) + self.shape[1:-1] + (d,)
 
 
 def init_block_kv_cache(spec: BlockKVCacheSpec) -> Dict[str, jax.Array]:
@@ -408,11 +413,24 @@ class BlockKVLayout:
     layer from ``cache_inputs["layer_idx"]``: the pool is one buffer that a
     step program writes at ``(layer, slot)`` in place and reads at
     ``(layer, slot)``; no per-layer slice of it is ever taken (a slice riding
-    the layer scan as xs/ys costs whole-pool copies every step)."""
+    the layer scan as xs/ys costs whole-pool copies every step).
+
+    KEY TILES: a key row wider than one 128-lane tile (mimo-v2's 192) is kept
+    zero-padded as ``n`` whole lane tiles, one tile a pool row: the key pool
+    is (n * L, slots, KV, 128) beside a value pool of L layers, tile ``j`` of
+    layer ``l`` at pool layer ``j * L + l``. (As (L, slots, KV, n * 128) XLA
+    tiles the pool by (KV, 128), and the paged kernel's (slots * KV, width)
+    view of it is then a pool-sized copy every step, not a bitcast.) ``n`` is
+    read from the two pools' layer counts; the caller pads keys and queries
+    to ``n * 128``."""
 
     block_size: int
     k_scale: float = 1.0  # scaled fp8 store, see ContiguousKVLayout
     v_scale: float = 1.0
+
+    @staticmethod
+    def key_tiles(k_pool, v_pool) -> int:
+        return k_pool.shape[0] // v_pool.shape[0]
 
     def update(self, k_pool, v_pool, k_new, v_new, cache_inputs, spec):
         # k_new (B, KV, S_act, D); slot_mapping (B, S_act) flat slot per token
@@ -426,9 +444,17 @@ class BlockKVLayout:
             v_new = v_new / jnp.asarray(self.v_scale, v_new.dtype)
         k_vals = jnp.swapaxes(k_new, 1, 2).astype(store)  # (B, S_act, KV, D)
         v_vals = jnp.swapaxes(v_new, 1, 2).astype(store)
-        k_pool = k_pool.at[layer, slots].set(
-            k_vals.reshape((-1,) + k_vals.shape[-2:]), mode="drop"
-        )
+        n = self.key_tiles(k_pool, v_pool)
+        if n > 1:  # tile j of the row to pool layer j * L + layer
+            rows = k_vals.reshape(-1, k_vals.shape[-2], n, k_pool.shape[-1])
+            layers = layer + v_pool.shape[0] * jnp.arange(n, dtype=jnp.int32)
+            k_pool = k_pool.at[layers[:, None], slots[None, :]].set(
+                jnp.moveaxis(rows, 2, 0), mode="drop"
+            )
+        else:
+            k_pool = k_pool.at[layer, slots].set(
+                k_vals.reshape((-1,) + k_vals.shape[-2:]), mode="drop"
+            )
         v_pool = v_pool.at[layer, slots].set(
             v_vals.reshape((-1,) + v_vals.shape[-2:]), mode="drop"
         )
@@ -444,7 +470,10 @@ class BlockKVLayout:
         # holes (negative table entries) clip onto slot 0; kv_pos hides them
         slots = jnp.clip(slots, 0, k_pool.shape[1] - 1)
         compute = spec.compute_dtype
-        kk = k_pool[layer, slots].astype(compute)  # (B, W, KV, D)
+        n, L = self.key_tiles(k_pool, v_pool), v_pool.shape[0]
+        kk = jnp.concatenate(  # (B, W, KV, D): a row's lane tiles side by side
+            [k_pool[layer + j * L, slots] for j in range(n)], axis=-1
+        ).astype(compute)
         vv = v_pool[layer, slots].astype(compute)
         if self.k_scale != 1.0:
             kk = kk * jnp.asarray(self.k_scale, compute)

@@ -144,6 +144,9 @@ class DecoderArch:
     embed_scale: Optional[float] = None
     # gpt-oss style learned attention-sink logits (params: attn["sink"] (H,))
     attention_sink: bool = False
+    # mimo-v2: v = v_proj(x) * attention_value_scale, in the graph (a converter
+    # could fold it into v_proj, but weights that never pass one must not need it)
+    attention_value_scale: Optional[float] = None
     # gemma3-vision: prefill image-token spans attend each other
     # bidirectionally (HF token_type_ids_mask_function); needs image_token_id
     bidirectional_image_attention: bool = False
@@ -518,6 +521,9 @@ def attention_block(
         q = q.reshape(B, S, H, D)
         k = k.reshape(B, S, KV, D)
         v = v.reshape(B, S, KV, Dv)
+        if arch.attention_value_scale is not None:
+            # mimo-v2: the values are scaled before they are cached or attended
+            v = (v.astype(jnp.float32) * arch.attention_value_scale).astype(v.dtype)
 
         if arch.qk_norm:
             q = _norm(arch, q, p_attn["q_norm"])
@@ -576,6 +582,20 @@ def attention_block(
             )[:, None, :, None]
             q = jnp.where(use_rope, q, (q * scales).astype(q.dtype))
 
+    k_store = k
+    if isinstance(layout, BlockKVLayout) and arch.mla is None:
+        stored = layout.key_tiles(k_cache_l, v_cache_l) * k_cache_l.shape[-1]
+        if stored > D:
+            # the pool keeps a key row zero-padded to whole lane tiles
+            # (kvcache BlockKVLayout, KEY TILES: 192 -> 2 x 128): the fresh
+            # keys are padded on their way in, and the queries that attend
+            # the pool alike, so the scores are the D-wide ones (the
+            # architecture states its scale: D ** -0.5, not the padded width's)
+            lanes = ((0, 0), (0, 0), (0, 0), (0, stored - D))
+            k_store = jnp.pad(k, lanes)
+            if attend_to_cache:
+                q, k = jnp.pad(q, lanes), k_store
+
     ci = dict(cache_inputs or {})
     ci["position_ids"] = position_ids
     if layer_idx is not None:
@@ -596,6 +616,7 @@ def attention_block(
         layer_flags=(window_enabled is not None, use_rope is not None),
         stacked=tkg_stacked is not None and stacked_layer_idx is not None,
         spec_window=spec_window is not None,
+        v_cache=v_cache_l if isinstance(layout, BlockKVLayout) else None,
     )
     name = attn_select.select(site)
 
@@ -617,11 +638,14 @@ def attention_block(
         k_read, v_read, written = k_cache_l, v_cache_l, (k, v)
     else:
         with jax.named_scope("kv.write"):
-            written = layout.update(k_cache_l, v_cache_l, k, v, ci, cache_spec)
+            written = layout.update(k_cache_l, v_cache_l, k_store, v, ci, cache_spec)
         k_read, v_read = written
 
     # -- one core per strategy name, each (B, H, S, Dv)
-    sink = p_attn.get("sink") if arch.attention_sink else None
+    sink = None
+    if arch.attention_sink:
+        with jax.named_scope("attn.sink"):
+            sink = p_attn["sink"].astype(jnp.float32)
     # the kernels take the static window and chunk; ops/attention.py every term
     static_terms = dict(
         scale=arch.attention_scale,
@@ -744,7 +768,8 @@ def attention_block(
     def flat_kernel():
         kk, vv, kv_pos = attended()
         return attn_kernels.sharded_kernel_call(
-            policy, q, kk, vv, position_ids, kv_pos, decode=attend_to_cache, **static_terms
+            policy, q, kk, vv, position_ids, kv_pos, decode=attend_to_cache, **static_terms,
+            sink=None if attend_to_cache else sink,  # the table: the prefill kernel's term
         )
 
     def positions_xla():
@@ -1373,13 +1398,24 @@ def run_decoder_layers(
     layer_replacements: Optional[Tuple[jax.Array, jax.Array]] = None,
     spec_window_inputs: Optional[Tuple[jax.Array, jax.Array]] = None,
     moe_held_tally: Optional[list] = None,
+    first_layer: Optional[int] = None,
 ):
     """Scan the layer stack.
 
-    ``moe_held_tally`` (paged pool only): a list that gains ONE int32 pair:
-    the (row, expert) pairs of this forward that fell on held experts, summed
-    over the routed layers, and the number of routed layers — it rides the
-    scan's carry beside the pool.
+    ``moe_held_tally``: a list that gains ONE int32 pair: the (row, expert)
+    pairs of this forward that fell on held experts, summed over the routed
+    layers, and the number of routed layers — it rides the scan's carry
+    (beside the pool, where the pool is carried).
+
+    ``first_layer``: the segments are layers ``first_layer ..`` of a stack
+    that ``cache`` holds WHOLE and that other calls walk too (mimo-v2: two
+    kinds of layer interleaved in depth, each kind with a stack of its own).
+    The paged pool is addressed from that layer on, in place as ever. Any
+    other stack (ring rows a slot) is read in place at the layer's index and
+    NOT written here: the call attends cache plus fresh rows and hands back
+    ``{"k_rows", "v_rows"}``, the layers' fresh rows, for the caller's ONE
+    commit over the whole stack after the last call (a slice of the stack as
+    the scan's xs, or a per-layer write as its ys, would copy the stack).
 
     Where the cache rides: the paged pool (``BlockKVLayout``) is the scan's
     CARRY beside the hidden state — every layer writes its rows at
@@ -1449,7 +1485,13 @@ def run_decoder_layers(
     # rows; they commit in ONE scatter on the stacked cache below — carrying
     # full cache slices through the scan as ys round-trips the whole cache
     # per layer (measured ~6x the pure-attention cost on v5e)
-    defer = attn_select.defers(stack_site)
+    shared = first_layer is not None and not paged
+    if shared and not attend_to_cache:
+        raise NotImplementedError(
+            "a shared per-slot stack is read here, never written: its prefill "
+            "rows are the caller's to commit"
+        )
+    defer = attn_select.defers(stack_site) or shared
     # stacked-cache fused TKG kernel (round-4): the kernel reads the OLD cache
     # from the full stack via scalar-prefetched layer index, so the scan's
     # per-layer cache slices are never pallas operands (round-3's slice-copy
@@ -1586,9 +1628,9 @@ def run_decoder_layers(
 
     ks, vs, hs = [], [], []
     k_pool, v_pool = (cache["k"], cache["v"]) if paged else (None, None)
-    count_held = paged and moe_held_tally is not None
+    count_held = moe_held_tally is not None
     held_pairs = jnp.zeros((2,), jnp.int32)  # [held pairs, routed layers]
-    off = 0
+    off = first_layer or 0
     for seg in segments:
         # kernel-stacked weights: keep the big MLP/QKV weights OUT of the
         # scanned xs (a pallas operand on a scan slice materializes a full
@@ -1608,12 +1650,15 @@ def run_decoder_layers(
             lp, kl, vl, ksp, vsp, inj, li, repl = xs
             held = layer_tally = None
             if count_held:
-                h, kl, vl, held = carry
+                carry, held = carry
                 layer_tally = []
-            elif paged:
+            if paged:
                 h, kl, vl = carry  # the whole pool, addressed at ``li``
             else:
                 h = carry
+            if shared:  # the layer's rows of the whole stack, read in place
+                kl = jax.lax.dynamic_index_in_dim(cache["k"], li, 0, keepdims=False)
+                vl = jax.lax.dynamic_index_in_dim(cache["v"], li, 0, keepdims=False)
             li_local = li - jnp.int32(seg_off)
             spec_win = None
             if ksp is not None:
@@ -1629,17 +1674,19 @@ def run_decoder_layers(
             if repl is not None:
                 rv, rm = repl
                 h = jnp.where(rm > 0, rv.astype(h.dtype), h)
+            if paged:
+                out, ys = (h, nk, nv), (h if collect_hidden else None)
+            else:
+                out, ys = h, ((nk, nv, h) if collect_hidden else (nk, nv))
             if count_held:
                 held = held + jnp.stack(
                     [sum(layer_tally, jnp.int32(0)), jnp.int32(len(layer_tally))]
                 )
-                return (h, nk, nv, held), (h if collect_hidden else None)
-            if paged:
-                return (h, nk, nv), (h if collect_hidden else None)
-            return h, ((nk, nv, h) if collect_hidden else (nk, nv))
+                out = (out, held)
+            return out, ys
 
         k_seg = v_seg = None
-        if not paged:
+        if not paged and not shared:
             with jax.named_scope("layers"):
                 k_seg = jax.lax.slice_in_dim(cache["k"], off, off + n_seg, axis=0)
                 v_seg = jax.lax.slice_in_dim(cache["v"], off, off + n_seg, axis=0)
@@ -1667,16 +1714,14 @@ def run_decoder_layers(
         xs = (seg, k_seg, v_seg, ksp_seg, vsp_seg, inj_seg,
               off + jnp.arange(n_seg, dtype=jnp.int32), repl_seg)
         with jax.named_scope("layers"):
+            carry = (hidden, k_pool, v_pool) if paged else hidden
+            carry, ys = jax.lax.scan(body, (carry, held_pairs) if count_held else carry, xs)
             if count_held:
-                (hidden, k_pool, v_pool, held_pairs), seg_h = jax.lax.scan(
-                    body, (hidden, k_pool, v_pool, held_pairs), xs
-                )
-            elif paged:
-                (hidden, k_pool, v_pool), seg_h = jax.lax.scan(
-                    body, (hidden, k_pool, v_pool), xs
-                )
+                carry, held_pairs = carry
+            if paged:
+                (hidden, k_pool, v_pool), seg_h = carry, ys
             else:
-                hidden, ys = jax.lax.scan(body, hidden, xs)
+                hidden = carry
         off += n_seg
         if paged:
             hs.append(seg_h)
@@ -1689,6 +1734,8 @@ def run_decoder_layers(
         moe_held_tally.append(held_pairs)
     if paged:
         new_cache = {"k": k_pool, "v": v_pool}
+    elif shared:
+        new_cache = {"k_rows": cat(ks), "v_rows": cat(vs)}
     elif spec_mode:
         # full cache untouched; the scratch stacks carry this step's rows and
         # the whole window commits once, after the draft scan (fused.py)

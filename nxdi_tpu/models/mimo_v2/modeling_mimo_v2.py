@@ -12,9 +12,29 @@ pieces and how they land here:
   - partial rotary (partial_rotary_factor, even-rounded) per type ->
     DecoderArch.rotary_dim.
   - moe_layer_freq: per-layer MoE or dense MLP (:888) — segments also split
-    on the ff-type boundary; sigmoid router, renormalized top-k.
+    on the ff-type boundary; sigmoid router, renormalized top-k, chosen over
+    ``scores + e_score_correction_bias`` (``topk_method: noaux_tc``).
+  - ``add_swa_attention_sink_bias``: the window layers' softmax has one more
+    column a head, a learned logit whose probability is dropped
+    (``DecoderArch.attention_sink`` on the window arch alone);
+    ``attention_value_scale``: ``v = v_proj(x) * scale``, in the graph.
+  - the three multi-token-prediction layers are no part of the served pass.
 
-HF weight layout: llama-style attention; router ``mlp.gate``; experts
+One chip's share of an expert-parallel deployment (``n_routed_experts_total``
+in the config): the router keeps its published width, this chip's tree holds
+``n_routed_experts`` experts from ``first_routed_expert`` on (ops/moe.py
+``MoEArch.held_experts``, as models/deepseek reads the same keys).
+
+Under the block KV layout the two kinds of layer keep two kinds of cache: the
+full layers' rows in the block pool (``k``/``v``: by block table, keys
+zero-padded to whole lane tiles, one tile a pool row), the window layers' in ``k_swa``/``v_swa``, ring
+rows a SLOT (``sliding_window`` rounded up to a lane tile), which no block
+table addresses: a window layer never needs more, so it needs no allocator
+and no release rule. ``causal_lm_forward`` walks the segments with each kind's
+cache WHOLE (``run_decoder_layers(first_layer=)``).
+
+HF weight layout: llama-style attention (+ ``self_attn.attention_sink_bias``);
+router ``mlp.gate`` (+ ``e_score_correction_bias``); experts
 ``mlp.experts.{i}.gate/up/down_proj``; dense layers ``mlp.gate/up/down_proj``.
 """
 
@@ -29,7 +49,9 @@ import numpy as np
 from nxdi_tpu.config import InferenceConfig
 from nxdi_tpu.models import dense
 from nxdi_tpu.models.base import DecoderArch
-from nxdi_tpu.ops.moe import MoEArch, convert_hf_experts, moe_parallel_fields
+from nxdi_tpu.ops.moe import (
+    MoEArch, convert_hf_experts, moe_parallel_fields, moe_shape_struct,
+)
 from nxdi_tpu.ops.rope import inv_freq_from_hf_config
 from nxdi_tpu.parallel import gqa
 
@@ -46,6 +68,12 @@ class MiMoV2InferenceConfig(dense.DenseInferenceConfig):
     ]
 
     def add_derived_config(self):
+        # the published lists cover all 48 layers: a cut in depth reads a prefix
+        n = self.num_hidden_layers
+        for key in ("hybrid_layer_pattern", "moe_layer_freq"):
+            if len(getattr(self, key)) < n:
+                raise ValueError(f"{key} has {len(getattr(self, key))} entries for {n} layers")
+            setattr(self, key, list(getattr(self, key))[:n])
         if not hasattr(self, "rms_norm_eps"):
             self.rms_norm_eps = getattr(self, "layernorm_epsilon", 1e-6)
         if not hasattr(self, "intermediate_size"):
@@ -75,6 +103,13 @@ class MiMoV2Arch:
     schedule: Tuple[Tuple[str, int, int, int], ...]
     swa_theta: float
 
+    #: under the block layout the window layers keep ring rows a SLOT beside
+    #: the pool, in these cache leaves: their programs' batches carry the
+    #: rows' slot ids (runtime/model_wrapper.py ``per_slot_cache``,
+    #: serving/engine.py ``_layout_kwargs``) and every program takes the
+    #: leaves in one memory layout (``_pinned_cache_layouts``)
+    ring_cache_keys = ("k_swa", "v_swa")
+
     # the app sizes the FULL-type cache through the usual path
     def kv_cache_spec(self, batch_size, max_len, quant_dtype=None):
         return self.full.kv_cache_spec(batch_size, max_len, quant_dtype=quant_dtype)
@@ -101,14 +136,28 @@ class MiMoV2Arch:
 
 
 def _moe_arch(config: InferenceConfig) -> MoEArch:
+    # a share: ``n_routed_experts`` counts the experts held here, the router
+    # keeps the published width (models/deepseek reads the same three keys)
+    held = config.n_routed_experts
+    E = getattr(config, "n_routed_experts_total", None) or held
+    sigmoid = str(getattr(config, "scoring_func", "sigmoid")) == "sigmoid"
+    n_group = getattr(config, "n_group", None) or 1
     return MoEArch(
-        num_experts=config.n_routed_experts,
+        num_experts=E,
         top_k=config.num_experts_per_tok,
         intermediate_size=config.moe_intermediate_size,
         hidden_act=getattr(config, "hidden_act", "silu"),
         norm_topk_prob=bool(getattr(config, "norm_topk_prob", True)),
-        sigmoid_routing=str(getattr(config, "scoring_func", "sigmoid")) == "sigmoid",
-        **moe_parallel_fields(config.tpu_config, config.n_routed_experts),
+        sigmoid_routing=sigmoid,
+        n_group=n_group if n_group > 1 else None,
+        topk_group=getattr(config, "topk_group", None) if n_group > 1 else None,
+        routed_scaling=float(getattr(config, "routed_scaling_factor", None) or 1.0),
+        # noaux_tc: the top k are chosen over scores + a learned bias, the
+        # weights come from the scores alone
+        correction_bias=sigmoid and getattr(config, "topk_method", None) == "noaux_tc",
+        held_experts=held if held != E else None,
+        first_held=int(getattr(config, "first_routed_expert", 0) or 0),
+        **moe_parallel_fields(config.tpu_config, E),
     )
 
 
@@ -131,10 +180,12 @@ def build_arch(config: InferenceConfig, **overrides) -> MiMoV2Arch:
             heads, kv = config.swa_num_attention_heads, config.swa_num_key_value_heads
             hd, vd = config.swa_head_dim, config.swa_v_head_dim
             window = config.sliding_window
+            sink = bool(getattr(config, "add_swa_attention_sink_bias", False))
         else:
             heads, kv = config.num_attention_heads, config.num_key_value_heads
             hd, vd = config.head_dim, config.v_head_dim
             window = None
+            sink = bool(getattr(config, "add_full_attention_sink_bias", False))
         plan = gqa.plan_gqa_sharding(tp, heads, kv)
         return dense.build_arch(
             config,
@@ -145,6 +196,11 @@ def build_arch(config: InferenceConfig, **overrides) -> MiMoV2Arch:
             v_head_dim=None if vd == hd else vd,
             sliding_window=window,
             rotary_dim=(lambda rd: rd if rd < hd else None)(_rope_dim(hd, prf)),
+            attention_sink=sink,
+            attention_value_scale=getattr(config, "attention_value_scale", None),
+            # stated, not left to a kernel's default: the pool pads its key
+            # rows to a lane tile, and the scale is the head's, not the row's
+            attention_scale=float(hd) ** -0.5,
             moe=moe,
             **overrides,
         )
@@ -216,8 +272,8 @@ def causal_lm_forward(
     import jax.numpy as jnp
 
     from nxdi_tpu.config import to_jax_dtype
-    from nxdi_tpu.kvcache.kv_cache import DEFAULT_KV_LAYOUT
-    from nxdi_tpu.models.base import constrain, run_decoder_layers
+    from nxdi_tpu.kvcache.kv_cache import DEFAULT_KV_LAYOUT, BlockKVLayout
+    from nxdi_tpu.models.base import constrain
     from nxdi_tpu.ops import sampling as sampling_ops
     from nxdi_tpu.ops.norms import rms_norm
     from nxdi_tpu.ops.rope import rope_cos_sin
@@ -236,57 +292,27 @@ def causal_lm_forward(
     cos_full, sin_full = rope_cos_sin(position_ids, np.asarray(inv_freq["full"]))
     cos_swa, sin_swa = rope_cos_sin(position_ids, np.asarray(inv_freq["swa"]))
 
-    caches = {
-        "full": (cache["k"], cache["v"]),
-        "swa": (cache["k_swa"], cache["v_swa"]),
-    }
-    # window-sized swa stack: when the swa cache holds fewer slots than the
-    # full stack it is a W-slot ring — swa segments then read/write through
-    # the ring layout (reference: per-layer window-sized caches,
-    # kv_cache_manager.py:195-210); the full stack keeps the primary layout
-    layouts = {"full": layout, "swa": layout}
-    if cache["k_swa"].shape[3] < cache["k"].shape[3]:
-        from nxdi_tpu.kvcache.kv_cache import WindowKVLayout
-
-        layouts["swa"] = WindowKVLayout(
-            window=cache["k_swa"].shape[3],
-            route_by_seq_id=getattr(layout, "route_by_seq_id", False),
-        )
-    # full layout-input pass-through: seq_ids (continuous batching),
-    # write_positions (spec verify windows), attn_mask, last_token_index
-    # (the ring write's keep-mask under right padding — WindowKVLayout.update)
     from nxdi_tpu.models.base import collect_cache_inputs
 
+    # full layout-input pass-through: seq_ids (continuous batching; under the
+    # block layout the rows' SLOT ids, which address the window stack's store),
+    # write_positions (spec verify windows), attn_mask, last_token_index
+    # (the ring write's keep-mask under right padding — WindowKVLayout.update)
     cache_inputs = collect_cache_inputs(batch) or None
-    seg_new = {"full": {}, "swa": {}}  # type -> {lo: (k, v)}
-    for kind, lo, hi, seg_idx in arch.schedule:
-        ta = arch.full if kind == "full" else arch.swa
-        ck, cv = caches[kind]
-        k_sl = jax.lax.slice_in_dim(ck, lo, hi, axis=0)
-        v_sl = jax.lax.slice_in_dim(cv, lo, hi, axis=0)
-        spec = ta.kv_cache_spec(ck.shape[1], ck.shape[3])
-        cs = (cos_full, sin_full) if kind == "full" else (cos_swa, sin_swa)
-        hidden, seg_cache = run_decoder_layers(
-            ta, params["segments"][seg_idx], hidden, cs[0], cs[1],
-            {"k": k_sl, "v": v_sl}, position_ids, spec, attend_to_cache,
-            kv_window=kv_window, policy=policy, layout=layouts[kind],
-            cache_inputs=cache_inputs,
+    rope = {"full": (cos_full, sin_full), "swa": (cos_swa, sin_swa)}
+    held_tally = None
+    if isinstance(layout, BlockKVLayout):
+        if t.moe.held_experts is not None and attend_to_cache and input_ids.shape[1] == 1:
+            held_tally = []  # as models/base.py causal_lm_forward counts a share
+        hidden, new_cache = _walk_paged(
+            arch, params, hidden, rope, cache, position_ids, attend_to_cache,
+            policy, layout, cache_inputs, held_tally,
         )
-        seg_new[kind][lo] = seg_cache
-
-    def rebuild(kind):
-        parts = [seg_new[kind][lo] for lo in sorted(seg_new[kind])]
-        if not parts:
-            z = caches[kind]
-            return z[0], z[1]
-        ks = [p["k"] for p in parts]
-        vs = [p["v"] for p in parts]
-        cat = lambda xs: xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=0)  # noqa: E731
-        return cat(ks), cat(vs)
-
-    new_cache = {}
-    new_cache["k"], new_cache["v"] = rebuild("full")
-    new_cache["k_swa"], new_cache["v_swa"] = rebuild("swa")
+    else:
+        hidden, new_cache = _walk_contiguous(
+            arch, params, hidden, rope, cache, position_ids, attend_to_cache,
+            kv_window, policy, layout, cache_inputs,
+        )
 
     hidden = rms_norm(hidden, params["norm"], t.rms_norm_eps)
     lm_head = params.get("lm_head")
@@ -312,6 +338,10 @@ def causal_lm_forward(
         last_logits = logits
 
     outputs: Dict[str, jax.Array] = {}
+    if held_tally:
+        # two scalars beside the tokens (serving/engine.py _collect_decode):
+        # one pair a segment, summed over the walk
+        outputs["moe_held_pairs"], outputs["moe_routed_layers"] = sum(held_tally[1:], held_tally[0])
     if on_device_sampling:
         outputs["tokens"] = sampling_ops.sample(
             last_logits[:, -1, :],
@@ -324,6 +354,192 @@ def causal_lm_forward(
     if output_logits or output_all_logits or not on_device_sampling:
         outputs["logits"] = logits[..., : t.vocab_size - t.vocab_pad]
     return outputs, new_cache
+
+
+def _walk_contiguous(
+    arch, params, hidden, rope, cache, position_ids, attend_to_cache,
+    kv_window, policy, layout, cache_inputs,
+):
+    """The contiguous layout's walk: each segment scans its slice of its
+    kind's (L, B, KV, S, D) stack, and the slices are stacked back."""
+    import jax.numpy as jnp
+
+    from nxdi_tpu.models.base import run_decoder_layers
+
+    caches = {
+        "full": (cache["k"], cache["v"]),
+        "swa": (cache["k_swa"], cache["v_swa"]),
+    }
+    # window-sized swa stack: when the swa cache holds fewer slots than the
+    # full stack it is a W-slot ring — swa segments then read/write through
+    # the ring layout (reference: per-layer window-sized caches,
+    # kv_cache_manager.py:195-210); the full stack keeps the primary layout
+    layouts = {"full": layout, "swa": layout}
+    if cache["k_swa"].shape[3] < cache["k"].shape[3]:
+        from nxdi_tpu.kvcache.kv_cache import WindowKVLayout
+
+        layouts["swa"] = WindowKVLayout(
+            window=cache["k_swa"].shape[3],
+            route_by_seq_id=getattr(layout, "route_by_seq_id", False),
+        )
+    seg_new = {"full": {}, "swa": {}}  # type -> {lo: (k, v)}
+    for kind, lo, hi, seg_idx in arch.schedule:
+        ta = arch.full if kind == "full" else arch.swa
+        ck, cv = caches[kind]
+        k_sl = jax.lax.slice_in_dim(ck, lo, hi, axis=0)
+        v_sl = jax.lax.slice_in_dim(cv, lo, hi, axis=0)
+        spec = ta.kv_cache_spec(ck.shape[1], ck.shape[3])
+        with jax.named_scope(_SCOPE[kind]):
+            hidden, seg_cache = run_decoder_layers(
+                ta, params["segments"][seg_idx], hidden, *rope[kind],
+                {"k": k_sl, "v": v_sl}, position_ids, spec, attend_to_cache,
+                kv_window=kv_window, policy=policy, layout=layouts[kind],
+                cache_inputs=cache_inputs,
+            )
+        seg_new[kind][lo] = seg_cache
+
+    def rebuild(kind):
+        parts = [seg_new[kind][lo] for lo in sorted(seg_new[kind])]
+        if not parts:
+            return caches[kind]
+        cat = lambda xs: xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=0)  # noqa: E731
+        return cat([p["k"] for p in parts]), cat([p["v"] for p in parts])
+
+    new_cache = {}
+    new_cache["k"], new_cache["v"] = rebuild("full")
+    new_cache["k_swa"], new_cache["v_swa"] = rebuild("swa")
+    return hidden, new_cache
+
+
+def _walk_paged(
+    arch, params, hidden, rope, cache, position_ids, attend_to_cache,
+    policy, layout, cache_inputs, held_tally,
+):
+    """The block layout's walk. Each kind's cache goes WHOLE into every
+    segment's ``run_decoder_layers`` with the segment's first type-local layer
+    (``first_layer``): no slice and no stacking of either.
+
+    The full layers' pool is the layer scan's carry, written at (layer, slot)
+    and read through the block table. The window layers' store holds ``R``
+    ring rows a slot, position ``p`` in row ``p % R`` of the request's SLOT
+    (``seq_ids``): a decode step attends the store in place plus its fresh
+    row, and the five layers' fresh rows are committed by ONE in-place call
+    after the walk (``WindowKVLayout.commit_rows``: the commit kernel); a
+    prefill attends its fresh rows alone and its last ``min(S, R)`` rows go
+    into the slot's rows by one ``dynamic_update_slice`` a batch row."""
+    import jax.numpy as jnp
+
+    from nxdi_tpu.kvcache.kv_cache import (
+        DEFAULT_KV_LAYOUT, BlockKVCacheSpec, WindowKVLayout,
+    )
+    from nxdi_tpu.models.base import run_decoder_layers
+
+    if cache_inputs is None or "seq_ids" not in cache_inputs:
+        raise ValueError(
+            "the window layers' store is addressed by the rows' slot ids: the "
+            "batch needs seq_ids beside block_table / slot_mapping"
+        )
+    full, swa = arch.full, arch.swa
+    pool = {"k": cache["k"], "v": cache["v"]}
+    store = {"k": cache["k_swa"], "v": cache["v_swa"]}
+    pool_spec = BlockKVCacheSpec(
+        num_layers=full.num_layers,
+        num_blocks=pool["k"].shape[1] // layout.block_size,
+        block_size=layout.block_size,
+        num_kv_heads=pool["k"].shape[2],
+        head_dim=pool["k"].shape[3],
+        dtype=full.dtype,
+        v_head_dim=pool["v"].shape[3],
+        key_tiles=layout.key_tiles(pool["k"], pool["v"]),
+    )
+    slots, R = store["k"].shape[1], store["k"].shape[3]
+    ring = WindowKVLayout(window=R, route_by_seq_id=True)
+    ring_spec = swa.kv_cache_spec(slots, R)
+    B, S = position_ids.shape
+    rows = {}  # first type-local layer -> the segment's fresh (n, B, KV, S, D) rows
+    for kind, lo, hi, seg_idx in arch.schedule:
+        seg = params["segments"][seg_idx]
+        with jax.named_scope(_SCOPE[kind]):
+            if kind == "full":
+                hidden, pool = run_decoder_layers(
+                    full, seg, hidden, *rope[kind], pool, position_ids, pool_spec,
+                    attend_to_cache, policy=policy, layout=layout,
+                    cache_inputs=cache_inputs, moe_held_tally=held_tally, first_layer=lo,
+                )
+                # a one-layer segment is no loop: without this the compiler is
+                # free to recompute the segment's write on the pool it was
+                # handed instead of keeping the written one, and then holds
+                # two pools (3.4 GiB of ``temp`` at the served sizes)
+                hidden, pool = jax.lax.optimization_barrier((hidden, pool))
+            elif attend_to_cache:
+                hidden, out = run_decoder_layers(
+                    swa, seg, hidden, *rope[kind], store, position_ids, ring_spec,
+                    True, policy=policy, layout=ring, cache_inputs=cache_inputs,
+                    moe_held_tally=held_tally, first_layer=lo,
+                )
+                rows[lo] = (out["k_rows"], out["v_rows"])
+            else:
+                # a prefill reads no cache: it runs over a scratch of its own
+                # rows (the contiguous layout's write at the origin IS the
+                # fresh rows), and the store gets their tail below
+                spec = swa.kv_cache_spec(B, S)
+                scratch = {
+                    "k": jnp.zeros((hi - lo,) + spec.shape[1:], spec.store_dtype),
+                    "v": jnp.zeros((hi - lo,) + spec.shape_v[1:], spec.store_dtype),
+                }
+                hidden, out = run_decoder_layers(
+                    swa, seg, hidden, *rope[kind], scratch, position_ids, spec,
+                    False, policy=policy, layout=DEFAULT_KV_LAYOUT,
+                )
+                rows[lo] = (out["k"], out["v"])
+    if rows:
+        k_rows = jnp.concatenate([rows[lo][0] for lo in sorted(rows)], axis=0)
+        v_rows = jnp.concatenate([rows[lo][1] for lo in sorted(rows)], axis=0)
+        ci = dict(cache_inputs, position_ids=position_ids)
+        with jax.named_scope("kv.write"):
+            if attend_to_cache:
+                store = ring.commit_rows(store, k_rows, v_rows, ci, ring_spec, policy=policy)
+            else:
+                store = _commit_prompt_tail(store, k_rows, v_rows, ci)
+    return hidden, {
+        "k": pool["k"], "v": pool["v"], "k_swa": store["k"], "v_swa": store["v"],
+    }
+
+
+def _commit_prompt_tail(store, k_rows, v_rows, ci):
+    """A fresh prefill's rows (L, B, KV, S, D) into the slots' ring rows: row
+    ``s`` of a slot gets the LAST position ``p <= last`` with ``p % R == s``
+    (``last``: the row's last real token; bucket padding never lands), one
+    in-place ``dynamic_update_slice`` of (L, 1, KV, R, D) a batch row. Rows no
+    position has reached yet keep whatever they held: the ring's read places
+    them at negative positions and hides them (``WindowKVLayout.read``)."""
+    import jax.numpy as jnp
+
+    R = store["k"].shape[3]
+    pos = ci["position_ids"].astype(jnp.int32)  # (B, S), an arange from its first
+    lti = ci.get("last_token_index")
+    last = pos[:, -1] if lti is None else jnp.take_along_axis(
+        pos, lti[:, None].astype(jnp.int32), axis=1)[:, 0]
+    s = jnp.arange(R, dtype=jnp.int32)[None, :]
+    src = last[:, None] - ((last[:, None] - s) % R) - pos[:, :1]  # (B, R) index into S
+    src = jnp.clip(src, 0, pos.shape[1] - 1)
+    slot_ids = ci["seq_ids"].astype(jnp.int32)
+    out = {}
+    for name, fresh in (("k", k_rows), ("v", v_rows)):
+        arr = store[name]
+        tail = jnp.take_along_axis(
+            fresh, src[None, :, None, :, None], axis=3
+        ).astype(arr.dtype)  # (L, B, KV, R, D)
+        for b in range(tail.shape[1]):
+            arr = jax.lax.dynamic_update_slice(
+                arr, tail[:, b : b + 1], (0, slot_ids[b], 0, 0, 0)
+            )
+        out[name] = arr
+    return out
+
+
+#: ``jax.named_scope`` of the two kinds of segment
+_SCOPE = {"full": "layers.full", "swa": "layers.window"}
 
 
 # ---------------------------------------------------------------------------
@@ -364,14 +580,24 @@ def _convert_layer(state_dict, config, arch: MiMoV2Arch, i: int, kind: str, use_
             "o_proj": {"w": cast(gqa.convert_o(get("self_attn.o_proj.weight"), Dv, plan).T)},
         },
     }
+    if ta.attention_sink:
+        # one logit a q head, in the q weights' head order
+        sink = np.asarray(get("self_attn.attention_sink_bias"), np.float32)
+        layer["attn"]["sink"] = cast(gqa.convert_q(sink[:, None], 1, plan)[:, 0])
     if use_moe:
+        moe = ta.moe
+        first = moe.first_held  # a share converts its own experts alone
         layer["moe"] = convert_hf_experts(
             get,
             cast,
-            arch.full.moe.num_experts,
+            moe.experts_here,
             "mlp.gate.weight",
-            lambda j, proj: f"mlp.experts.{j}.{proj}_proj.weight",
+            lambda j, proj: f"mlp.experts.{first + j}.{proj}_proj.weight",
         )
+        if moe.correction_bias:  # selection only: float32, as the scores are
+            layer["moe"]["router"]["e_bias"] = np.asarray(
+                get("mlp.gate.e_score_correction_bias"), np.float32
+            )
     else:
         layer["mlp"] = {
             "gate_proj": {"w": cast(get("mlp.gate_proj.weight").T)},
@@ -460,6 +686,8 @@ def param_specs(config: InferenceConfig):
             "post_attention_layernorm": REPLICATED,
             "attn": attention_param_specs(ta),
         }
+        if ta.attention_sink:
+            layer["attn"]["sink"] = REPLICATED
         if use_moe:
             layer["moe"] = expert_parallel_specs(ta.moe)
         else:
@@ -512,16 +740,11 @@ def param_shape_struct(config: InferenceConfig):
                 "o_proj": {"w": s(n, NH * Dv, H)},
             },
         }
+        if ta.attention_sink:
+            layer["attn"]["sink"] = s(n, NH)
         if use_moe:
-            m = ta.moe
-            layer["moe"] = {
-                "router": {"w": s(n, H, m.num_experts)},
-                "experts": {
-                    "gate_proj": {"w": s(n, m.num_experts, H, m.intermediate_size)},
-                    "up_proj": {"w": s(n, m.num_experts, H, m.intermediate_size)},
-                    "down_proj": {"w": s(n, m.num_experts, m.intermediate_size, H)},
-                },
-            }
+            # the router scores all experts, the tree holds this chip's
+            layer["moe"] = moe_shape_struct(ta.moe, H, n, dt)
         else:
             I = config.intermediate_size
             layer["mlp"] = {

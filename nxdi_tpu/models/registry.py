@@ -43,6 +43,8 @@ _REGISTRY: Dict[str, Tuple[str, str]] = {
     "qwen2_5_vl": ("nxdi_tpu.models.qwen2_5_vl.modeling_qwen2_5_vl", "Qwen2_5_VLInferenceConfig"),
     "minimax_m2": ("nxdi_tpu.models.minimax_m2.modeling_minimax_m2", "MiniMaxM2InferenceConfig"),
     "mimo_v2": ("nxdi_tpu.models.mimo_v2.modeling_mimo_v2", "MiMoV2InferenceConfig"),
+    # the published model_type of MiMo-V2-Flash (MiMo-V2.5 says "mimo_v2")
+    "mimo_v2_flash": ("nxdi_tpu.models.mimo_v2.modeling_mimo_v2", "MiMoV2InferenceConfig"),
     "olmo2": ("nxdi_tpu.models.olmo2.modeling_olmo2", "Olmo2InferenceConfig"),
     "granite": ("nxdi_tpu.models.granite.modeling_granite", "GraniteInferenceConfig"),
     "smollm3": ("nxdi_tpu.models.smollm3.modeling_smollm3", "SmolLM3InferenceConfig"),

@@ -103,6 +103,12 @@ def _pool(supported):  # a ``*_kernel_supported(q_shape, pool_shape, block_size)
     return lambda s: supported(s.q_shape, s.pool_shape, s.block_size)
 
 
+def _paged_decode(s: Site) -> bool:  # both pools' widths: the values may have their own
+    return kernels.paged_decode_kernel_supported(
+        s.q_shape, s.pool_shape, s.block_size, s.v_pool_shape
+    )
+
+
 ATTENDING = ("decode", "prefill_cached")
 _XLA = TERMS - {"attn_mask", "bidir"}  # ops/attention.py's position masks take the others
 _STATIC = frozenset({"window", "chunk"})  # the flat kernels take a window and a chunk, no flag
@@ -130,8 +136,9 @@ TABLE: Tuple[Row, ...] = (
     Row("mixed_ragged_xla", ("mixed",), frozenset({"v_width"}), ("block",)),
     Row("cte_paged_kernel", ("prefill_cached",), flag=ATTN, **_PAGED,
         shape=_pool(kernels.paged_prefill_kernel_supported)),
-    Row("tkg_paged_kernel", ("decode",), _WRITTEN, flag=BLOCK_TKG, **_PAGED,
-        shape=_pool(kernels.paged_decode_kernel_supported)),
+    # the one paged kernel that takes values of their own width
+    Row("tkg_paged_kernel", ("decode",), _WRITTEN | {"v_width"}, flag=BLOCK_TKG, **_PAGED,
+        shape=_paged_decode),
     # the caller's mask IS the mask (tree verification): applications reject
     # window / chunk architectures with it up front; sink and softcap apply
     Row("attn_mask_override_xla", ("mask_override",), TERMS - {"bidir"}),
@@ -139,8 +146,10 @@ TABLE: Tuple[Row, ...] = (
         shape=_flat(kernels.decode_kernel_supported), sharding=_kv_seq_local),
     Row("tkg_xla", ATTENDING, _XLA),
     # latent attention pads its values to the key width; its one-token fresh
-    # call stays in XLA
-    Row("cte_flash_kernel", ("fresh",), _STATIC | _WRITTEN, forms=(None, "expanded"), flag=ATTN,
+    # call stays in XLA. The prefill kernel takes values of their own width
+    # and a learned sink (the flat decode kernel, ``tkg_kernel``, neither)
+    Row("cte_flash_kernel", ("fresh",), _STATIC | _WRITTEN | {"v_width", "sink"},
+        forms=(None, "expanded"), flag=ATTN,
         shape=lambda s: kernels.prefill_kernel_supported(s.q_shape, s.kv_shape)
         and (s.mla is None or s.q_shape[2] > 1),
         sharding=_kv_seq_local),
@@ -246,7 +255,7 @@ def site_of(
     kv_shape = k_shape
     if attend_to_cache and block and "block_table" in ci:
         width = ci["block_table"].shape[-1] * layout.block_size
-        kv_shape = (q_shape[0], k_cache.shape[2], width, k_cache.shape[3])
+        kv_shape = (q_shape[0], k_cache.shape[2], width, q_shape[3])  # as ``layout.read`` hands it
     elif attend_to_cache and not block:
         kv_shape = tuple(k_cache.shape)
     mesh = jax.sharding.get_abstract_mesh()

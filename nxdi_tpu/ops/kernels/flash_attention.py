@@ -122,22 +122,38 @@ def _online_softmax_step(s, mask, m_ref, l_ref, acc_ref, v, sl=slice(None)):
 
 
 def _prefill_kernel(
-    qs_ref, ks_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, scale, sliding_window, chunk_size, n_kv_blocks, H, block_q, block_k,
+    qs_ref, ks_ref, *refs,
+    scale, sliding_window, chunk_size, n_kv_blocks, H, block_q, block_k, has_sink,
 ):
+    if has_sink:
+        sink_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     qi, ki = pl.program_id(1), pl.program_id(2)
-    b = pl.program_id(0) // H
+    b, head = pl.program_id(0) // H, pl.program_id(0) % H
     q_start = qs_ref[b]
     kv_start = ks_ref[b]
 
     @pl.when(ki == 0)
     def _():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        if has_sink:
+            # a learned sink is one more softmax column a head whose
+            # probability is dropped: the running state starts as if that
+            # column had been seen (max = the sink's logit, denominator 1)
+            m_ref[:] = jnp.full_like(m_ref, sink_ref[head])
+            l_ref[:] = jnp.ones_like(l_ref)
+        else:
+            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # causal skip: kv block entirely in the future of the q block
-    @pl.when(kv_start + ki * block_k <= q_start + qi * block_q + block_q - 1)
+    # causal skip: kv block entirely in the future of the q block; window
+    # skip: entirely behind the window of the q block's first row
+    live = kv_start + ki * block_k <= q_start + qi * block_q + block_q - 1
+    if sliding_window is not None:
+        live &= kv_start + ki * block_k + block_k - 1 > q_start + qi * block_q - sliding_window
+
+    @pl.when(live)
     def _():
         q = q_ref[0]  # (block_q, D) — native dtype feeds the MXU
         k = k_ref[0]
@@ -159,17 +175,21 @@ def _prefill_kernel(
 def flash_attention_prefill(
     q,  # (B, H, Sq, D)
     k,  # (B, KV, Sk, D)
-    v,  # (B, KV, Sk, D)
+    v,  # (B, KV, Sk, Dv): values of their own width (Dv = D unless the model says so)
     q_pos,  # (B, Sq) int32 — affine per row (start + arange)
     kv_pos,  # (B, Sk) int32 — affine per row
     *,
     scale: Optional[float] = None,
     sliding_window: Optional[int] = None,
     chunk_size: Optional[int] = None,
+    sink=None,  # (H,) learned sink logits, one a head (gpt-oss, mimo-v2 window layers)
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
 ):
-    """512x1024 default blocks: at 128x128 the (B*H, Sq/bq, Sk/bk) grid hits
+    """``sink``: the softmax gains one column a head, ``sink[h]``, whose
+    probability is dropped (the running max and denominator start from it).
+
+    512x1024 default blocks: at 128x128 the (B*H, Sq/bq, Sk/bk) grid hits
     ~65k steps/layer at prefill shapes and per-step overhead dominated the
     kernel (xprof: 30 ms/layer vs ~11 ms of FLOPs; 512x512 measured ~3x
     faster end to end on v5e). The round-5 sweep (scripts/kernel_ab.py --cte,
@@ -188,7 +208,7 @@ def flash_attention_prefill(
             os.environ.get("NXDI_TPU_PREFILL_BLOCK_K", DEFAULT_PREFILL_BLOCK_K)
         )
     B, H, Sq, D = q.shape
-    KV, Sk = k.shape[1], k.shape[2]
+    KV, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
     G = H // KV
     scale = D ** -0.5 if scale is None else scale
     block_q = _pick_block(Sq, block_q)
@@ -197,7 +217,7 @@ def flash_attention_prefill(
 
     qf = q.reshape(B * H, Sq, D)
     kf = k.reshape(B * KV, Sk, D)
-    vf = v.reshape(B * KV, Sk, D)
+    vf = v.reshape(B * KV, Sk, Dv)
     q_start = q_pos[:, 0].astype(jnp.int32)
     kv_start = kv_pos[:, 0].astype(jnp.int32)
 
@@ -210,34 +230,39 @@ def flash_attention_prefill(
         H=H,
         block_q=block_q,
         block_k=block_k,
+        has_sink=sink is not None,
     )
 
     def kv_index(bh, qi, ki, *prefetch):
         return (bh // H) * KV + (bh % H) // G, ki, 0
 
+    sink_specs, sink_args = [], []
+    if sink is not None:  # H scalars, read one a grid row
+        sink_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
+        sink_args = [sink.astype(jnp.float32).reshape(H)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B * H, Sq // block_q, n_kv_blocks),
-        in_specs=[
+        in_specs=sink_specs + [
             pl.BlockSpec((1, block_q, D), lambda bh, qi, ki, *_: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, D), kv_index),
-            pl.BlockSpec((1, block_k, D), kv_index),
+            pl.BlockSpec((1, block_k, Dv), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda bh, qi, ki, *_: (bh, qi, 0)),
+        out_specs=pl.BlockSpec((1, block_q, Dv), lambda bh, qi, ki, *_: (bh, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),  # running max
             pltpu.VMEM((block_q, 1), jnp.float32),  # running denom
-            pltpu.VMEM((block_q, D), jnp.float32),  # weighted-V accumulator
+            pltpu.VMEM((block_q, Dv), jnp.float32),  # weighted-V accumulator
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B * H, Sq, Dv), q.dtype),
         name="flash_attention_prefill",
         interpret=mode.interpret(),
-    )(q_start, kv_start, qf, kf, vf)
-    return out.reshape(B, H, Sq, D)
+    )(q_start, kv_start, *sink_args, qf, kf, vf)
+    return out.reshape(B, H, Sq, Dv)
 
 
 # ---------------------------------------------------------------------------
@@ -674,18 +699,26 @@ def sharded_fused_decode_call(
 # ---------------------------------------------------------------------------
 
 
-def paged_decode_kernel_supported(q_shape, cache_shape, block_size) -> bool:
-    """``cache_shape`` is the stacked pool's (L, total_slots, KV, D)."""
+def paged_decode_kernel_supported(q_shape, cache_shape, block_size, v_cache_shape=None) -> bool:
+    """``cache_shape`` is the stacked key pool's (L, total_slots, KV, D);
+    ``v_cache_shape`` the value pool's where its width is its own."""
     B, H, Sq, D = q_shape
     total_slots, KV = cache_shape[1], cache_shape[2]
     if H % KV or Sq != 1 or total_slots % block_size:
         return False
+    Dv, tiles = D, 1
+    if v_cache_shape is not None:
+        if tuple(v_cache_shape[1:3]) != tuple(cache_shape[1:3]) or cache_shape[0] % v_cache_shape[0]:
+            return False
+        Dv, tiles = v_cache_shape[3], cache_shape[0] // v_cache_shape[0]
+    if D != tiles * cache_shape[3] or (tiles > 1 and cache_shape[3] % 128):
+        return False  # a key row is one pool row, or whole lane tiles of one
     if mode.interpret():
         return True
     # the cache block is (block_size, KV, D): Mosaic needs the last two dims
     # (KV, D) full (they are) and the head count small enough that the
     # per-head python loop stays reasonable
-    return D % 8 == 0 and block_size % 8 == 0 and KV <= 16
+    return D % 8 == 0 and Dv % 8 == 0 and block_size % 8 == 0 and KV <= 16
 
 
 #: table entries whose blocks one grid step of the paged decode kernel
@@ -724,13 +757,14 @@ def _paged_block_chain(
     nor the state.
 
     ``pools``: HBM refs (L, pool rows, width), one block being ``rows`` of
-    them; ``bufs``: their VMEM buffers (2, pages, rows, width); ``sem``: DMA
+    them, each read at ``layer`` (one index, or one a pool); ``bufs``: their VMEM buffers (2, pages, rows, width); ``sem``: DMA
     semaphores (2, len(pools), pages); ``slot_ref``: SMEM (1,), the buffer
     the current step reads. ``reset()`` runs at a row's first chunk, ``ctx =
     prepare()`` once in a step that computes, ``block(ctx, p, slot, q_pos)``
     on table entry ``p`` of the chunk once its copies have landed. ``b, c``:
     the grid step (``pl.program_id`` of both axes)."""
     span = pages * block_size  # positions one chunk covers
+    layers = layer if isinstance(layer, tuple) else (layer,) * len(pools)
 
     def page_copies(row, chunk, slot):
         """[(live, one copy a pool)] of one step's table entries: a block is
@@ -745,9 +779,9 @@ def _paged_block_chain(
                 live,
                 [
                     pltpu.make_async_copy(
-                        hbm.at[layer, src], buf.at[slot, p], sem.at[slot, i, p]
+                        hbm.at[at, src], buf.at[slot, p], sem.at[slot, i, p]
                     )
-                    for i, (hbm, buf) in enumerate(zip(pools, bufs))
+                    for i, (hbm, buf, at) in enumerate(zip(pools, bufs, layers))
                 ],
             ))
         return out
@@ -795,9 +829,8 @@ def _paged_block_chain(
 
 
 def _paged_decode_kernel(
-    li_ref, bt_ref, qp_ref, q_ref, k_hbm, v_hbm, o_ref,
-    m_ref, l_ref, acc_ref, k_buf, v_buf, sem, slot_ref,
-    *, scale, v_scale, n_rows, n_chunks, pages, KV, G, block_size, compute_dtype,
+    li_ref, bt_ref, qp_ref, q_ref, k_hbm, v_hbm, o_ref, m_ref, l_ref, acc_ref, *rest,
+    scale, v_scale, n_rows, n_chunks, pages, KV, G, block_size, compute_dtype, key_tiles,
 ):
     """Grid (row, chunk of ``pages`` table entries); ``_paged_block_chain``
     brings the blocks.
@@ -808,10 +841,18 @@ def _paged_decode_kernel(
     row, the columns of its own kv head (the others' exp is an exact 0, so
     they add nothing to l or acc). That spends KV x the MXU work a per-head
     dot needs — nothing beside the block's DMA at decode widths — and never
-    pulls a head's rows out from between the others'."""
+    pulls a head's rows out from between the others'.
+
+    ``key_tiles`` > 1: a key row wider than the pool's rows is kept as that
+    many lane tiles, tile ``j`` of layer ``l`` in pool layer ``j * L + l``
+    (``kvcache BlockKVLayout``): each tile is a block copy of its own from the
+    same key pool, and the score is the sum of the tiles' dots."""
+    k_bufs, (v_buf, sem, slot_ref) = rest[:key_tiles], rest[key_tiles:]
     b, c = pl.program_id(0), pl.program_id(1)
     layer = li_ref[0]
     rows = block_size * KV  # pool rows of one block
+    n_layers = k_hbm.shape[0] // key_tiles
+    width = k_hbm.shape[-1]
 
     def prepare():
         q = q_ref[0].reshape(KV * G, q_ref.shape[-1])  # row = head * G + g
@@ -821,18 +862,23 @@ def _paged_decode_kernel(
 
     def block(ctx, p, slot, q_pos):
         q, own_head, token = ctx
-        k = k_buf[slot, p].astype(compute_dtype)  # (block_size * KV, D)
         v = v_buf[slot, p].astype(compute_dtype)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (H, block_size * KV)
+        s = None
+        for j, k_buf in enumerate(k_bufs):
+            k = k_buf[slot, p].astype(compute_dtype)  # (block_size * KV, width)
+            part = jax.lax.dot_general(
+                q if key_tiles == 1 else q[:, j * width:(j + 1) * width], k,
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            )  # (H, block_size * KV)
+            s = part if s is None else s + part
         kv_pos = (c * pages + p) * block_size + token
         mask = own_head & (kv_pos <= q_pos)
-        _online_softmax_step(s, mask, m_ref, l_ref, acc_ref, v)
+        _online_softmax_step(s * scale, mask, m_ref, l_ref, acc_ref, v)
 
     _paged_block_chain(
-        b, c, bt_ref, qp_ref, layer, (k_hbm, v_hbm), (k_buf, v_buf), sem, slot_ref,
+        b, c, bt_ref, qp_ref,
+        tuple(layer + j * n_layers for j in range(key_tiles)) + (layer,),
+        (k_hbm,) * key_tiles + (v_hbm,), tuple(k_bufs) + (v_buf,), sem, slot_ref,
         n_rows=n_rows, n_chunks=n_chunks, pages=pages, block_size=block_size, rows=rows,
         reset=lambda: _reset_softmax_state(m_ref, l_ref, acc_ref),
         prepare=prepare, block=block,
@@ -888,7 +934,7 @@ def _paged_decode_narrow_kernel(
 def paged_attention_decode(
     q,  # (B, H, 1, D)
     k_cache,  # (L, total_slots, KV, D) — the WHOLE layer-stacked paged pool
-    v_cache,  # (L, total_slots, KV, D)
+    v_cache,  # (L, total_slots, KV, Dv): values at their own width
     block_table,  # (B, NB) int32 block ids in logical token order; <0 = hole
     q_pos,  # (B, 1) int32 decode positions
     layer_idx,  # scalar/1-elt int32 — the layer of the stack to read
@@ -911,7 +957,21 @@ def paged_attention_decode(
     on a per-layer slice of the pool materializes the slice (and made the
     scan copy the pool). A block is ONE copy for all kv heads.
 
-    At lane-wide heads (D a multiple of 128) the pool stays in HBM and the
+    Keys and values each have their own width (the score dot runs over D,
+    the accumulator and the output are Dv wide). A width that is no multiple
+    of the 128-lane tile cannot be copied from HBM by the kernel itself:
+    Mosaic refuses the slice (a 192-wide row sits in 256 lanes of HBM either
+    way), and a row of SEVERAL lane tiles beside a handful of kv heads is laid
+    out by XLA (tiles of (KV, 128)) so that the (slots * KV, D) view below is
+    a copy of the pool, not a bitcast. So a model with such keys keeps them
+    zero-padded to whole lane tiles, ONE tile a pool row: ``k_cache`` is
+    (tiles * L, slots, KV, 128), tile ``j`` of layer ``l`` at pool layer
+    ``j * L + l`` (models/mimo_v2: 192 -> 2 tiles; the queries padded alike
+    at the call, so the scores are the 192-wide ones), the tile count read
+    from the two pools' layer counts, and each tile is a block copy of its
+    own, whole tiles like every other.
+
+    At lane-wide heads (D and Dv multiples of 128) the pool stays in HBM and the
     kernel fetches the blocks itself (``_paged_decode_kernel``): the live
     blocks of up to ``PAGED_DECODE_PAGES_PER_STEP`` table entries fly
     together, one step ahead of the compute, through the pool's
@@ -924,7 +984,9 @@ def paged_attention_decode(
     keep the BlockSpec form (``_paged_decode_narrow_kernel``)."""
     B, H, Sq, D = q.shape
     assert Sq == 1, "paged decode kernel is single-position"
-    L, slots, KV = k_cache.shape[:3]
+    L, slots, KV, W = k_cache.shape
+    Dv = v_cache.shape[3]
+    key_tiles = L // v_cache.shape[0]  # lane tiles a key row is kept as (D = key_tiles * W)
     G = H // KV
     NB = block_table.shape[1]
     scale = (D ** -0.5 if scale is None else scale) * k_scale
@@ -933,32 +995,34 @@ def paged_attention_decode(
         compute_dtype=q.dtype,
     )
     q_spec = pl.BlockSpec((1, KV, G, D), lambda b, i, *_: (b, 0, 0, 0))
+    o_spec = pl.BlockSpec((1, KV, G, Dv), lambda b, i, *_: (b, 0, 0, 0))
     state = [  # the running (m, l, acc) of one row, all heads
         pltpu.VMEM((KV * G, 1), jnp.float32),
         pltpu.VMEM((KV * G, 1), jnp.float32),
-        pltpu.VMEM((KV * G, D), jnp.float32),
+        pltpu.VMEM((KV * G, Dv), jnp.float32),
     ]
     bt = block_table.astype(jnp.int32)
-    if D % 128 == 0:
+    if W % 128 == 0 and Dv % 128 == 0:
         pages = min(PAGED_DECODE_PAGES_PER_STEP, NB)
         n_chunks = -(-NB // pages)
         if n_chunks * pages != NB:  # entries past the table are holes
             bt = jnp.pad(bt, ((0, 0), (0, n_chunks * pages - NB)), constant_values=-1)
-        k_cache = k_cache.reshape(L, slots * KV, D)
-        v_cache = v_cache.reshape(L, slots * KV, D)
+        k_cache = k_cache.reshape(L, slots * KV, W)
+        v_cache = v_cache.reshape(v_cache.shape[0], slots * KV, Dv)
         kernel = functools.partial(
-            _paged_decode_kernel, n_rows=B, n_chunks=n_chunks, pages=pages, **static
+            _paged_decode_kernel, n_rows=B, n_chunks=n_chunks, pages=pages,
+            key_tiles=key_tiles, **static
         )
         grid = (B, n_chunks)
-        pool_spec = pl.BlockSpec(memory_space=pl.ANY)
-        buf = (2, pages, block_size * KV, D)  # [buffer, table entry] blocks
-        scratch = state + [
-            pltpu.VMEM(buf, k_cache.dtype),
-            pltpu.VMEM(buf, v_cache.dtype),
-            pltpu.SemaphoreType.DMA((2, 2, pages)),  # [buffer, K | V, entry]
+        pool_spec = v_pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+        buf = (2, pages, block_size * KV)  # [buffer, table entry] blocks
+        scratch = state + [pltpu.VMEM(buf + (W,), k_cache.dtype)] * key_tiles + [
+            pltpu.VMEM(buf + (Dv,), v_cache.dtype),
+            pltpu.SemaphoreType.DMA((2, key_tiles + 1, pages)),  # [buffer, K tiles | V, entry]
             pltpu.SMEM((1,), jnp.int32),  # the buffer the current step reads
         ]
     else:
+        assert key_tiles == 1, "narrow heads are one pool row"
         kernel = functools.partial(_paged_decode_narrow_kernel, n_blocks=NB, **static)
         grid = (B, NB)
 
@@ -966,25 +1030,26 @@ def paged_attention_decode(
             # unallocated/future blocks clamp to block 0 — the kernel masks them out
             return li_ref[0], jnp.maximum(bt_ref[b, bi], 0), 0, 0
 
-        pool_spec = pl.BlockSpec((None, block_size, KV, D), cache_index)
+        pool_spec = pl.BlockSpec((None, block_size, KV, W), cache_index)
+        v_pool_spec = pl.BlockSpec((None, block_size, KV, Dv), cache_index)
         scratch = state
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,
-            in_specs=[q_spec, pool_spec, pool_spec],
-            out_specs=q_spec,
+            in_specs=[q_spec, pool_spec, v_pool_spec],
+            out_specs=o_spec,
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, Dv), q.dtype),
         name="paged_attention_decode",
         interpret=mode.interpret(),
     )(
         jnp.asarray(layer_idx, jnp.int32).reshape(1), bt, q_pos[:, 0].astype(jnp.int32),
         q.reshape(B, KV, G, D), k_cache, v_cache,
     )
-    return out.reshape(B, H, 1, D)
+    return out.reshape(B, H, 1, Dv)
 
 
 def paged_prefill_kernel_supported(q_shape, cache_shape, block_size) -> bool:
@@ -1222,6 +1287,7 @@ def sharded_kernel_call(
     scale=None,
     sliding_window=None,
     chunk_size=None,
+    sink=None,
 ):
     """Run the flash kernel per mesh shard via ``shard_map`` (GSPMD cannot
     partition a pallas_call by itself). Head/batch shardings follow the
@@ -1239,9 +1305,13 @@ def sharded_kernel_call(
         sliding_window=sliding_window,
         chunk_size=chunk_size,
     )
+    sinks = ()
+    if sink is not None:  # the prefill kernel's alone (the table's ``computes``)
+        sinks = (sink,)
+        fn = lambda q_, k_, v_, qp_, kp_, s_, fn=fn: fn(q_, k_, v_, qp_, kp_, sink=s_)  # noqa: E731
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
-        return fn(q, k, v, q_pos, kv_pos)
+        return fn(q, k, v, q_pos, kv_pos, *sinks)
 
     kv_spec = policy.cache_kv if decode else policy.kv
     q_spec = P(*policy.q)
@@ -1250,8 +1320,9 @@ def sharded_kernel_call(
     shard_fn = jax.shard_map(
         fn,
         mesh=mesh,
-        in_specs=(q_spec, P(*kv_spec), P(*kv_spec), qp_spec, kp_spec),
+        in_specs=(q_spec, P(*kv_spec), P(*kv_spec), qp_spec, kp_spec)
+        + (P(policy.q[1]),) * len(sinks),  # one logit a head, sharded as the heads
         out_specs=q_spec,
         check_vma=False,
     )
-    return shard_fn(q, k, v, q_pos, kv_pos)
+    return shard_fn(q, k, v, q_pos, kv_pos, *sinks)

@@ -500,8 +500,12 @@ class ModelWrapper:
         # the donated round-trip (a drifting output sharding breaks aliasing
         # and re-triggers per-step relayouts — seen with the qwen3_next conv
         # state); only the memory layout is left to the compiler
-        auto = jax.tree_util.tree_map(
-            lambda sh: Format(Layout.AUTO, sh), cache_shardings
+        pinned = self._pinned_cache_layouts()
+        auto = jax.tree_util.tree_map_with_path(
+            lambda path, sh: Format(
+                pinned.get(getattr(path[-1], "key", None), Layout.AUTO), sh
+            ),
+            cache_shardings,
         )
         return _AutoLayoutProgram(
             fn,
@@ -535,9 +539,41 @@ class ModelWrapper:
             req.append(("fused_qkv", ("qkv_fused_matmul", "qkv_fused_kernel")))
         return tuple(req)
 
+    def _pinned_cache_layouts(self) -> dict:
+        """``{cache leaf: Layout}`` for the leaves whose memory layout is NOT
+        left to each program: a store of ring rows a slot (the architecture's
+        ``ring_cache_keys``), rows-minor on the TPU. The decode program's
+        two-part attention and the commit kernel want it so (ops/kernels/
+        kv_commit.py), a prefill only writes a slot's tail into it and would
+        pick the default; left to AUTO, every prefill relaid the store twice,
+        each time into a new buffer of its size, on a device that is four
+        fifths full (PERF.md, PR 35: single steps of 1-3 s in ``enqueue``)."""
+        keys = getattr(self.arch, "ring_cache_keys", ())
+        if not keys or not isinstance(self.layout, BlockKVLayout) or self._mesh is None:
+            return {}
+        if self._mesh.devices.flat[0].platform != "tpu":
+            return {}
+        from jax.experimental.layout import Layout
+
+        from nxdi_tpu.config import to_jax_dtype
+
+        packed = jnp.dtype(to_jax_dtype(self.arch.dtype)).itemsize == 2
+        tiling = ((8, 128), (2, 1)) if packed else ((8, 128),)
+        return {k: Layout(major_to_minor=(0, 1, 2, 4, 3), tiling=tiling) for k in keys}
+
+    @property
+    def per_slot_cache(self) -> bool:
+        """The cache tree holds a store per SLOT beside the block pool (the
+        architecture says so: mimo-v2's window layers): the batch carries the
+        rows' slot ids as ``seq_ids`` beside the block tables."""
+        return isinstance(self.layout, BlockKVLayout) and bool(
+            getattr(self.arch, "ring_cache_keys", ())
+        )
+
     def _layout_input_keys(self):
         if isinstance(self.layout, BlockKVLayout):
-            return ("slot_mapping", "block_table")
+            keys = ("slot_mapping", "block_table")
+            return keys + ("seq_ids",) if self.per_slot_cache else keys
         if getattr(self.layout, "route_by_seq_id", False):
             return ("seq_ids",)
         return ()
@@ -838,7 +874,7 @@ class ModelWrapper:
         first batchline + garbage-slot convention,
         block_kv_cache_manager.py:376 generate_tokengen_slot_mapping)."""
         extra: Dict[str, np.ndarray] = {}
-        if getattr(self.layout, "route_by_seq_id", False):
+        if getattr(self.layout, "route_by_seq_id", False) or self.per_slot_cache:
             sids = np.asarray(batch_np.get("seq_ids", np.arange(b)), dtype=np.int32)
             tc = self.config.tpu_config
             # bound = the CACHE LINE count (what seq_ids index), not the
@@ -854,7 +890,7 @@ class ModelWrapper:
                     f"{sids.tolist()}"
                 )
             extra["seq_ids"] = sids
-        elif isinstance(self.layout, BlockKVLayout):
+        if isinstance(self.layout, BlockKVLayout):
             bs = self.layout.block_size
             width = self._block_table_width()
             bt = np.asarray(

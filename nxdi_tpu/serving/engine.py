@@ -306,6 +306,7 @@ class InferenceEngine:
         # counters of a model that holds a share of its experts; made on the
         # first step whose program returns the count (none for other models)
         self._moe_held_pairs = self._moe_routed_layer_steps = self._moe_routed_layers = None
+        self._kv_store = self._kv_window_rows = None  # ``_kv_held``, on first use
         self._can_continue_prefill = TAG_PREFIX_PREFILL in app.models
         #: the decode dispatch not collected yet (None: nothing in flight)
         self._inflight: Optional[_InFlight] = None
@@ -699,6 +700,7 @@ class InferenceEngine:
                 self.scheduler.slots_busy,
                 self.block_manager.num_free_blocks()
                 if self.block_manager is not None else None,
+                kv_held=self._kv_held(),
             )
             # SLO-breach postmortems fire AFTER end_step so the bundle's
             # timeline includes the step the breaching request finished in
@@ -1413,8 +1415,47 @@ class InferenceEngine:
                     for _, r in rows
                 ]
             )
-            return {"block_table": bt}
-        return {"seq_ids": np.array([slot for slot, _ in rows], dtype=np.int32)}
+            if not self._tkg.per_slot_cache:
+                return {"block_table": bt}
+            # a store held per slot beside the pool is addressed by slot id
+            return {"block_table": bt, "seq_ids": self._slot_ids(rows)}
+        return {"seq_ids": self._slot_ids(rows)}
+
+    def _kv_held(self) -> Optional[Tuple[int, int, int]]:
+        """(live tokens, ring rows held, bytes held) of a cache tree with a
+        store per slot beside the pool, as the arrays store them; the gauge
+        ``nxdi_kv_window_rows_held`` follows. None for every other tree."""
+        if not (self.paged and self._tkg.per_slot_cache):
+            return None
+        if self._kv_store is None:
+            cache, mgr = self.app.kv_cache, self.block_manager
+            per_slot = [a for name, a in cache.items() if name not in ("k", "v")]
+            self._kv_store = (
+                per_slot[0].shape[3],  # ring rows a slot (L, slots, KV, rows, D)
+                sum(a.nbytes for a in per_slot) // per_slot[0].shape[1],
+                (cache["k"].nbytes + cache["v"].nbytes) // mgr.num_blocks,
+            )
+            tel = self.telemetry
+            if tel is not None and tel.enabled:
+                self._kv_window_rows = tel.registry.gauge(
+                    "nxdi_kv_window_rows_held",
+                    "ring rows of the per-slot window store held by seated requests",
+                )
+        rows_a_slot, slot_bytes, block_bytes = self._kv_store
+        running = self.scheduler.running()
+        mgr = self.block_manager
+        held = len(running) * rows_a_slot
+        if self._kv_window_rows is not None:
+            self._kv_window_rows.set(held)
+        return (
+            sum(r.total_len for r in running),
+            held,
+            (mgr.num_blocks - mgr.num_free_blocks()) * block_bytes + len(running) * slot_bytes,
+        )
+
+    @staticmethod
+    def _slot_ids(rows: List[Tuple[int, Request]]) -> np.ndarray:
+        return np.array([slot for slot, _ in rows], dtype=np.int32)
 
     def _maybe_rng(self, kwargs: Dict[str, np.ndarray]) -> None:
         if self._tkg.needs_rng:

@@ -75,6 +75,7 @@ class StepRecord:
         "mixed", "preempted", "retired", "programs", "kv_blocks_free",
         "queue_depth", "slots_busy", "dispatch_s", "host_s", "faults",
         "phases", "open_phase", "moe_held_pairs", "moe_routed_layers",
+        "kv_live_tokens", "kv_window_rows_held", "kv_bytes_held",
         "chained", "overrun_tokens",
     )
 
@@ -123,6 +124,14 @@ class StepRecord:
         self.moe_held_pairs: Optional[int] = None
         #: the routed layers those pairs were summed over
         self.moe_routed_layers: Optional[int] = None
+        #: a cache tree with a store per SLOT beside the block pool (mimo-v2's
+        #: window layers): the tokens of the seated requests at the end of the
+        #: step, the ring rows their slots hold (rows a slot x busy slots),
+        #: and the bytes of cache held for them (used blocks + those rows, as
+        #: the arrays store them); None for every other tree
+        self.kv_live_tokens: Optional[int] = None
+        self.kv_window_rows_held: Optional[int] = None
+        self.kv_bytes_held: Optional[int] = None
         #: this step's decode was dispatched before the previous step's
         #: tokens were collected (its input ids never left the device)
         self.chained = False
@@ -172,6 +181,9 @@ class StepRecord:
             "slots_busy": self.slots_busy,
             "moe_held_pairs": self.moe_held_pairs,
             "moe_routed_layers": self.moe_routed_layers,
+            "kv_live_tokens": self.kv_live_tokens,
+            "kv_window_rows_held": self.kv_window_rows_held,
+            "kv_bytes_held": self.kv_bytes_held,
             "chained": self.chained,
             "overrun_tokens": self.overrun_tokens,
         }
@@ -396,6 +408,7 @@ class FlightRecorder:
         queue_depth: int,
         slots_busy: int,
         kv_blocks_free: Optional[int],
+        kv_held: Optional[tuple] = None,
     ) -> StepRecord:
         """Close the open record, fold it into the ring + metrics, and run
         the step-scoped triggers (storm, retrace). Returns the record."""
@@ -406,6 +419,8 @@ class FlightRecorder:
         rec.queue_depth = int(queue_depth)
         rec.slots_busy = int(slots_busy)
         rec.kv_blocks_free = kv_blocks_free
+        if kv_held is not None:  # (live tokens, window rows held, bytes held)
+            rec.kv_live_tokens, rec.kv_window_rows_held, rec.kv_bytes_held = kv_held
         rec.host_s = rec.wall_s - rec.phases.get("fetch", 0.0)
         with self._lock:
             self.records.append(rec)

@@ -213,7 +213,10 @@ CASES = {
     "paged-decode-chunk": (dict(PAGED_DECODE, arch=CHUNK), "tkg_kernel"),
     "paged-decode-sink": (dict(PAGED_DECODE, arch=SINK), "tkg_xla"),
     "paged-decode-softcap": (dict(PAGED_DECODE, arch=SOFTCAP), "tkg_xla"),
-    "paged-decode-v-width": (dict(PAGED_DECODE, arch=V_WIDTH), "tkg_xla"),
+    "paged-decode-v-width": (dict(PAGED_DECODE, arch=V_WIDTH), "tkg_paged_kernel"),  # PR 35: the value pool's own width
+    "paged-decode-v-width-shape": (dict(PAGED_DECODE, arch=V_WIDTH, mosaic=True, head_dim=12), "tkg_xla"),
+    "paged-decode-v-width-sink": (dict(PAGED_DECODE, arch=dict(V_WIDTH, **SINK)), "tkg_xla"),
+    "paged-decode-v-width-window": (dict(PAGED_DECODE, arch=dict(V_WIDTH, **WINDOW)), "tkg_xla"),
     "paged-decode-window-flag": (dict(PAGED_DECODE, layer_flags=("use_sliding_window",)), "tkg_xla"),
     "paged-decode-rope-flag": (dict(PAGED_DECODE, layer_flags=("use_rope",)), "tkg_xla"),
     "paged-decode-attn-mask": (dict(PAGED_DECODE, ci=("attn_mask",)), "attn_mask_override_xla"),
@@ -253,9 +256,14 @@ CASES = {
     "flash+window": (dict(FRESH, arch=WINDOW), "cte_flash_kernel"),
     "flash+chunk": (dict(FRESH, arch=CHUNK), "cte_flash_kernel"),
     "flash+write-positions": (dict(FRESH, ci=("write_positions",)), "cte_flash_kernel"),
-    "flash-sink": (dict(FRESH, arch=SINK), "cte_xla"),
+    "flash-sink": (dict(FRESH, arch=SINK), "cte_flash_kernel"),  # PR 35: the sink starts the running state
+    "flash-sink+window+v-width": (dict(FRESH, arch=dict(SINK, **WINDOW, **V_WIDTH)), "cte_flash_kernel"),
+    "flash-sink-softcap": (dict(FRESH, arch=dict(SINK, **SOFTCAP)), "cte_xla"),
+    "flash-sink-flag-off": (dict(FRESH, flags=(), arch=SINK), "cte_xla"),
+    "ring-decode-sink+window+v-width": (dict(defer=True, layout="window", arch=dict(SINK, **WINDOW, **V_WIDTH)),
+                                        "tkg_two_part_xla"),
     "flash-softcap": (dict(FRESH, arch=SOFTCAP), "cte_xla"),
-    "flash-v-width": (dict(FRESH, arch=V_WIDTH), "cte_xla"),
+    "flash-v-width": (dict(FRESH, arch=V_WIDTH), "cte_flash_kernel"),  # PR 35: values of their own width
     "flash-window-flag": (dict(FRESH, layer_flags=("use_sliding_window",)), "cte_xla"),
     "flash-rope-flag": (dict(FRESH, layer_flags=("use_rope",)), "cte_xla"),
     "flash-bidirectional-spans": (dict(FRESH, ci=("bidir_spans",)), "cte_xla"),

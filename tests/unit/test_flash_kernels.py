@@ -360,3 +360,98 @@ def test_paged_kernels_read_the_layer_the_index_names(kernel):
         lambda c, li: (c, call(li)), 0, jnp.arange(LAYERS, dtype=jnp.int32)
     )
     np.testing.assert_allclose(np.asarray(scanned), np.stack(refs), atol=2e-5)
+
+
+# -- PR 35: values of their own width, keys kept as lane tiles, a learned sink ----
+
+
+@pytest.mark.parametrize("window", [None, 6], ids=["causal", "window"])
+@pytest.mark.parametrize("sink", [False, True], ids=["no-sink", "sink"])
+@pytest.mark.parametrize("Dv", [16, 8], ids=["same-width", "narrow-values"])
+def test_prefill_kernel_value_width_and_sink(window, sink, Dv):
+    """Values narrower than the keys, and one more softmax column a head whose
+    probability is dropped: the running state starts from the sink."""
+    B, H, KV, S, D = 2, 8, 2, 32, 16
+    q, k, v = _rand((B, H, S, D), 0), _rand((B, KV, S, D), 1), _rand((B, KV, S, Dv), 2)
+    sinks = _rand((H,), 3) * 2.0 if sink else None
+    pos = jnp.tile(jnp.arange(S, dtype=jnp.int32), (B, 1))
+    expected = attention_with_positions(q, k, v, pos, pos, sliding_window=window, sink=sinks)
+    actual = flash_attention_prefill(
+        q, k, v, pos, pos, sliding_window=window, sink=sinks, block_q=8, block_k=8
+    )
+    assert actual.shape == (B, H, S, Dv)
+    np.testing.assert_allclose(np.asarray(actual), np.asarray(expected), atol=2e-5)
+
+
+def test_prefill_kernel_skips_blocks_behind_the_window():
+    """A key block wholly behind the window of the query block's first row is
+    not computed; the rows' results are the masked ones all the same, also
+    where a query block's rows see no key of some block at all."""
+    B, H, KV, S, D = 1, 4, 2, 64, 8
+    q, k, v = _rand((B, H, S, D), 0), _rand((B, KV, S, D), 1), _rand((B, KV, S, D), 2)
+    pos = jnp.tile(jnp.arange(S, dtype=jnp.int32), (B, 1))
+    for window in (3, 8, 13):
+        expected = attention_with_positions(q, k, v, pos, pos, sliding_window=window)
+        actual = flash_attention_prefill(q, k, v, pos, pos, sliding_window=window, block_q=8, block_k=8)
+        np.testing.assert_allclose(np.asarray(actual), np.asarray(expected), atol=2e-5)
+
+
+@pytest.mark.parametrize("D,W,Dv,tiles", [(16, 16, 8, 1), (128, 128, 128, 1), (256, 128, 128, 2)],
+                         ids=["narrow-values", "lane-wide", "two-key-tiles"])
+def test_paged_decode_kernel_value_width_and_key_tiles(D, W, Dv, tiles):
+    """The value pool at its own width; a key row of two lane tiles kept as two
+    pool rows, tile j of layer l at pool layer j * L + l (the last 64 lanes of
+    the second tile zero, as a 192-wide key is padded): against the XLA
+    attention over the layout's own gathered read."""
+    from nxdi_tpu.kvcache.kv_cache import BlockKVCacheSpec, BlockKVLayout
+    from nxdi_tpu.ops.kernels import paged_attention_decode, paged_decode_kernel_supported
+
+    B, H, KV, L, bs, blocks = 3, 8, 2, 2, 8, 12
+    real = 192 if tiles == 2 else D
+    rng = np.random.default_rng(5)
+    keys = rng.standard_normal((L, blocks * bs, KV, D)).astype(np.float32)
+    keys[..., real:] = 0.0
+    q = rng.standard_normal((B, H, 1, D)).astype(np.float32)
+    q[..., real:] = 0.0
+    k_pool = jnp.asarray(  # (tiles * L, slots, KV, W): tile-major over the layers
+        np.concatenate([keys[..., j * W:(j + 1) * W] for j in range(tiles)], axis=0))
+    v_pool = _rand((L, blocks * bs, KV, Dv), 6)
+    table = jnp.asarray([[3, 1, 7, -1], [0, 2, -1, -1], [5, 4, 6, 8]], jnp.int32)
+    q_pos = jnp.asarray([[19], [9], [31]], jnp.int32)
+    assert paged_decode_kernel_supported(q.shape, k_pool.shape, bs, v_pool.shape)
+    layout = BlockKVLayout(block_size=bs)
+    spec = BlockKVCacheSpec(num_layers=L, num_blocks=blocks, block_size=bs, num_kv_heads=KV,
+                            head_dim=W, dtype="float32", v_head_dim=Dv, key_tiles=tiles)
+    for layer in range(L):
+        ci = {"layer_idx": jnp.int32(layer), "block_table": table}
+        kk, vv, kv_pos = layout.read(k_pool, v_pool, ci, spec)
+        assert kk.shape[-1] == D and vv.shape[-1] == Dv
+        expected = attention_with_positions(jnp.asarray(q), kk, vv, q_pos, kv_pos, scale=real ** -0.5)
+        actual = paged_attention_decode(
+            jnp.asarray(q), k_pool, v_pool, table, q_pos, jnp.int32(layer), block_size=bs,
+            scale=real ** -0.5,
+        )
+        assert actual.shape == (B, H, 1, Dv)
+        np.testing.assert_allclose(np.asarray(actual), np.asarray(expected), atol=3e-5)
+
+
+def test_block_layout_writes_a_wide_key_as_lane_tiles():
+    """``BlockKVLayout.update`` lands tile j of a key row in pool layer
+    j * L + layer, and ``read`` puts the tiles side by side again."""
+    from nxdi_tpu.kvcache.kv_cache import BlockKVCacheSpec, BlockKVLayout
+
+    L, bs, blocks, KV, W, B = 2, 4, 3, 2, 128, 2
+    spec = BlockKVCacheSpec(num_layers=L, num_blocks=blocks, block_size=bs, num_kv_heads=KV,
+                            head_dim=W, dtype="float32", v_head_dim=8, key_tiles=2)
+    assert spec.shape == (4, 12, KV, W) and spec.shape_v == (2, 12, KV, 8)
+    k_pool, v_pool = jnp.zeros(spec.shape), jnp.zeros(spec.shape_v)
+    k_new, v_new = _rand((B, KV, 1, 2 * W), 1), _rand((B, KV, 1, 8), 2)
+    layout = BlockKVLayout(block_size=bs)
+    ci = {"layer_idx": jnp.int32(1), "slot_mapping": jnp.asarray([[5], [-1]], jnp.int32),
+          "block_table": jnp.asarray([[1, -1], [0, -1]], jnp.int32)}
+    k_pool, v_pool = layout.update(k_pool, v_pool, k_new, v_new, ci, spec)
+    np.testing.assert_array_equal(np.asarray(k_pool[1, 5]), np.asarray(k_new[0, :, 0, :W]))
+    np.testing.assert_array_equal(np.asarray(k_pool[3, 5]), np.asarray(k_new[0, :, 0, W:]))
+    assert float(jnp.abs(k_pool).sum()) == pytest.approx(float(jnp.abs(k_new[0]).sum()), rel=1e-6)
+    kk, vv, _ = layout.read(k_pool, v_pool, ci, spec)
+    np.testing.assert_array_equal(np.asarray(kk[0, :, 5 - 4]), np.asarray(k_new[0, :, 0]))
