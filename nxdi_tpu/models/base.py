@@ -769,7 +769,9 @@ def attention_block(
         kk, vv, kv_pos = attended()
         return attn_kernels.sharded_kernel_call(
             policy, q, kk, vv, position_ids, kv_pos, decode=attend_to_cache, **static_terms,
-            sink=None if attend_to_cache else sink,  # the table: the prefill kernel's term
+            # the table: the prefill kernel's terms (a learned sink, a block selection)
+            sink=None if attend_to_cache else sink,
+            block_mask=None if attend_to_cache else ci.get("block_select"),
         )
 
     def positions_xla():

@@ -19,7 +19,20 @@ build_inv_freq = dense.build_inv_freq
 
 
 class MiniCPMInferenceConfig(dense.DenseInferenceConfig):
+    #: whether the family computes a ``sparse_config``'s block selection (models/minicpm_sala does)
+    COMPUTES_BLOCK_SELECTION = False
+
     def add_derived_config(self):
+        if getattr(self, "sparse_config", None) is not None and not self.COMPUTES_BLOCK_SELECTION:
+            # MiniCPM4's InfLLM-V2 switch: past ``dense_len`` tokens its layers
+            # read a SELECTION of blocks, which this dense family does not compute
+            raise NotImplementedError(
+                "minicpm4 with a sparse_config (block-sparse attention past dense_len "
+                f"{self.sparse_config.get('dense_len')}) is not served by the dense family: "
+                "it would attend densely in silence. Block selection lives in "
+                "models/minicpm_sala (ops/block_select.py); drop sparse_config to serve "
+                "the model dense at every length"
+            )
         for name, default in (("scale_emb", 1.0), ("scale_depth", 1.0),
                               ("dim_model_base", None)):
             if not hasattr(self, name):

@@ -161,6 +161,10 @@ _REGISTRY: Dict[str, Tuple[str, str]] = {
     ),
     "minicpm": ("nxdi_tpu.models.minicpm.modeling_minicpm", "MiniCPMInferenceConfig"),
     "minicpm4": ("nxdi_tpu.models.minicpm.modeling_minicpm", "MiniCPMInferenceConfig"),
+    "minicpm_sala": (
+        "nxdi_tpu.models.minicpm_sala.modeling_minicpm_sala",
+        "MiniCPMSALAInferenceConfig",
+    ),
     "internlm3": (
         "nxdi_tpu.models.internlm3.modeling_internlm3",
         "InternLM3InferenceConfig",

@@ -42,6 +42,9 @@ TERMS = frozenset({
     "window_flag", "rope_flag",  # per-layer scan flags gating window / chunk
     "bidir", "attn_mask", "write_positions",  # the call's cache inputs
     "v_width",  # values of another width than the keys (arch.v_head_dim)
+    # the call reads a SELECTION of blocks (ops/block_select.py; its cache
+    # inputs carry it: a decode step's compact table, a prefill's block mask)
+    "block_select",
 })
 ATTN, TKG, BLOCK_TKG = ATTENTION_FLAGS = (
     "attn_kernel_enabled", "attn_tkg_kernel_enabled", "attn_block_tkg_kernel_enabled"
@@ -110,7 +113,8 @@ def _paged_decode(s: Site) -> bool:  # both pools' widths: the values may have t
 
 
 ATTENDING = ("decode", "prefill_cached")
-_XLA = TERMS - {"attn_mask", "bidir"}  # ops/attention.py's position masks take the others
+# ops/attention.py's position masks take the others
+_XLA = TERMS - {"attn_mask", "bidir", "block_select"}
 _STATIC = frozenset({"window", "chunk"})  # the flat kernels take a window and a chunk, no flag
 _WRITTEN = frozenset({"write_positions"})  # the row is in the cache before the core reads it
 _PAGED = dict(layouts=("block",), sharding=_rows_local)  # causal by position and no more
@@ -136,24 +140,27 @@ TABLE: Tuple[Row, ...] = (
     Row("mixed_ragged_xla", ("mixed",), frozenset({"v_width"}), ("block",)),
     Row("cte_paged_kernel", ("prefill_cached",), flag=ATTN, **_PAGED,
         shape=_pool(kernels.paged_prefill_kernel_supported)),
-    # the one paged kernel that takes values of their own width
-    Row("tkg_paged_kernel", ("decode",), _WRITTEN | {"v_width"}, flag=BLOCK_TKG, **_PAGED,
+    # the one paged kernel that takes values of their own width; a selection
+    # reaches it as the table it walks (holes are skipped without a copy)
+    Row("tkg_paged_kernel", ("decode",), _WRITTEN | {"v_width", "block_select"}, flag=BLOCK_TKG,
+        **_PAGED,
         shape=_paged_decode),
     # the caller's mask IS the mask (tree verification): applications reject
     # window / chunk architectures with it up front; sink and softcap apply
-    Row("attn_mask_override_xla", ("mask_override",), TERMS - {"bidir"}),
+    Row("attn_mask_override_xla", ("mask_override",), TERMS - {"bidir", "block_select"}),
     Row("tkg_kernel", ("decode",), _STATIC | _WRITTEN, flag=TKG,
         shape=_flat(kernels.decode_kernel_supported), sharding=_kv_seq_local),
     Row("tkg_xla", ATTENDING, _XLA),
     # latent attention pads its values to the key width; its one-token fresh
     # call stays in XLA. The prefill kernel takes values of their own width
     # and a learned sink (the flat decode kernel, ``tkg_kernel``, neither)
-    Row("cte_flash_kernel", ("fresh",), _STATIC | _WRITTEN | {"v_width", "sink"},
+    # ... and a block selection as one more operand (a block mask a query token)
+    Row("cte_flash_kernel", ("fresh",), _STATIC | _WRITTEN | {"v_width", "sink", "block_select"},
         forms=(None, "expanded"), flag=ATTN,
         shape=lambda s: kernels.prefill_kernel_supported(s.q_shape, s.kv_shape)
         and (s.mla is None or s.q_shape[2] > 1),
         sharding=_kv_seq_local),
-    Row("cte_xla", ("fresh",), TERMS - {"attn_mask"}, forms=(None, "expanded")),
+    Row("cte_xla", ("fresh",), TERMS - {"attn_mask", "block_select"}, forms=(None, "expanded")),
     Row("tkg_mla_paged_kernel", ("decode",), _WRITTEN, forms=("absorbed",), flag=BLOCK_TKG,
         **_PAGED, shape=lambda s: s.v_pool_shape is not None
         and mla_decode.mla_paged_decode_supported(
@@ -165,6 +172,9 @@ TABLE: Tuple[Row, ...] = (
 
 #: what the user can do about a term no row computes for the call
 REMEDIES = {
+    "block_select": "a block selection is computed by the paged decode kernel and the flash "
+                    "prefill kernel alone (attn_block_tkg_kernel_enabled / attn_kernel_enabled): "
+                    "a dense pass in its place would be another model",
     # span ids restart per chunk: same-image tokens of the cached prefix could never match
     "bidir": "bidirectional image attention (gemma3-vision) does not compose with "
              "prefix-cached/chunked prefill; disable prefix caching for this model",
@@ -233,6 +243,7 @@ def site_of(
         "attn_mask": ci.get("attn_mask") is not None,
         "write_positions": ci.get("write_positions") is not None,
         "v_width": arch.v_head_dim is not None,
+        "block_select": ci.get("block_select") is not None,
     }
     needs = frozenset(term for term, is_needed in needed.items() if is_needed)
     if not attend_to_cache:

@@ -124,9 +124,19 @@ def _online_softmax_step(s, mask, m_ref, l_ref, acc_ref, v, sl=slice(None)):
 def _prefill_kernel(
     qs_ref, ks_ref, *refs,
     scale, sliding_window, chunk_size, n_kv_blocks, H, block_q, block_k, has_sink,
+    select_block=None, G=1,
 ):
+    """``select_block``: a BLOCK SELECTION rides along (ops/block_select.py): one
+    more prefetched array says which (kv head, q tile, kv tile) hold a selected
+    block at all (the others are skipped like a tile in the future), and one
+    more operand holds, a query row, the bits of its selected blocks: the tile's
+    mask keeps a key column iff its block's bit is set."""
+    if select_block is not None:
+        any_ref, refs = refs[0], refs[1:]
     if has_sink:
-        sink_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+        sink_ref, refs = refs[0], refs[1:]
+    if select_block is not None:
+        q_ref, k_ref, v_ref, bits_ref, o_ref, m_ref, l_ref, acc_ref = refs
     else:
         q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     qi, ki = pl.program_id(1), pl.program_id(2)
@@ -152,6 +162,8 @@ def _prefill_kernel(
     live = kv_start + ki * block_k <= q_start + qi * block_q + block_q - 1
     if sliding_window is not None:
         live &= kv_start + ki * block_k + block_k - 1 > q_start + qi * block_q - sliding_window
+    if select_block is not None:
+        live &= any_ref[pl.program_id(0) // G, qi, ki] > 0
 
     @pl.when(live)
     def _():
@@ -164,6 +176,16 @@ def _prefill_kernel(
         mask = _mask_tile(
             q_start, kv_start, qi, ki, block_q, block_k, sliding_window, chunk_size
         )
+        if select_block is not None:
+            # the tile's blocks lie in ONE 32-bit word of a row's selection
+            # (block_k // select_block divides 32): pick the word, test the bit
+            words = bits_ref[0]  # (block_q, n_words) int32
+            first = ki * (block_k // select_block)  # the tile's first block
+            lane = jax.lax.broadcasted_iota(jnp.int32, words.shape, 1)
+            word = jnp.sum(jnp.where(lane == first // 32, words, 0), axis=1, keepdims=True)
+            bit = first % 32 + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1) // select_block
+            mask &= ((word >> bit) & 1) == 1
         _online_softmax_step(s, mask, m_ref, l_ref, acc_ref, v)
 
     @pl.when(ki == n_kv_blocks - 1)
@@ -185,9 +207,16 @@ def flash_attention_prefill(
     sink=None,  # (H,) learned sink logits, one a head (gpt-oss, mimo-v2 window layers)
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
+    block_mask=None,  # (B, KV, Sq, Sk // select_block) bool: the blocks a query reads
 ):
     """``sink``: the softmax gains one column a head, ``sink[h]``, whose
     probability is dropped (the running max and denominator start from it).
+
+    ``block_mask``: a block selection (ops/block_select.py): query ``s`` of kv
+    head ``g`` attends key ``c`` only where ``block_mask[b, g, s, c //
+    select_block]`` (and the position mask holds); a kv tile none of whose
+    blocks any query of the q tile selected is skipped. An unselected block adds
+    nothing to the softmax: the result is the selection's, not a dense one's.
 
     512x1024 default blocks: at 128x128 the (B*H, Sq/bq, Sk/bk) grid hits
     ~65k steps/layer at prefill shapes and per-step overhead dominated the
@@ -214,6 +243,27 @@ def flash_attention_prefill(
     block_q = _pick_block(Sq, block_q)
     block_k = _pick_block(Sk, block_k)
     n_kv_blocks = Sk // block_k
+    select_block = None
+    select_prefetch, select_specs, select_args = [], [], []
+    if block_mask is not None:
+        n_sel = block_mask.shape[-1]
+        select_block = Sk // n_sel
+        per_tile = block_k // select_block  # selection blocks a kv tile spans
+        if block_k % select_block or 32 % per_tile or block_mask.shape != (B, KV, Sq, n_sel):
+            raise ValueError(
+                f"block_mask {block_mask.shape} does not tile (B, KV, Sq, Sk // block) with "
+                f"kv tiles of {block_k}"
+            )
+        tiles = block_mask.reshape(B * KV, Sq // block_q, block_q, n_kv_blocks, per_tile)
+        select_prefetch = [tiles.any(axis=(2, 4)).astype(jnp.int32)]
+        n_words = -(-n_sel // 32)
+        padded = jnp.pad(block_mask, ((0, 0),) * 3 + ((0, n_words * 32 - n_sel),))
+        weights = jnp.left_shift(jnp.uint32(1), jnp.arange(32, dtype=jnp.uint32))
+        words = (padded.reshape(B * KV, Sq, n_words, 32).astype(jnp.uint32) * weights).sum(
+            axis=-1, dtype=jnp.uint32)
+        select_args = [jax.lax.bitcast_convert_type(words, jnp.int32)]
+        select_specs = [pl.BlockSpec(
+            (1, block_q, n_words), lambda bh, qi, ki, *_: ((bh // H) * KV + (bh % H) // G, qi, 0))]
 
     qf = q.reshape(B * H, Sq, D)
     kf = k.reshape(B * KV, Sk, D)
@@ -231,6 +281,8 @@ def flash_attention_prefill(
         block_q=block_q,
         block_k=block_k,
         has_sink=sink is not None,
+        select_block=select_block,
+        G=G,
     )
 
     def kv_index(bh, qi, ki, *prefetch):
@@ -241,13 +293,13 @@ def flash_attention_prefill(
         sink_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
         sink_args = [sink.astype(jnp.float32).reshape(H)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=2 + len(select_prefetch),
         grid=(B * H, Sq // block_q, n_kv_blocks),
         in_specs=sink_specs + [
             pl.BlockSpec((1, block_q, D), lambda bh, qi, ki, *_: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, D), kv_index),
             pl.BlockSpec((1, block_k, Dv), kv_index),
-        ],
+        ] + select_specs,
         out_specs=pl.BlockSpec((1, block_q, Dv), lambda bh, qi, ki, *_: (bh, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),  # running max
@@ -261,7 +313,7 @@ def flash_attention_prefill(
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, Dv), q.dtype),
         name="flash_attention_prefill",
         interpret=mode.interpret(),
-    )(q_start, kv_start, *sink_args, qf, kf, vf)
+    )(q_start, kv_start, *select_prefetch, *sink_args, qf, kf, vf, *select_args)
     return out.reshape(B, H, Sq, Dv)
 
 
@@ -1288,6 +1340,7 @@ def sharded_kernel_call(
     sliding_window=None,
     chunk_size=None,
     sink=None,
+    block_mask=None,
 ):
     """Run the flash kernel per mesh shard via ``shard_map`` (GSPMD cannot
     partition a pallas_call by itself). Head/batch shardings follow the
@@ -1299,21 +1352,32 @@ def sharded_kernel_call(
     attention table never selects this call for it."""
     from jax.sharding import PartitionSpec as P
 
-    fn = functools.partial(
+    base = functools.partial(
         flash_attention_decode if decode else flash_attention_prefill,
         scale=scale,
         sliding_window=sliding_window,
         chunk_size=chunk_size,
     )
-    sinks = ()
-    if sink is not None:  # the prefill kernel's alone (the table's ``computes``)
-        sinks = (sink,)
-        fn = lambda q_, k_, v_, qp_, kp_, s_, fn=fn: fn(q_, k_, v_, qp_, kp_, sink=s_)  # noqa: E731
+    kv_spec = policy.cache_kv if decode else policy.kv
+    # the prefill kernel's alone (the table's ``computes``): one logit a head,
+    # sharded as the heads; a block selection a kv head and query token
+    extras = [
+        (name, operand, spec)
+        for name, operand, spec in (
+            ("sink", sink, P(policy.q[1])),
+            ("block_mask", block_mask, P(policy.q[0], kv_spec[1], policy.q[2], None)),
+        )
+        if operand is not None
+    ]
+
+    def fn(q_, k_, v_, qp_, kp_, *more):
+        return base(q_, k_, v_, qp_, kp_, **{name: x for (name, _, _), x in zip(extras, more)})
+
+    operands = tuple(operand for _, operand, _ in extras)
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
-        return fn(q, k, v, q_pos, kv_pos, *sinks)
+        return fn(q, k, v, q_pos, kv_pos, *operands)
 
-    kv_spec = policy.cache_kv if decode else policy.kv
     q_spec = P(*policy.q)
     qp_spec = P(policy.q[0], policy.q[2])  # (B, Sq) follows q's batch/seq axes
     kp_spec = P(kv_spec[0], None)
@@ -1321,8 +1385,8 @@ def sharded_kernel_call(
         fn,
         mesh=mesh,
         in_specs=(q_spec, P(*kv_spec), P(*kv_spec), qp_spec, kp_spec)
-        + (P(policy.q[1]),) * len(sinks),  # one logit a head, sharded as the heads
+        + tuple(spec for _, _, spec in extras),
         out_specs=q_spec,
         check_vma=False,
     )
-    return shard_fn(q, k, v, q_pos, kv_pos, *sinks)
+    return shard_fn(q, k, v, q_pos, kv_pos, *operands)

@@ -549,7 +549,8 @@ class ModelWrapper:
         each time into a new buffer of its size, on a device that is four
         fifths full (PERF.md, PR 35: single steps of 1-3 s in ``enqueue``)."""
         keys = getattr(self.arch, "ring_cache_keys", ())
-        if not keys or not isinstance(self.layout, BlockKVLayout) or self._mesh is None:
+        plain = getattr(self.arch, "slot_cache_keys", {})  # {leaf: its row-major tiling}
+        if not (keys or plain) or not isinstance(self.layout, BlockKVLayout) or self._mesh is None:
             return {}
         if self._mesh.devices.flat[0].platform != "tpu":
             return {}
@@ -559,7 +560,13 @@ class ModelWrapper:
 
         packed = jnp.dtype(to_jax_dtype(self.arch.dtype)).itemsize == 2
         tiling = ((8, 128), (2, 1)) if packed else ((8, 128),)
-        return {k: Layout(major_to_minor=(0, 1, 2, 4, 3), tiling=tiling) for k in keys}
+        pinned = {k: Layout(major_to_minor=(0, 1, 2, 4, 3), tiling=tiling) for k in keys}
+        # any other store a slot (an index, a recurrent state): row-major as it
+        # is declared, in the tiling its architecture names, for the same reason
+        pinned.update({
+            k: Layout(major_to_minor=(0, 1, 2, 3, 4), tiling=tiles) for k, tiles in plain.items()
+        })
+        return pinned
 
     @property
     def per_slot_cache(self) -> bool:
@@ -567,7 +574,7 @@ class ModelWrapper:
         architecture says so: mimo-v2's window layers): the batch carries the
         rows' slot ids as ``seq_ids`` beside the block tables."""
         return isinstance(self.layout, BlockKVLayout) and bool(
-            getattr(self.arch, "ring_cache_keys", ())
+            getattr(self.arch, "ring_cache_keys", ()) or getattr(self.arch, "slot_cache_keys", ())
         )
 
     def _layout_input_keys(self):

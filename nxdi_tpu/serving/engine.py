@@ -307,6 +307,7 @@ class InferenceEngine:
         # first step whose program returns the count (none for other models)
         self._moe_held_pairs = self._moe_routed_layer_steps = self._moe_routed_layers = None
         self._prefill_moe_held_pairs = self._prefill_moe_expert_rows = None
+        self._sparse_blocks = None  # (read, live) counters of a block-sparse model
         self._kv_store = self._kv_window_rows = None  # ``_kv_held``, on first use
         self._can_continue_prefill = TAG_PREFIX_PREFILL in app.models
         #: the decode dispatch not collected yet (None: nothing in flight)
@@ -1439,8 +1440,9 @@ class InferenceEngine:
             cache, mgr = self.app.kv_cache, self.block_manager
             per_slot = [a for name, a in cache.items() if name not in ("k", "v")]
             self._kv_store = (
-                per_slot[0].shape[3],  # ring rows a slot (L, slots, KV, rows, D)
-                sum(a.nbytes for a in per_slot) // per_slot[0].shape[1],
+                # rows a slot: ring rows (L, slots, KV, rows, D), or the axis the architecture names
+                per_slot[0].shape[getattr(self._tkg.arch, "slot_rows_axis", 3)],
+                sum(a.nbytes // a.shape[1] for a in per_slot),  # bytes a slot, each store its own
                 (cache["k"].nbytes + cache["v"].nbytes) // mgr.num_blocks,
             )
             tel = self.telemetry
@@ -1566,7 +1568,7 @@ class InferenceEngine:
                 submodel=TAG_TOKEN_GENERATION, keep_batch_padding=True, **batch,
             ),
         )
-        for key in ("tokens", "moe_held_pairs"):
+        for key in ("tokens", "moe_held_pairs", "sparse_blocks_read", "sparse_blocks_live"):
             if key in out:  # the copies start now and ride behind the program
                 out[key].copy_to_host_async()
         if prev is not None and self._chained_steps is not None:
@@ -1606,8 +1608,13 @@ class InferenceEngine:
                 if self._moe_routed_layers is None:  # the same number every step
                     self._moe_routed_layers = int(out["moe_routed_layers"])
                 held = int(held)
+            blocks = None
+            if kept and "sparse_blocks_read" in out:
+                blocks = int(out["sparse_blocks_read"]), int(out["sparse_blocks_live"])
         if held is not None:
             self._note_moe_held_pairs(held, self._moe_routed_layers, flight.record)
+        if blocks is not None:
+            self._note_sparse_blocks(*blocks, flight.record)
         clock = self.telemetry.clock if self.telemetry is not None else None
         dt = None
         if clock:
@@ -1935,6 +1942,30 @@ class InferenceEngine:
             )
         self._moe_held_pairs.inc(pairs)
         self._moe_routed_layer_steps.inc(routed_layers)
+
+    def _note_sparse_blocks(self, read: int, live: int, record) -> None:
+        """A model with block-sparse attention layers counts, inside its
+        token-generation program, the pool blocks it read and those visible to
+        it (models/minicpm_sala), summed over rows, KV heads and sparse layers."""
+        if self.flight is not None:
+            self.flight.note_sparse_blocks(read, live, record)
+        if self.telemetry is None:
+            return
+        if self._sparse_blocks is None:
+            r = self.telemetry.registry
+            self._sparse_blocks = (
+                r.counter(
+                    "nxdi_sparse_blocks_read_total",
+                    "pool blocks token generation read in its block-sparse layers "
+                    "(rows x KV heads x layers; batch-padding rows included)",
+                ),
+                r.counter(
+                    "nxdi_sparse_blocks_live_total",
+                    "pool blocks visible to those reads: what a dense read would take",
+                ),
+            )
+        self._sparse_blocks[0].inc(read)
+        self._sparse_blocks[1].inc(live)
 
     def _note_prefill_moe(self, pairs: int, expert_rows: int) -> None:
         """A share's prefill program whose expert layers took the compact form

@@ -77,7 +77,7 @@ class StepRecord:
         "phases", "open_phase", "moe_held_pairs", "moe_routed_layers",
         "prefill_moe_held_pairs", "prefill_moe_expert_rows",
         "kv_live_tokens", "kv_window_rows_held", "kv_bytes_held",
-        "chained", "overrun_tokens",
+        "chained", "overrun_tokens", "sparse_blocks_read", "sparse_blocks_live",
     )
 
     def __init__(self, step: int, t_start: float):
@@ -132,6 +132,12 @@ class StepRecord:
         #: no such prefill ran in the step
         self.prefill_moe_held_pairs: Optional[int] = None
         self.prefill_moe_expert_rows: Optional[int] = None
+        #: a model with block-sparse attention layers: the pool blocks this
+        #: step's token generation READ and the blocks visible to it (what a
+        #: dense read would have taken), summed over rows, KV heads and sparse
+        #: layers as the step program counted them; None for every other model
+        self.sparse_blocks_read: Optional[int] = None
+        self.sparse_blocks_live: Optional[int] = None
         #: a cache tree with a store per SLOT beside the block pool (mimo-v2's
         #: window layers): the tokens of the seated requests at the end of the
         #: step, the ring rows their slots hold (rows a slot x busy slots),
@@ -196,6 +202,8 @@ class StepRecord:
             "kv_bytes_held": self.kv_bytes_held,
             "chained": self.chained,
             "overrun_tokens": self.overrun_tokens,
+            "sparse_blocks_read": self.sparse_blocks_read,
+            "sparse_blocks_live": self.sparse_blocks_live,
         }
 
 
@@ -373,6 +381,12 @@ class FlightRecorder:
         if rec is not None:
             rec.moe_held_pairs = (rec.moe_held_pairs or 0) + int(pairs)
             rec.moe_routed_layers = int(routed_layers)
+
+    def note_sparse_blocks(self, read: int, live: int, rec=None) -> None:
+        rec = rec or self.current
+        if rec is not None:
+            rec.sparse_blocks_read = (rec.sparse_blocks_read or 0) + int(read)
+            rec.sparse_blocks_live = (rec.sparse_blocks_live or 0) + int(live)
 
     def note_prefill_moe(self, pairs: int, expert_rows: int) -> None:
         rec = self.current

@@ -5,7 +5,9 @@ v5e:2x2: `qwen25-3b` token generation at 64 rows and CTE 256 / 512 / 1024 / 2048
 optimised HLO text is written with what differs from process to process or with the sources' line
 numbers blanked, so that two trees' programs compare with `cmp` (`pr32_hlo_cmp.sh` does both).
 
-    python3 scripts/chip_calls/pr32_cells_hlo.py <repo root> <output directory>
+    python3 scripts/chip_calls/pr32_cells_hlo.py <repo root> <output directory> [<config>:<largest prompt> ...]
+
+(PR 37: other configurations than the two of PR 32 as further arguments.)
 """
 import json
 import os
@@ -30,7 +32,8 @@ topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
 # column 0) differs where the checkout's path or the sources' line numbers moved
 FRAME_TABLE = re.compile(r'(FileNames|FunctionNames|FileLocations|StackFrames)|\d+ ["{]')
 # the cells' largest prompts: chat-steady 2048 (qwen25-3b), reason-saturated 1024 (pangu)
-CELLS = {"qwen25-3b": 2048, "pangu-ultra-moe-ep16": 1024}
+CELLS = {name: int(size) for name, size in (pair.split(":") for pair in sys.argv[3:])} or {
+    "qwen25-3b": 2048, "pangu-ultra-moe-ep16": 1024}
 
 for name, max_prompt in CELLS.items():
     with open(os.path.join(root, "benchmark", "configs", f"{name}.json")) as f:

@@ -92,7 +92,9 @@ def call(
         inputs["mixed_row_ids"] = _s((1, S), I32)
         inputs["last_token_index"] = _s((2,), I32)  # two rows packed into the stream
     extra = {"attn_mask": _s((b, S, CACHE_LEN), jnp.bool_), "write_positions": _s((b, S), I32),
-             "bidir_spans": _s((b, S), I32)}
+             "bidir_spans": _s((b, S), I32),
+             # a block selection: a fresh call's block mask, a decode step's own table
+             "block_select": _s((b, 2), I32) if attend else _s((b, KV, S, max(S // BLOCK, 1)), jnp.bool_)}
     inputs.update({k: extra[k] for k in ci})
     if mla:
         p_attn = {"q_proj": {"w": _s((HID, H * 16))}, "kv_a": {"w": _s((HID, 32 + 8))},
@@ -165,6 +167,9 @@ SINK, SOFTCAP, V_WIDTH = dict(attention_sink=True), dict(attn_logit_softcap=30.0
 KV_SEQ_SHARDED, ROWS_SHARDED = dict(sharded="kv_seq"), dict(sharded="rows")
 
 CASES = {
+    # -- PR 37: a block selection reaches the two kernels that read one
+    "block-select-fresh": (dict(FRESH, ci=("block_select",)), "cte_flash_kernel"),
+    "block-select-paged-decode": (dict(PAGED_DECODE, ci=("block_select",)), "tkg_paged_kernel"),
     # -- each of the sixteen names reached
     "spec-window": (dict(spec=True, defer=True), "tkg_spec_window_xla"),
     "stacked": (dict(DEFERRED, stacked=True), "tkg_fused_kernel_stacked"),
@@ -299,6 +304,12 @@ REFUSED_SINCE_THE_TABLE = {
     "latent-fresh-chunk": (dict(FRESH, mla=True, arch=CHUNK), "latent attention computes no .*'chunk'"),
     "latent-paged-softcap": (dict(PAGED_DECODE, mla=True, arch=SOFTCAP), "latent attention computes no .*'softcap'"),
     "latent-attn-mask": (dict(mla=True, S=4, ci=("attn_mask",)), "latent attention computes no .*'attn_mask'"),
+    # PR 37: a block selection is the two kernels' alone; no XLA row stands in with a dense pass
+    "block-select-fresh-no-kernel": (dict(attend=False, S=8, ci=("block_select",)), "'block_select'.*another model"),
+    "block-select-paged-decode-no-kernel": (dict(layout="block", ci=("block_select",)), "'block_select'.*another model"),
+    "block-select-deferred": (dict(DEFERRED, ci=("block_select",)), "'block_select'"),
+    "block-select-paged-prefill": (dict(PAGED_PREFILL, ci=("block_select",)), "'block_select'"),
+    "block-select-fresh-wrong-flag": (dict(attend=False, S=8, flags=(TKG,), ci=("block_select",)), "'block_select'"),
 }
 
 

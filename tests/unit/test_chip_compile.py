@@ -21,6 +21,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -716,3 +717,114 @@ def test_two_store_share_prefills_its_largest_bucket_compact(topo, mosaic):
     assert "moe.experts.compact" in text and "moe.experts.dense" not in text and "ragged-dot" not in text
     assert _a_layers_experts_as_buffers(text, config) == []
     assert _pool_movers(text, cache["k"].shape) + _pool_movers(text, cache["v"].shape) == []
+
+
+# -- PR 37: a stage of block-sparse and lightning layers: pool, index and state in place --
+
+
+def _sala_stage():
+    import json
+
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "benchmark", "configs",
+                        "minicpm-sala-l12.json")
+    with open(path) as f:
+        body = json.load(f)
+    own = ("name", "source", "deployment", "reduced", "published", "published_why", "assumed", "benchmark")
+    return {k: v for k, v in body.items() if k not in own}, body["benchmark"]
+
+
+def _sala_app(topo, bucket):
+    from nxdi_tpu.config import OnDeviceSamplingConfig, TpuConfig
+    from nxdi_tpu.models.registry import get_family
+    from nxdi_tpu.parallel.mesh import mesh_from_config
+
+    config, b = _sala_stage()
+    family, cfg_cls = get_family(config["model_type"])
+    tc = TpuConfig(
+        tp_degree=1, dtype="bfloat16", on_device_sampling_config=OnDeviceSamplingConfig(),
+        is_block_kv_layout=True, telemetry="off",
+        batch_size=b["slots"], ctx_batch_size=1, tkg_batch_size=b["slots"], seq_len=b["seq_len"],
+        max_context_length=bucket, context_encoding_buckets=[bucket],
+        pa_block_size=b["pa_block_size"], pa_num_blocks=b["pa_num_blocks"],
+        attn_kernel_enabled=True, attn_block_tkg_kernel_enabled=True,
+    )
+    app = family.APPLICATION_CLS(
+        "<shapes>", cfg_cls(tc, load_config=lambda: dict(config)), model_family=family)
+    app.mesh = mesh_from_config(tc, devices=topo.devices[:1])
+    app._build_wrappers()
+    return app, b
+
+
+def _store_movers(hlo_text, cache, movers=_MOVERS):
+    """Copies, slices or stackings of something the size of a whole cache leaf
+    (in any of its views), in the optimised HLO."""
+    found = []
+    for name, s in cache.items():
+        n = int(np.prod(s.shape))
+        for line in hlo_text.splitlines():
+            m = _HLO_LINE.match(line)
+            if not m:
+                continue
+            dims = re.match(r"\w+\[([\d,]+)\]", m.group(2))
+            if not dims or int(np.prod([int(d) for d in dims.group(1).split(",")])) != n:
+                continue
+            inst, _, opcode = m.groups()
+            if opcode in movers or (opcode == "fusion" and any(w in inst for w in movers)):
+                found.append(f"{name}: {opcode} {inst} {m.group(2)}")
+    return found
+
+
+def test_sparse_linear_stage_decodes_with_pool_index_and_state_in_place(topo, mosaic):
+    """The cell's real token-generation program (32 rows, 24 640 positions) at the
+    published widths: pool, index and state aliased from the donated input to the
+    output, no copy of any of them in any view (the index as (L, slots, KV, rows,
+    D) was relaid whole on the way in: 76 MB a step), ``temp`` tens of MiB, the
+    paged decode kernel over the compact tables and the state's in-place kernel."""
+    app, b = _sala_app(topo, 256)
+    cache = app._cache_struct()
+    blocks = b["pa_num_blocks"] * b["pa_block_size"]
+    assert cache["k"].shape == cache["v"].shape == (3, blocks, 2, 128)
+    assert cache["kc"].shape == (3, 32, 1552, 2, 128) and cache["lin_state"].shape == (9, 33, 32, 128, 128)
+    held = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in cache.values())
+    assert held == pytest.approx(3.115e9, rel=0.01)  # pool 2.42 GB, index 0.08, state 0.62
+
+    (compiled,) = app.models["token_generation_model"].aot_compile(
+        app.build_params_struct(), cache).values()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= held, memory
+    assert memory.temp_size_in_bytes < 64 * 2 ** 20, memory
+    assert memory.argument_size_in_bytes < 10.3 * 2 ** 30, memory  # 7.86 GB of weights + the caches
+    text = compiled.as_text()
+    assert "paged_attention_decode" in text and "lightning_decode_step" in text
+    assert _store_movers(text, cache) == []
+    (prog,) = app.models["token_generation_model"]._programs.values()
+    assert set(prog.attention_strategies) == {"tkg_paged_kernel"}
+    for scope in ("layers.sparse", "layers.lightning", "attn.index", "attn.select", "lin.step", "lin.out"):
+        assert scope in text, scope
+    # every store in ONE memory layout in both kinds of program
+    (prefill,) = app.models["context_encoding_model"].aot_compile(
+        app.build_params_struct(), cache).values()
+    for name in cache:
+        assert prefill.input_formats[0][1][name].layout == compiled.input_formats[0][1][name].layout, name
+
+
+def test_sparse_linear_stage_prefills_16k_beside_what_it_holds(topo, mosaic):
+    """CTE[16384], the bucket every prompt of the cell takes: the flash kernel
+    with the selection's mask operand, the chunked linear attention in XLA, and
+    ``temp`` that leaves the 16 GB chip room beside 10.2 GiB of arguments."""
+    app, _ = _sala_app(topo, 16384)
+    cache = app._cache_struct()
+    (compiled,) = app.models["context_encoding_model"].aot_compile(
+        app.build_params_struct(), cache).values()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 2.5 * 2 ** 30, memory  # 2.27 GiB: the MLP's, the scores' and the float32 q, k, v's
+    assert memory.temp_size_in_bytes + memory.argument_size_in_bytes < 13.0 * 2 ** 30, memory
+    text = compiled.as_text()
+    assert "flash_attention_prefill" in text and "lin.chunk" in text and "attn.select" in text
+    # a slot's index rows and state land by a dynamic-update-slice of the donated store: no copy of
+    # the pool or of the state. The 76 MB index alone is relaid KV-major for the prompt's rows and
+    # back, once a prefill of ~1 s (PERF.md section 7)
+    big = {name: s for name, s in cache.items() if name != "kc"}
+    assert _store_movers(text, big, ("copy",)) == []
+    (prog,) = app.models["context_encoding_model"]._programs.values()
+    assert set(prog.attention_strategies) == {"cte_flash_kernel"}

@@ -455,3 +455,107 @@ def test_block_layout_writes_a_wide_key_as_lane_tiles():
     assert float(jnp.abs(k_pool).sum()) == pytest.approx(float(jnp.abs(k_new[0]).sum()), rel=1e-6)
     kk, vv, _ = layout.read(k_pool, v_pool, ci, spec)
     np.testing.assert_array_equal(np.asarray(kk[0, :, 5 - 4]), np.asarray(k_new[0, :, 0]))
+
+
+# -- PR 37: a block selection: the prefill kernel's mask operand, the decode kernel's compact table --
+
+
+@pytest.mark.parametrize("S, select_block, bq, bk", [(64, 8, 16, 32), (128, 8, 32, 128), (64, 4, 8, 64)],
+                         ids=["4-blocks-a-tile", "16-blocks-a-tile", "one-kv-tile"])
+def test_prefill_kernel_reads_the_selected_blocks_alone(S, select_block, bq, bk):
+    """A block mask a (kv head, query token): a key is attended iff its block is
+    selected and it is not in the future. Whole kv tiles that no query of a q
+    tile selected are skipped; the result is the masked softmax all the same."""
+    from nxdi_tpu.ops.attention import grouped_attention
+
+    B, H, KV, D = 2, 8, 2, 16
+    q, k, v = _rand((B, H, S, D), 0), _rand((B, KV, S, D), 1), _rand((B, KV, S, D), 2)
+    pos = jnp.tile(jnp.arange(S, dtype=jnp.int32), (B, 1))
+    rng = np.random.default_rng(5)
+    NB = S // select_block
+    mask = rng.random((B, KV, S, NB)) < 0.3
+    own = np.arange(S)[:, None] // select_block == np.arange(NB)[None, :]
+    mask |= own[None, None]  # a query's own block, as the selection always holds it
+    mask[:, :, : S // 2, NB // 2:] = False  # whole tiles nobody selected (and the future)
+    mask |= own[None, None]
+    block_mask = jnp.asarray(mask)
+    actual = flash_attention_prefill(q, k, v, pos, pos, block_q=bq, block_k=bk, block_mask=block_mask)
+    tokens = np.repeat(mask, select_block, axis=-1) & (np.arange(S)[None, :] <= np.arange(S)[:, None])
+    expected = jnp.stack([  # one kv head's group at a time: the mask is the head's
+        grouped_attention(q[:, g * 4:(g + 1) * 4], k[:, g:g + 1], v[:, g:g + 1], jnp.asarray(tokens[:, g]))
+        for g in range(KV)], axis=1).reshape(B, H, S, D)
+    np.testing.assert_allclose(np.asarray(actual), np.asarray(expected), atol=2e-5)
+    dense = flash_attention_prefill(q, k, v, pos, pos, block_q=bq, block_k=bk)
+    assert float(jnp.abs(dense - actual).max()) > 1e-2  # and it is not the dense result
+    with pytest.raises(ValueError, match="block_mask"):
+        flash_attention_prefill(q, k, v, pos, pos, block_q=bq, block_k=bk, block_mask=block_mask[:, :1])
+
+
+def test_paged_decode_kernel_reads_a_compact_table_a_row_and_kv_head():
+    """``block_select.decode_tables`` + the paged decode kernel AS IT IS, over
+    the pool as one-head blocks: every (row, KV head) reads its own ``topk``
+    blocks, chosen by scores over the index of compressed keys; rows under
+    ``dense_len`` read their own table. Against the selection and the softmax
+    written out in numpy."""
+    from nxdi_tpu.ops import block_select
+
+    cfg = block_select.BlockSelectConfig(kernel_size=4, kernel_stride=2, block_size=8, topk=6,
+                                         init_blocks=1, window_size=16, dense_len=64)
+    B, KV, G, D, NB = 3, 2, 4, 16, 16
+    bs, H = cfg.block_size, KV * G
+    rng = np.random.default_rng(11)
+    positions = np.array([100, 37, 127])  # past dense_len, under it, the last position of a block
+    keys = rng.standard_normal((B, KV, NB * bs, D)).astype(np.float32)
+    values = rng.standard_normal((B, KV, NB * bs, D)).astype(np.float32)
+    q = rng.standard_normal((B, KV, G, D)).astype(np.float32)
+    table = rng.permutation(B * NB).reshape(B, NB).astype(np.int32)  # physical blocks, shuffled
+    pool_k = np.zeros((1, B * NB * KV * bs, D), np.float32)
+    pool_v = np.zeros_like(pool_k)
+    for b in range(B):
+        for g in range(KV):
+            for n in range(NB):
+                rows = (table[b, n] * KV + g) * bs + np.arange(bs)
+                pool_k[0, rows] = keys[b, g, n * bs:(n + 1) * bs]
+                pool_v[0, rows] = values[b, g, n * bs:(n + 1) * bs]
+    kc = block_select.compress_keys(jnp.asarray(keys), cfg)  # (B, J, KV, D)
+    scale = D ** -0.5
+    tables, q_pos, read, live = block_select.decode_tables(
+        jnp.asarray(q), kc, jnp.asarray(positions), jnp.asarray(table), scale, cfg)
+    assert tables.shape == (B, KV, cfg.table_width) and list(np.asarray(live)) == [13, 5, 16]
+    assert np.asarray(read).tolist() == [[6, 6], [5, 5], [6, 6]]
+    head = jnp.arange(KV, dtype=jnp.int32)[None, :, None]
+    entries = jnp.where(tables >= 0, tables * KV + head, -1).reshape(B * KV, -1)
+    out = paged_attention_decode(
+        jnp.asarray(q).reshape(B * KV, G, 1, D), jnp.asarray(pool_k)[:, :, None, :],
+        jnp.asarray(pool_v)[:, :, None, :], entries, q_pos.reshape(B * KV, 1), jnp.int32(0),
+        block_size=bs, scale=scale,
+    ).reshape(B, KV, G, D)
+
+    kc_np = np.asarray(kc)
+    for b, t in enumerate(positions):
+        cur = t // bs
+        for g in range(KV):
+            if t < cfg.dense_len:
+                chosen = np.arange(cur + 1)
+            else:
+                whole = np.arange(kc_np.shape[1]) * cfg.kernel_stride + cfg.kernel_size - 1 <= t
+                s = np.einsum("gd,jd->gj", q[b, g], kc_np[b, :, g]) * scale
+                s = np.where(whole, s, -np.inf)
+                p = np.exp(s - s.max(axis=-1, keepdims=True))
+                mass = (p / p.sum(axis=-1, keepdims=True)).sum(axis=0)
+                score = np.full(NB, -np.inf)
+                for n in range(cur + 1):  # windows that share a position with block n
+                    js = [j for j in range(len(mass)) if whole[j] and j * 2 + 3 >= n * bs and j * 2 <= n * bs + bs - 1]
+                    score[n] = max((mass[j] for j in js), default=-np.inf)
+                forced = [n for n in range(cur + 1) if n < 1 or n >= max(t - 16 + 1, 0) // bs]
+                rest = sorted((n for n in range(cur + 1) if n not in forced), key=lambda n: -score[n])
+                chosen = np.array(sorted(forced + rest[: cfg.topk - len(forced)]))
+                assert len(chosen) == cfg.topk
+                got = np.asarray(tables[b, g])
+                assert got[got >= 0].tolist() == table[b, chosen].tolist(), (b, g)
+            cols = np.concatenate([np.arange(n * bs, (n + 1) * bs) for n in chosen])
+            cols = cols[cols <= t]
+            a = np.einsum("gd,sd->gs", q[b, g], keys[b, g, cols]) * scale
+            a = np.exp(a - a.max(axis=-1, keepdims=True))
+            want = (a / a.sum(axis=-1, keepdims=True)) @ values[b, g, cols]
+            np.testing.assert_allclose(np.asarray(out[b, g]), want, atol=2e-5)
