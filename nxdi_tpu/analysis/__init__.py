@@ -53,6 +53,7 @@ _EXPORTS = {
     "audit_application": "nxdi_tpu.analysis.auditor",
     "audit_wrapper": "nxdi_tpu.analysis.auditor",
     "check_cache_format_agreement": "nxdi_tpu.analysis.auditor",
+    "check_param_formats": "nxdi_tpu.analysis.auditor",
     "collective_summary": "nxdi_tpu.analysis.auditor",
     # budget
     "expected_collective_budget": "nxdi_tpu.analysis.budget",

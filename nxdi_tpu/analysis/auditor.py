@@ -57,6 +57,16 @@ def _cache_input_formats(compiled):
     return compiled.input_formats[0][1]
 
 
+def _param_input_formats(compiled):
+    """The layouts an executable takes its params in (arg 0)."""
+    return compiled.input_formats[0][0]
+
+
+#: the passes over the whole program set (not per-program checkers): valid
+#: names in a ``checkers`` selection beside ``CHECKERS``
+CROSS_PROGRAM = ("cache_format", "param_format")
+
+
 def _key_str(key) -> str:
     if isinstance(key, tuple):
         return "k" + ",".join(str(k) for k in key)
@@ -83,6 +93,10 @@ class ProgramReport:
     # stringified per-leaf cache input formats (AUTO layout resolution) for
     # the cross-program agreement check; None when the backend has no view
     cache_formats: Optional[tuple] = None
+    # the same for the params (check_param_formats), and the instructions
+    # that move a weight into another layout (analysis/hlo.py param_moves)
+    param_formats: Optional[tuple] = None
+    param_moves: List[str] = field(default_factory=list)
 
     def to_dict(self) -> dict:
         return {
@@ -98,6 +112,7 @@ class ProgramReport:
             "cache_formats": (
                 list(self.cache_formats) if self.cache_formats else None
             ),
+            "param_moves": list(self.param_moves),
             "findings": [f.to_dict() for f in self.findings],
         }
 
@@ -176,13 +191,13 @@ def audit_wrapper(
         shared = {}
 
     config = config or wrapper.config
-    # "cache_format" is the cross-program pass audit_application runs — a
-    # valid selection here, just not a per-program checker. Anything else
-    # unknown still surfaces as a finding (a typo'd name must not read as
-    # "checker ran clean").
+    # the CROSS_PROGRAM passes audit_application runs are valid selections
+    # here, just not per-program checkers. Anything else unknown still
+    # surfaces as a finding (a typo'd name must not read as "checker ran
+    # clean").
     requested = list(checkers) if checkers is not None else list(CHECKERS)
     names = [n for n in requested if n in CHECKERS]
-    unknown = [n for n in requested if n not in CHECKERS and n != "cache_format"]
+    unknown = [n for n in requested if n not in CHECKERS and n not in CROSS_PROGRAM]
 
     def attach(struct, shardings):
         return jtu.tree_map(
@@ -193,6 +208,8 @@ def audit_wrapper(
     ps = attach(params_struct, wrapper._param_shardings)
     cs = attach(cache_struct, wrapper._cache_shardings)
     n_param_leaves = len(jtu.tree_leaves(ps))
+    param_paths = _leaf_paths(ps)
+    param_dims = [tuple(a.shape) for a in jtu.tree_leaves(ps)]
     cache_paths = tuple(_leaf_paths(cs))
     from nxdi_tpu.analysis.costs import tree_bytes
 
@@ -208,7 +225,7 @@ def audit_wrapper(
             report.findings.append(Finding(
                 "auditor", "warning", wrapper.tag, label,
                 f"unknown checker {n!r} requested; known: "
-                f"{sorted(CHECKERS) + ['cache_format']}",
+                f"{sorted(CHECKERS) + list(CROSS_PROGRAM)}",
             ))
         try:
             example = wrapper._example_for_key(key)
@@ -280,6 +297,16 @@ def audit_wrapper(
         report.cache_formats = tuple(
             str(f) for f in jtu.tree_leaves(_cache_input_formats(compiled))
         )
+        # read by check_param_formats
+        report.param_formats = tuple(
+            str(f.layout) for f in jtu.tree_leaves(_param_input_formats(compiled))
+        )
+        if wrapper.attend_to_cache and not wrapper.prefill_to_cache:
+            report.param_moves = [
+                f"{m.what} {m.instruction} {m.shape}: "
+                + " or ".join(param_paths[i] for i in m.leaves)
+                for m in hlo_views.param_moves(art.hlo or "", param_dims, art.kept_args)
+            ]
     return reports
 
 
@@ -314,6 +341,49 @@ def check_cache_format_agreement(
                 f"{ref.label} resolved {list(ref.cache_formats)} (differing "
                 f"leaves: {diff}) — every {ref.tag} -> {report.tag} phase "
                 "transition pays a full-cache relayout at dispatch time",
+            )
+            report.findings.append(f)
+            findings.append(f)
+    return findings
+
+
+def check_param_formats(reports: Sequence[ProgramReport]) -> List[Finding]:
+    """Every program of one app takes the params in the same per-leaf
+    formats (the app holds ONE copy of the weights, laid out once at load:
+    runtime/param_layout.py), and no decode-step program moves a weight into
+    another layout: a copy of a leaf, or of the layer scan's slice of one,
+    inside the step is that copy on every token. Findings on the later
+    program, naming the reference one."""
+    ref = None
+    findings: List[Finding] = []
+    for report in reports:
+        for move in report.param_moves:
+            f = Finding(
+                "param_format", "error", report.tag, report.label,
+                f"{report.label} moves a weight into another layout on every "
+                f"step: {move} — lay the params out once at load in the format "
+                "this program reads them in (runtime/param_layout.py)",
+            )
+            report.findings.append(f)
+            findings.append(f)
+        if report.param_formats is None:
+            continue
+        if ref is None:
+            ref = report
+            continue
+        if report.param_formats != ref.param_formats:
+            diff = [
+                i for i, (a, b) in enumerate(zip(report.param_formats, ref.param_formats))
+                if a != b
+            ] or ["count"]
+            f = Finding(
+                "param_format", "error", report.tag, report.label,
+                f"the params' layouts disagree across the program set: "
+                f"{report.label} takes leaves {diff} as "
+                f"{[report.param_formats[i] for i in diff if isinstance(i, int)]} but "
+                f"{ref.label} as {[ref.param_formats[i] for i in diff if isinstance(i, int)]}"
+                " — one copy of the weights cannot serve both without a relayout "
+                "on every call",
             )
             report.findings.append(f)
             findings.append(f)
@@ -355,6 +425,8 @@ def audit_application(
     # (not a per-program checker — it needs the whole program set)
     if checkers is None or "cache_format" in checkers:
         check_cache_format_agreement(report.programs)
+    if checkers is None or "param_format" in checkers:
+        check_param_formats(report.programs)
     guard = getattr(app, "retrace_guard", None)
     if guard is not None:
         report.retrace = guard.to_dict()

@@ -206,12 +206,13 @@ def main(argv: Optional[List[str]] = None) -> int:
         use_cpu_backend(max(8, args.tp_degree))
 
     from nxdi_tpu.analysis import CHECKERS, audit_application
+    from nxdi_tpu.analysis.auditor import CROSS_PROGRAM
 
     checkers = None
     if args.checkers:
         checkers = [c.strip() for c in args.checkers.split(",") if c.strip()]
-        # cache_format is the cross-program agreement pass (not per-program)
-        unknown = sorted(set(checkers) - set(CHECKERS) - {"cache_format"})
+        # the cross-program passes (cache_format, param_format) are not per-program
+        unknown = sorted(set(checkers) - set(CHECKERS) - set(CROSS_PROGRAM))
         if unknown:
             print(f"lint: unknown checkers {unknown}; have {sorted(CHECKERS)}",
                   file=sys.stderr)

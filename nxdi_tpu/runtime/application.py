@@ -150,6 +150,8 @@ class ApplicationBase:
         self.kv_cache = None
         self.is_loaded = False
         self.retrace_guard = None  # created in _build_wrappers per TpuConfig
+        # the params' formats and what load moved (runtime/param_layout.py)
+        self.param_layout = None
         # serving telemetry (nxdi_tpu/telemetry): always-on registry + spans,
         # per TpuConfig(telemetry=...); the wrappers, generation adapter,
         # block manager, and retrace guard all record into it
@@ -239,8 +241,18 @@ class ApplicationBase:
             raise RuntimeError("LoRA serving is not enabled (set lora_config)")
         if path_or_sd is not None:
             self.adapter_cache.register(name, path_or_sd, adapter_cfg)
-        slot, self.params = self.adapter_cache.ensure(name, self.params)
+        slot, params = self.adapter_cache.ensure(name, self.params)
+        # the swapped buffers come back as held by default: lay them out as
+        # every program takes them (never on a call)
+        from nxdi_tpu.runtime import param_layout
+
+        self.params = param_layout.move(params, self.param_formats)
         return slot
+
+    @property
+    def param_formats(self):
+        """The formats every program takes the params in (None: as held)."""
+        return None if self.param_layout is None else self.param_layout.formats
 
     def lora_adapter_id(self, name: str) -> int:
         """Adapter id for a resident adapter (0 = base model)."""
@@ -453,6 +465,15 @@ class ApplicationBase:
 
         cache_host = self.init_cache_host()
         self.kv_cache = shard_pytree(cache_host, self.cache_partition_specs(), self.mesh)
+        # the weights in the layout the token-generation program's dots read,
+        # laid out once here; every program takes them pinned to it
+        from nxdi_tpu.runtime import param_layout
+
+        self.param_layout = param_layout.lay_out(self)
+        self.telemetry.record_param_layout(
+            len(self.param_layout.moved), self.param_layout.moved_bytes
+        )
+        logger.info(param_layout.describe(self.param_layout))
 
         if not self.tpu_config.skip_warmup:
             self.warmup()

@@ -21,6 +21,7 @@ import itertools
 import logging
 import re
 import secrets
+import warnings
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
@@ -129,7 +130,20 @@ def _sanitised(label: str) -> str:
 def _outside_the_persistent_cache(label: str):
     """Yields a program name that starts with the sanitised ``label`` and
     that no persistent-cache entry has or will have; what compiles inside
-    the block is not written to the cache either.
+    the block is not written to the cache either (``_uncached_window``)."""
+    with _uncached_window():
+        yield _uncached_name(label)
+
+
+def _uncached_name(label: str) -> str:
+    """A program name no persistent-cache entry has or will have."""
+    return f"{_sanitised(label)}__{_PROCESS_TOKEN}_{next(_UNCACHED_NAMES)}"
+
+
+@contextlib.contextmanager
+def _uncached_window():
+    """Nothing that compiles inside the block is written to the persistent
+    cache.
 
     Inside the block JAX keeps whole tracebacks in the program's locations
     (its own default). ``enable_persistent_cache`` turns that off so that a
@@ -144,7 +158,7 @@ def _outside_the_persistent_cache(label: str):
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1e9)
     jax.config.update("jax_include_full_tracebacks_in_locations", True)
     try:
-        yield f"{_sanitised(label)}__{_PROCESS_TOKEN}_{next(_UNCACHED_NAMES)}"
+        yield
     finally:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", was)
         jax.config.update("jax_include_full_tracebacks_in_locations", tracebacks)
@@ -158,20 +172,28 @@ def _named(fn, name: str):
     return call
 
 
-# (from format, to format, shape, dtype) -> compiled layout-changing identity
+# (from format, to format, shape, dtype[, "donated"]) -> compiled
+# layout-changing identity
 _RELAYOUTS: Dict[Any, Callable] = {}
 
 
-def _relayout(a, fmt):
+def _relayout(a, fmt, donate: bool = False):
     """``a`` in the memory layout ``fmt`` — ``jax.device_put(a, fmt)`` through
-    an identity program that never touches the persistent cache."""
-    key = (a.format, fmt, a.shape, a.dtype)
+    an identity program that never touches the persistent cache. ``donate``:
+    ``a``'s buffer is given up to the program and freed once it has run (a
+    new layout cannot alias the old buffer, so the two are held together
+    only while the one move runs)."""
+    key = (a.format, fmt, a.shape, a.dtype) + (("donated",) if donate else ())
     move = _RELAYOUTS.get(key)
     if move is None:
         what = f"relayout_{a.dtype}_{'x'.join(map(str, a.shape))}"
-        with _outside_the_persistent_cache(what) as name:
+        with _outside_the_persistent_cache(what) as name, warnings.catch_warnings():
+            # "donated buffers were not usable": no output can alias a buffer
+            # of another layout; the donation still frees it after the run
+            warnings.filterwarnings("ignore", message="Some donated buffers")
             move = _RELAYOUTS[key] = (
-                jax.jit(_named(lambda x: x, name), out_shardings=fmt)
+                jax.jit(_named(lambda x: x, name), out_shardings=fmt,
+                        donate_argnums=(0,) if donate else ())
                 .lower(a)
                 .compile()
             )
@@ -183,7 +205,13 @@ class _AutoLayoutProgram:
     lazily lowered on the first concrete call; the cache pytree is moved
     (``_relayout``) into the executable's preferred input formats when (and
     only when) its current layout differs — one relayout at a program
-    transition (e.g. prefill -> decode), zero in the steady-state chain."""
+    transition (e.g. prefill -> decode), zero in the steady-state chain.
+
+    The params are never moved on a call: they take the formats they are
+    held in (``param_formats`` None) or the formats the app pinned them to
+    (``pin_params``, runtime/param_layout.py), and a param held in another
+    format than a pinned program's is an error — one such copy would be
+    gigabytes on every step."""
 
     def __init__(self, fn, jit_kwargs, label: str = "?", required_strategies=(),
                  retrace_guard=None, persist: bool = True, telemetry=None):
@@ -195,6 +223,12 @@ class _AutoLayoutProgram:
         self.label = label
         self._compiled = None
         self._cache_formats = None
+        # a tree of Format the params are pinned to (None: the args' own)
+        self.param_formats = None
+        self._params_checked = None  # id() of the params tree last checked
+        self._name = None  # the name a persist=False program was last lowered under
+        # XLA options every compile of this program takes (pin_params)
+        self.compiler_options: Dict[str, Any] = {}
         # attention strategies the traced program actually chose (reference:
         # FlashAttentionStrategy logging, attention_base.py:1330) — filled at
         # lowering; silent kernel fallbacks become visible and assertable
@@ -213,10 +247,12 @@ class _AutoLayoutProgram:
         # so a (re)trace after serving starts is caught per TpuConfig
         self.retrace_guard = retrace_guard
 
-    def _lower(self, *args):
-        """The ONE lowering path — AOT artifact (`compile`) and lazy
-        first-call (`__call__`) both come through here, so required-strategy
-        verification and retrace-guard recording provably run on both."""
+    def _lower(self, *args, count: bool = True):
+        """The ONE lowering path — AOT artifact (`compile`), lazy first-call
+        (`__call__`) and the load-time layout pass (`lowered`) all come
+        through here, so required-strategy verification and retrace-guard
+        recording provably run on each. ``count``: the program's expert
+        forms go into the registry now (False: at ``install``)."""
         from nxdi_tpu.ops import moe as moe_ops
 
         if self.retrace_guard is not None:
@@ -225,12 +261,13 @@ class _AutoLayoutProgram:
         moe_ops._FORM_TRACE.clear()
         lowered = self.jitted.lower(*args)
         self._snap_strategies()
-        self._snap_expert_forms(moe_ops)
-        return lowered
-
-    def _snap_expert_forms(self, moe_ops):
         if moe_ops._FORM_TRACE:  # empty: no expert layer, or a tracing-cache hit
             self.expert_forms = tuple(sorted(set(moe_ops._FORM_TRACE)))
+        if count:
+            self._count_expert_forms()
+        return lowered
+
+    def _count_expert_forms(self):
         if self.telemetry is not None and self.telemetry.enabled:
             for form in self.expert_forms:
                 self.telemetry.record_expert_form(self.label.partition("[")[0], form)
@@ -261,22 +298,77 @@ class _AutoLayoutProgram:
         compilation cache, or around it where a served executable would be
         wrong (``persist=False``)."""
         if self.persist:
-            return self._lower(*args).compile()
-        with _outside_the_persistent_cache(self.label) as name:
+            return self._lower(*args).compile(self.compiler_options or None)
+        with _uncached_window():
+            return self.lowered(*args).compile(self.compiler_options or None)
+
+    def lowered(self, *args, count: bool = True):
+        """``compile``'s lowering alone; a ``persist=False`` program is lowered
+        under a name of its own, and its ``.compile()`` belongs inside an
+        ``_uncached_window``."""
+        if not self.persist:
+            self._name = _uncached_name(self.label)
+            self.jitted = jax.jit(_named(self._fn, self._name), **self._jit_kwargs)
+        return self._lower(*args, count=count)
+
+    def pin_params(self, formats, compiler_options=None):
+        """Take the params in ``formats`` from now on: a tree of ``Format``
+        (``Layout.AUTO`` leaves: the compiler's choice), or None (the formats
+        the params are held in), and compile with ``compiler_options``. What
+        was compiled is dropped."""
+        self.compiler_options = dict(compiler_options or {})
+        shardings = self._jit_kwargs["in_shardings"]
+        self._jit_kwargs = dict(self._jit_kwargs, in_shardings=(formats,) + tuple(shardings[1:]))
+        fn = self._fn if self._name is None else _named(self._fn, self._name)
+        self.jitted = jax.jit(fn, **self._jit_kwargs)
+        self.param_formats = formats
+        self._compiled = self._cache_formats = self._params_checked = None
+
+    def install(self, compiled, name: Optional[str] = None):
+        """Serve ``compiled`` (built outside ``__call__``, from ``lowered``;
+        ``name``: the name it was lowered under, where that was another
+        program object's)."""
+        if name is not None:
+            self._name = name
             self.jitted = jax.jit(_named(self._fn, name), **self._jit_kwargs)
-            return self._lower(*args).compile()
+        self._compiled = compiled
+        self._cache_formats = compiled.input_formats[0][1]
+        self._params_checked = None
+        self._count_expert_forms()
+
+    def _check_params(self, params):
+        """A pinned program's params must be held in its formats: a call never
+        moves them (JAX would, silently, on every call)."""
+        want = jax.tree_util.tree_leaves(self._compiled.input_formats[0][0])
+        held = jax.tree_util.tree_flatten_with_path(params)[0]
+        for (path, a), f in zip(held, want):
+            if a.format.layout != f.layout:
+                raise ValueError(
+                    f"{self.label}: param {jax.tree_util.keystr(path)} is held in "
+                    f"{a.format.layout}, but the program takes it in {f.layout}: "
+                    "params are laid out once at load (runtime/param_layout.py), "
+                    "never on a call — lay out the new weights the same way"
+                )
+        self._params_checked = id(params)
 
     def __call__(self, params, cache, batch):
         if self._compiled is None:
             # AUTO layouts resolve at compile time, so lowering must see
             # ABSTRACT args (concrete arrays carry a fixed layout and trip
-            # jit's layout check)
-            absargs = jax.tree_util.tree_map(
+            # jit's layout check); the params keep theirs: an unpinned program
+            # takes them as they are held (laid out at load or not)
+            absargs = (
+                jax.tree_util.tree_map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.format), params
+                ),
+            ) + jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
-                (params, cache, batch),
+                (cache, batch),
             )
             self._compiled = self.compile(*absargs)
             self._cache_formats = self._compiled.input_formats[0][1]
+        if self.param_formats is not None and id(params) != self._params_checked:
+            self._check_params(params)
         flat, treedef = jax.tree_util.tree_flatten(cache)
         fmts = jax.tree_util.tree_leaves(self._cache_formats)
         moved = [
@@ -520,6 +612,14 @@ class ModelWrapper:
             persist=not isinstance(self.layout, BlockKVLayout),
             telemetry=self.telemetry,
         )
+
+    def pin_params(self, formats, compiler_options=None) -> None:
+        """Every program of this wrapper takes the params in ``formats`` (a
+        tree of ``Format``; None: the formats they are held in) from now on,
+        compiled with ``compiler_options`` — ``runtime/param_layout.py``
+        decides both once for the whole app."""
+        for prog in self._programs.values():
+            prog.pin_params(formats, compiler_options)
 
     def _required_strategies(self):
         """Kernel flags this program MUST engage (checked post-lowering).

@@ -52,6 +52,8 @@ Metric catalog (labels in parens):
 ``nxdi_serve_preemptions_total``      counter
 ``nxdi_program_lowerings_total``      counter    (phase: warmup|serving)
 ``nxdi_moe_expert_form_programs_total`` counter  (submodel, form: dense|sorted|compact)
+``nxdi_param_leaves_relaid``          gauge      weight leaves laid out anew at load
+``nxdi_param_bytes_relaid``           gauge      their bytes (runtime/param_layout.py)
 ``nxdi_program_mfu_pct``              gauge      (submodel, bucket, steps)
 ``nxdi_program_hbm_bw_pct``           gauge      (submodel, bucket, steps)
 ``nxdi_roofline_gap_ratio``           gauge      (submodel, bucket, steps)
@@ -477,6 +479,16 @@ class Telemetry:
             "its shapes (ops/moe.py expert_form)",
             ("submodel", "form"),
         )
+        # set once at load (runtime/param_layout.py): the weight leaves laid
+        # out in the formats the token-generation program reads them in
+        self.param_leaves_relaid = r.gauge(
+            "nxdi_param_leaves_relaid",
+            "weight leaves moved at load into the layout the token-generation "
+            "program's dots read (0: every program reads them as held)",
+        )
+        self.param_bytes_relaid = r.gauge(
+            "nxdi_param_bytes_relaid", "bytes of the weight leaves moved at load"
+        )
         # roofline gauges, set by the cost-observatory attachment
         # (analysis/costs.attach_cost_gauges) from measured-mean / CostSheet
         self.program_mfu_pct = r.gauge(
@@ -671,6 +683,12 @@ class Telemetry:
 
     def record_expert_form(self, submodel: str, form: str) -> None:
         self.expert_form_programs.inc(submodel=submodel, form=form)
+
+    def record_param_layout(self, leaves: int, nbytes: int) -> None:
+        if not self.enabled:
+            return
+        self.param_leaves_relaid.set(leaves)
+        self.param_bytes_relaid.set(nbytes)
 
     def attach_flight(self, recorder) -> None:
         """Adopt an engine's :class:`~nxdi_tpu.telemetry.flight.FlightRecorder`:

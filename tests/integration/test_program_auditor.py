@@ -777,6 +777,104 @@ def test_cache_format_agreement_pure_function():
 
 
 # ---------------------------------------------------------------------------
+# the params: one set of formats for every program, no weight moved in a step
+# ---------------------------------------------------------------------------
+
+def _pin_q_transposed(app):
+    """Pin the token-generation program to take the q projection stack
+    transposed (the CPU compiler keeps weights in the default layout, so the
+    seeded format is what a TPU program might have been handed)."""
+    from jax.experimental.layout import Format, Layout
+
+    app._build_wrappers()
+    tkg = app.models[TAG_TOKEN_GENERATION]
+
+    def fmt(path, s, sh):
+        dims = tuple(range(len(s.shape)))
+        if "q_proj" in jax.tree_util.keystr(path) and len(dims) == 3:
+            dims = (0, 2, 1)
+        return Format(Layout(major_to_minor=dims), sh)
+
+    tkg.pin_params(jax.tree_util.tree_map_with_path(fmt, app.build_params_struct(), tkg._param_shardings))
+
+
+def test_param_format_clean_on_reference_app():
+    """Every program takes the weights as they are held and no decode step
+    moves one: the auditor recorded each program's param formats."""
+    report = make_app().audit()
+    assert errors_of(report, "param_format") == []
+    formats = {p.param_formats for p in report.programs}
+    assert len(formats) == 1 and None not in formats
+    assert all(p.param_moves == [] for p in report.programs)
+
+
+def test_param_format_disagreement_detected(monkeypatch):
+    """Two programs taking the weights in different layouts cannot share the
+    app's one copy of them."""
+    from nxdi_tpu.analysis import auditor as auditor_mod
+
+    real = auditor_mod._param_input_formats
+    seen = []
+
+    def drifting(compiled):
+        from jax.experimental.layout import Format, Layout
+
+        seen.append(compiled)
+        leaves, treedef = jax.tree_util.tree_flatten(real(compiled))
+        if len(seen) > 1:  # the second program takes its first 3-d leaf transposed
+            i = next(i for i, f in enumerate(leaves) if len(f.layout.major_to_minor) == 3)
+            leaves[i] = Format(Layout(major_to_minor=(0, 2, 1)), leaves[i].sharding)
+        return jax.tree_util.tree_unflatten(treedef, leaves)
+
+    monkeypatch.setattr(auditor_mod, "_param_input_formats", drifting)
+    findings = errors_of(make_app().audit(checkers=["param_format"]), "param_format")
+    assert len(findings) == 1, findings
+    msg = findings[0].message
+    assert "disagree" in msg and "(0, 2, 1)" in msg
+    assert "context_encoding_model[32]" in msg and "token_generation_model[64]" in msg
+
+
+def test_param_format_finds_a_weight_moved_inside_the_decode_step():
+    """A decode program handed a weight in a layout its dot does not read
+    copies it on every step: named, with the leaf's path, on that program."""
+    app = make_app()
+    _pin_q_transposed(app)
+    findings = errors_of(app.audit(checkers=["param_format"]), "param_format")
+    moves = [f for f in findings if "every step" in f.message]
+    assert moves and all(f.program == "token_generation_model[64]" for f in moves)
+    assert "['layers']['attn']['q_proj']['w']" in moves[0].message
+    # and the prefill, which takes it as held, disagrees with it
+    assert any("disagree" in f.message for f in findings)
+
+
+def test_param_format_pure_function():
+    from nxdi_tpu.analysis import check_param_formats
+    from nxdi_tpu.analysis.auditor import ProgramReport
+
+    agree = [
+        ProgramReport("cte", 32, "cte[32]", param_formats=("A", "B")),
+        ProgramReport("tkg", 64, "tkg[64]", param_formats=("A", "B")),
+        ProgramReport("x", None, "x[?]", param_formats=None),  # no view: skipped
+    ]
+    assert check_param_formats(agree) == []
+    moved = [
+        ProgramReport("cte", 32, "cte[32]", param_formats=("A", "B")),
+        ProgramReport("tkg", 64, "tkg[64]", param_formats=("A", "C"),
+                      param_moves=["copy copy.1 bf16[1,8,8]: ['w']"]),
+    ]
+    findings = check_param_formats(moved)
+    assert [f.checker for f in findings] == ["param_format", "param_format"]
+    assert {f.program for f in findings} == {"tkg[64]"}
+    assert moved[1].findings == findings
+
+
+def test_cli_lint_accepts_param_format_checker_name():
+    from nxdi_tpu.cli.lint import main
+
+    assert main(["--reference-app", "--tp-degree", "1", "-q", "--checkers", "param_format"]) == 0
+
+
+# ---------------------------------------------------------------------------
 # retrace guard
 # ---------------------------------------------------------------------------
 

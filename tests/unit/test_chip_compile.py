@@ -341,6 +341,53 @@ def test_paged_step_program_keeps_the_pool_in_place(tag, topo, mosaic):
     assert _pool_movers(text, pool_shape) == []
 
 
+def _laid_out(app):
+    """The app's programs compiled by the load-time layout pass
+    (runtime/param_layout.py ``decide``) for abstract params held in the
+    default layout: ``(result, param dims, {label: compiled})``."""
+    from nxdi_tpu.runtime import param_layout
+
+    tkg = app.models["token_generation_model"]
+
+    def attach(struct, shardings):
+        return jax.tree_util.tree_map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh), struct, shardings)
+
+    params = attach(app.build_params_struct(), tkg._param_shardings)
+    cache = attach(app._cache_struct(), tkg._cache_shardings)
+    result = param_layout.decide(app, params, cache)
+    compiled = {p.label: p._compiled for w in app.models.values() for p in w._programs.values()}
+    return result, [tuple(a.shape) for a in jax.tree_util.tree_leaves(params)], compiled
+
+
+def test_weights_laid_out_for_the_decode_program_are_never_copied_in_it(topo, mosaic):
+    """`qwen25-3b`'s step programs after the load-time layout pass: held
+    row-major, the q, k and v stacks were sliced a layer at a time inside the
+    decode program's layer loop and each slice copied into the layout the dot
+    reads (`copy.47`, 0.35 ms of a 12.7 ms step on the v5e); laid out for that
+    dot once at load, no program copies a weight, the prefill included."""
+    from nxdi_tpu.analysis.hlo import param_moves
+
+    app = _paged_app(
+        QWEN25_3B, topo.devices[:1],
+        batch_size=ROWS, ctx_batch_size=1, tkg_batch_size=ROWS, seq_len=WINDOW,
+        max_context_length=CTE_BUCKET, context_encoding_buckets=[CTE_BUCKET],
+        pa_block_size=BLOCK, pa_num_blocks=POOL_BLOCKS,
+        attn_kernel_enabled=True, attn_block_tkg_kernel_enabled=True,
+    )
+    (compiled,) = app.models["token_generation_model"].aot_compile(
+        app.build_params_struct(), app._cache_struct()).values()
+    dims = [tuple(a.shape) for a in jax.tree_util.tree_leaves(app.build_params_struct())]
+    as_held = param_moves(compiled.as_text(), dims)
+    assert {m.what for m in as_held} == {"copy of a layer's slice"} and len(as_held) == 3, as_held
+    result, dims, programs = _laid_out(app)
+    assert result.moved == [f"['layers']['attn']['{w}']['w']" for w in ("k_proj", "q_proj", "v_proj")]
+    assert result.kept == [] and result.moved_bytes == 36 * 2048 * (2048 + 2 * 256) * 2
+    for label, c in programs.items():
+        assert param_moves(c.as_text(), dims) == [], label
+        assert c.memory_analysis().temp_size_in_bytes < 2 ** 20, label
+
+
 def _scans(jaxpr):
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "scan":
@@ -654,13 +701,22 @@ def test_two_store_step_program_keeps_the_pool_in_place(topo, mosaic):
     pool_bytes = (4 + 2) * slots * 4 * 128 * 2
     store_bytes = 5 * 128 * 8 * 128 * (192 + 128) * 2
 
-    (compiled,) = app.models["token_generation_model"].aot_compile(
-        app.build_params_struct(), cache).values()
+    # the programs as load() compiles them: the weights in the layout the decode program reads
+    from nxdi_tpu.analysis.hlo import param_moves
+
+    result, dims, programs = _laid_out(app)
+    compiled = programs["token_generation_model[8320]"]
+    assert result.moved == [f"['segments'][{s}]['attn']['{w}_proj']['w']"
+                            for s in range(4) for w in "kqv"]  # q, k, v of each segment
+    assert result.kept == []
+    assert [param_moves(c.as_text(), dims) for c in programs.values()] == [[], []]
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= pool_bytes + store_bytes, memory
-    # ISSUE 35 asked for 256 MiB; 484 MiB is what the program has: a layer's q_proj and o_proj
-    # relaid (101 + 67 MiB) and, a window layer at a time, the gathered ring rows (PERF.md section 7)
-    assert memory.temp_size_in_bytes < 512 * 2 ** 20, memory
+    # 484 MiB with the weights held row-major: a window layer's q, k and v slices relaid inside the
+    # layer loop and the single-layer segments' projections relaid at the entry. 436 MiB laid out
+    # for the decode program; what is left is no weight's (param_moves finds none): a window layer's
+    # gathered ring rows and its float32 scores (PERF.md section 7)
+    assert memory.temp_size_in_bytes < 448 * 2 ** 20, memory
     assert memory.argument_size_in_bytes < 12.0 * 2 ** 30, memory
     text = compiled.as_text()
     assert "paged_attention_decode" in text and "kv_commit_rows" in text
@@ -673,8 +729,10 @@ def test_two_store_step_program_keeps_the_pool_in_place(topo, mosaic):
     assert "layers.full" in text and "layers.window" in text and "attn.sink" in text
     # the ring store in ONE memory layout in every program (rows minor): left to each program's
     # choice a prefill relaid it on the way in and the next decode step on the way back
-    (prefill,) = app.models["context_encoding_model"].aot_compile(
-        app.build_params_struct(), cache).values()
+    prefill = programs["context_encoding_model[256]"]
+    # laid out, a prefill kept a late segment's q_proj in fast memory between executions (XLA's
+    # cross-program prefetch) and lost the placements of its own buffers: it takes none now
+    assert "cross_program_prefetch_index" not in prefill.as_text()
     for name in ("k_swa", "v_swa", "k", "v"):
         decode_layout = compiled.input_formats[0][1][name].layout
         assert prefill.input_formats[0][1][name].layout == decode_layout, name
