@@ -235,9 +235,13 @@ def _kda_segment(arch, seg, hidden, conv, state, lo, hi, ci, attend_to_cache, po
     # a FRESH prefill's rows behind a prompt's last token are bucket padding
     last_real = None if attend_to_cache else ci.get("last_token_index")
     count = held_tally is not None
+    # ([held pairs, routed layers], slot rows where the expert layers are compact), as
+    # run_decoder_layers tallies them
+    tallied = (jnp.zeros((2,), jnp.int32),
+               jnp.int32(0) if moe_ops.expert_form(t.moe, B * S) == "compact" else None)
 
     def body(carry, xs):
-        (h, conv_, state_), pair = carry
+        (h, conv_, state_), (pair, slot_rows) = carry
         lp, li = xs
         with jax.named_scope("attn.block"):
             x = _norm(t, h, lp["input_layernorm"])
@@ -251,13 +255,14 @@ def _kda_segment(arch, seg, hidden, conv, state, lo, hi, ci, attend_to_cache, po
             h = constrain(h + ff, policy.hidden)
         if count:
             pair = pair + jnp.stack([sum((n for n, _ in tally), jnp.int32(0)), jnp.int32(len(tally))])
-        return ((h, conv_, state_), pair), None
+            if slot_rows is not None:
+                slot_rows = sum((r for _, r in tally), slot_rows)
+        return ((h, conv_, state_), (pair, slot_rows)), None
 
     idx = jnp.arange(lo, hi, dtype=jnp.int32)
-    ((hidden, conv, state), pair), _ = jax.lax.scan(
-        body, ((hidden, conv, state), jnp.zeros((2,), jnp.int32)), (seg, idx))
+    ((hidden, conv, state), tallied), _ = jax.lax.scan(body, ((hidden, conv, state), tallied), (seg, idx))
     if count:
-        held_tally.append((pair, None))
+        held_tally.append(tallied)
     return hidden, conv, state
 
 

@@ -146,8 +146,9 @@ class MoEArch:
     # compute-bound on it (analysis/costs.py ``ChipSpec.ridge_rows``: 240.5 on
     # a v5e), resolved ONCE from the TpuConfig where a share's arch is built:
     # what sizes the compact form's slots and puts its crossing
-    # (compact_capacity, expert_form rule (c)). None = built for no chip (a
-    # bare MoEArch): the rule never says compact
+    # (compact_capacity, expert_form rule (c)), and what tells a share that
+    # its dense einsums wait on its weights (expert_form). None = built for no
+    # chip (a bare MoEArch): the rule never says compact and keeps rule (b)
     ridge_rows: Optional[float] = None
 
     def __post_init__(self):
@@ -428,48 +429,57 @@ def expert_form(moe: MoEArch, rows: int) -> str:
     token-generation program and each of its prefill buckets choose apart),
     under the mesh in scope.
 
-    Not sorted where the layer computes with no exchange and both hold:
+    Sorted where the layer exchanges rows with other chips, or where
 
-    (a) ``rows * top_k >= num_experts``: every held expert expects at least
-        one row, so both forms stream all the held weights and the dense one
-        reads nothing extra (one row over 256 experts stays sorted and reads
-        only the experts that were hit);
-    (b) ``experts_here <= 2 * top_k``: the dense form multiplies ``rows *
-        experts_here`` row-experts where the sorted one sends ``rows * top_k``
-        sorted pairs through the grouped matmul, routed here or not; at no
-        more than twice the rows, with no sort, gather or scatter and its
-        einsums at the HBM rate, it won weight-bound and compute-bound alike
-        (PERF.md section 6, PR 31).
+    (a) ``rows * top_k < num_experts``: a held expert expects less than one
+        row, so the sorted form reads only the experts that were hit (one row
+        over 320 experts) where the other two stream all the held weights.
 
-    Of those, compact and not dense where also
+    Otherwise, a share built for a chip (``held_experts`` and ``ridge_rows``
+    set) takes
 
-    (c) the layer is a share built for a chip (``MoEArch.ridge_rows``) and
-        its slots are at most half its row-experts: ``2 * compact_capacity(moe,
-        rows) <= rows``. The slots are no fewer than the chip's ridge (240.5
-        rows on a v5e: 256 slots), so there this is ``rows >= 512`` (and
-        ``num_experts >= 4 * top_k``): past twice the
+    (c) the compact form where its slots are at most half its row-experts:
+        ``2 * compact_capacity(moe, rows) <= rows``. The slots are no fewer
+        than the chip's ridge (240.5 rows on a v5e: 256 slots), so there this
+        is ``rows >= 512`` (and ``num_experts >= 4 * top_k``): past twice the
         ridge the dense einsums are compute-bound on pairs the router never
         made, and the slots' einsums stream the same weights over half the
         rows or fewer. One layer alone on the chip, 16 of 256 held, top 8,
         ms dense / compact at 256 | 512 | 1024 | 4096 rows: hidden 7680
         **3.22** / 3.34 | 5.46 / **3.29** | 10.46 / **3.79** | 39.99 /
         **6.34**; hidden 4096 **2.04** / 2.12 | 3.23 / **2.18** | 6.08 /
-        **2.43** | 22.61 / **3.73** (PERF.md section 6, PR 36).
+        **2.43** | 22.61 / **3.73** (PERF.md section 6, PR 36);
+
+    and the dense form under that. The dense einsums do ``rows`` operations a
+    weight byte whatever the held count, so up to the ridge they wait on the
+    held weights, which the sorted form streams too once (a) fails, and from
+    the ridge to twice it they cost at most twice that stream; the sorted
+    form's grouped matmul reads them at 30-37 % of the HBM rate at 3-6 rows
+    an expert. One Solar-Open2 layer alone on the chip, 40 of 320 held, top
+    8, ms dense / sorted / compact at 128 | 256 | 512 | 1024 rows: **2.43** /
+    5.15 / 2.98 | **2.81** / 5.56 / 3.11 | 4.72 / 6.03 / **3.40** | 9.48 /
+    6.96 / **3.61**; 24 and 32 held read alike (PERF.md section 6, PR 42).
+
+    A layer built for no chip (a whole published layer: 8 experts top 2, 60
+    top 4, 128 top 8, 256 top 8; or a bare ``MoEArch``) knows no ridge and
+    keeps, beside (a),
+
+    (b) ``experts_here <= 2 * top_k`` for the dense form, else sorted: the
+        dense form multiplies ``rows * experts_here`` row-experts where the
+        sorted one sends ``rows * top_k`` sorted pairs through the grouped
+        matmul (PERF.md section 6, PR 31).
 
     Under an expert or intermediate mesh axis the sorted form inside
-    ``shard_map`` stays (its collectives are what analysis/budget.py budgets).
-    A whole published layer (8 experts top 2, 60 top 4, 128 top 8, 256 top 8)
-    fails (b) and stays sorted at every row count."""
+    ``shard_map`` stays (its collectives are what analysis/budget.py budgets)."""
     if moe.dispatch is not None:
         return moe.dispatch
     if not _no_exchange(moe, jax.sharding.get_abstract_mesh()):
         return "sorted"
-    if rows * moe.top_k < moe.num_experts or moe.experts_here > 2 * moe.top_k:
+    if rows * moe.top_k < moe.num_experts:
         return "sorted"
-    share_on_a_chip = moe.held_experts is not None and moe.ridge_rows is not None
-    if share_on_a_chip and 2 * compact_capacity(moe, rows) <= rows:
-        return "compact"
-    return "dense"
+    if moe.held_experts is None or moe.ridge_rows is None:
+        return "dense" if moe.experts_here <= 2 * moe.top_k else "sorted"
+    return "compact" if 2 * compact_capacity(moe, rows) <= rows else "dense"
 
 
 def _expert_act(moe: MoEArch, gate: jax.Array, up: jax.Array) -> jax.Array:

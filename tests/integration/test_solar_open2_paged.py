@@ -3,9 +3,9 @@ layers' float32 state and convolution window a slot beside it, through
 ``InferenceEngine`` and ``BlockSpaceManager``, against the plain reference
 ``benchmark/references/kda_moe_decoder.py`` on LOGITS: after a prefill, and after
 decoding through the pool and both stores. A toy share (tests/benchmark/
-toy_solar.py): 8 of 64 experts held, top 2, so the expert layer runs the sorted
-form as the cell does; the mixers' projections and decays drawn as a trained
-model's would be (long memory, O(1) keys and gates)."""
+toy_solar.py): 8 of 64 experts held, top 2, so the 4-row decode runs the sorted
+form and the 128-row prefill the dense one; the mixers' projections and decays
+drawn as a trained model's would be (long memory, O(1) keys and gates)."""
 
 import os
 import sys
@@ -152,14 +152,40 @@ def test_the_tree_has_a_pool_and_two_stores_and_the_programs_are_recorded(share)
     assert set(strategies["token_generation_model[256]"]) == {"tkg_paged_kernel"}
     assert set(strategies["context_encoding_model[128]"]) == {"cte_flash_kernel"}
     assert serving_app.strategy_faults(share.app, share.cfg["benchmark"]["attention_strategies"]) == []
-    for w in share.app.models.values():
-        for prog in w._programs.values():
-            assert set(prog.expert_forms) == {"sorted"}, prog.label  # 8 held > 2 x top 2
+    forms = {prog.label: prog.expert_forms for w in share.app.models.values() for prog in w._programs.values()}
+    # token generation: 4 rows x top 2 < 64, a held expert expects less than a row (rule (a));
+    # the 128-row prefill streams every held expert anyway and is under twice the ridge: dense
+    assert forms == {"token_generation_model[256]": ("sorted",), "context_encoding_model[128]": ("dense",)}
     recs = [r for r in share.engine.flight.snapshot_records() if r.decode is not None]
     # bytes held count the pool's blocks and both stores of every seated slot
     slot_bytes = 3 * 5 * 3 * 3 * 64 * 2 // 5 + 3 * 5 * 4 * 16 * 16 * 4 // 5
     assert all(r.kv_bytes_held >= slot_bytes * len(r.decode["rows"]) for r in recs if r.kv_bytes_held)
     assert any(r.moe_held_pairs for r in recs)
+
+
+def test_a_compact_prefill_reads_as_the_reference_and_returns_its_pairs_and_slot_rows():
+    """A 400-token prompt in the 512 bucket: 512 rows are twice the slots a
+    held expert gets (the ridge of the chip the share was built for, in whole
+    tiles), so the four routed layers (the GQA layer's scan and the KDA scan)
+    take the compact form; the program returns the live pairs and the slot
+    rows of both scans beside its token, the engine puts them on the step's
+    record, and what it serves reads as the reference."""
+    from nxdi_tpu.ops.moe import compact_capacity
+
+    cfg = toy_share()
+    cfg["benchmark"]["seq_len"] = 1024
+    app, engine = _engine(cfg, 14, slots=2, buckets=(512,))
+    served = _serve(engine, _prompts(14, (400,)), 8)
+    (cte,) = app.models["context_encoding_model"]._programs.values()
+    assert cte.expert_forms == ("compact",)
+    (prefill,) = [r for r in engine.flight.snapshot_records() if r.prefills]
+    slots = 8 * compact_capacity(app.models["context_encoding_model"].arch.gqa.moe, 512)
+    # every routed layer runs a trip at least (a held expert expects 400 x 2 / 64 = 12.5 rows); the pairs
+    # are the real rows': at most 2 a row a layer
+    assert prefill.prefill_moe_expert_rows % slots == 0 and prefill.prefill_moe_expert_rows >= 4 * slots
+    assert 0 < prefill.prefill_moe_held_pairs <= 400 * 2 * 4
+    (gap,) = _gaps(SimpleNamespace(app=app, cfg=cfg), served)
+    assert len(gap) > 4 and gap.max() < LIMIT, gap
 
 
 def test_a_preempted_request_rebuilds_its_state_and_window_in_its_prefill():

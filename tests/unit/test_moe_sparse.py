@@ -80,19 +80,27 @@ def test_sparse_matches_dense(variant):
     np.testing.assert_allclose(np.asarray(out_s), np.asarray(out_d), atol=1e-5)
 
 
+#: what a share's arch resolves from its TpuConfig (models/deepseek, models/mimo_v2): a v5e's 240.5 rows
+V5E_RIDGE = CHIP_SPECS["v5e"].ridge_rows
+#: a share built for a chip whose held experts outnumber twice its top k (Solar-Open2's 40 of 320, top 8, in small)
+WIDE_SHARE = dict(num_experts=80, held_experts=10, ridge_rows=V5E_RIDGE)
+
+
 @pytest.mark.parametrize(
-    "held, rows, chosen",
-    [(None, 10, "sorted"), (4, 10, "dense"), (4, 1, "sorted")],
-    ids=["whole-layer", "share-of-4", "share-at-one-row"],
+    "fields, rows, chosen",
+    [(dict(), 10, "sorted"), (dict(held_experts=4), 10, "dense"), (dict(held_experts=4), 1, "sorted"),
+     (WIDE_SHARE, 64, "dense"), (WIDE_SHARE, 512, "compact")],
+    ids=["whole-layer", "share-of-4", "share-at-one-row", "wide-share-dense", "wide-share-compact"],
 )
-def test_the_layers_own_choice_equals_both_pinned_forms(held, rows, chosen):
+def test_the_layers_own_choice_equals_both_pinned_forms(fields, rows, chosen):
     """``dispatch=None``: the layer chooses from its shapes, records what it
     chose, and gives what either pinned form gives."""
     from nxdi_tpu.ops import moe as moe_ops
 
     rng = np.random.default_rng(4)
     H = 16
-    own = MoEArch(**BASE, held_experts=held, first_held=0 if held is None else 2)
+    held = fields.get("held_experts")
+    own = MoEArch(**{**BASE, **fields}, first_held=0 if held is None else 2)
     p = _params(rng, own, H)
     if held is not None:
         p["experts"] = jax.tree_util.tree_map(lambda w: w[2: 2 + held], p["experts"])
@@ -107,9 +115,6 @@ def test_the_layers_own_choice_equals_both_pinned_forms(held, rows, chosen):
 
 
 # -- the compact form: a share's live pairs in slots ---------------------------------------
-
-#: what a share's builder resolves from its TpuConfig (models/deepseek, models/mimo_v2): a v5e's 240.5 rows
-V5E_RIDGE = CHIP_SPECS["v5e"].ridge_rows
 
 
 def _share(experts, top_k, held, first=2, H=16, seed=0, **fields):
@@ -139,7 +144,7 @@ def _forms(moe, p, x, **kw):
     return out
 
 
-_SHARES = {"4-of-16": (16, 2, 4), "16-of-256": (256, 8, 16)}
+_SHARES = {"4-of-16": (16, 2, 4), "16-of-256": (256, 8, 16), "10-of-80": (80, 2, 10)}
 _ROUTERS = {
     "renormalised": dict(norm_topk_prob=True),
     "biased-sigmoid": dict(sigmoid_routing=True, correction_bias=True, routed_scaling=2.5, norm_topk_prob=True),
@@ -152,8 +157,11 @@ _ROUTERS = {
 @pytest.mark.parametrize("share", sorted(_SHARES))
 def test_compact_equals_both_pinned_forms(share, router, rows):
     """Float32, a share with no exchange: the slots give what every held expert
-    on every row gives and what the sorted pairs give, and count the same pairs."""
+    on every row gives and what the sorted pairs give, and count the same pairs.
+    Each share built for a chip chooses dense at 64 rows and compact at 512,
+    also where its held experts outnumber twice its top k (10 of 80, top 2)."""
     moe, p = _share(*_SHARES[share], seed=rows, **_ROUTERS[router])
+    assert expert_form(moe, rows) == ("dense" if rows < 512 else "compact")
     x = jnp.asarray(np.random.default_rng(rows + 1).standard_normal((1, rows, 16)), jnp.float32)
     got = _forms(moe, p, x)
     for form in ("dense", "sorted"):
@@ -230,6 +238,10 @@ def test_compact_gives_bucket_padding_no_slot():
 # (rows, held experts or None = the whole layer, top k, router width E)
 _CELL = [(rows, 16, 8, 256) for rows in (128, 256, 512, 1024, 2048, 4096)]
 _CELL_WANT = {128: "dense", 256: "dense", 512: "compact", 1024: "compact", 2048: "compact", 4096: "compact"}
+#: solar-open2-ep8's share: 40 of 320 held, top 8 (rule (a) up to 39 rows, dense to 511, compact from 512)
+_SOLAR = [(rows, 40, 8, 320) for rows in (1, 39, 40, 128, 256, 511, 512, 1024)]
+_SOLAR_WANT = {1: "sorted", 39: "sorted", 40: "dense", 128: "dense", 256: "dense", 511: "dense",
+               512: "compact", 1024: "compact"}
 _WHOLE = [(rows, None, k, e) for e, k in ((8, 2), (60, 4), (128, 8), (256, 8))
           for rows in (1, 16, 128, 1024, 8192)]
 
@@ -239,28 +251,35 @@ _WHOLE = [(rows, None, k, e) for e, k in ((8, 2), (60, 4), (128, 8), (256, 8))
     [(*c, _CELL_WANT[c[0]]) for c in _CELL]
     + [(1, 16, 8, 256, "sorted"), (16, 16, 8, 256, "sorted")]
     + [(*c, "sorted") for c in _WHOLE]
-    + [(128, 17, 8, 256, "sorted"), (32, 16, 8, 256, "dense"), (31, 16, 8, 256, "sorted")]
+    + [(*c, _SOLAR_WANT[c[0]]) for c in _SOLAR]
+    # a share built for a chip needs no held count under twice its top k (rule (b) is for layers built
+    # for no chip): 17 of 256 held take dense and compact as 16 do
+    + [(128, 17, 8, 256, "dense"), (32, 16, 8, 256, "dense"), (31, 16, 8, 256, "sorted")]
     # rule (c): a share whose slots (no fewer than the ridge's 256 on a v5e) are at most half its
     # row-experts: 16 of 16 held has nothing to compact, 511 rows are short of twice the ridge
-    + [(1024, 17, 8, 256, "sorted"), (1024, 16, 8, 16, "dense"), (1024, 4, 2, 16, "compact"),
+    + [(1024, 17, 8, 256, "compact"), (1024, 16, 8, 16, "dense"), (1024, 4, 2, 16, "compact"),
        (1024, 4, 2, 8, "compact"), (1024, 4, 2, 7, "dense"), (511, 16, 8, 256, "dense"),
        (8192, 16, 8, 256, "compact")],
     ids=lambda v: str(v),
 )
 def test_expert_form_table(rows, held, top_k, experts, want):
     """The rule in rows, held experts, top k and router width alone: of the
-    routed cells' programs (a share of 16 of 256, top 8) token generation at
-    128 rows and CTE[256] are dense and the prefill buckets from 512 rows up
-    compact; the same share at 1 and 16 rows, and a whole published layer at
-    every row count, are sorted."""
+    routed cells' programs (a share of 16 of 256, top 8, and Solar-Open2's 40
+    of 320, top 8) token generation at 128 rows and CTE[256] are dense and the
+    prefill buckets from 512 rows up compact; a share below one row an expert
+    (1 and 16 rows of 16 of 256, 39 rows of 40 of 320), and a whole published
+    layer at every row count, are sorted."""
     moe = MoEArch(num_experts=experts, top_k=top_k, intermediate_size=8, held_experts=held,
                   ridge_rows=V5E_RIDGE)
     assert expert_form(moe, rows) == want
     for form in ("dense", "sorted", "compact"):  # a pin is a pin
         assert expert_form(dataclasses.replace(moe, dispatch=form), rows) == form
-    # built for no chip (a bare MoEArch), nothing says where compute-bound begins: never compact
+    # built for no chip (a bare MoEArch), nothing says where compute-bound begins: never compact, and
+    # dense only where the held experts are at most twice the top k (rule (b)): Solar's 40 of 320 stays
+    # sorted at every row count
     bare = expert_form(dataclasses.replace(moe, ridge_rows=None), rows)
-    assert bare == ("dense" if want == "compact" else want)
+    here = experts if held is None else held
+    assert bare == ("sorted" if want == "sorted" or here > 2 * top_k else "dense")
 
 
 @pytest.mark.parametrize("chip, crossing", [("v4", 448), ("v5e", 512), ("v5p", 352), ("v6e", 1120)])
