@@ -981,9 +981,13 @@ def decoder_layer(
                 ff = mlp_block(arch, lp["mlp"], h_mlp, adapter_ids, mlp_stacked, stacked_layer_idx, policy=policy)
             hidden = hidden + (attn_out + ff) * arch.residual_multiplier
         elif arch.post_block_norm:
-            # olmo2: x + norm(attn(x)); x + norm(mlp(x))
+            # olmo2: x + norm(attn(x)); x + norm(mlp(x)). K-EXAONE's expert layers
+            # alike: on a share the norm takes the held experts' partial sum
             hidden = hidden + _norm(arch, attn_out, lp["input_layernorm"]) * arch.residual_multiplier
-            ff = mlp_block(arch, lp["mlp"], hidden, adapter_ids, mlp_stacked, stacked_layer_idx, policy=policy)
+            if arch.moe is not None and "moe" in lp:
+                ff = routed(hidden)
+            else:
+                ff = mlp_block(arch, lp["mlp"], hidden, adapter_ids, mlp_stacked, stacked_layer_idx, policy=policy)
             hidden = hidden + _norm(arch, ff, lp["post_attention_layernorm"]) * arch.residual_multiplier
         elif arch.sandwich_norm:
             # gemma lineage: post-norms applied to the block OUTPUT before the

@@ -25,13 +25,9 @@ in the config): the router keeps its published width, this chip's tree holds
 ``n_routed_experts`` experts from ``first_routed_expert`` on (ops/moe.py
 ``MoEArch.held_experts``, as models/deepseek reads the same keys).
 
-Under the block KV layout the two kinds of layer keep two kinds of cache: the
-full layers' rows in the block pool (``k``/``v``: by block table, keys
-zero-padded to whole lane tiles, one tile a pool row), the window layers' in ``k_swa``/``v_swa``, ring
-rows a SLOT (``sliding_window`` rounded up to a lane tile), which no block
-table addresses: a window layer never needs more, so it needs no allocator
-and no release rule. ``causal_lm_forward`` walks the segments with each kind's
-cache WHOLE (``run_decoder_layers(first_layer=)``).
+The walk over the two kinds of layer, their two kinds of cache (the full
+layers' block pool, the window layers' ring rows a slot) and the application
+are ``models/window_full.py``'s, shared with K-EXAONE (``models/exaone_moe``).
 
 HF weight layout: llama-style attention (+ ``self_attn.attention_sink_bias``);
 router ``mlp.gate`` (+ ``e_score_correction_bias``); experts
@@ -40,19 +36,16 @@ router ``mlp.gate`` (+ ``e_score_correction_bias``); experts
 
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List
 
-import jax
 import numpy as np
 
 from nxdi_tpu.analysis.costs import resolve_chip
 from nxdi_tpu.config import InferenceConfig
-from nxdi_tpu.models import dense
+from nxdi_tpu.models import dense, window_full
 from nxdi_tpu.models.base import DecoderArch
-from nxdi_tpu.ops.moe import (
-    MoEArch, convert_hf_experts, moe_parallel_fields, moe_shape_struct,
-)
+from nxdi_tpu.models.window_full import FULL, WINDOW, WindowFullArch
+from nxdi_tpu.ops.moe import MoEArch, convert_hf_experts, moe_parallel_fields
 from nxdi_tpu.ops.rope import inv_freq_from_hf_config
 from nxdi_tpu.parallel import gqa
 
@@ -91,51 +84,6 @@ def _rope_dim(head_dim: int, factor: float) -> int:
     return rd - (rd % 2)
 
 
-@dataclass(frozen=True)
-class MiMoV2Arch:
-    """Two per-type decoder arches + the depth-ordered segment walk.
-
-    Each schedule entry: (attn_type, type_lo, type_hi, seg_idx) — half-open
-    type-local layer range into that type's stacked params/cache, and the
-    index of the stacked params segment in ``params["segments"]``."""
-
-    full: DecoderArch
-    swa: DecoderArch
-    schedule: Tuple[Tuple[str, int, int, int], ...]
-    swa_theta: float
-
-    #: under the block layout the window layers keep ring rows a SLOT beside
-    #: the pool, in these cache leaves: their programs' batches carry the
-    #: rows' slot ids (runtime/model_wrapper.py ``per_slot_cache``,
-    #: serving/engine.py ``_layout_kwargs``) and every program takes the
-    #: leaves in one memory layout (``_pinned_cache_layouts``)
-    ring_cache_keys = ("k_swa", "v_swa")
-
-    # the app sizes the FULL-type cache through the usual path
-    def kv_cache_spec(self, batch_size, max_len, quant_dtype=None):
-        return self.full.kv_cache_spec(batch_size, max_len, quant_dtype=quant_dtype)
-
-    @property
-    def num_layers(self):
-        return self.full.num_layers + self.swa.num_layers
-
-    @property
-    def kv_window_pattern(self):
-        """Depth-ordered window flags (schedule order) — lets the wrapper's
-        layout selection keep the CONTIGUOUS layout primary under
-        window_sized_kv (only the swa stack rides the ring; see
-        application.py / kv_layout_from_config)."""
-        flags = []
-        for kind, lo, hi, _ in self.schedule:
-            flags.extend([kind == "swa"] * (hi - lo))
-        return tuple(flags)
-
-    def __getattr__(self, name):
-        # the runtime reads generic decoder attrs (vocab, dtype, sampler
-        # wiring) — proxy them to the full-attention arch
-        return getattr(object.__getattribute__(self, "full"), name)
-
-
 def _moe_arch(config: InferenceConfig) -> MoEArch:
     # a share: ``n_routed_experts`` counts the experts held here, the router
     # keeps the published width (models/deepseek reads the same three keys)
@@ -165,21 +113,21 @@ def _moe_arch(config: InferenceConfig) -> MoEArch:
 
 
 def _layer_types(config) -> List[str]:
-    return ["swa" if p == 1 else "full" for p in config.hybrid_layer_pattern]
+    return [WINDOW if p == 1 else FULL for p in config.hybrid_layer_pattern]
 
 
 def _layer_moe(config) -> List[bool]:
     return [bool(f) for f in config.moe_layer_freq]
 
 
-def build_arch(config: InferenceConfig, **overrides) -> MiMoV2Arch:
+def build_arch(config: InferenceConfig, **overrides) -> WindowFullArch:
     tp = config.tpu_config.tp_degree
     prf = float(config.partial_rotary_factor)
     types = _layer_types(config)
     moe = _moe_arch(config)
 
     def type_arch(kind: str) -> DecoderArch:
-        if kind == "swa":
+        if kind == WINDOW:
             heads, kv = config.swa_num_attention_heads, config.swa_num_key_value_heads
             hd, vd = config.swa_head_dim, config.swa_v_head_dim
             window = config.sliding_window
@@ -209,37 +157,16 @@ def build_arch(config: InferenceConfig, **overrides) -> MiMoV2Arch:
         )
 
     # depth walk, splitting segments on (type, ff-kind) boundaries
-    uses_moe = _layer_moe(config)
-    schedule = []
-    counters = {"full": 0, "swa": 0}
-    seg_idx = -1
-    prev = None
-    for i, kind in enumerate(types):
-        key = (kind, uses_moe[i])
-        lo = counters[kind]
-        if key == prev:
-            t, a, b, s = schedule[-1]
-            schedule[-1] = (t, a, b + 1, s)
-        else:
-            seg_idx += 1
-            schedule.append((kind, lo, lo + 1, seg_idx))
-            prev = key
-        counters[kind] += 1
-    return MiMoV2Arch(
-        full=type_arch("full"),
-        swa=type_arch("swa"),
-        schedule=tuple(schedule),
-        swa_theta=float(getattr(config, "swa_rope_theta", 10000.0)),
-    )
+    return window_full.build(type_arch(FULL), type_arch(WINDOW), types, _layer_moe(config))
 
 
 def build_inv_freq(config: InferenceConfig) -> Dict[str, np.ndarray]:
     prf = float(config.partial_rotary_factor)
     return {
-        "full": inv_freq_from_hf_config(
+        FULL: inv_freq_from_hf_config(
             _rope_dim(config.head_dim, prf), config.rope_theta, None
         ),
-        "swa": inv_freq_from_hf_config(
+        WINDOW: inv_freq_from_hf_config(
             _rope_dim(config.swa_head_dim, prf),
             getattr(config, "swa_rope_theta", 10000.0),
             None,
@@ -248,320 +175,14 @@ def build_inv_freq(config: InferenceConfig) -> Dict[str, np.ndarray]:
 
 
 # ---------------------------------------------------------------------------
-# Forward — segment walk over two attention types
-# ---------------------------------------------------------------------------
-
-
-def causal_lm_forward(
-    arch: MiMoV2Arch,
-    inv_freq,
-    params: Dict[str, Any],
-    cache: Dict[str, jax.Array],
-    batch: Dict[str, jax.Array],
-    *,
-    attend_to_cache: bool,
-    kv_window=None,
-    policy=None,
-    layout=None,
-    gather_last_token: bool = True,
-    output_logits: bool = False,
-    output_all_logits: bool = False,
-    on_device_sampling: bool = True,
-    do_sample: bool = False,
-    global_topk: int = 256,
-    deterministic: bool = False,
-    **_unused,
-):
-    import jax.numpy as jnp
-
-    from nxdi_tpu.config import to_jax_dtype
-    from nxdi_tpu.kvcache.kv_cache import DEFAULT_KV_LAYOUT, BlockKVLayout
-    from nxdi_tpu.models.base import constrain
-    from nxdi_tpu.ops import sampling as sampling_ops
-    from nxdi_tpu.ops.norms import rms_norm
-    from nxdi_tpu.ops.rope import rope_cos_sin
-    from nxdi_tpu.parallel.policy import DEFAULT_POLICY
-
-    policy = policy or DEFAULT_POLICY
-    layout = layout or DEFAULT_KV_LAYOUT
-    t = arch.full
-    compute_dtype = to_jax_dtype(t.dtype)
-    input_ids = batch["input_ids"]
-    position_ids = batch["position_ids"]
-    B = input_ids.shape[0]
-
-    with jax.named_scope("embed"):
-        hidden = jnp.take(params["embed_tokens"], input_ids, axis=0).astype(compute_dtype)
-        hidden = constrain(hidden, policy.hidden)
-    cos_full, sin_full = rope_cos_sin(position_ids, np.asarray(inv_freq["full"]))
-    cos_swa, sin_swa = rope_cos_sin(position_ids, np.asarray(inv_freq["swa"]))
-
-    from nxdi_tpu.models.base import collect_cache_inputs, counts_held_pairs, held_pair_outputs
-
-    # full layout-input pass-through: seq_ids (continuous batching; under the
-    # block layout the rows' SLOT ids, which address the window stack's store),
-    # write_positions (spec verify windows), attn_mask, last_token_index
-    # (the ring write's keep-mask under right padding — WindowKVLayout.update)
-    cache_inputs = collect_cache_inputs(batch) or None
-    rope = {"full": (cos_full, sin_full), "swa": (cos_swa, sin_swa)}
-    held_tally = None
-    if isinstance(layout, BlockKVLayout):
-        if counts_held_pairs(t.moe, input_ids.shape, attend_to_cache, layout):
-            held_tally = []  # as models/base.py causal_lm_forward counts a share
-        hidden, new_cache = _walk_paged(
-            arch, params, hidden, rope, cache, position_ids, attend_to_cache,
-            policy, layout, cache_inputs, held_tally,
-        )
-    else:
-        hidden, new_cache = _walk_contiguous(
-            arch, params, hidden, rope, cache, position_ids, attend_to_cache,
-            kv_window, policy, layout, cache_inputs,
-        )
-
-    with jax.named_scope("final_norm"):
-        hidden = rms_norm(hidden, params["norm"], t.rms_norm_eps)
-    with jax.named_scope("lm_head"):
-        lm_head = params.get("lm_head")
-        if lm_head is None:
-            lm_head = jnp.swapaxes(params["embed_tokens"], 0, 1)
-        if gather_last_token and not output_all_logits:
-            idx = batch["last_token_index"][:, None, None]
-            hidden = jnp.take_along_axis(
-                hidden, jnp.broadcast_to(idx, (B, 1, hidden.shape[2])), axis=1
-            )
-        logits = (hidden @ lm_head.astype(hidden.dtype)).astype(jnp.float32)
-        logits = constrain(logits, policy.logits)
-        logits = sampling_ops.mask_padded_logits(logits, t.vocab_pad)
-
-    if output_all_logits and gather_last_token:
-        # ungathered hidden: the sampler still needs the TRUE last position,
-        # not the bucket-padded tail (base.py:1464-1469)
-        idx = batch["last_token_index"][:, None, None]
-        last_logits = jnp.take_along_axis(
-            logits, jnp.broadcast_to(idx, (B, 1, logits.shape[2])), axis=1
-        )
-    else:
-        last_logits = logits
-
-    outputs: Dict[str, jax.Array] = {}
-    if held_tally:
-        # two scalars beside the tokens (serving/engine.py _collect_decode,
-        # _prefill_chunk): one pair a segment, summed over the walk
-        outputs.update(held_pair_outputs(held_tally, prefill=input_ids.shape[1] > 1))
-    if on_device_sampling:
-        with jax.named_scope("sample"):
-            outputs["tokens"] = sampling_ops.sample(
-                last_logits[:, -1, :],
-                batch["sampling_params"],
-                rng=batch.get("rng"),
-                do_sample=do_sample,
-                global_topk=global_topk,
-                deterministic=deterministic,
-            )[:, None]
-    if output_logits or output_all_logits or not on_device_sampling:
-        outputs["logits"] = logits[..., : t.vocab_size - t.vocab_pad]
-    return outputs, new_cache
-
-
-def _walk_contiguous(
-    arch, params, hidden, rope, cache, position_ids, attend_to_cache,
-    kv_window, policy, layout, cache_inputs,
-):
-    """The contiguous layout's walk: each segment scans its slice of its
-    kind's (L, B, KV, S, D) stack, and the slices are stacked back."""
-    import jax.numpy as jnp
-
-    from nxdi_tpu.models.base import run_decoder_layers
-
-    caches = {
-        "full": (cache["k"], cache["v"]),
-        "swa": (cache["k_swa"], cache["v_swa"]),
-    }
-    # window-sized swa stack: when the swa cache holds fewer slots than the
-    # full stack it is a W-slot ring — swa segments then read/write through
-    # the ring layout (reference: per-layer window-sized caches,
-    # kv_cache_manager.py:195-210); the full stack keeps the primary layout
-    layouts = {"full": layout, "swa": layout}
-    if cache["k_swa"].shape[3] < cache["k"].shape[3]:
-        from nxdi_tpu.kvcache.kv_cache import WindowKVLayout
-
-        layouts["swa"] = WindowKVLayout(
-            window=cache["k_swa"].shape[3],
-            route_by_seq_id=getattr(layout, "route_by_seq_id", False),
-        )
-    seg_new = {"full": {}, "swa": {}}  # type -> {lo: (k, v)}
-    for kind, lo, hi, seg_idx in arch.schedule:
-        ta = arch.full if kind == "full" else arch.swa
-        ck, cv = caches[kind]
-        k_sl = jax.lax.slice_in_dim(ck, lo, hi, axis=0)
-        v_sl = jax.lax.slice_in_dim(cv, lo, hi, axis=0)
-        spec = ta.kv_cache_spec(ck.shape[1], ck.shape[3])
-        with jax.named_scope(_SCOPE[kind]):
-            hidden, seg_cache = run_decoder_layers(
-                ta, params["segments"][seg_idx], hidden, *rope[kind],
-                {"k": k_sl, "v": v_sl}, position_ids, spec, attend_to_cache,
-                kv_window=kv_window, policy=policy, layout=layouts[kind],
-                cache_inputs=cache_inputs,
-            )
-        seg_new[kind][lo] = seg_cache
-
-    def rebuild(kind):
-        parts = [seg_new[kind][lo] for lo in sorted(seg_new[kind])]
-        if not parts:
-            return caches[kind]
-        cat = lambda xs: xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=0)  # noqa: E731
-        return cat([p["k"] for p in parts]), cat([p["v"] for p in parts])
-
-    new_cache = {}
-    new_cache["k"], new_cache["v"] = rebuild("full")
-    new_cache["k_swa"], new_cache["v_swa"] = rebuild("swa")
-    return hidden, new_cache
-
-
-def _walk_paged(
-    arch, params, hidden, rope, cache, position_ids, attend_to_cache,
-    policy, layout, cache_inputs, held_tally,
-):
-    """The block layout's walk. Each kind's cache goes WHOLE into every
-    segment's ``run_decoder_layers`` with the segment's first type-local layer
-    (``first_layer``): no slice and no stacking of either.
-
-    The full layers' pool is the layer scan's carry, written at (layer, slot)
-    and read through the block table. The window layers' store holds ``R``
-    ring rows a slot, position ``p`` in row ``p % R`` of the request's SLOT
-    (``seq_ids``): a decode step attends the store in place plus its fresh
-    row, and the five layers' fresh rows are committed by ONE in-place call
-    after the walk (``WindowKVLayout.commit_rows``: the commit kernel); a
-    prefill attends its fresh rows alone and its last ``min(S, R)`` rows go
-    into the slot's rows by one ``dynamic_update_slice`` a batch row."""
-    import jax.numpy as jnp
-
-    from nxdi_tpu.kvcache.kv_cache import (
-        DEFAULT_KV_LAYOUT, BlockKVCacheSpec, WindowKVLayout,
-    )
-    from nxdi_tpu.models.base import run_decoder_layers
-
-    if cache_inputs is None or "seq_ids" not in cache_inputs:
-        raise ValueError(
-            "the window layers' store is addressed by the rows' slot ids: the "
-            "batch needs seq_ids beside block_table / slot_mapping"
-        )
-    full, swa = arch.full, arch.swa
-    pool = {"k": cache["k"], "v": cache["v"]}
-    store = {"k": cache["k_swa"], "v": cache["v_swa"]}
-    pool_spec = BlockKVCacheSpec(
-        num_layers=full.num_layers,
-        num_blocks=pool["k"].shape[1] // layout.block_size,
-        block_size=layout.block_size,
-        num_kv_heads=pool["k"].shape[2],
-        head_dim=pool["k"].shape[3],
-        dtype=full.dtype,
-        v_head_dim=pool["v"].shape[3],
-        key_tiles=layout.key_tiles(pool["k"], pool["v"]),
-    )
-    slots, R = store["k"].shape[1], store["k"].shape[3]
-    ring = WindowKVLayout(window=R, route_by_seq_id=True)
-    ring_spec = swa.kv_cache_spec(slots, R)
-    B, S = position_ids.shape
-    rows = {}  # first type-local layer -> the segment's fresh (n, B, KV, S, D) rows
-    for kind, lo, hi, seg_idx in arch.schedule:
-        seg = params["segments"][seg_idx]
-        with jax.named_scope(_SCOPE[kind]):
-            if kind == "full":
-                hidden, pool = run_decoder_layers(
-                    full, seg, hidden, *rope[kind], pool, position_ids, pool_spec,
-                    attend_to_cache, policy=policy, layout=layout,
-                    cache_inputs=cache_inputs, moe_held_tally=held_tally, first_layer=lo,
-                )
-                # a one-layer segment is no loop: without this the compiler is
-                # free to recompute the segment's write on the pool it was
-                # handed instead of keeping the written one, and then holds
-                # two pools (3.4 GiB of ``temp`` at the served sizes)
-                hidden, pool = jax.lax.optimization_barrier((hidden, pool))
-            elif attend_to_cache:
-                hidden, out = run_decoder_layers(
-                    swa, seg, hidden, *rope[kind], store, position_ids, ring_spec,
-                    True, policy=policy, layout=ring, cache_inputs=cache_inputs,
-                    moe_held_tally=held_tally, first_layer=lo,
-                )
-                rows[lo] = (out["k_rows"], out["v_rows"])
-            else:
-                # a prefill reads no cache: it runs over a scratch of its own
-                # rows (the contiguous layout's write at the origin IS the
-                # fresh rows), and the store gets their tail below
-                spec = swa.kv_cache_spec(B, S)
-                scratch = {
-                    "k": jnp.zeros((hi - lo,) + spec.shape[1:], spec.store_dtype),
-                    "v": jnp.zeros((hi - lo,) + spec.shape_v[1:], spec.store_dtype),
-                }
-                hidden, out = run_decoder_layers(
-                    swa, seg, hidden, *rope[kind], scratch, position_ids, spec,
-                    False, policy=policy, layout=DEFAULT_KV_LAYOUT,
-                    # the rows behind a prompt's last token: the expert layer's
-                    # compact form leaves them out (ops/moe.py)
-                    cache_inputs={k: v for k, v in cache_inputs.items() if k == "last_token_index"},
-                    moe_held_tally=held_tally,
-                )
-                rows[lo] = (out["k"], out["v"])
-    if rows:
-        k_rows = jnp.concatenate([rows[lo][0] for lo in sorted(rows)], axis=0)
-        v_rows = jnp.concatenate([rows[lo][1] for lo in sorted(rows)], axis=0)
-        ci = dict(cache_inputs, position_ids=position_ids)
-        with jax.named_scope("kv.write"):
-            if attend_to_cache:
-                store = ring.commit_rows(store, k_rows, v_rows, ci, ring_spec, policy=policy)
-            else:
-                store = _commit_prompt_tail(store, k_rows, v_rows, ci)
-    return hidden, {
-        "k": pool["k"], "v": pool["v"], "k_swa": store["k"], "v_swa": store["v"],
-    }
-
-
-def _commit_prompt_tail(store, k_rows, v_rows, ci):
-    """A fresh prefill's rows (L, B, KV, S, D) into the slots' ring rows: row
-    ``s`` of a slot gets the LAST position ``p <= last`` with ``p % R == s``
-    (``last``: the row's last real token; bucket padding never lands), one
-    in-place ``dynamic_update_slice`` of (L, 1, KV, R, D) a batch row. Rows no
-    position has reached yet keep whatever they held: the ring's read places
-    them at negative positions and hides them (``WindowKVLayout.read``)."""
-    import jax.numpy as jnp
-
-    R = store["k"].shape[3]
-    pos = ci["position_ids"].astype(jnp.int32)  # (B, S), an arange from its first
-    lti = ci.get("last_token_index")
-    last = pos[:, -1] if lti is None else jnp.take_along_axis(
-        pos, lti[:, None].astype(jnp.int32), axis=1)[:, 0]
-    s = jnp.arange(R, dtype=jnp.int32)[None, :]
-    src = last[:, None] - ((last[:, None] - s) % R) - pos[:, :1]  # (B, R) index into S
-    src = jnp.clip(src, 0, pos.shape[1] - 1)
-    slot_ids = ci["seq_ids"].astype(jnp.int32)
-    out = {}
-    for name, fresh in (("k", k_rows), ("v", v_rows)):
-        arr = store[name]
-        tail = jnp.take_along_axis(
-            fresh, src[None, :, None, :, None], axis=3
-        ).astype(arr.dtype)  # (L, B, KV, R, D)
-        for b in range(tail.shape[1]):
-            arr = jax.lax.dynamic_update_slice(
-                arr, tail[:, b : b + 1], (0, slot_ids[b], 0, 0, 0)
-            )
-        out[name] = arr
-    return out
-
-
-#: ``jax.named_scope`` of the two kinds of segment
-_SCOPE = {"full": "layers.full", "swa": "layers.window"}
-
-
-# ---------------------------------------------------------------------------
 # Conversion / specs / structs
 # ---------------------------------------------------------------------------
 
 
-def _convert_layer(state_dict, config, arch: MiMoV2Arch, i: int, kind: str, use_moe: bool):
-    ta = arch.full if kind == "full" else arch.swa
+def _convert_layer(state_dict, config, arch: WindowFullArch, i: int, kind: str, use_moe: bool):
+    ta = arch.of(kind)
     tp = config.tpu_config.tp_degree
-    if kind == "swa":
+    if kind == WINDOW:
         plan = gqa.plan_gqa_sharding(
             tp, config.swa_num_attention_heads, config.swa_num_key_value_heads
         )
@@ -622,171 +243,18 @@ def convert_hf_state_dict(
     state_dict: Dict[str, np.ndarray], config: InferenceConfig
 ) -> Dict[str, Any]:
     arch = build_arch(config)
-    types = _layer_types(config)
-    uses_moe = _layer_moe(config)
-    dt = dense.np_dtype(arch.full.dtype)
-
-    # group depth-contiguous layers into the schedule's segments
-    segments: List[Any] = []
-    bucket: List[Any] = []
-    prev = None
-    for i, kind in enumerate(types):
-        key = (kind, uses_moe[i])
-        if prev is not None and key != prev:
-            segments.append(dense.tree_stack(bucket))
-            bucket = []
-        bucket.append(_convert_layer(state_dict, config, arch, i, kind, uses_moe[i]))
-        prev = key
-    segments.append(dense.tree_stack(bucket))
-    assert len(segments) == len({s for (_, _, _, s) in arch.schedule})
-
-    def top(name):
-        for k in (f"model.{name}", name):
-            if k in state_dict:
-                return state_dict[k]
-        raise KeyError(name)
-
-    embed = np.asarray(top("embed_tokens.weight"))
-    if arch.full.vocab_pad:
-        embed = np.concatenate(
-            [embed, np.zeros((arch.full.vocab_pad, embed.shape[1]), embed.dtype)]
-        )
-    params: Dict[str, Any] = {
-        "embed_tokens": np.asarray(embed, dt),
-        "segments": segments,
-        "norm": np.asarray(top("norm.weight"), dt),
-    }
-    if not arch.full.tie_word_embeddings:
-        head = np.asarray(state_dict["lm_head.weight"])
-        if arch.full.vocab_pad:
-            head = np.concatenate(
-                [head, np.zeros((arch.full.vocab_pad, head.shape[1]), head.dtype)]
-            )
-        params["lm_head"] = np.asarray(head.T, dt)
-    return params
-
-
-def _map_segments(config, per_layer_fn, top_fn):
-    """Build the segments-list structure by mapping a per-layer constructor."""
-    arch = build_arch(config)
-    types = _layer_types(config)
-    uses_moe = _layer_moe(config)
-    segs, bucket, prev = [], [], None
-    for i, kind in enumerate(types):
-        key = (kind, uses_moe[i])
-        if prev is not None and key != prev:
-            segs.append(bucket)
-            bucket = []
-        bucket.append(per_layer_fn(arch, kind, uses_moe[i]))
-        prev = key
-    segs.append(bucket)
-    return top_fn(arch, segs)
+    return window_full.convert_segments(
+        state_dict, arch,
+        lambda i, kind, routed: _convert_layer(state_dict, config, arch, i, kind, routed),
+    )
 
 
 def param_specs(config: InferenceConfig):
-    from jax.sharding import PartitionSpec as P
-
-    from nxdi_tpu.models.base import attention_param_specs, mlp_param_specs
-    from nxdi_tpu.ops.moe import expert_parallel_specs
-    from nxdi_tpu.parallel.layers import REPLICATED, VOCAB_PARALLEL
-
-    def per_layer(arch, kind, use_moe):
-        ta = arch.full if kind == "full" else arch.swa
-        layer = {
-            "input_layernorm": REPLICATED,
-            "post_attention_layernorm": REPLICATED,
-            "attn": attention_param_specs(ta),
-        }
-        if ta.attention_sink:
-            layer["attn"]["sink"] = REPLICATED
-        if use_moe:
-            layer["moe"] = expert_parallel_specs(ta.moe)
-        else:
-            layer["mlp"] = mlp_param_specs(ta)
-        return layer
-
-    def top(arch, segs):
-        def stack(tree):
-            return jax.tree_util.tree_map(
-                lambda sp: P(*((None,) + tuple(sp))),
-                tree,
-                is_leaf=lambda x: isinstance(x, P),
-            )
-
-        specs = {
-            "embed_tokens": VOCAB_PARALLEL,
-            "segments": [stack(s[0]) for s in segs],
-            "norm": REPLICATED,
-        }
-        if not arch.full.tie_word_embeddings:
-            from nxdi_tpu.parallel.layers import COLUMN_PARALLEL
-
-            specs["lm_head"] = COLUMN_PARALLEL
-        return specs
-
-    return _map_segments(config, per_layer, top)
+    return window_full.param_specs(build_arch(config))
 
 
 def param_shape_struct(config: InferenceConfig):
-    arch = build_arch(config)
-    types = _layer_types(config)
-    uses_moe = _layer_moe(config)
-    dt = dense.np_dtype(arch.full.dtype)
-    H = arch.full.hidden_size
-
-    def s(*shape):
-        return jax.ShapeDtypeStruct(shape, dt)
-
-    def layer_struct(kind, use_moe, n):
-        ta = arch.full if kind == "full" else arch.swa
-        D, Dv = ta.head_dim, ta.v_head_dim or ta.head_dim
-        NH, NKV = ta.num_attention_heads, ta.num_kv_heads
-        layer = {
-            "input_layernorm": s(n, H),
-            "post_attention_layernorm": s(n, H),
-            "attn": {
-                "q_proj": {"w": s(n, H, NH * D)},
-                "k_proj": {"w": s(n, H, NKV * D)},
-                "v_proj": {"w": s(n, H, NKV * Dv)},
-                "o_proj": {"w": s(n, NH * Dv, H)},
-            },
-        }
-        if ta.attention_sink:
-            layer["attn"]["sink"] = s(n, NH)
-        if use_moe:
-            # the router scores all experts, the tree holds this chip's
-            layer["moe"] = moe_shape_struct(ta.moe, H, n, dt)
-        else:
-            I = config.intermediate_size
-            layer["mlp"] = {
-                "gate_proj": {"w": s(n, H, I)},
-                "up_proj": {"w": s(n, H, I)},
-                "down_proj": {"w": s(n, I, H)},
-            }
-        return layer
-
-    segs, run, prev = [], 0, None
-    order = []
-    for i, kind in enumerate(types):
-        key = (kind, uses_moe[i])
-        if prev is not None and key != prev:
-            order.append((prev, run))
-            run = 0
-        run += 1
-        prev = key
-    order.append((prev, run))
-    for (kind, use_moe), n in order:
-        segs.append(layer_struct(kind, use_moe, n))
-
-    V = arch.full.vocab_size
-    struct = {
-        "embed_tokens": s(V, H),
-        "segments": segs,
-        "norm": s(H),
-    }
-    if not arch.full.tie_word_embeddings:
-        struct["lm_head"] = s(H, V)
-    return struct
+    return window_full.param_shape_struct(build_arch(config))
 
 
 class MiMoV2ForCausalLM:

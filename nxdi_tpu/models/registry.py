@@ -111,6 +111,10 @@ _REGISTRY: Dict[str, Tuple[str, str]] = {
         "nxdi_tpu.models.exaone4.modeling_exaone4",
         "Exaone4InferenceConfig",
     ),
+    "exaone_moe": (
+        "nxdi_tpu.models.exaone_moe.modeling_exaone_moe",
+        "ExaoneMoeInferenceConfig",
+    ),
     "olmo3": (
         "nxdi_tpu.models.olmo3.modeling_olmo3",
         "Olmo3InferenceConfig",

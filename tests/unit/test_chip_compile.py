@@ -1074,3 +1074,48 @@ def test_mla_paged_decode_body_is_the_parents(one_chip, mosaic):
     bodies = _mosaic_bodies(jax.jit(fn).lower(*args).as_text())
     assert list(bodies) == ["mla_paged_decode"]
     assert hashlib.sha256(bodies["mla_paged_decode"].encode()).hexdigest() == MLA_BODY_BEFORE_PR38
+
+
+# -- a post-normed window/full share: K-EXAONE-236B-A23B's widths as the cell
+# `k-exaone-ep8.longctx-saturated` serves them: layers 0-4 (4 window with RoPE, 1 full NoPE),
+# 16 of 128 experts held + a shared one, 128 rows, 6912 blocks of 128: the full layer's pool
+# (1, 884736, 8, 128) twice and the window layers' 128 ring rows a slot (4, 128, 8, 128, 128)
+def test_window_full_share_with_post_norms_compiles_its_decode_step_in_place(topo, mosaic):
+    """The token-generation program at the configuration's widths through the
+    load-time layout pass: the pool and the ring rows aliased from the donated
+    input to the output, no copy of either or of a weight, ``temp`` far under a
+    pool's size, the paged kernel for the full layer and the two-part XLA form
+    over the ring rows for the window layers, the held experts dense, and the
+    ring's commit under the window layers' scope."""
+    import json
+
+    from benchmark import serving_app
+    from nxdi_tpu.analysis.hlo import param_moves
+    from nxdi_tpu.parallel.mesh import mesh_from_config
+
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "benchmark", "configs", "k-exaone-ep8.json")
+    with open(path) as f:
+        body = json.load(f)
+    app = serving_app.build_app(body, [256], seed=0)
+    app.mesh = mesh_from_config(app.tpu_config, devices=topo.devices[:1])
+    app._build_wrappers()
+    cache = app._cache_struct()
+    assert cache["k"].shape == (1, 884736, 8, 128) and cache["k_swa"].shape == (4, 128, 8, 128, 128)
+    result, dims, programs = _laid_out(app)
+    assert result.kept == []
+    assert [param_moves(c.as_text(), dims) for c in programs.values()] == [[], []]
+    compiled = programs["token_generation_model[8320]"]
+    memory = compiled.memory_analysis()
+    pool_bytes = 2 * 884736 * 8 * 128 * 2
+    store_bytes = 2 * 4 * 128 * 8 * 128 * 128 * 2
+    assert memory.alias_size_in_bytes >= pool_bytes + store_bytes, memory
+    assert memory.temp_size_in_bytes < 400 * 2 ** 20, memory  # 325 MiB compiled
+    text = compiled.as_text()
+    assert "paged_attention_decode" in text and "kv_commit_rows" in text
+    assert _pool_movers(text, cache["k"].shape) + _pool_movers(text, cache["v"].shape) == []
+    (prog,) = app.models["token_generation_model"]._programs.values()
+    assert set(prog.attention_strategies) == {"tkg_paged_kernel", "tkg_two_part_xla"}
+    assert prog.expert_forms == ("dense",)
+    ops = set(re.findall(r'op_name="([^"]*)"', text))
+    assert any("/layers.window/kv.write/" in op for op in ops)
+    assert any("/layers.full/" in op and "/attn.core/" in op for op in ops)
